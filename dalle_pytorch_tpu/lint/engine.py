@@ -1,8 +1,7 @@
 """graftlint engine: file walking, pragma suppression, baseline, fixes.
 
 The engine is jax-free and runs in milliseconds per file — it must stay
-importable and fast on a bare CPU box (CI's lint job budget is seconds;
-the chip babysitter runs it before every queue arm).
+importable and fast on a bare CPU box (CI's lint job budget is seconds).
 """
 from __future__ import annotations
 

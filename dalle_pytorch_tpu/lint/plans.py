@@ -36,7 +36,7 @@ twin (``plans_fixtures.py``, ``tools/plan_check.py --selftest``):
   ``dcn_dp`` axis leaves slice pinning undefined), and in the traced
   step only a ``psum`` over the dp axis (the grad all-reduce) may cross
   DCN — any other collective over a DCN-crossing axis is a finding.
-  The jaxpr walk reuses graftspmd's collective taxonomy
+  The jaxpr walk reuses graftspmd's collective classes
   (``spmd.collective_trace``), so shard_map plans with explicit
   collectives are covered by the same sweep.
 
@@ -567,7 +567,7 @@ def crossing_axes(plan: ParallelPlan, topo: Topology
 def check_collective_placement(plan: ParallelPlan, topo: Topology, *,
                                preset: str = "?",
                                jaxpr=None) -> List[Finding]:
-    """P4.  Structural slice-pinning checks plus the graftspmd-taxonomy
+    """P4.  Structural slice-pinning checks plus the graftspmd-classified
     jaxpr walk: only a ``psum`` over the dp axis (the grad all-reduce)
     may cross DCN."""
     cross, problems = crossing_axes(plan, topo)

@@ -130,7 +130,7 @@ def dcn_crossing_jaxpr():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import shard_map
+    from jax import shard_map
 
     def local(x):
         return jax.lax.all_gather(x, "dp").sum(axis=0)
@@ -147,7 +147,7 @@ def dcn_clean_jaxpr():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ..parallel.mesh import shard_map
+    from jax import shard_map
 
     def local(x):
         return jax.lax.psum(x * 2.0, "dp")

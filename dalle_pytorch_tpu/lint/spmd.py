@@ -62,8 +62,11 @@ class SPMDViolation(AssertionError):
 def fresh_stats_compile():
     """Compile with the persistent XLA compilation cache fully bypassed:
     a cache-deserialized executable can report zeroed or stale
-    ``memory_analysis()`` stats (jax 0.4.37 serializes the executable,
-    not all of its analyses), which would corrupt the S4 budget.
+    ``memory_analysis()`` stats (the cache serializes the executable, not
+    all of its analyses), which would corrupt the S4 budget — and an
+    executable compiled for a described, unattached TPU is written to the
+    cache but cannot be read back without a chip
+    (tests/test_tpu_compile.py).
     Toggling ``jax_enable_compilation_cache`` alone does NOT stop
     disk-cache reads on the AOT ``lowered.compile()`` path — the cache
     directory itself must be unset for the duration.  The analyzed
@@ -84,7 +87,7 @@ def fresh_stats_compile():
 
 # --- S1: collective order -------------------------------------------------
 
-# cross-shard primitives in jax 0.4.x jaxprs: a shard blocking in any of
+# cross-shard primitives in jaxprs: a shard blocking in any of
 # these waits for every peer on the named axes.  axis_index is deliberately
 # absent (it is shard-local — no synchronization).
 COLLECTIVE_PRIMS = frozenset((

@@ -15,8 +15,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-
-from ..parallel.mesh import shard_map
+from jax import shard_map
 
 
 # --- S1: a collective dominated by data-dependent control flow ------------

@@ -3,8 +3,8 @@
 ``ui.perfetto.dev`` / ``chrome://tracing`` load the emitted document
 directly, putting spans from every thread of every host on ONE zoomable
 timeline — the step loop, the async checkpoint writer, and the serve
-driver side by side, which is exactly the view the wedged-tunnel
-post-mortems never had.
+driver side by side, which is exactly the view the hung-run post-mortems
+never had.
 
 Mapping:
 

@@ -384,8 +384,10 @@ class MultiHeadAttention(nn.Module):
         elif self.use_pallas:
             from .attention_pallas import flash_pattern_attention
 
-            # the kernels lower through Mosaic only on TPU; anywhere else
-            # (CPU tests, GPU) fall back to the interpreter
+            # always the compiled Mosaic kernel: off-TPU this fails at
+            # lowering instead of silently interpreting (tests that want
+            # the interpreter wrap the call in
+            # ``pltpu.force_tpu_interpret_mode()``)
             assert self.pallas_block_q >= 1 and self.pallas_block_k >= 1, (
                 f"invalid Pallas block sizes {self.pallas_block_q}x"
                 f"{self.pallas_block_k}")
@@ -393,8 +395,7 @@ class MultiHeadAttention(nn.Module):
                 out = flash_pattern_attention(
                     q, k, v, self.pattern,
                     key_pad_bias=self._key_pad_bias(mask, n),
-                    block_q=self.pallas_block_q, block_k=self.pallas_block_k,
-                    interpret=jax.default_backend() != "tpu")
+                    block_q=self.pallas_block_q, block_k=self.pallas_block_k)
         else:
             with prof.scope("attn-scores"):
                 scale = self.dim_head ** -0.5

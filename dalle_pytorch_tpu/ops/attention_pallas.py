@@ -214,10 +214,7 @@ def _bwd_dkv_kernel(bsum_ref, q_ref, k_ref, v_ref, mask_ref, bias_ref,
 # only inside the per-program fori_loop), so both grid axes are parallel —
 # this lets Mosaic pipeline/reorder programs freely (megacore splits on
 # v4/v5p; no-op on single-tensorcore chips).
-# CompilerParams was TPUCompilerParams before jax 0.5.x — accept either so
-# the module imports across the jax versions CI and the chip box run
-_PARALLEL_GRID = getattr(pltpu, "CompilerParams",
-                         getattr(pltpu, "TPUCompilerParams", None))(
+_PARALLEL_GRID = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"))
 
 
@@ -451,7 +448,7 @@ def flash_pattern_attention(q, k, v, pattern: AttnPattern,
         # width (the lse output [b, h, n] blocks the q axis in its last
         # dim; k blocks stream through the same lanes) — sub-128 tiles
         # fail deep inside lowering, so reject them at the API edge.
-        # Measured failure: perf_ab pallas-b64, 2026-08-02 (chip-logs).
+        # Seen on the chip: perf_ab pallas-b64, manual session 2026-08-02.
         raise ValueError(
             f"block_q/block_k must be multiples of the TPU lane width 128 "
             f"(got {block_q}/{block_k})")

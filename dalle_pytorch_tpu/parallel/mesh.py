@@ -22,27 +22,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    _shard_map_impl = jax.shard_map
-    _SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:  # jax <= 0.4.x: only the experimental entry point
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions.
-
-    The top-level ``jax.shard_map`` (and its ``check_vma`` kwarg) only
-    exists on newer jax; 0.4.x ships it as
-    ``jax.experimental.shard_map.shard_map`` with the same semantics under
-    the ``check_rep`` name.  Every shard_map in the repo goes through here
-    so the sp/pp training paths work on both."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs,
-                           **{_SHARD_MAP_CHECK_KW: check_vma})
-
 # The partition rule table lives on the declarative plan (plan.py is the
 # single source of the sharding contract); this name survives for the many
 # existing call sites that read it from here.

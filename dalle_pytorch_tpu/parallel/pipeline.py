@@ -29,9 +29,8 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from .mesh import shard_map
 
 
 def stack_stage_params(params: dict, depth: int, pp: int,

@@ -28,10 +28,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import AttnPattern, _allowed
-from .mesh import shard_map
 
 NEG_INF = -1e30
 

@@ -29,10 +29,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import AttnPattern
-from .mesh import shard_map
 from .ring import NEG_INF, _chunk_mask
 
 

@@ -20,7 +20,7 @@ resolution audit (zero dropped futures under replica loss).
 
 graftwire (§21) pushes the same seam across a process boundary:
 ``wire`` is the stdlib framed-JSON RPC transport (typed failure
-taxonomy, deadline + bounded retry + jittered backoff, ``rpc_send`` /
+classes, deadline + bounded retry + jittered backoff, ``rpc_send`` /
 ``rpc_recv`` fault sites), and ``remote`` pairs a subprocess-side
 ``ReplicaServer`` with a router-side ``RemoteReplica`` that presents
 the exact ``Replica`` surface — the router needs no remote-aware code.

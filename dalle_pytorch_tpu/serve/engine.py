@@ -84,9 +84,17 @@ class SlotArena:
 
     def __init__(self, dalle: DALLE, variables, num_slots: int, *,
                  filter_thres: float = 0.9,
-                 top_p: Optional[float] = None):
+                 top_p: Optional[float] = None,
+                 device: Optional[jax.Device] = None):
         cfg = dalle.cfg
         self.dalle = dalle
+        # ``device`` pins this arena to one chip: the params and the arena
+        # state are committed there, and every entry point then runs where
+        # its committed arguments live.  N arenas in one process (a fleet
+        # of one-chip replicas) each name their own device; None keeps
+        # jax's default device, the single-server deployment.
+        if device is not None:
+            variables = jax.device_put(variables, device)
         self.variables = variables
         self.geometry = ArenaGeometry(
             num_slots=num_slots, n_pre=cfg.text_seq_len + 1,
@@ -147,7 +155,10 @@ class SlotArena:
                    if cfg.spec_decode else {}),
             )
 
-        self.state = jax.jit(fresh_state)()
+        self.state = jax.jit(
+            fresh_state,
+            out_shardings=(jax.sharding.SingleDeviceSharding(device)
+                           if device is not None else None))()
         n_pre = self.geometry.n_pre
         k_vocab = cfg.total_tokens
 

@@ -18,7 +18,7 @@ Two halves of one seam (DESIGN.md §21):
   ``begin_drain`` / ``finish_drain`` / ``halt`` / ``server.submit`` /
   ``server.backlog()``), so the router needs NO remote-aware code.
 
-The transport failure taxonomy maps onto the router's three existing
+The transport failure classes map onto the router's three existing
 policies:
 
 ======================  =====================================  ========
@@ -685,6 +685,8 @@ def spawn_replica(name: str, *, out_dir, slots: int = 2,
     if prefix_cache:
         cmd.append("--prefix-cache")
     env = dict(os.environ)
+    # a CPU replica by design: a chip belongs to one process at a time, so
+    # a child cannot share the one its parent holds
     env.setdefault("JAX_PLATFORMS", "cpu")
     env["PYTHONPATH"] = (str(REPO_ROOT) + os.pathsep
                          + env.get("PYTHONPATH", "")).rstrip(os.pathsep)
@@ -754,7 +756,7 @@ def main(argv=None) -> int:
     """Subprocess entry: one Replica + wire server + own obs lane."""
     import argparse
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")  # see spawn_replica
     parser = argparse.ArgumentParser(
         description="graftwire remote replica (subprocess half)")
     parser.add_argument("--name", required=True)

@@ -126,9 +126,13 @@ class GenerationServer:
                  metrics_labels: Optional[Dict[str, str]] = None,
                  mem_watermark_ticks: int = 256,
                  mem_hbm_bytes: Optional[int] = None,
-                 prefix_cache: bool = False, prefix_capacity: int = 32):
+                 prefix_cache: bool = False, prefix_capacity: int = 32,
+                 device=None):
+        # device: the chip this server's params and arena live on (None =
+        # jax's default device) — see SlotArena
         self.arena = SlotArena(dalle, variables, num_slots,
-                               filter_thres=filter_thres, top_p=top_p)
+                               filter_thres=filter_thres, top_p=top_p,
+                               device=device)
         # spec_decode (a model-plan flag, default OFF): the scheduler's
         # only change is variable tokens-per-tick — tick_spec returns each
         # slot's accepted span length m and `done`/token accounting add m

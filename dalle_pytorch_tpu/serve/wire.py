@@ -17,7 +17,7 @@ responses ``{"id": seq, "ok": result}`` or ``{"id": seq, "err":
 are small int32 vectors, so JSON beats inventing a binary layout the
 next reader has to learn.
 
-**Failure is typed, and the types are the taxonomy** the router's three
+**Failure is typed, and the types are the classification** the router's three
 policies key off (see serve/remote.py for the mapping):
 
 * :class:`WireUnavailable` — connect refused / no listener: the peer
@@ -32,9 +32,8 @@ policies key off (see serve/remote.py for the mapping):
   (NEVER retried at this layer → surfaces as a health failure → drain).
 
 **Every call** gets a deadline, bounded retries, and exponential
-backoff with deterministic jitter — the constants are shared with
-``tools/chip_babysitter.sh``'s healthz probe so the fleet has ONE
-retry policy, not one per caller.
+backoff with deterministic jitter — module constants, so the fleet has
+ONE retry policy, not one per caller.
 
 **Injection** (utils/faults.py): the ``rpc_send`` / ``rpc_recv`` sites
 fire once per frame the CLIENT writes/reads — never on the server side,
@@ -64,8 +63,8 @@ from ..utils import locks
 MAGIC = b"GWR1"
 MAX_FRAME_BYTES = 64 * 1024 * 1024  # a torn length field must not OOM us
 
-# ONE retry policy for the fleet: the transport here and the babysitter's
-# healthz probe (tools/chip_babysitter.sh) use the same constants
+# ONE retry policy for the fleet: every caller of the transport reads
+# these constants
 RETRY_ATTEMPTS = 3        # total tries per call
 BACKOFF_BASE_S = 0.05     # first retry waits ~this
 BACKOFF_CAP_S = 1.0       # exponential growth stops here
@@ -74,7 +73,7 @@ JITTER_FRAC = 0.25        # +/- fraction of the backoff, decorrelates herds
 
 class WireError(RuntimeError):
     """Base of every transport-layer failure a :class:`WireClient` call
-    can raise.  Subclasses ARE the failure taxonomy; callers map them to
+    can raise.  Subclasses ARE the failure classes; callers map them to
     router policy, never parse messages."""
 
 

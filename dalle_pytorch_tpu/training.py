@@ -25,9 +25,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 
 from .obs import prof
-from .parallel.mesh import shard_map
 from .utils import guardrails
 
 

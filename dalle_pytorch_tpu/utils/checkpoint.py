@@ -136,80 +136,12 @@ def save_checkpoint_sharded(path: str | Path, obj: dict) -> None:
         ckptr.save(path, args=ocp.args.PyTreeSave(obj), force=True)
 
 
-def _checkpoint_meta_tree(ckptr, path):
-    """Checkpoint metadata tree across orbax generations: older versions
-    return it directly, newer ones wrap it as ``.item_metadata.tree``."""
-    meta = ckptr.metadata(path)
-    meta = getattr(meta, "item_metadata", meta)
-    return getattr(meta, "tree", meta)
-
-
-def _fill_skips_from_meta(item, meta, repl):
-    """Replace ``...`` skip-leaves with replicated ShapeDtypeStruct targets
-    read off the checkpoint metadata (structure-parallel walk)."""
-    if item is ...:
-        return jax.ShapeDtypeStruct(tuple(meta.shape), meta.dtype,
-                                    sharding=repl)
-    if isinstance(item, dict):
-        return {k: _fill_skips_from_meta(v, meta[k], repl)
-                for k, v in item.items()}
-    if isinstance(item, list):
-        return [_fill_skips_from_meta(v, m, repl)
-                for v, m in zip(item, meta)]
-    return item
-
-
-def _reinsert_skips(template, restored):
-    """Walk ``template`` and the restore output in parallel, putting the
-    ``...`` sentinel back at every skipped position."""
-    if template is ...:
-        return ...
-    if isinstance(template, dict):
-        return {k: _reinsert_skips(v, restored[k])
-                for k, v in template.items()}
-    if isinstance(template, list):
-        return [_reinsert_skips(v, r) for v, r in zip(template, restored)]
-    return restored
-
-
-def _rebuffer_cpu(tree):
-    """Copy restored arrays into XLA-allocated buffers on the CPU backend.
-
-    XLA:CPU (jax 0.4.37) segfaults outright when a *donating* executable —
-    specifically one deserialized from the persistent compile cache —
-    consumes buffers that orbax/tensorstore allocated rather than XLA
-    (observed: sharded-resume params fed to the cached train step).  An
-    eager ``jnp.copy`` reallocates through XLA and keeps each leaf's
-    sharding; TPU restores keep the zero-copy path."""
-    if jax.default_backend() != "cpu":
-        return tree
-    import jax.numpy as jnp
-
-    return jax.tree.map(
-        lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x, tree)
-
-
 def _restore_with_skips(ckptr, ocp, path, item):
-    """Restore ``item``, where a ``...`` leaf means "skip reading this
-    leaf".  orbax >= 0.9 understands the sentinel natively
-    (``ocp.PLACEHOLDER`` is ``...``).  Older orbax has no placeholder
-    concept, so skipped leaves are restored by value onto a replicated
-    sharding (shape/dtype from the checkpoint metadata) and then dropped —
-    same results, just without the lazy-read memory win; multi-host pods
-    (where that win matters) run new enough orbax for the native path."""
-    has_skips = any(
-        leaf is ... for leaf in
-        jax.tree.leaves(item, is_leaf=lambda l: l is ...))
-    if not has_skips or hasattr(ocp, "PLACEHOLDER"):
-        return _rebuffer_cpu(ckptr.restore(path, args=ocp.args.PyTreeRestore(
-            item=item,
-            restore_args=ocp.checkpoint_utils.construct_restore_args(item))))
-    filled = _fill_skips_from_meta(item, _checkpoint_meta_tree(ckptr, path),
-                                   _replicated_sharding())
-    out = ckptr.restore(path, args=ocp.args.PyTreeRestore(
-        item=filled,
-        restore_args=ocp.checkpoint_utils.construct_restore_args(filled)))
-    return _reinsert_skips(item, _rebuffer_cpu(out))
+    """Restore ``item``, where a ``...`` leaf (``ocp.PLACEHOLDER``) means
+    "skip reading this leaf": it comes back as ``...``."""
+    return ckptr.restore(path, args=ocp.args.PyTreeRestore(
+        item=item,
+        restore_args=ocp.checkpoint_utils.construct_restore_args(item)))
 
 
 def load_checkpoint_sharded(path: str | Path, target=None):
@@ -257,7 +189,7 @@ def load_sharded_small(path: str | Path):
     # them "by value" leaves the deserializer without one and fails
     repl = _replicated_sharding()
     with ocp.PyTreeCheckpointer() as ckptr:
-        meta = _checkpoint_meta_tree(ckptr, path)
+        meta = ckptr.metadata(path).item_metadata.tree
 
         def to_item(node):
             if isinstance(node, dict):
@@ -279,7 +211,13 @@ def load_sharded_small(path: str | Path):
             return ""  # string leaf
 
         item = to_item(meta)
-        return _restore_with_skips(ckptr, ocp, path, item)
+        restored = _restore_with_skips(ckptr, ocp, path, item)
+    # leaves restored by value come back as 0-d numpy arrays; configs
+    # rebuilt from them need plain Python scalars (``[None] * depth``,
+    # hashable shapes)
+    return jax.tree.map(
+        lambda v: v.item() if isinstance(v, np.ndarray) and v.ndim == 0
+        else v, restored, is_leaf=lambda v: v is ...)
 
 
 def migrate_head_kernels(tree, total_text: int):

@@ -43,11 +43,10 @@ from ..obs import telemetry
 
 
 class ExitCode(enum.IntEnum):
-    """The process exit-code taxonomy — THE one place these numbers live.
+    """The process exit-code table — THE one place these numbers live.
 
     Supervisors key restart decisions off these values (``tools/monitor.py``,
-    ``chip_babysitter.sh``'s ``BABYSIT_TRAIN_CMD`` loop, any external
-    scheduler), so they are a frozen contract: never renumber, only add
+    any external scheduler), so they are a frozen contract: never renumber, only add
     (``tests/test_failure.py`` pins them).
 
     Trainer processes (train_dalle.py / train_vae.py):
@@ -291,7 +290,7 @@ class Heartbeat:
         dead one (otherwise the aging heartbeat of a completed run reads as
         STALLED and an auto-restart wrapper would relaunch it forever).
         Interrupted/preempted runs close with ``done=False`` on purpose —
-        there a restart is exactly what the babysitter should do."""
+        there a restart is exactly what a supervisor should do."""
         self._stop.set()
         if self._thread is not None:
             self._thread.join(timeout=5.0)
@@ -327,7 +326,7 @@ class Heartbeat:
     @staticmethod
     def is_stalled(path, timeout: float, now: Optional[float] = None) -> bool:
         """True if the heartbeat file is older than ``timeout`` seconds (or
-        missing) — for an external babysitter scanning ``heartbeat-p*.json``
+        missing) — for an external supervisor scanning ``heartbeat-p*.json``
         to find dead/wedged hosts."""
         path = Path(path)
         if not path.exists():

@@ -368,8 +368,8 @@ def cancel_preempt_grace() -> None:
 
 def maybe_hang(step: int, cap: float = 3600.0) -> None:
     """The ``step_hang:at_step=N`` site: wedge the step loop at step N —
-    a device call that never returns (the DESIGN.md §6 tunnel-wedge class,
-    which raises no exception).  Sleeps inside the StepWatchdog's armed
+    a device call that never returns (the hung-device class, which raises
+    no exception).  Sleeps inside the StepWatchdog's armed
     window so the watchdog's stack-dump + ``ExitCode.WEDGED`` exit is what
     ends it; ``cap`` bounds the sleep so a test that forgot to arm a
     watchdog still terminates eventually."""

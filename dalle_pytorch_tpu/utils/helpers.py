@@ -19,7 +19,7 @@ def env_flag(name: str, default: bool = False) -> bool:
 
     ``bool(os.environ.get(X))`` treats ``X=0`` as ON — an operator
     disabling a flag with 0 would silently enable it (the BENCH_PALLAS /
-    GRAFT_DRYRUN_FULL footgun, ADVICE.md round 5).  All boolean env knobs
+    GRAFT_DRYRUN_FULL footgun of review round 5).  All boolean env knobs
     parse through here.
     """
     import os

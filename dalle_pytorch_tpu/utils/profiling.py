@@ -33,15 +33,16 @@ PEAK_FLOPS = (
 )
 
 
-def device_peak_flops(default: float = 197e12) -> float:
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # pragma: no cover — graftlint: disable=EXC001 (no-device probe: any backend failure means fall back to the analytic default)
-        return default
+def device_peak_flops(device_kind: Optional[str] = None) -> Optional[float]:
+    """Peak dense bf16 FLOP/s of ``device_kind`` (default: the first
+    device's), or None for a kind the table does not know — MFU is then
+    "not measured", never computed from a guessed peak."""
+    kind = (device_kind if device_kind is not None
+            else jax.devices()[0].device_kind).lower()
     for sub, peak in PEAK_FLOPS:
         if sub in kind:
             return peak
-    return default
+    return None
 
 
 def transformer_train_flops(dim: int, depth: int, seq_len: int, heads: int,
@@ -209,8 +210,10 @@ class StepTimer:
         self._dt_n = 0
         self._stall_res: list = []
         self._stall_n = 0
-        # flops_per_step covers the global batch, so peak spans all chips
-        self.peak = device_peak_flops() * max(1, jax.device_count())
+        # flops_per_step covers the global batch, so peak spans all chips;
+        # None on a device the peaks table does not know (no "mfu" then)
+        peak = device_peak_flops()
+        self.peak = peak * max(1, jax.device_count()) if peak else None
 
     def _reservoir_add(self, res: list, n: int, value: float) -> None:
         """Algorithm R: after n samples every one had cap/n odds of being
@@ -233,7 +236,7 @@ class StepTimer:
             self._reservoir_add(self._dt_res, self._dt_n, dt)
             out["step_time_s"] = self.avg_dt
             out["images_per_sec"] = batch / self.avg_dt
-            if self.flops_per_step:
+            if self.flops_per_step and self.peak:
                 out["mfu"] = self.flops_per_step / self.avg_dt / self.peak
             if stall_s is not None:
                 self.avg_stall = (stall_s if self.avg_stall is None

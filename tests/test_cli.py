@@ -446,7 +446,7 @@ def test_genrank_ranking_order_with_trained_clip(tiny_tokenizer_json,
     out = tmp_path / "rank_out"
     # --save_all: this test drives the legacy file-based path (its stub
     # seam is generate_images; the fused default's scorer equivalence is
-    # pinned in tests/test_chip_equiv.py)
+    # pinned in tests/test_generation_equiv.py)
     genrank.main(["--dalle_path", "dalle-fake.pt", "--text", "red",
                   "--num_images", "6", "--bpe_path",
                   str(tiny_tokenizer_json), "--clip_path", str(clip_path),

@@ -276,8 +276,17 @@ def test_dense_decode_control_matches_sliced():
 
 def test_tile_prefill_matches_batched_prefill(small):
     """Shared prompt prefill: prefilling ONE row and tiling the state
-    (models.dalle.tile_prefill) must equal prefilling the repeated prompt
-    at full batch — logits and every layer's caches."""
+    (models.dalle.tile_prefill) must agree with prefilling the repeated
+    prompt at full batch — logits and every layer's caches.
+
+    What is claimed is agreement to one ulp of the cache's STORAGE dtype,
+    not bitwise equality: XLA compiles the batch-1 and the batch-3 forward
+    as different programs, so their f32 k/v differ in the last bits
+    (~1e-7), and where such a pair straddles a bf16 rounding boundary the
+    stored values land on adjacent bf16 numbers.  One bf16 ulp is at most
+    2**-7 of the value (8 significand bits); anything beyond that, or
+    more than a stray handful of such elements, is a real divergence.
+    The f32 logits are held to the f32-sized 1e-5."""
     from dalle_pytorch_tpu.models.dalle import prefill_codes, tile_prefill
 
     cfg, dalle, params, text, _ = small
@@ -291,14 +300,16 @@ def test_tile_prefill_matches_batched_prefill(small):
     np.testing.assert_allclose(np.asarray(flt), np.asarray(fln),
                                rtol=1e-5, atol=1e-5)
     assert len(ct) == len(cn)
-    for (kt, vt), (kn, vn) in zip(ct, cn):
-        assert kt.shape == kn.shape and kt.dtype == kn.dtype
-        np.testing.assert_allclose(np.asarray(kt, np.float32),
-                                   np.asarray(kn, np.float32),
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(vt, np.float32),
-                                   np.asarray(vn, np.float32),
-                                   rtol=1e-5, atol=1e-5)
+    for tiled, batched in zip(ct, cn):
+        for t, n in zip(tiled, batched):
+            assert t.shape == n.shape and t.dtype == n.dtype
+            t32, n32 = np.asarray(t, np.float32), np.asarray(n, np.float32)
+            if t.dtype == jnp.bfloat16:
+                np.testing.assert_allclose(t32, n32, rtol=2.0 ** -7,
+                                           atol=1e-6)
+                assert (t32 != n32).mean() < 0.01
+            else:
+                np.testing.assert_allclose(t32, n32, rtol=1e-5, atol=1e-5)
 
     with pytest.raises(AssertionError):  # batch>1 prefills cannot be tiled
         tile_prefill(fln, cn, 2)

@@ -16,10 +16,10 @@ from dalle_pytorch_tpu.utils.failure import (ExitCode, GracefulShutdown,
                                              Heartbeat)
 
 
-def test_exit_code_taxonomy_is_frozen():
+def test_exit_codes_are_frozen():
     """The ExitCode enum is THE one place the supervisor contract lives
-    (tools/monitor.py, chip_babysitter.sh's BABYSIT_TRAIN_CMD loop, any
-    external scheduler key restart decisions off these values) — pin every
+    (tools/monitor.py and any external scheduler key restart decisions off
+    these values) — pin every
     number so a renumbering can never slip through a refactor."""
     assert int(ExitCode.CLEAN) == 0
     # a graceful preemption stop exits CLEANLY (supervisors tell "finished"

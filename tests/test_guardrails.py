@@ -20,8 +20,9 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 
-from dalle_pytorch_tpu.parallel.mesh import make_mesh, shard_map
+from dalle_pytorch_tpu.parallel.mesh import make_mesh
 from dalle_pytorch_tpu.utils import faults, guardrails
 from dalle_pytorch_tpu.utils.failure import ExitCode
 from dalle_pytorch_tpu.utils.guardrails import (HealthMonitor, RollbackAndSkip,

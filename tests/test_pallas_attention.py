@@ -3,7 +3,10 @@
 Runs the kernels in interpret mode (CPU), checking forward outputs and
 gradients for every attention variant against the plain XLA dense-with-mask
 computation that `MultiHeadAttention` uses (SURVEY.md §4: 'sparse-attention
-equivalence vs dense-with-mask').
+equivalence vs dense-with-mask').  Direct kernel calls pass
+``interpret=True``; the model never does (``use_pallas`` always asks for the
+compiled kernel), so the model-level tests steer the interpreter from here,
+with Pallas' own ``force_tpu_interpret_mode`` context.
 """
 from __future__ import annotations
 
@@ -11,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from dalle_pytorch_tpu.ops.attention import AttnPattern
 from dalle_pytorch_tpu.ops.attention_pallas import flash_pattern_attention
@@ -133,13 +137,16 @@ def test_dalle_use_pallas_matches_dense():
     params = dalle_d.init(rng, text, codes)["params"]
 
     loss_d = dalle_d.apply({"params": params}, text, codes, return_loss=True)
-    loss_p = dalle_p.apply({"params": params}, text, codes, return_loss=True)
+    with pltpu.force_tpu_interpret_mode():
+        loss_p = dalle_p.apply({"params": params}, text, codes,
+                               return_loss=True)
     np.testing.assert_allclose(float(loss_d), float(loss_p), rtol=1e-4)
 
     gd = jax.grad(lambda p: dalle_d.apply({"params": p}, text, codes,
                                           return_loss=True))(params)
-    gp = jax.grad(lambda p: dalle_p.apply({"params": p}, text, codes,
-                                          return_loss=True))(params)
+    with pltpu.force_tpu_interpret_mode():
+        gp = jax.grad(lambda p: dalle_p.apply({"params": p}, text, codes,
+                                              return_loss=True))(params)
     flat_d, flat_p = jax.tree.leaves(gd), jax.tree.leaves(gp)
     for a, b in zip(flat_d, flat_p):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
@@ -174,23 +181,37 @@ def test_block_size_config_override(monkeypatch):
 
     monkeypatch.setattr(ap, "flash_pattern_attention", spy)
 
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
     pattern = AttnPattern(variant="full", seq_len=24, text_len=8, fmap=4)
     attn = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16,
-                              use_pallas=True, pallas_block_q=64,
-                              pallas_block_k=64)
+                              use_pallas=True, pallas_block_q=256,
+                              pallas_block_k=256)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
-    params = attn.init(jax.random.PRNGKey(1), x)
-    out = attn.apply(params, x)
-    assert seen == {"block_q": 64, "block_k": 64}
-
     dense = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16)
+    params = dense.init(jax.random.PRNGKey(1), x)
+    with pltpu.force_tpu_interpret_mode():
+        out = attn.apply(params, x)
+    assert seen == {"block_q": 256, "block_k": 256}
+
     ref = dense.apply(params, x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+
+
+def test_use_pallas_off_tpu_fails_loudly():
+    """``use_pallas`` asks for the compiled Mosaic kernel whatever the
+    backend: off-TPU that is an error at lowering, never a silent drop to
+    the interpreter (which would report interpreter results — and
+    interpreter speed — under the kernel's name)."""
+    from dalle_pytorch_tpu.ops.attention import AttnPattern, MultiHeadAttention
+
+    pattern = AttnPattern(variant="full", seq_len=24, text_len=8, fmap=4)
+    attn = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16,
+                              use_pallas=True)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
+    params = MultiHeadAttention(pattern=pattern, dim=32, heads=2,
+                                dim_head=16).init(jax.random.PRNGKey(1), x)
+    with pytest.raises(ValueError, match="[Ii]nterpret"):
+        attn.apply(params, x)
 
 
 def test_vmem_budget_guard():

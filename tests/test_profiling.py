@@ -59,18 +59,35 @@ def test_flops_count_phase_sliced_head():
     assert 0.05 < 1 - sliced / dense_head < 0.15
 
 
-def test_peak_flops_positive():
-    assert device_peak_flops() > 0
+@pytest.mark.parametrize("kind,peak", [
+    ("TPU v5 lite", 197e12), ("TPU v5e", 197e12), ("TPU v5", 459e12),
+    ("TPU v6 lite", 918e12), ("TPU v4", 275e12),
+    ("cpu", None), ("TPU v9 hypothetical", None),
+])
+def test_peak_flops_known_kinds_or_none(kind, peak):
+    """A kind the table does not know has NO peak — never a default."""
+    assert device_peak_flops(kind) == peak
 
 
-def test_step_timer():
+def test_step_timer(monkeypatch):
     t = StepTimer(flops_per_step=1e12)
+    assert t.peak is None  # the suite's CPU devices are not in the table
     assert t.tick(8) == {}  # first tick only arms the timer
     time.sleep(0.01)
     out = t.tick(8)
     assert out["step_time_s"] > 0
     assert out["images_per_sec"] > 0
-    assert 0 < out["mfu"] < 1e6
+    assert "mfu" not in out  # unknown device: MFU is "not measured"
+
+    import types
+
+    from dalle_pytorch_tpu.utils import profiling
+    fake = types.SimpleNamespace(device_kind="TPU v5 lite")
+    monkeypatch.setattr(profiling.jax, "devices", lambda: [fake])
+    t = StepTimer(flops_per_step=1e12)
+    t.tick(8)
+    time.sleep(0.01)
+    assert 0 < t.tick(8)["mfu"] < 1e6
 
 
 def test_step_timer_loader_stall():

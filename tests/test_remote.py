@@ -12,7 +12,7 @@ The claims, in dependency order:
   ONE ``submits``); a router re-dispatch after an ambiguous
   :class:`ReplicaDown` dedups the same way; an acked SUCCESS pins the
   wid forever while an acked ERROR forgets it so a retry re-executes.
-* **Taxonomy → policy** — each wire failure maps onto exactly one of
+* **Failure class → policy** — each wire failure maps onto exactly one of
   the router's three policies: connect-refused → transport dead
   (policy 2: declare dead + migrate), ambiguous timeout on submit →
   typed :class:`ReplicaDown` (policy 1: retry elsewhere), torn frame →
@@ -245,7 +245,7 @@ def test_acked_success_pins_wid_acked_error_forgets_it(small, pair):
         rr.close()
 
 
-# --- taxonomy → policy ------------------------------------------------------
+# --- failure class → policy -------------------------------------------------
 
 
 def test_connect_refused_marks_transport_dead_policy2():

@@ -97,6 +97,25 @@ def test_single_request_matches_static_sampler(small):
     np.testing.assert_array_equal(h.result(0), refs[0])
 
 
+def test_device_pins_params_and_arena(small):
+    """``device=`` commits the params and the arena state to that chip and
+    every entry point runs there — N servers in one process (a fleet of
+    one-chip replicas) must not all sit on device 0.  Results and the
+    no-retrace sentinel are unchanged."""
+    _, _, _, texts, refs = small
+    dev = jax.devices()[3]
+    srv = make_server(small, num_slots=2, device=dev)
+    handles = [srv.submit(texts[i]) for i in range(3)]
+    srv.run_until_idle(max_ticks=300)
+    for h, r in zip(handles, refs):
+        np.testing.assert_array_equal(h.result(0), r)
+    on = {d for leaf in jax.tree.leaves((srv.arena.state,
+                                         srv.arena.variables))
+          for d in leaf.devices()}
+    assert on == {dev}
+    assert srv.trace_counts() == {"prefill": 1, "admit": 1, "tick": 1}
+
+
 def test_scale_signals_surface_and_spec_toggle(small):
     """graftscale's per-server observation: one cheap dict with the
     demand side (queues, running), the capacity side (headroom + the

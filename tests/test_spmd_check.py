@@ -71,7 +71,7 @@ def test_s1_collective_in_while_body_caught():
 
     from jax.sharding import PartitionSpec as P
 
-    from dalle_pytorch_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     fn = shard_map(local, mesh=mesh, in_specs=(P("dp"),),
                    out_specs=P("dp"), check_vma=False)
@@ -94,7 +94,7 @@ def test_s1_recurses_into_scan_bodies():
 
     from jax.sharding import PartitionSpec as P
 
-    from dalle_pytorch_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     fn = shard_map(local, mesh=mesh, in_specs=(P(None, "dp"),),
                    out_specs=P("dp"), check_vma=False)

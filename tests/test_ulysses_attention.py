@@ -6,10 +6,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dalle_pytorch_tpu.ops.attention import AttnPattern
-from dalle_pytorch_tpu.parallel.mesh import shard_map
 from dalle_pytorch_tpu.parallel.ulysses import ulysses_attention_sharded
 
 from attention_refs import dense_reference

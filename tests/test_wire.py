@@ -4,7 +4,7 @@ What these tests pin, in order:
 
 * **Frames** — ``GWR1 | uint32 len | JSON`` roundtrips every payload
   shape the replica RPC carries, numpy arrays included, bit-exactly.
-* **Typed taxonomy** — each transport failure surfaces as exactly one
+* **Typed failures** — each transport failure surfaces as exactly one
   exception class: refused → :class:`WireUnavailable`, deadline →
   :class:`WireTimeout`, peer-vanished → :class:`WireReset`, torn frame →
   :class:`WireProtocolError` (NEVER retried), handler exception →
@@ -79,7 +79,7 @@ def test_torn_body_is_protocol_error():
         wire.decode_body(body[8: 8 + (len(body) - 8) // 2])
 
 
-# --- taxonomy over real sockets --------------------------------------------
+# --- typed failures over real sockets----------------------------------------
 
 
 def test_echo_roundtrip_and_counters():

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Collect perf_ab run logs into a markdown table for PERF.md.
 
-The chip-work babysitter leaves one perf_ab stdout log per stage; this
-tool parses each log's ``medians:`` block and emits one markdown table so
+A chip session leaves one perf_ab stdout log per A/B; this tool parses
+each log's ``medians:`` block and emits one markdown table so
 A/B results land in PERF.md in a uniform format:
 
     python tools/collect_ab.py /tmp/chip_ab_core.log /tmp/chip_ab_pallas.log

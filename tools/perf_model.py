@@ -39,10 +39,8 @@ def main(argv=None) -> int:
                         help="decode rows only (skip CUB train compiles)")
     args = parser.parse_args(argv)
 
-    from dalle_pytorch_tpu.cli import (apply_platform_env,
-                                       enable_compilation_cache)
+    from dalle_pytorch_tpu.cli import enable_compilation_cache
 
-    apply_platform_env()
     enable_compilation_cache()  # re-runs and the test suite share compiles
 
     # the same builders the gate tests use — this tool can never drift
