@@ -19,6 +19,7 @@ from . import DALLE, DALLEConfig, DiscreteVAE, VAEConfig
 from .data.tokenizer import ChineseTokenizer, HugTokenizer, SimpleTokenizer
 from .models.dalle import (decode_codes, generate_codes, prefill_codes,
                            tile_prefill)
+from .obs import compiles
 from .utils.checkpoint import (load_checkpoint, migrate_head_kernels,
                                migrate_qkv_kernels)
 
@@ -40,7 +41,13 @@ def enable_compilation_cache() -> None:
     configuration in a process win, so a tool invoked in-process never
     redirects a cache its host configured.  Otherwise the cache goes to
     ``DEFAULT_COMPILE_CACHE``.  ``JAX_ENABLE_COMPILATION_CACHE=0`` (jax's
-    own switch) turns it off."""
+    own switch) turns it off.
+
+    Also where the compile log (``obs/compiles.py``) is switched on: every
+    entry point and the benchmark's harness come through here before their
+    first jit, so trace / lower / compile-or-load / cache events are
+    counted from the start of the process."""
+    compiles.install()
     if jax.config.jax_compilation_cache_dir:
         return
     # LRU-bound the on-disk cache: the persistent cache never evicts by

@@ -13,6 +13,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+from . import compiles
+
 
 def _pct(values: List[float], q: float) -> Optional[float]:
     """Nearest-rank percentile, stdlib-only (no numpy on the read side)."""
@@ -225,6 +227,35 @@ def build_report(events: List[dict]) -> dict:
                               else None),
         }
 
+    # --- compiles -----------------------------------------------------------
+    # obs/compiles.py forwards jax's trace / lower / compile-or-load / cache
+    # events as `compile` records; a trace AFTER the first step record is a
+    # retrace inside the run's steady state (a new shape, a rebuilt jit)
+    comp = [r for r in events if r.get("kind") == "compile"]
+    compile_report: Optional[dict] = None
+    if comp:
+        summary = compiles.summarize(
+            {"phase": str(r.get("name", "?")), "fun_name": r.get("fun"),
+             "t": 0.0, "dur_s": r.get("dur_s")} for r in comp)
+
+        def stream(r):
+            return (str(r.get("run", "?")), r.get("host", 0))
+
+        first_step: Dict[tuple, int] = {}
+        for r in steps:
+            first_step[stream(r)] = min(r.get("seq", 0),
+                                        first_step.get(stream(r), 1 << 62))
+        compile_report = {
+            "phases": {p: row for p, row in summary["phases"].items()
+                       if row["count"]},
+            "top": [{"fun": f["fun_name"], "seconds": f["seconds"]}
+                    for f in summary["top"][:5]],
+            "traces_after_first_step": sum(
+                r.get("name") == "trace"
+                and r.get("seq", 0) > first_step.get(stream(r), 1 << 62)
+                for r in comp),
+        }
+
     # --- memory: predicted vs measured --------------------------------------
     # MemTracker emits `mem.watermark` at phase boundaries (obs/mem.py)
     # and trainers emit one `mem.predicted` record (the ledger's memory
@@ -315,6 +346,7 @@ def build_report(events: List[dict]) -> dict:
         "ckpt": ckpt_report,
         "serve": serve_report,
         "prof": prof_report,
+        "compiles": compile_report,
         "mem": mem_report,
         "faults": faults,
         "data": data_report,
@@ -494,6 +526,18 @@ def render_text(report: dict) -> str:
             f"measured: mfu {_fmt(prof.get('measured_mfu'))}, step_time p50 "
             f"{_fmt(prof.get('measured_step_time_p50'))}s -> attained "
             f"{_fmt(prof.get('attained_frac'))} of ceiling")
+
+    comp = report.get("compiles")
+    if comp:
+        lines.append("-- compiles --")
+        lines.append(", ".join(
+            f"{phase} {row['count']}"
+            + (f" ({_fmt(row['seconds'])}s)" if row["seconds"] else "")
+            for phase, row in sorted(comp["phases"].items())))
+        for row in comp["top"]:
+            lines.append(f"  {row['fun']}: {_fmt(row['seconds'])}s")
+        lines.append(f"traces after the first step record: "
+                     f"{comp['traces_after_first_step']}")
 
     memr = report.get("mem")
     if memr:

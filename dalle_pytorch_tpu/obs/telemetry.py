@@ -58,6 +58,9 @@ from ..utils import locks
 
 SCHEMA_VERSION = 1
 
+#: spans are mirrored into ``jax.profiler`` captures under this prefix
+PROFILER_PREFIX = "graft:"
+
 # envelope keys every record carries; payload fields must not collide
 # (event() lets the envelope win, so a colliding field is silently dropped
 # — keep payload keys out of this set)
@@ -83,7 +86,13 @@ EVENT_SCHEMA = {
         "name": {"type": "string"},
         "ph": {"enum": ["B", "E"]},          # span begin/end markers
         "sid": {"type": "integer"},          # E only: the paired B's seq
-        "dur_s": {"type": "number"},         # E only: monotonic duration
+        "dur_s": {"type": "number"},         # span E records: monotonic
+                                             # duration; `compile` records:
+                                             # seconds of the phase
+        # kind `compile` (obs/compiles.py): name = trace / lower / compile /
+        # cache_request / cache_hit / cache_miss, `fun` = the jitted
+        # function where jax names one
+        "fun": {"type": "string"},
     },
 }
 
@@ -141,7 +150,7 @@ class _Span:
     process death inside the span leaves the B unpaired — the torn-span
     signature obs_report and the Perfetto exporter surface explicitly."""
 
-    __slots__ = ("_tel", "_kind", "_name", "_fields", "_sid", "_t0")
+    __slots__ = ("_tel", "_kind", "_name", "_fields", "_sid", "_t0", "_ann")
 
     def __init__(self, tel: "Telemetry", kind: str, name: str, fields: dict):
         self._tel = tel
@@ -153,9 +162,23 @@ class _Span:
         self._t0 = time.monotonic()
         self._sid = self._tel.event(self._kind, self._name, ph="B",
                                     **self._fields)
+        # the same span on the profiler's clock: in an --xprof_dir capture
+        # `graft:serve.prefill`, `graft:ckpt.save`, `graft:prof.xprof` sit
+        # in the host plane beside the device ops.  Only where jax is
+        # already loaded (this module never imports it); outside a capture
+        # a TraceAnnotation is a no-op in the runtime.
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        self._ann = None
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(
+                f"{PROFILER_PREFIX}{self._kind}.{self._name}")
+            self._ann.__enter__()
         return self
 
     def __exit__(self, etype, evalue, tb) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(etype, evalue, tb)
         extra = {} if etype is None else {"error": repr(evalue)}
         self._tel.event(self._kind, self._name, ph="E", sid=self._sid,
                         dur_s=time.monotonic() - self._t0,

@@ -519,3 +519,91 @@ def test_xprof_window_captures_trace(tmp_path):
     assert not w.active and synced == [True]
     assert (tmp_path / "trace").exists()
     w.close()  # idempotent
+
+
+# --- the sampler's scope and the spans on the profiler's clock --------------
+
+
+def _tiny_decode_and_args():
+    from dalle_pytorch_tpu import DALLE, DALLEConfig
+    from dalle_pytorch_tpu.models.dalle import (decode_codes, prefill_codes,
+                                                tile_prefill)
+
+    cfg = DALLEConfig(dim=32, depth=2, heads=4, dim_head=8,
+                      num_text_tokens=50, text_seq_len=8,
+                      num_image_tokens=32, image_size=64, image_fmap_size=4)
+    model = DALLE(cfg)
+    text = jnp.ones((1, cfg.text_seq_len), jnp.int32)
+    codes = jnp.zeros((1, cfg.image_seq_len), jnp.int32)
+    variables = {"params": model.init(jax.random.PRNGKey(0), text,
+                                      codes)["params"]}
+    first, caches = tile_prefill(*prefill_codes(model, variables, text), 2)
+
+    def decode(v, first, caches, key):
+        return decode_codes(model, v, first, caches, key, filter_thres=0.9)
+
+    return decode, (variables, first, caches, jax.random.PRNGKey(1))
+
+
+def test_sampler_has_its_own_scope_and_totals_do_not_move(monkeypatch):
+    import contextlib
+
+    from dalle_pytorch_tpu.models import dalle as dalle_mod
+
+    decode, args = _tiny_decode_and_args()
+    # the compiled program's sort carries the scope: what the benchmark's
+    # trace_reduce.scopes_of reads to name `sample/sort` in a breakdown
+    hlo = jax.jit(decode).lower(*args).compile().as_text()
+    sorts = [line for line in hlo.splitlines()
+             if " sort(" in line and "op_name=" in line]
+    assert sorts and all("graftprof:sample" in line for line in sorts), sorts
+    assert "sample" in prof.SCOPES
+
+    attr = prof.attribute(jax.make_jaxpr(decode)(*args))
+    assert attr["scopes"]["sample"]["flops"] > 0
+    assert attr["scopes"]["sample"]["bytes"] > 0
+
+    # without the scope the same equations fall to decode-step: the row's
+    # totals are the same, only the attribution moves
+    real_scope = prof.scope
+    monkeypatch.setattr(
+        dalle_mod.prof, "scope",
+        lambda name: (contextlib.nullcontext() if name == "sample"
+                      else real_scope(name)))
+    # (a new function object: make_jaxpr caches on the function's identity)
+    bare = prof.attribute(jax.make_jaxpr(lambda *a: decode(*a))(*args))
+    monkeypatch.undo()
+    assert "sample" not in bare["scopes"]
+    assert bare["total"] == attr["total"]
+    for key in ("flops", "bytes"):
+        assert (bare["scopes"]["decode-step"][key]
+                == attr["scopes"]["decode-step"][key]
+                + attr["scopes"]["sample"][key])
+    for name in ("attn-scores", "attn-cache", "ff"):
+        assert bare["scopes"][name] == attr["scopes"][name]
+
+
+def test_spans_are_mirrored_into_a_profiler_capture(tmp_path):
+    import gzip
+
+    from dalle_pytorch_tpu.obs import telemetry
+
+    telemetry.init(tmp_path / "run", run_id="r")
+    try:
+        with prof.capture(tmp_path / "trace"):
+            with telemetry.span("serve", "prefill", slot=0):
+                jax.block_until_ready(jax.jit(lambda x: x * 2)(jnp.ones(4)))
+    finally:
+        telemetry.shutdown()
+    planes = list((tmp_path / "trace").glob("plugins/profile/*/*.xplane.pb"))
+    assert planes
+    blob = planes[0].read_bytes()
+    if blob[:2] == b"\x1f\x8b":
+        blob = gzip.decompress(blob)
+    assert b"graft:serve.prefill" in blob
+    # the stream's own record is unchanged
+    spans = [r for r in telemetry.read_events(tmp_path / "run")
+             if r["kind"] == "serve" and r["name"] == "prefill"]
+    assert [r["ph"] for r in spans] == ["B", "E"]
+    # no stream active: the shared null span, no annotation, no allocation
+    assert telemetry.span("serve", "prefill") is telemetry._NULL_SPAN
