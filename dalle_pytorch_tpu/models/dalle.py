@@ -34,7 +34,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..obs import prof
+from ..obs import metrics, prof, telemetry
 from ..ops.transformer import Transformer
 from ..utils.helpers import max_neg_value, top_k_filter, top_p_filter
 
@@ -619,6 +619,11 @@ class DALLE(nn.Module):
         logits = self._head(last, image_only=True)
         return logits[:, 0], kvs
 
+    def lane_dense_caches(self, caches):
+        """The prefill's caches as ``decode_codes``' scan carries them
+        (ops/transformer.py::Transformer.lane_dense_caches)."""
+        return self.transformer.lane_dense_caches(caches)
+
     def decode_step(self, code, caches, index, mask=None, write_pos=None,
                     qweights=None):
         """One sampled image code in, next-position logits out.
@@ -809,6 +814,32 @@ def tile_prefill(first_logits, caches, reps: int):
     return broadcast_prefill(first_logits, caches, reps)
 
 
+def _lane_dense_caches(dalle: DALLE, params, caches):
+    """``caches`` with the dense-read layers' entries head-folded wherever
+    XLA:TPU would pad the plain layout to the lanes
+    (ops/attention.py::kv_fold_factor): one relayout a call, so that every
+    tick of the scan reads each cache byte once.  A static choice, so its
+    counter is per trace: a ``decode.kv_layout`` record and two gauges say
+    how many layers' caches were folded and how many kept plain."""
+    from ..ops.quant import cache_values
+
+    with prof.scope("attn-cache"):
+        folded = dalle.apply(params, caches, method=DALLE.lane_dense_caches)
+    dense = sum(cache_values(new[0]).shape != cache_values(old[0]).shape
+                for new, old in zip(folded, caches))
+    counts = {"kv_lane_dense_layers": dense,
+              "kv_plain_layers": len(caches) - dense}
+    telemetry.emit("decode", "kv_layout",
+                   rows=int(cache_values(caches[0][0]).shape[0]), **counts)
+    reg = metrics.active()
+    if reg is not None:
+        for name, value in counts.items():
+            reg.gauge(f"graft_decode_{name}",
+                      "decode_codes' last trace: layers whose KV cache is "
+                      "carried head-folded / in the plain layout").set(value)
+    return folded
+
+
 def decode_codes(dalle: DALLE, params, first_logits, caches, rng, *,
                  n_prime: int = 0, prime_codes=None,
                  filter_thres: float = 0.5, temperature: float = 1.0,
@@ -854,6 +885,7 @@ def decode_codes(dalle: DALLE, params, first_logits, caches, rng, *,
                     if cfg.weights_int8 else None)
         rng, key0 = jax.random.split(rng)
         first_code = sample(first_logits, key0)
+        caches = _lane_dense_caches(dalle, params, caches)
 
         num_steps = cfg.seq_len - n_pre  # remaining image positions
         keys = (jax.random.split(rng, num_steps) if num_steps > 0

@@ -256,6 +256,18 @@ def build_report(events: List[dict]) -> dict:
                 for r in comp),
         }
 
+    # --- decode: the static sampler's cache layout ---------------------------
+    # models/dalle.py::decode_codes emits one `decode.kv_layout` record per
+    # trace: how many layers' KV caches its scan carries head-folded
+    # (lane-dense) and how many in the plain layout; the last trace speaks
+    layouts = [r for r in events
+               if r.get("kind") == "decode" and r.get("name") == "kv_layout"]
+    decode_report: Optional[dict] = None
+    if layouts:
+        decode_report = {"traces": len(layouts), **{
+            k: layouts[-1].get(k)
+            for k in ("rows", "kv_lane_dense_layers", "kv_plain_layers")}}
+
     # --- memory: predicted vs measured --------------------------------------
     # MemTracker emits `mem.watermark` at phase boundaries (obs/mem.py)
     # and trainers emit one `mem.predicted` record (the ledger's memory
@@ -347,6 +359,7 @@ def build_report(events: List[dict]) -> dict:
         "serve": serve_report,
         "prof": prof_report,
         "compiles": compile_report,
+        "decode": decode_report,
         "mem": mem_report,
         "faults": faults,
         "data": data_report,
@@ -538,6 +551,15 @@ def render_text(report: dict) -> str:
             lines.append(f"  {row['fun']}: {_fmt(row['seconds'])}s")
         lines.append(f"traces after the first step record: "
                      f"{comp['traces_after_first_step']}")
+
+    dec = report.get("decode")
+    if dec:
+        lines.append("-- decode --")
+        lines.append(
+            f"kv cache layout: {dec.get('kv_lane_dense_layers')} layers "
+            f"lane-dense, {dec.get('kv_plain_layers')} plain "
+            f"({dec.get('rows')} rows; last of {dec.get('traces')} "
+            f"decode_codes traces)")
 
     memr = report.get("mem")
     if memr:

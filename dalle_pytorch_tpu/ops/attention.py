@@ -42,10 +42,47 @@ import numpy as np
 
 from ..obs import prof
 from ..utils.helpers import max_neg_value
-from .quant import (cache_write, cache_write_rows, circular_slice_in_dim,
-                    qdense, scaled_qdot, split_cache)
+from .quant import (cache_values, cache_write, cache_write_rows,
+                    circular_slice_in_dim, fold_cache, qdense, scaled_qdot,
+                    split_cache)
 
 VARIANTS = ("full", "axial_row", "axial_col", "conv_like", "sparse")
+
+#: minor dimension of the TPU's tiled layouts (a vector register's lanes)
+LANES = 128
+
+
+def kv_fold_factor(heads: int, dim_head: int, rows: int, dtype) -> int:
+    """How many heads share the minor dimension of a lane-dense decode cache
+    (``quant.fold_heads``); 1 keeps the plain ``[b, heads, n, dh]``.
+
+    Inside the decode scan XLA:TPU lays a carried cache out with either
+    ``dim_head`` or the batch on the 128 lanes and pads it to them: at
+    ``dim_head`` 64 and 32 rows every tick streams twice the cache's bytes
+    (PERF.md, Findings PR 26).  Folding ``128 / dim_head`` heads into the
+    minor dimension fills the lanes exactly.  Decided from what the trace
+    can see: nothing to fold at a ``dim_head`` that already fills the lanes
+    or does not divide them, at a head count the fold does not divide, or at
+    ``rows`` a multiple of 128 (the batch fills the lanes, measured at the
+    memory rate); 4-byte caches stay plain too, since the folded reads are
+    dots and a dot on f32 multiplicands rounds them unless it runs six
+    passes."""
+    fold = LANES // dim_head if LANES % dim_head == 0 else 1
+    if (fold == 1 or heads % fold or rows % LANES == 0
+            or jnp.dtype(dtype).itemsize >= 4):
+        return 1
+    return fold
+
+
+def _block_diag_q(q, fold: int):
+    """``[b, h, 1, dh]`` -> ``[b, h / fold, fold * dh, fold]``: column ``f``
+    of group ``g`` holds head ``g * fold + f``'s query at rows
+    ``[f * dh, (f + 1) * dh)`` and exact zeros elsewhere, so a dot against a
+    head-folded cache row yields each head's own q.k."""
+    b, h, _, dh = q.shape
+    own = jnp.eye(fold, dtype=bool)[:, None, :]
+    return jnp.where(own, q.reshape(b, h // fold, fold, dh, 1), 0).reshape(
+        b, h // fold, fold * dh, fold)
 
 
 def make_variable_sparse_layout(
@@ -444,6 +481,16 @@ class MultiHeadAttention(nn.Module):
         multiplicand and apply the per-head scale to the f32 dots —
         either way no full-precision cache copy ever exists for XLA to
         hoist (contract_check C2/C3)."""
+        fold = q_scaled.shape[1] // k_sub.shape[1]
+        if fold > 1:
+            # head-folded cache [b, h/fold, n, fold*dh]: the same products
+            # and f32 sums as below plus the block-diagonal q's exact zeros
+            b, h = q_scaled.shape[:2]
+            mul = k_sub.dtype if k_scale is None else jnp.bfloat16
+            dots = scaled_qdot(
+                "bglf,bgnl->bgfn", _block_diag_q(q_scaled.astype(mul), fold),
+                k_sub, mul_dtype=mul).reshape(b, h, 1, k_sub.shape[2])
+            return dots if k_scale is None else dots * k_scale
         if k_scale is None:
             return jnp.einsum("bhid,bhjd->bhij",
                               q_scaled.astype(k_sub.dtype), k_sub,
@@ -458,6 +505,8 @@ class MultiHeadAttention(nn.Module):
         under ``kv_cache_int8``, the pair ``(values int8, scale f32
         [b, heads, 1, 1])`` (ops/quant.py); `index` is the traced
         absolute position of this token.  Returns (out, new_k, new_v).
+        On the dense read path the values may come head-folded
+        (:meth:`lane_dense_cache`) and are returned so.
 
         ``write_pos`` selects the PHASE-ALIGNED mode the serving arena
         (serve/engine.py) runs in: ``index`` may then be a per-sequence
@@ -485,14 +534,18 @@ class MultiHeadAttention(nn.Module):
             return self._decode_step_aligned(x, q, k, v, cache_k, cache_v,
                                              index, write_pos, mask, qw)
         with prof.scope("attn-cache"):
-            cache_k = cache_write(cache_k, k, (0, 0, index, 0))
-            cache_v = cache_write(cache_v, v, (0, 0, index, 0))
+            # a head-folded cache (lane_dense_cache) is told by its shape
+            fold = self.heads // cache_values(cache_k).shape[1]
+            cache_k = cache_write(cache_k, k, (0, 0, index, 0), fold)
+            cache_v = cache_write(cache_v, v, (0, 0, index, 0), fold)
             k_vals, k_scale = split_cache(cache_k)
             v_vals, v_scale = split_cache(cache_v)
         n_k = k_vals.shape[2]
         scale = self.dim_head ** -0.5
         sliced = (decode_key_positions(self.pattern, index)
                   if self.sliced_kv_decode else None)
+        assert fold == 1 or sliced is None, (
+            "only the dense read path takes a head-folded cache")
         if sliced is not None:
             # sliced-cache decode: read only the reachable keys (text +
             # row/col/neighborhood) — the decode loop is HBM-bound on cache
@@ -563,6 +616,19 @@ class MultiHeadAttention(nn.Module):
             out = out.transpose(0, 2, 1, 3).reshape(
                 b, 1, self.heads * self.dim_head)
         return self._out_proj(out, qw), cache_k, cache_v
+
+    def lane_dense_cache(self, cache):
+        """One of this layer's decode caches in the layout the static
+        decode scan should carry: head-folded (``quant.fold_cache`` by
+        :func:`kv_fold_factor`) where :meth:`decode_step` reads the whole
+        cache, as given where it reads slices (they touch a tenth of it).
+        The arena and the span pass never come here."""
+        if self.sliced_kv_decode and decode_key_positions(
+                self.pattern, jnp.int32(0)) is not None:
+            return cache
+        values = cache_values(cache)
+        return fold_cache(cache, kv_fold_factor(
+            self.heads, self.dim_head, values.shape[0], values.dtype))
 
     def _decode_step_aligned(self, x, q, k, v, cache_k, cache_v, index,
                              write_pos, mask, qw=None):
@@ -752,6 +818,21 @@ class MultiHeadAttention(nn.Module):
         scale multiplies the small f32 product.  When the dtypes already
         match, the contraction keeps the exact form the decode-byte gates
         are calibrated against."""
+        fold = attn.shape[1] // v.shape[1]
+        if fold > 1:
+            # head-folded cache [b, h/fold, n, fold*dh]: each head's attn
+            # row meets its group's folded values in one f32-accumulating
+            # dot and keeps its own dh lanes of the product
+            b, h, _, n = attn.shape
+            wide = scaled_qdot(
+                "bgfn,bgnl->bgfl", attn.reshape(b, h // fold, fold, n), v,
+                mul_dtype=v.dtype if v_scale is None else jnp.bfloat16,
+            ).reshape(b, h // fold, fold, fold, -1)
+            out = jnp.stack([wide[:, :, f, f] for f in range(fold)],
+                            axis=2).reshape(b, h, 1, -1)
+            if v_scale is not None:
+                out = out * v_scale
+            return out.astype(out_dtype)
         if v_scale is not None:
             return scaled_qdot("bhij,bhjd->bhid", attn, v,
                                v_scale).astype(out_dtype)

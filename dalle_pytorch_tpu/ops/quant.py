@@ -98,12 +98,35 @@ def requantize(new, scale: Optional[jax.Array], dtype):
     return q.astype(jnp.int8)
 
 
-def cache_write(cache: CacheLike, new, start) -> CacheLike:
+def fold_heads(kv, fold: int):
+    """``[b, heads, n, dh]`` -> ``[b, heads / fold, n, fold * dh]``: each
+    group of ``fold`` consecutive heads side by side in the minor dimension
+    (head ``g * fold + f`` at lanes ``[f * dh, (f + 1) * dh)`` of group
+    ``g``) — the lane-dense decode-cache layout
+    (ops/attention.py::kv_fold_factor)."""
+    if fold == 1:
+        return kv
+    b, h, n, dh = kv.shape
+    return kv.reshape(b, h // fold, fold, n, dh).transpose(
+        0, 1, 3, 2, 4).reshape(b, h // fold, n, fold * dh)
+
+
+def fold_cache(cache: CacheLike, fold: int) -> CacheLike:
+    """:func:`fold_heads` of a cache entry of either layout (the per-head
+    scale plane keeps its ``[b, heads, 1, 1]``)."""
+    values, scale = split_cache(cache)
+    values = fold_heads(values, fold)
+    return values if scale is None else (values, scale)
+
+
+def cache_write(cache: CacheLike, new, start, fold: int = 1) -> CacheLike:
     """``dynamic_update_slice`` of one decode-step row into a cache entry
-    of either layout (the scale plane is write-position-invariant)."""
+    of either layout (the scale plane is write-position-invariant).  With
+    ``fold`` the entry's values are head-folded (:func:`fold_heads`): the
+    row is quantized per head first, then folded the same way."""
     values, scale = split_cache(cache)
     updated = jax.lax.dynamic_update_slice(
-        values, requantize(new, scale, values.dtype), start)
+        values, fold_heads(requantize(new, scale, values.dtype), fold), start)
     if scale is None:
         return updated
     return (updated, scale)

@@ -375,6 +375,13 @@ class Transformer(nn.Module):
             for _ in range(self.depth)
         ]
 
+    def lane_dense_caches(self, caches):
+        """Per-layer caches as ``decode_codes``' scan should carry them
+        (MultiHeadAttention.lane_dense_cache): :meth:`decode_step` takes
+        either layout, told by the shape."""
+        return [(blk.attn.lane_dense_cache(ck), blk.attn.lane_dense_cache(cv))
+                for blk, (ck, cv) in zip(self.attn_blocks, caches)]
+
     def decode_step(self, x, caches, index, mask=None, write_pos=None,
                     qweights=None):
         """Single-token pass: x [b, 1, dim], per-layer KV caches, traced

@@ -14,7 +14,9 @@ executable is written to the cache but cannot be read back without a chip.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
+import re
 import sys
 from pathlib import Path
 
@@ -198,6 +200,57 @@ def test_serve_tick_compiles_and_fits(one_chip, serve_arena):
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             < V5E_HBM_BYTES)
+
+
+# --- the decode scan's carried KV caches: no lane padding --------------------
+
+_LAID_OUT = re.compile(r"\b(?:bf16|s8)\[([\d,]+)\]\{([\d,]+):T\(")
+
+
+def _lane_widths(hlo_text, elements):
+    """The lane (minor-most) dimension of every tiled 1- or 2-byte array of
+    ``elements`` elements in a compiled program's text."""
+    widths = set()
+    for dims, minor_to_major in _LAID_OUT.findall(hlo_text):
+        dims = [int(d) for d in dims.split(",")]
+        if math.prod(dims) == elements:
+            widths.add(dims[int(minor_to_major.split(",")[0])])
+    return widths
+
+
+@pytest.mark.parametrize("cell", ["lucid1024-generate", "cub200-generate"])
+def test_decode_scan_carries_unpadded_caches(one_chip, cell):
+    """``decode_codes`` at the two generate cells' shapes (depth cut to 2):
+    lucid1024 x 32 rows (heads 16 x 64, n 1280), where XLA pads ``dim_head``
+    64 to the 128 lanes unless the caches are carried head-folded, and
+    cub200 x 128 rows (heads 8 x 64, n 1104), where the batch fills the
+    lanes.  Neither program may hold a cache-sized array 64 lanes wide, nor
+    plan temporaries much over the caches' own bytes: a jax or libtpu that
+    pads again is caught here, without a chip."""
+    from benchmark import harness
+    from dalle_pytorch_tpu.models.dalle import (decode_codes, prefill_codes,
+                                                tile_prefill)
+
+    cell = harness.load_cell(cell)
+    rows = int(cell.traffic["fanout"])
+    cfg = dataclasses.replace(harness.build_configs(cell.config)[0], depth=2)
+    model, shapes = _param_shapes(cfg)
+    variables = {"params": shapes}
+    first, caches = jax.eval_shape(
+        lambda v, t: tile_prefill(*prefill_codes(model, v, t), rows),
+        variables, jnp.zeros((1, cfg.text_seq_len), jnp.int32))
+    compiled = jax.jit(
+        lambda v, f, c, k: decode_codes(model, v, f, c, k, filter_thres=0.9)
+    ).lower(_on(one_chip, variables), _on(one_chip, first),
+            _on(one_chip, caches),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+            ).compile()
+    one_cache = math.prod(caches[0][0].shape)
+    assert caches[0][0].dtype == jnp.bfloat16
+    widths = _lane_widths(compiled.as_text(), one_cache)
+    assert widths and min(widths) >= 128, widths
+    cache_bytes = 2 * cfg.depth * one_cache * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3 * cache_bytes
 
 
 # --- the long compiles: kept, but outside the quick tier --------------------
