@@ -35,7 +35,9 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import metrics, prof, telemetry
-from ..ops.transformer import Transformer
+from ..ops.ssm import normal_init
+from ..ops.transformer import (RMSNorm, Transformer, TrunkSpec,
+                                layer_mixers)
 from ..utils.helpers import max_neg_value, top_k_filter, top_p_filter
 
 
@@ -149,6 +151,15 @@ class DALLEConfig:
     # pins the fallback path's bit-equality without relying on drafts
     # happening to miss
     spec_force_reject: bool = False
+    # Per-layer block spec of another trunk under DALL-E's client (prompt
+    # layout, phase mask, sampler, loss): ops/transformer.py::TrunkSpec, or
+    # the plain dict it is built from (a checkpoint's hparams, a benchmark
+    # configuration).  A model hyperparameter: it changes the parameter
+    # tree (RMSNorm / Mamba / multi-query / SwiGLU layers, and ONE table of
+    # ``total_tokens`` rows tied between the embedding and the head in
+    # place of text_emb / image_emb / to_logits_dense).  None is the 2021
+    # DALL-E block, its parameter names and its programs.
+    trunk: Optional[TrunkSpec] = None
     dtype: Any = jnp.float32
 
     # execution-plan fields stripped from checkpoint hparams (like dtype):
@@ -161,6 +172,22 @@ class DALLEConfig:
                     "spec_force_reject")
 
     def __post_init__(self):
+        if isinstance(self.trunk, dict):
+            object.__setattr__(self, "trunk", TrunkSpec(**self.trunk))
+        if self.trunk is not None:
+            # a recurrent layer has no meaning yet on these paths
+            for field in ("reversible", "spec_decode", "weights_int8",
+                          "kv_cache_int8", "use_pallas", "sparse_attn"):
+                assert not getattr(self, field), (
+                    f"{field} is not supported over a TrunkSpec trunk (its "
+                    "state-space layers carry a recurrent state, not keys)")
+            assert self.ring_axis is None and self.ff_experts <= 1, (
+                "a TrunkSpec trunk runs unsharded in sequence and with a "
+                "dense feed-forward")
+            assert self.attn_dropout == 0 and self.ff_dropout == 0, (
+                "a TrunkSpec trunk has no dropout")
+            assert self.heads % self.trunk.kv_heads == 0, (
+                self.heads, self.trunk.kv_heads)
         assert not (self.weights_int8 and self.ff_experts > 1), (
             "weights_int8 quantizes the dense GEGLU kernels; MoE expert "
             "kernels are not supported on the quantized decode path")
@@ -195,6 +222,17 @@ class DALLEConfig:
     def total_tokens(self) -> int:
         return self.total_text_tokens + self.num_image_tokens
 
+    @property
+    def kv_heads(self) -> int:
+        """Key/value heads an attention layer's cache holds."""
+        return self.heads if self.trunk is None else self.trunk.kv_heads
+
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        """Each layer's mixer, and so the kind of its decode state:
+        "attention" carries ``(k, v)``, "mamba" ``(window, h)``."""
+        return layer_mixers(self.trunk, self.depth)
+
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
         d.pop("dtype")
@@ -202,6 +240,8 @@ class DALLEConfig:
             d.pop(f)
         if d.get("attn_types") is not None:
             d["attn_types"] = list(d["attn_types"])
+        if d.get("trunk") is not None:
+            d["trunk"]["mixers"] = list(d["trunk"]["mixers"])
         return d
 
     @classmethod
@@ -304,13 +344,18 @@ class AxialPositionalEmbedding(nn.Module):
 
     dim: int
     fmap: int
+    std: float = 1.0
 
     @nn.compact
     def __call__(self, n: int):
-        row = self.param("row", nn.initializers.normal(1.0), (self.fmap, 1, self.dim))
-        col = self.param("col", nn.initializers.normal(1.0), (1, self.fmap, self.dim))
+        row = self.param("row", nn.initializers.normal(self.std), (self.fmap, 1, self.dim))
+        col = self.param("col", nn.initializers.normal(self.std), (1, self.fmap, self.dim))
         grid = (row + col).reshape(self.fmap * self.fmap, self.dim)
         return grid[:n]
+
+
+#: init std of a TrunkSpec model's tied table and position embeddings
+TABLE_STD = 0.02
 
 
 def transformer_kwargs(cfg: DALLEConfig) -> dict:
@@ -338,7 +383,7 @@ def transformer_kwargs(cfg: DALLEConfig) -> dict:
         ff_experts=cfg.ff_experts, ff_expert_top_k=cfg.ff_expert_top_k,
         ff_expert_dispatch=cfg.ff_expert_dispatch,
         ff_expert_capacity_factor=cfg.ff_expert_capacity_factor,
-        dtype=cfg.dtype)
+        trunk=cfg.trunk, dtype=cfg.dtype)
 
 
 class DALLE(nn.Module):
@@ -346,6 +391,26 @@ class DALLE(nn.Module):
 
     def setup(self):
         cfg = self.cfg
+        if cfg.trunk is not None:
+            # one table over the joint vocabulary [text ids | per-position
+            # pad ids | image codes], embedding and head tied; the learned
+            # position embeddings are DALL-E's, outside the trunk, at the
+            # table's scale
+            self.table = nn.Embed(
+                cfg.total_tokens, cfg.dim,
+                param_dtype=jnp.dtype(cfg.trunk.param_dtype),
+                embedding_init=normal_init(TABLE_STD), name="table")
+            self.text_pos_emb = nn.Embed(
+                cfg.text_seq_len + 1, cfg.dim,
+                embedding_init=nn.initializers.normal(TABLE_STD),
+                name="text_pos_emb")
+            self.image_pos_emb = AxialPositionalEmbedding(
+                cfg.dim, cfg.image_fmap_size, std=TABLE_STD,
+                name="image_pos_emb")
+            self.transformer = Transformer(name="transformer",
+                                           **transformer_kwargs(cfg))
+            self.final_norm = RMSNorm(cfg.trunk.norm_eps, name="final_norm")
+            return
         self.text_emb = nn.Embed(cfg.total_text_tokens, cfg.dim,
                                  embedding_init=nn.initializers.normal(1.0),
                                  name="text_emb")
@@ -396,12 +461,24 @@ class DALLE(nn.Module):
             f"text length {text.shape[-1]} != text_seq_len {cfg.text_seq_len}"
         )
         text = jnp.pad(self._remap_pad_tokens(text), ((0, 0), (1, 0)))  # <bos> id 0
-        tokens = self._lookup(self.text_emb, text, onehot)
+        tokens = self._lookup_ids(text, False, onehot)
         tokens = tokens + self.text_pos_emb(jnp.arange(text.shape[1]))
         return tokens.astype(cfg.dtype)
 
+    def _lookup_ids(self, ids, image: bool, onehot: bool = False):
+        """Token embeddings of text ids or image codes: each phase's own
+        table, or its rows of the tied table (image codes after the text
+        vocabulary, as the joint logits order them)."""
+        cfg = self.cfg
+        if cfg.trunk is not None:
+            return self._lookup(
+                self.table, ids + cfg.total_text_tokens if image else ids,
+                onehot)
+        return self._lookup(self.image_emb if image else self.text_emb, ids,
+                            onehot)
+
     def _embed_image_codes(self, codes, onehot: bool = False):
-        emb = self._lookup(self.image_emb, codes, onehot)
+        emb = self._lookup_ids(codes, True, onehot)
         emb = emb + self.image_pos_emb(codes.shape[1])
         return emb.astype(self.cfg.dtype)
 
@@ -459,6 +536,15 @@ class DALLE(nn.Module):
                 assert image_only, "quantized head is the decode (image) phase"
                 from ..ops.quant import qdense
                 return qdense(h, *qhead)  # f32 logits
+            if self.cfg.trunk is not None:
+                # the tied table's rows of the wanted phase (DALL-E's phase
+                # mask), multiplicands in the table's dtype, f32 logits
+                split = self.cfg.total_text_tokens
+                rows = self.table.embedding
+                rows = (rows[split:] if image_only else
+                        rows[:split] if text_only else rows)
+                return jnp.einsum("...d,vd->...v", h.astype(rows.dtype),
+                                  rows, preferred_element_type=jnp.float32)
             return self.to_logits_dense(h, image_only=image_only,
                                         text_only=text_only)
 
@@ -595,11 +681,23 @@ class DALLE(nn.Module):
             pad = cfg.seq_len - tokens.shape[1]
             assert pad >= 0, ("priming must leave at least one image token "
                               "to sample")
-            tokens = jnp.pad(tokens, ((0, 0), (0, pad), (0, 0)))
+            if cfg.trunk is None:
+                tokens = jnp.pad(tokens, ((0, 0), (0, pad), (0, 0)))
 
         out, kvs = self.transformer(tokens, mask=self._pad_mask_for_bos(mask),
                                     return_kv=True)
-        if cfg.kv_cache_int8:
+        if cfg.trunk is not None:
+            # the prompt's positions only: a recurrent layer's state is the
+            # one after the last of them, and an attention layer's keys and
+            # values are padded out to the cache's static length
+            with prof.scope("attn-cache"):
+                room = ((0, 0), (0, 0), (0, pad), (0, 0))
+                kvs = [kv if kind == "mamba" else
+                       tuple(jnp.pad(a.astype(jnp.bfloat16)
+                                     if cfg.kv_cache_bf16 else a, room)
+                             for a in kv)
+                       for kind, kv in zip(cfg.mixers, kvs)]
+        elif cfg.kv_cache_int8:
             # int8 cache storage: per-head symmetric scales computed HERE,
             # at prefill write time — the one place the whole sequence is
             # in hand — then frozen for the decode writes (ops/quant.py
@@ -618,6 +716,14 @@ class DALLE(nn.Module):
         last = out[:, n_pre - 1 : n_pre]
         logits = self._head(last, image_only=True)
         return logits[:, 0], kvs
+
+    def decode_init_state(self, batch: int):
+        """A zero decode state for ``batch`` rows, one pair per layer
+        (ops/transformer.py::Transformer.decode_init_cache) in the prefill's
+        storage dtypes."""
+        cfg = self.cfg
+        return self.transformer.decode_init_cache(
+            batch, jnp.bfloat16 if cfg.kv_cache_bf16 else cfg.dtype)
 
     def lane_dense_caches(self, caches):
         """The prefill's caches as ``decode_codes``' scan carries them
@@ -645,7 +751,7 @@ class DALLE(nn.Module):
         cfg = self.cfg
         with prof.scope("decode-step"):
             with prof.scope("embed"):
-                emb = self.image_emb(code[:, None])
+                emb = self._lookup_ids(code[:, None], True)
                 img_index = index - (cfg.text_seq_len + 1)
                 pos_grid = self.image_pos_emb(cfg.image_seq_len)
                 if jnp.ndim(index) > 0:
@@ -685,7 +791,7 @@ class DALLE(nn.Module):
         cfg = self.cfg
         with prof.scope("decode-step"):
             with prof.scope("embed"):
-                emb = self.image_emb(codes)               # [b, K, dim]
+                emb = self._lookup_ids(codes, True)       # [b, K, dim]
                 img_index = qpos - (cfg.text_seq_len + 1)
                 pos_grid = self.image_pos_emb(cfg.image_seq_len)
                 rows = jnp.clip(img_index, 0, cfg.image_seq_len - 1)
@@ -818,25 +924,37 @@ def _lane_dense_caches(dalle: DALLE, params, caches):
     """``caches`` with the dense-read layers' entries head-folded wherever
     XLA:TPU would pad the plain layout to the lanes
     (ops/attention.py::kv_fold_factor): one relayout a call, so that every
-    tick of the scan reads each cache byte once.  A static choice, so its
-    counter is per trace: a ``decode.kv_layout`` record and two gauges say
-    how many layers' caches were folded and how many kept plain."""
+    tick of the scan reads each cache byte once.  Recurrent entries pass as
+    they are.  A static choice, so its counters are per trace: a
+    ``decode.kv_layout`` record and two gauges say how many attention
+    layers' caches were folded and how many kept plain, a
+    ``decode.state_layout`` record and three gauges how many layers carry
+    keys and values, how many a recurrent state, and the bytes of decode
+    state one row holds."""
     from ..ops.quant import cache_values
 
+    cfg = dalle.cfg
     with prof.scope("attn-cache"):
         folded = dalle.apply(params, caches, method=DALLE.lane_dense_caches)
-    dense = sum(cache_values(new[0]).shape != cache_values(old[0]).shape
-                for new, old in zip(folded, caches))
-    counts = {"kv_lane_dense_layers": dense,
-              "kv_plain_layers": len(caches) - dense}
-    telemetry.emit("decode", "kv_layout",
-                   rows=int(cache_values(caches[0][0]).shape[0]), **counts)
+    attn = [i for i, kind in enumerate(cfg.mixers) if kind == "attention"]
+    dense = sum(cache_values(folded[i][0]).shape
+                != cache_values(caches[i][0]).shape for i in attn)
+    rows = int(jax.tree.leaves(caches)[0].shape[0])
+    records = {
+        "kv_layout": {"kv_lane_dense_layers": dense,
+                      "kv_plain_layers": len(attn) - dense},
+        "state_layout": {
+            "ssm_layers": len(caches) - len(attn), "kv_layers": len(attn),
+            "state_bytes_per_row": sum(
+                a.size * a.dtype.itemsize
+                for a in jax.tree.leaves(caches)) // rows}}
     reg = metrics.active()
-    if reg is not None:
-        for name, value in counts.items():
-            reg.gauge(f"graft_decode_{name}",
-                      "decode_codes' last trace: layers whose KV cache is "
-                      "carried head-folded / in the plain layout").set(value)
+    for record, counts in records.items():
+        telemetry.emit("decode", record, rows=rows, **counts)
+        if reg is not None:
+            for name, value in counts.items():
+                reg.gauge(f"graft_decode_{name}",
+                          f"decode_codes' last trace ({record})").set(value)
     return folded
 
 

@@ -40,7 +40,8 @@ SCOPE_PREFIX = "graftprof:"
 #: rows enumerate.
 SCOPES = ("embed", "attn-qkv", "attn-scores", "attn-cache", "attn-out",
           "ff", "logits-head", "vae-conv", "optimizer", "decode-step",
-          "serve-tick", "spec-draft", "spec-verify", "sample")
+          "serve-tick", "spec-draft", "spec-verify", "sample",
+          "ssm-proj", "ssm-conv", "ssm-scan")
 
 #: Residual bucket for equations under no scope.
 UNATTRIBUTED = "unattributed"

@@ -259,14 +259,22 @@ def build_report(events: List[dict]) -> dict:
     # --- decode: the static sampler's cache layout ---------------------------
     # models/dalle.py::decode_codes emits one `decode.kv_layout` record per
     # trace: how many layers' KV caches its scan carries head-folded
-    # (lane-dense) and how many in the plain layout; the last trace speaks
-    layouts = [r for r in events
-               if r.get("kind") == "decode" and r.get("name") == "kv_layout"]
+    # (lane-dense) and how many in the plain layout; and one
+    # `decode.state_layout` record: how many layers carry keys and values,
+    # how many a recurrent state, the bytes a row holds; the last trace speaks
+    def last_decode(name, keys):
+        found = [r for r in events
+                 if r.get("kind") == "decode" and r.get("name") == name]
+        return len(found), ({k: found[-1].get(k) for k in keys}
+                            if found else {})
+
+    traces, kv = last_decode(
+        "kv_layout", ("rows", "kv_lane_dense_layers", "kv_plain_layers"))
+    _, state = last_decode(
+        "state_layout", ("kv_layers", "ssm_layers", "state_bytes_per_row"))
     decode_report: Optional[dict] = None
-    if layouts:
-        decode_report = {"traces": len(layouts), **{
-            k: layouts[-1].get(k)
-            for k in ("rows", "kv_lane_dense_layers", "kv_plain_layers")}}
+    if traces:
+        decode_report = {"traces": traces, **kv, **state}
 
     # --- memory: predicted vs measured --------------------------------------
     # MemTracker emits `mem.watermark` at phase boundaries (obs/mem.py)
@@ -560,6 +568,11 @@ def render_text(report: dict) -> str:
             f"lane-dense, {dec.get('kv_plain_layers')} plain "
             f"({dec.get('rows')} rows; last of {dec.get('traces')} "
             f"decode_codes traces)")
+        if "kv_layers" in dec:
+            lines.append(
+                f"decode state: {dec.get('kv_layers')} layers of keys and "
+                f"values, {dec.get('ssm_layers')} recurrent; "
+                f"{dec.get('state_bytes_per_row')} bytes a row")
 
     memr = report.get("mem")
     if memr:

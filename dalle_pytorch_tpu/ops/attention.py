@@ -45,6 +45,7 @@ from ..utils.helpers import max_neg_value
 from .quant import (cache_values, cache_write, cache_write_rows,
                     circular_slice_in_dim, fold_cache, qdense, scaled_qdot,
                     split_cache)
+from .ssm import fan_in_normal
 
 VARIANTS = ("full", "axial_row", "axial_col", "conv_like", "sparse")
 
@@ -72,6 +73,27 @@ def kv_fold_factor(heads: int, dim_head: int, rows: int, dtype) -> int:
             or jnp.dtype(dtype).itemsize >= 4):
         return 1
     return fold
+
+
+def grouped_dots(q, k):
+    """``q`` ``[b, h, i, d]`` against ``k`` ``[b, g, j, d]`` where each of
+    the ``g`` key heads serves ``h / g`` query heads (``g`` 1: multi-query).
+    Multiplicands in ``k``'s dtype, float32 sums: ``[b, h, i, j]``."""
+    b, h, i, d = q.shape
+    g = k.shape[1]
+    return jnp.einsum("bgrid,bgjd->bgrij",
+                      q.reshape(b, g, h // g, i, d).astype(k.dtype), k,
+                      preferred_element_type=jnp.float32).reshape(b, h, i, -1)
+
+
+def grouped_values(attn, v):
+    """``attn`` ``[b, h, i, j]`` over the values ``v`` ``[b, g, j, d]`` of
+    :func:`grouped_dots`' grouping: float32 ``[b, h, i, d]``."""
+    b, h, i, j = attn.shape
+    g = v.shape[1]
+    return jnp.einsum("bgrij,bgjd->bgrid",
+                      attn.reshape(b, g, h // g, i, j).astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).reshape(b, h, i, -1)
 
 
 def _block_diag_q(q, fold: int):
@@ -364,9 +386,35 @@ class MultiHeadAttention(nn.Module):
     #   dynamic_slice spans (<=2 per row) instead of the per-key vmapped
     #   gather; bit-identical (same key order/masks), False is the A/B
     #   control — again part of the traced config
+    # ``kv_heads`` keys and values serve ``heads`` queries (1: multi-query);
+    # None is one each, the fused ``to_qkv`` kernel.  The dense paths take
+    # it (``__call__``, ``decode_step``, the arena's aligned read), in the
+    # plain cache layouts; the Pallas, ring and int8 paths do not.
+    kv_heads: Optional[int] = None
+    use_bias: bool = True             # the output projection's
     dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
 
     def setup(self):
+        self.drop = nn.Dropout(self.dropout)
+        if self.kv_heads is not None:
+            assert self.heads % self.kv_heads == 0, (self.heads, self.kv_heads)
+            assert not self.use_pallas and self.ring_axis is None, (
+                "grouped keys run the dense attention paths only")
+            proj = dict(axis=-1, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.param_dtype,
+                        kernel_init=fan_in_normal(self.dim))
+            self.to_q = nn.DenseGeneral(
+                features=(self.heads, self.dim_head), name="to_q", **proj)
+            self.to_kv = nn.DenseGeneral(
+                features=(2, self.kv_heads, self.dim_head), name="to_kv",
+                **proj)
+            self.to_out = nn.Dense(
+                self.dim, use_bias=self.use_bias, dtype=self.dtype,
+                param_dtype=self.param_dtype,
+                kernel_init=fan_in_normal(self.heads * self.dim_head),
+                name="to_out")
+            return
         # fused QKV as a [dim, 3, heads, dh] DenseGeneral: the (3,) axis is
         # never sharded, so splitting q/k/v is a free unsharded-axis index,
         # and tensor parallelism shards the heads axis cleanly (a flat
@@ -375,11 +423,15 @@ class MultiHeadAttention(nn.Module):
         self.to_qkv = nn.DenseGeneral(
             features=(3, self.heads, self.dim_head), axis=-1, use_bias=False,
             dtype=self.dtype, name="to_qkv")
-        self.to_out = nn.Dense(self.dim, use_bias=True, dtype=self.dtype, name="to_out")
-        self.drop = nn.Dropout(self.dropout)
+        self.to_out = nn.Dense(self.dim, use_bias=self.use_bias,
+                               dtype=self.dtype, name="to_out")
 
     def _qkv(self, x):
         with prof.scope("attn-qkv"):
+            if self.kv_heads is not None:
+                q = self.to_q(x).transpose(0, 2, 1, 3)      # [b, heads, n, dh]
+                kv = self.to_kv(x).transpose(2, 0, 3, 1, 4)  # [2, b, g, n, dh]
+                return q, kv[0], kv[1]
             qkv = self.to_qkv(x)  # [b, n, 3, heads, dh]
             qkv = qkv.transpose(2, 0, 3, 1, 4)  # [3, b, heads, n, dh]
             return qkv[0], qkv[1], qkv[2]
@@ -436,14 +488,20 @@ class MultiHeadAttention(nn.Module):
         else:
             with prof.scope("attn-scores"):
                 scale = self.dim_head ** -0.5
-                dots = jnp.einsum("bhid,bhjd->bhij", q * scale, k,
-                                  preferred_element_type=jnp.float32)
+                if self.kv_heads is not None:
+                    dots = grouped_dots(q * scale, k)
+                else:
+                    dots = jnp.einsum("bhid,bhjd->bhij", q * scale, k,
+                                      preferred_element_type=jnp.float32)
                 allow = jnp.asarray(dense_pattern_mask(self.pattern, n, n))[None, None]
                 allow = _merge_key_pad_mask(self.pattern, allow, mask)
                 dots = jnp.where(allow, dots, max_neg_value(dots.dtype))
                 attn = jax.nn.softmax(dots, axis=-1).astype(x.dtype)
-                # graftlint: disable=DOT001 (uniform: attn is cast to x.dtype above, matching v; parity pinned by tests/attention_refs)
-                out = jnp.einsum("bhij,bhjd->bhid", attn, v)
+                if self.kv_heads is not None:
+                    out = grouped_values(attn, v)
+                else:
+                    # graftlint: disable=DOT001 (uniform: attn is cast to x.dtype above, matching v; parity pinned by tests/attention_refs)
+                    out = jnp.einsum("bhij,bhjd->bhid", attn, v)
 
         with prof.scope("attn-out"):
             out = out.astype(x.dtype)
@@ -481,6 +539,9 @@ class MultiHeadAttention(nn.Module):
         multiplicand and apply the per-head scale to the f32 dots —
         either way no full-precision cache copy ever exists for XLA to
         hoist (contract_check C2/C3)."""
+        if self.kv_heads is not None:
+            assert k_scale is None, "grouped keys take no int8 cache"
+            return grouped_dots(q_scaled, k_sub)
         fold = q_scaled.shape[1] // k_sub.shape[1]
         if fold > 1:
             # head-folded cache [b, h/fold, n, fold*dh]: the same products
@@ -535,7 +596,8 @@ class MultiHeadAttention(nn.Module):
                                              index, write_pos, mask, qw)
         with prof.scope("attn-cache"):
             # a head-folded cache (lane_dense_cache) is told by its shape
-            fold = self.heads // cache_values(cache_k).shape[1]
+            fold = (1 if self.kv_heads is not None else
+                    self.heads // cache_values(cache_k).shape[1])
             cache_k = cache_write(cache_k, k, (0, 0, index, 0), fold)
             cache_v = cache_write(cache_v, v, (0, 0, index, 0), fold)
             k_vals, k_scale = split_cache(cache_k)
@@ -598,7 +660,7 @@ class MultiHeadAttention(nn.Module):
                     row = row & jnp.take(pad, safe, axis=1)[:, None, None, :]
                 dots = jnp.where(row, dots, max_neg_value(dots.dtype))
                 attn = jax.nn.softmax(dots, axis=-1)  # f32
-                out = self._attn_v(attn, v_sub, v_scale, x.dtype)
+                out = self._cache_values(attn, v_sub, v_scale, x.dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(
                     b, 1, self.heads * self.dim_head)
             return self._out_proj(out, qw), cache_k, cache_v
@@ -612,7 +674,7 @@ class MultiHeadAttention(nn.Module):
             row = _merge_key_pad_mask(self.pattern, row, mask)
             dots = jnp.where(row, dots, max_neg_value(dots.dtype))
             attn = jax.nn.softmax(dots, axis=-1)  # f32
-            out = self._attn_v(attn, v_vals, v_scale, x.dtype)
+            out = self._cache_values(attn, v_vals, v_scale, x.dtype)
             out = out.transpose(0, 2, 1, 3).reshape(
                 b, 1, self.heads * self.dim_head)
         return self._out_proj(out, qw), cache_k, cache_v
@@ -623,9 +685,10 @@ class MultiHeadAttention(nn.Module):
         :func:`kv_fold_factor`) where :meth:`decode_step` reads the whole
         cache, as given where it reads slices (they touch a tenth of it).
         The arena and the span pass never come here."""
-        if self.sliced_kv_decode and decode_key_positions(
-                self.pattern, jnp.int32(0)) is not None:
-            return cache
+        if self.kv_heads is not None or (
+                self.sliced_kv_decode and decode_key_positions(
+                    self.pattern, jnp.int32(0)) is not None):
+            return cache    # grouped keys' reads have no folded form
         values = cache_values(cache)
         return fold_cache(cache, kv_fold_factor(
             self.heads, self.dim_head, values.shape[0], values.dtype))
@@ -735,7 +798,7 @@ class MultiHeadAttention(nn.Module):
                        & valid)[:, None, None, :]
                 dots = jnp.where(row, dots, max_neg_value(dots.dtype))
                 attn = jax.nn.softmax(dots, axis=-1)  # f32
-                return self._attn_v(attn, v_sub, v_scale, out_dtype)
+                return self._cache_values(attn, v_sub, v_scale, out_dtype)
         with prof.scope("attn-scores"):
             dots = self._cache_dots(q * scale, k_vals, k_scale)
             logical = jnp.remainder(
@@ -748,7 +811,7 @@ class MultiHeadAttention(nn.Module):
             dots = jnp.where(row[:, None, None, :], dots,
                              max_neg_value(dots.dtype))
             attn = jax.nn.softmax(dots, axis=-1)  # f32
-            return self._attn_v(attn, v_vals, v_scale, out_dtype)
+            return self._cache_values(attn, v_vals, v_scale, out_dtype)
 
     def decode_span(self, x, cache_k, cache_v, qpos, rot, valid, qw=None):
         """K-token span pass with KV cache — the speculative-decode
@@ -800,6 +863,13 @@ class MultiHeadAttention(nn.Module):
         out = out.transpose(0, 2, 1, 3).reshape(
             b, K, self.heads * self.dim_head)
         return self._out_proj(out, qw), cache_k, cache_v
+
+    def _cache_values(self, attn, v, v_scale, out_dtype):
+        """``attn`` (f32) over a cache read's values: :meth:`_attn_v`, or
+        the grouped contraction where ``kv_heads`` is set."""
+        if self.kv_heads is not None:
+            return grouped_values(attn, v).astype(out_dtype)
+        return self._attn_v(attn, v, v_scale, out_dtype)
 
     @staticmethod
     def _attn_v(attn, v, v_scale, out_dtype):

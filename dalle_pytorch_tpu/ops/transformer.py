@@ -17,6 +17,7 @@ jitted sampler.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -27,6 +28,56 @@ from ..obs import prof
 from ..utils.helpers import cast_tuple, default
 from .attention import AttnPattern, MultiHeadAttention
 from .reversible import reversible_sequence, reversible_sequence_naive
+from .ssm import MambaMixer, fan_in_normal, rms_norm
+
+MIXERS = ("attention", "mamba")
+
+
+@dataclasses.dataclass(frozen=True)
+class TrunkSpec:
+    """Per-layer block spec of a trunk that is not the 2021 DALL-E block:
+    layer ``i`` is ``x += Mixer_i(Norm(x)); x += FF(Norm(x))`` with no
+    LayerScale, bias or dropout, its mixer ``mixers[i % len(mixers)]``.
+    Absent (``DALLEConfig.trunk`` None) the stack is LayerScale(PreNorm(
+    attention)) + LayerScale(PreNorm(GEGLU x4)) as before.
+
+    Taken as far as the Jamba family needs: RMSNorm, multi-query or
+    multi-head attention without position encoding, Mamba-1 with normed
+    ``dt``/``B``/``C``, a dense gated-SiLU feed-forward, and one table tied
+    between the embedding and the head (``models/dalle.py``).  Built from a
+    plain dict (a checkpoint's hparams, a benchmark configuration)."""
+
+    mixers: Tuple[str, ...]
+    ff_dim: int
+    kv_heads: int = 1
+    norm: str = "rms"
+    norm_eps: float = 1e-6
+    ff: str = "swiglu"
+    ssm_expand: int = 2
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_dt_rank: int = 160
+    param_dtype: str = "bfloat16"     # matrices and the table; gains stay f32
+
+    def __post_init__(self):
+        object.__setattr__(self, "mixers", tuple(self.mixers))
+        assert self.mixers and set(self.mixers) <= set(MIXERS), (
+            f"trunk mixers {self.mixers} outside {MIXERS}")
+        assert self.norm == "rms" and self.ff == "swiglu", (
+            f"trunk norm {self.norm!r} / ff {self.ff!r}: only 'rms' and "
+            "'swiglu' blocks exist")
+        assert self.param_dtype in ("bfloat16", "float32"), self.param_dtype
+
+    def mixer(self, layer: int) -> str:
+        return self.mixers[layer % len(self.mixers)]
+
+
+def layer_mixers(trunk: Optional[TrunkSpec], depth: int) -> Tuple[str, ...]:
+    """Each layer's mixer kind, which is also the kind of its decode state:
+    ``(k, v)`` for "attention", ``(window, h)`` for "mamba"."""
+    if trunk is None:
+        return ("attention",) * depth
+    return tuple(trunk.mixer(i) for i in range(depth))
 
 
 def layerscale_init(layer_index: int) -> float:
@@ -155,6 +206,112 @@ class FFBlock(nn.Module):
             return h * self.scale.astype(h.dtype)
 
 
+class RMSNorm(nn.Module):
+    """``x * rsqrt(mean(x^2) + eps) * scale`` in float32."""
+
+    eps: float = 1e-6
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        return rms_norm(x, scale, self.eps)
+
+
+class TrunkAttnBlock(nn.Module):
+    """PreNorm(attention) of a :class:`TrunkSpec` trunk: RMSNorm, ``heads``
+    queries over ``kv_heads`` keys and values, no bias, no LayerScale.  Same
+    calls as :class:`AttnBlock`."""
+
+    pattern: AttnPattern
+    dim: int
+    heads: int
+    dim_head: int
+    kv_heads: int
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        self.norm = RMSNorm(self.eps, name="norm")
+        self.attn = MultiHeadAttention(
+            pattern=self.pattern, dim=self.dim, heads=self.heads,
+            dim_head=self.dim_head, kv_heads=self.kv_heads, use_bias=False,
+            dtype=self.dtype, param_dtype=self.param_dtype, name="attn")
+
+    def _normed(self, x):
+        with prof.scope("attn-qkv"):
+            return self.norm(x).astype(x.dtype)
+
+    def __call__(self, x, mask=None, deterministic: bool = True,
+                 return_kv: bool = False):
+        return self.attn(self._normed(x), mask=mask, return_kv=return_kv)
+
+    def decode_step(self, x, cache_k, cache_v, index, mask=None,
+                    write_pos=None, qw=None):
+        return self.attn.decode_step(self._normed(x), cache_k, cache_v,
+                                     index, mask=mask, write_pos=write_pos)
+
+
+class TrunkSSMBlock(nn.Module):
+    """PreNorm(Mamba mixer) of a :class:`TrunkSpec` trunk.  Its decode state
+    ``(window, h)`` (ops/ssm.py) rides where an attention layer's ``(k, v)``
+    does; it has no position axis, so ``index``, ``mask`` and ``write_pos``
+    mean nothing to it."""
+
+    dim: int
+    spec: TrunkSpec
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        self.norm = RMSNorm(self.spec.norm_eps, name="norm")
+        self.ssm = MambaMixer(
+            dim=self.dim, expand=self.spec.ssm_expand,
+            state=self.spec.ssm_state, conv=self.spec.ssm_conv,
+            dt_rank=self.spec.ssm_dt_rank, eps=self.spec.norm_eps,
+            dtype=self.dtype, param_dtype=self.param_dtype, name="ssm")
+
+    def _normed(self, x):
+        with prof.scope("ssm-proj"):
+            return self.norm(x).astype(x.dtype)
+
+    def __call__(self, x, mask=None, deterministic: bool = True,
+                 return_kv: bool = False):
+        return self.ssm(self._normed(x), return_state=return_kv)
+
+    def decode_step(self, x, window, h, index, mask=None, write_pos=None,
+                    qw=None):
+        return self.ssm.decode_step(self._normed(x), window, h)
+
+
+class SwiGLUBlock(nn.Module):
+    """PreNorm(``W_down(silu(W_gate h) * (W_up h))``) of a
+    :class:`TrunkSpec` trunk: RMSNorm, no bias, no LayerScale."""
+
+    dim: int
+    ff_dim: int
+    eps: float = 1e-6
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        def dense(features, fan_in, name):
+            return nn.Dense(features, use_bias=False, dtype=self.dtype,
+                            param_dtype=self.param_dtype,
+                            kernel_init=fan_in_normal(fan_in), name=name)
+
+        self.norm = RMSNorm(self.eps, name="norm")
+        self.gate = dense(self.ff_dim, self.dim, "gate")
+        self.up = dense(self.ff_dim, self.dim, "up")
+        self.down = dense(self.dim, self.ff_dim, "down")
+
+    def __call__(self, x, deterministic: bool = True):
+        with prof.scope("ff"):
+            h = self.norm(x).astype(x.dtype)
+            return self.down(jax.nn.silu(self.gate(h)) * self.up(h))
+
+
 class MoEFFBlock(nn.Module):
     """LayerScale(PreNorm(MoE feed-forward)) — the FFBlock with its GEGLU
     swapped for a top-k routed expert mixture (ops/moe.py).  The switch
@@ -198,7 +355,8 @@ class MoEFFBlock(nn.Module):
 
 class Transformer(nn.Module):
     """Depth x (attn, ff) residual stack with cycled attention variants
-    (ref transformer.py:71-123)."""
+    (ref transformer.py:71-123); with a ``trunk`` (:class:`TrunkSpec`),
+    depth x (mixer, SwiGLU) with each layer's mixer attention or Mamba."""
 
     dim: int
     depth: int
@@ -228,7 +386,12 @@ class Transformer(nn.Module):
     ff_expert_capacity_factor: float = 1.25
     ff_expert_capacity_group: int = 1024
     sparse_layout_seed: int = 0
+    trunk: Optional[TrunkSpec] = None  # per-layer block spec; None = above
     dtype: Any = jnp.float32
+
+    @property
+    def mixers(self) -> Tuple[str, ...]:
+        return layer_mixers(self.trunk, self.depth)
 
     def setup(self):
         attn_types = cast_tuple(default(self.attn_types, ("full",)))
@@ -246,6 +409,22 @@ class Transformer(nn.Module):
                 fmap=fmap, causal=self.causal,
                 layout_seed=self.sparse_layout_seed + ind,
             )
+            if self.trunk is not None:
+                spec = self.trunk
+                kw = dict(dim=self.dim, dtype=self.dtype,
+                          param_dtype=jnp.dtype(spec.param_dtype))
+                if spec.mixer(ind) == "mamba":
+                    attn_blocks.append(TrunkSSMBlock(
+                        spec=spec, name=f"layers_{ind}_ssm", **kw))
+                else:
+                    attn_blocks.append(TrunkAttnBlock(
+                        pattern=pattern, heads=self.heads,
+                        dim_head=self.dim_head, kv_heads=spec.kv_heads,
+                        eps=spec.norm_eps, name=f"layers_{ind}_attn", **kw))
+                ff_blocks.append(SwiGLUBlock(
+                    ff_dim=spec.ff_dim, eps=spec.norm_eps,
+                    name=f"layers_{ind}_ff", **kw))
+                continue
             attn_blocks.append(AttnBlock(
                 pattern=pattern, dim=self.dim, layer_index=ind + 1,
                 heads=self.heads, dim_head=self.dim_head,
@@ -367,25 +546,33 @@ class Transformer(nn.Module):
         return (y1 + y2) / 2
 
     def decode_init_cache(self, batch: int, dtype=None):
-        """Zeroed KV caches, one (k, v) pair per layer: [b, h, seq_len, dh]."""
+        """Zeroed decode state, one pair per layer: ``(k, v)`` ``[b, kv
+        heads, seq_len, dh]`` for an attention layer, ``(window, h)``
+        (ops/ssm.py) for a state-space one."""
         dtype = dtype or self.dtype
-        shape = (batch, self.heads, self.seq_len, self.dim_head)
+        kv_heads = self.heads if self.trunk is None else self.trunk.kv_heads
+        shape = (batch, kv_heads, self.seq_len, self.dim_head)
         return [
-            (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in range(self.depth)
+            blk.ssm.init_state(batch) if kind == "mamba"
+            else (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+            for blk, kind in zip(self.attn_blocks, self.mixers)
         ]
 
     def lane_dense_caches(self, caches):
         """Per-layer caches as ``decode_codes``' scan should carry them
         (MultiHeadAttention.lane_dense_cache): :meth:`decode_step` takes
         either layout, told by the shape."""
-        return [(blk.attn.lane_dense_cache(ck), blk.attn.lane_dense_cache(cv))
-                for blk, (ck, cv) in zip(self.attn_blocks, caches)]
+        return [(ck, cv) if kind == "mamba" else
+                (blk.attn.lane_dense_cache(ck), blk.attn.lane_dense_cache(cv))
+                for blk, kind, (ck, cv) in zip(self.attn_blocks, self.mixers,
+                                               caches)]
 
     def decode_step(self, x, caches, index, mask=None, write_pos=None,
                     qweights=None):
-        """Single-token pass: x [b, 1, dim], per-layer KV caches, traced
-        absolute position `index`.  Returns (out, new_caches).
+        """Single-token pass: x [b, 1, dim], the per-layer decode state
+        (``(k, v)`` caches, or ``(window, h)`` for a state-space layer of a
+        ``trunk``: :attr:`mixers` says which), traced absolute position
+        `index`.  Returns (out, new_caches).
 
         ``write_pos`` enables the phase-aligned serving mode (``index``
         may be per-row, caches rotated, one shared physical write column —

@@ -47,10 +47,35 @@ from typing import Optional, Tuple
 
 from jax.sharding import PartitionSpec as P
 
+# Rules for the leaves of a ``TrunkSpec`` trunk (ops/transformer.py), which
+# share no name with the DALL-E block's: they lead the table below.
+TRUNK_RULES: Tuple[Tuple[str, P], ...] = (
+    # multi-query attention (TrunkSpec trunks): queries [dim, heads, dh]
+    # over tp like the fused kernel's heads; the few key/value heads
+    # [dim, 2, kv_heads, dh] stay whole on every tp shard
+    (r".*attn/to_q/kernel$", P("fsdp", "tp", None)),
+    (r".*attn/to_kv/kernel$", P("fsdp", None, None, None)),
+    # Mamba mixer: the d_in channels are independent through the
+    # convolution and the scan, so they split over tp — in_proj [dim, 2,
+    # d_in] and dt_proj [R, d_in] column-parallel, x_proj [d_in, R + 2N] and
+    # out_proj [d_in, dim] row-parallel, A_log [d_in, N] with its channels
+    (r".*ssm/in_proj/kernel$", P("fsdp", None, "tp")),
+    (r".*ssm/dt_proj/kernel$", P(None, "tp")),
+    (r".*ssm/x_proj/kernel$", P("tp", None)),
+    (r".*ssm/out_proj/kernel$", P("tp", "fsdp")),
+    (r".*ssm/conv_kernel$", P(None, "tp")),
+    (r".*ssm/A_log$", P("tp", None)),
+    # SwiGLU: gate and up column-parallel, down row-parallel
+    (r".*ff/(gate|up)/kernel$", P("fsdp", "tp")),
+    (r".*ff/down/kernel$", P("tp", "fsdp")),
+    # the tied table [vocab, dim]: as the token embeddings below
+    (r".*table/embedding$", P("fsdp", "tp")),
+)
+
 # Default partition rules for our models' flax param trees.  Matched against
 # the '/'-joined param path; first hit wins; default = replicated.
 # Dense kernels are [d_in, d_out]; embeddings are [vocab, dim].
-PARTITION_RULES: Tuple[Tuple[str, P], ...] = (
+PARTITION_RULES: Tuple[Tuple[str, P], ...] = TRUNK_RULES + (
     # fused QKV [dim, 3, heads, dh]: fsdp on features, tp on heads
     (r".*to_qkv/kernel$", P("fsdp", None, "tp", None)),
     # column-parallel projections (split output features over tp)

@@ -3,7 +3,7 @@
 One place naming the (config geometry, ParallelPlan) pairs a run can ask
 for by name, so ``train_dalle.py``'s hard-coded CUB block is one preset
 of many and the analysis suite can gate rungs that do not fit a single
-chip.  Four rungs today:
+chip.  Four rungs of the 2021 block and one other trunk today:
 
 ==========  ======  ========  =======================================
 preset      params  geometry  role
@@ -19,6 +19,11 @@ cub-1024    ~1.3B   dim-1024  the MFU rung (ROADMAP direction 1):
                               crosses the v5e ridge and fsdp-x-tp /
                               dcn-hybrid plan choices diverge —
                               graftplan's autotuner sweep lives here
+jamba-tiny  ~0.05M  dim-32    a ``TrunkSpec`` trunk (Mamba + one
+                              attention layer) at toy width (tests)
+jamba2-3b   3.03B   dim-2560  DALL-E over AI21-Jamba2-3B's trunk: 26
+                              Mamba-1 + 2 multi-query attention
+                              layers, bf16; one chip generates
 ==========  ======  ========  =======================================
 
 ``cub-512`` and ``cub-1024`` are ALSO :data:`~dalle_pytorch_tpu.parallel.
@@ -48,6 +53,8 @@ PARAM_BANDS = {
     "cub": (10e6, 25e6),
     "cub-512": (300e6, 400e6),
     "cub-1024": (1.15e9, 1.45e9),
+    "jamba-tiny": (0.01e6, 1e6),
+    "jamba2-3b": (2.9e9, 3.2e9),
 }
 
 
@@ -123,12 +130,57 @@ def cub1024_config(**overrides):
     return DALLEConfig(**base)
 
 
+#: AI21-Jamba2-3B's trunk (huggingface.co/ai21labs/AI21-Jamba2-3B,
+#: config.json): layer i of 28 is multi-query attention iff i mod 14 == 7,
+#: else Mamba-1; every width as published.
+JAMBA2_3B_TRUNK = dict(
+    mixers=("mamba",) * 7 + ("attention",) + ("mamba",) * 6, ff_dim=8192,
+    kv_heads=1, norm="rms", norm_eps=1e-6, ff="swiglu", ssm_expand=2,
+    ssm_state=16, ssm_conv=4, ssm_dt_rank=160, param_dtype="bfloat16")
+
+
+def jamba_tiny_config(**overrides):
+    """One attention layer among Mamba layers at toy width (tests)."""
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=32, depth=3, heads=4, dim_head=8, num_text_tokens=50,
+                text_seq_len=8, num_image_tokens=32, image_size=64,
+                image_fmap_size=4,
+                trunk=dict(mixers=("mamba", "attention", "mamba"), ff_dim=96,
+                           ssm_state=4, ssm_dt_rank=4,
+                           param_dtype="float32"))
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
+def jamba2_3b_config(**overrides):
+    """DALL-E's client over the AI21-Jamba2-3B trunk (3.03B parameters,
+    6.06 GB in bfloat16: generation fits one v5e chip whole): the tied
+    table's 65,536 rows are 57,088 text ids + 256 per-position pad ids +
+    8,192 image codes of a 256 px, 32 x 32 code grid.
+    ``benchmark/configs/jamba2-3b.json`` is the same model as the benchmark
+    runs it."""
+    import jax.numpy as jnp
+
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=2560, depth=28, heads=20, dim_head=128,
+                num_text_tokens=57088, text_seq_len=256,
+                num_image_tokens=8192, image_size=256, image_fmap_size=32,
+                attn_types=("full",), trunk=JAMBA2_3B_TRUNK,
+                dtype=jnp.bfloat16)
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
 #: Every named config geometry (CLI ``--preset`` surface).
 CONFIG_PRESETS = {
     "tiny": tiny_config,
     "cub": cub_config,
     "cub-512": cub512_config,
     "cub-1024": cub1024_config,
+    "jamba-tiny": jamba_tiny_config,
+    "jamba2-3b": jamba2_3b_config,
 }
 
 #: The scale rungs that are ALSO plan-registry entries: registry name ->

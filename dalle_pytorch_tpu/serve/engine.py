@@ -40,6 +40,11 @@ This module is the device half of the fix:
   translate physical -> logical per slot (``ops/attention.py::
   MultiHeadAttention._decode_step_aligned``), which also hides the
   previous resident's stale keys.
+* **Recurrent entries beside the caches.**  A state-space layer
+  (``DALLEConfig.trunk``) keeps ``(window, h)`` per slot, ``[num_slots,
+  ...]`` with no position axis: nothing to rotate and nothing a mask could
+  hide.  ``admit`` writes the prefilled state whole; ``tick`` advances it
+  only where ``active``, so an idle or finished slot's state stands still.
 
 Sampling reuses ``models.dalle.sample_image_code`` — the serve path and
 ``decode_codes`` share one sampler, so semantics cannot drift; temperature
@@ -111,7 +116,8 @@ class SlotArena:
                              else jnp.bfloat16 if cfg.kv_cache_bf16
                              else cfg.dtype)
         S = num_slots
-        cache_shape = (S, cfg.heads, cfg.seq_len, cfg.dim_head)
+        cache_shape = (S, cfg.kv_heads, cfg.seq_len, cfg.dim_head)
+        recurrent = [kind == "mamba" for kind in cfg.mixers]
 
         def fresh_entry():
             values = jnp.zeros(cache_shape, self._cache_dtype)
@@ -128,9 +134,14 @@ class SlotArena:
             if cfg.weights_int8 else None)
 
         def fresh_state():
+            # a recurrent layer's zero state comes from the model itself
+            # (shapes and dtypes of ops/ssm.py), an attention layer's cache
+            # from the geometry above
+            zero = (dalle.apply(variables, S, method=DALLE.decode_init_state)
+                    if any(recurrent) else [None] * cfg.depth)
             return dict(
-                caches=[(fresh_entry(), fresh_entry())
-                        for _ in range(cfg.depth)],
+                caches=[entry if rec else (fresh_entry(), fresh_entry())
+                        for rec, entry in zip(recurrent, zero)],
                 code=jnp.zeros((S,), jnp.int32),
                 index=jnp.zeros((S,), jnp.int32),
                 pos=jnp.zeros((S,), jnp.int32),
@@ -200,8 +211,16 @@ class SlotArena:
                 return (vals, jax.lax.dynamic_update_slice(
                     scale, new_scale, (slot, 0, 0, 0)))
 
-            caches = [(install(ak, k1), install(av, v1))
-                      for (ak, av), (k1, v1) in zip(state["caches"], caches1)]
+            def install_whole(arena_entry, new_entry):
+                """A recurrent entry has no position axis: the slot's row
+                is replaced whole."""
+                return jax.lax.dynamic_update_slice(
+                    arena_entry, new_entry.astype(arena_entry.dtype),
+                    (slot,) + (0,) * (arena_entry.ndim - 1))
+
+            caches = [tuple(map(install_whole if rec else install, old, new))
+                      for rec, old, new in zip(recurrent, state["caches"],
+                                               caches1)]
             ks = jax.random.split(key, self.geometry.image_seq_len)
             code0 = sample_one(first_logits[0], ks[0], temp)
 
@@ -247,6 +266,14 @@ class SlotArena:
                         ks, (p, 0), (1, 2))[0])(state["keys"], state["pos"])
                 sampled = jax.vmap(sample_one)(logits, sub, state["temp"])
 
+                # a recurrent state has no mask to hide a junk update behind:
+                # it moves only where the slot is active
+                caches = [tuple(jnp.where(
+                              active.reshape((-1,) + (1,) * (new.ndim - 1)),
+                              new, old) for new, old in zip(entry, before))
+                          if rec else entry
+                          for rec, entry, before in zip(
+                              recurrent, caches, state["caches"])]
                 adv = active.astype(jnp.int32)
                 written = jax.vmap(
                     lambda row, p, val: jax.lax.dynamic_update_slice(

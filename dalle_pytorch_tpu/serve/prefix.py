@@ -30,6 +30,13 @@ twice.  This module is the host-side index that makes the sharing safe:
   accumulate here; the scheduler exports them through ``stats()``,
   /metrics gauges and the telemetry stream obs_report aggregates.
 
+Whole prompts only: a payload may hold recurrent state (a state-space
+layer's ``(window, h)``, ``DALLEConfig.trunk``), which is the state *after
+the prompt's last token* and cannot be cut back to a shorter prefix the way
+a key/value cache can be sliced.  The exact-match lookup above is therefore
+the only hit such a payload serves; a partial-prefix walk must not return
+it.
+
 Device memory: payloads are batch-1 caches — ``depth * 2 * heads *
 seq_len * dim_head`` elements each (graftmem's ``serve-prefix`` row
 budgets ``capacity`` of them).  The tree itself is host-side and tiny.

@@ -272,6 +272,9 @@ def make_dalle_pp_train_step(dalle, tx, params, mesh, *,
     from .parallel.pipeline import pipeline_transformer
 
     cfg = dalle.cfg
+    assert cfg.trunk is None, (
+        "the pipeline step stacks identical (attn, ff) stages; a TrunkSpec "
+        "trunk's layers differ in kind")
     tf = Transformer(**transformer_kwargs(cfg))
     _, stacked, apply_fn = pipeline_transformer(
         tf, params["transformer"], mesh=mesh, pp_axis=pp_axis,

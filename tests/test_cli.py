@@ -282,6 +282,45 @@ def test_train_dalle_moe_cli(trained_vae, tiny_dataset, tiny_tokenizer_json,
     assert np.isfinite(_first_loss(wd))
 
 
+def test_train_then_generate_over_a_named_trunk(trained_vae, tiny_dataset,
+                                                tiny_tokenizer_json,
+                                                tmp_path_factory):
+    """`train_dalle.py --trunk jamba-tiny` trains DALL-E over a Mamba +
+    multi-query trunk; the block spec is a checkpointed model
+    hyperparameter, so `generate.py` rebuilds the same model from the
+    checkpoint alone and samples through the mixed decode carry."""
+    wd = tmp_path_factory.mktemp("trunk_cli")
+    _run_train_dalle(wd, dict(BATCH_SIZE=4, TEXT_SEQ_LEN=8),
+                     ["--trunk", "jamba-tiny"], trained_vae, tiny_dataset,
+                     tiny_tokenizer_json)
+    from dalle_pytorch_tpu.utils.checkpoint import load_checkpoint
+
+    ckpt = load_checkpoint(wd / "dalle-final.pt")
+    assert ckpt["hparams"]["trunk"]["mixers"] == ["mamba", "attention",
+                                                  "mamba"]
+    assert ckpt["hparams"]["dim"] == 32
+    layers = ckpt["weights"]["transformer"]
+    assert "ssm" in layers["layers_0_ssm"] and "attn" in layers[
+        "layers_1_attn"]
+    assert "table" in ckpt["weights"] and "text_emb" not in ckpt["weights"]
+    assert np.isfinite(_first_loss(wd))
+    cwd = os.getcwd()
+    os.chdir(wd)
+    try:
+        import generate
+
+        generate.main(["--dalle_path", str(wd / "dalle-final.pt"),
+                       "--text", "red bird", "--num_images", "2",
+                       "--batch_size", "2",
+                       "--bpe_path", str(tiny_tokenizer_json),
+                       "--outputs_dir", str(wd / "outputs")])
+    finally:
+        os.chdir(cwd)
+    images = list((wd / "outputs").rglob("*.jpg")) + list(
+        (wd / "outputs").rglob("*.png"))
+    assert len(images) == 2
+
+
 def test_generate_cli(trained_dalle, tiny_tokenizer_json, workdir):
     cwd = os.getcwd()
     os.chdir(workdir)

@@ -264,8 +264,9 @@ def test_decode_trace_reports_its_cache_layout(tmp_path):
         telemetry.shutdown()
         metrics.shutdown()
     events = telemetry.read_events(tel.path)
-    layout = [e for e in events if e["kind"] == "decode"]
-    assert len(layout) == 1 and layout[0]["name"] == "kv_layout"
+    layout = [e for e in events
+              if e["kind"] == "decode" and e["name"] == "kv_layout"]
+    assert len(layout) == 1
     assert layout[0]["kv_lane_dense_layers"] == 1
     assert layout[0]["kv_plain_layers"] == 1
     assert layout[0]["rows"] == 4
@@ -274,6 +275,9 @@ def test_decode_trace_reports_its_cache_layout(tmp_path):
     report = build_report(events)
     assert report["decode"] == {"traces": 1, "rows": 4,
                                 "kv_lane_dense_layers": 1,
-                                "kv_plain_layers": 1}
+                                "kv_plain_layers": 1, "kv_layers": 2,
+                                "ssm_layers": 0,
+                                "state_bytes_per_row": report["decode"][
+                                    "state_bytes_per_row"]}
     assert ("kv cache layout: 1 layers lane-dense, 1 plain (4 rows; last of 1 "
             "decode_codes traces)") in render_text(report)

@@ -38,7 +38,8 @@ sys.path.insert(0, str(REPO))
 from dalle_pytorch_tpu.parallel.mesh import (DEFAULT_RULES,  # noqa: E402
                                              Partitioner, make_mesh)
 from dalle_pytorch_tpu.parallel.plan import (PARTITION_RULES,  # noqa: E402
-                                             PLAN_REGISTRY, ParallelPlan,
+                                             PLAN_REGISTRY, TRUNK_RULES,
+                                             ParallelPlan,
                                              current_topology,
                                              describe_transition,
                                              resolve_plan_args)
@@ -93,10 +94,14 @@ def tiny_trees():
 
 def test_partition_rules_pin_legacy_table():
     """The plan-owned rule table (and its mesh.DEFAULT_RULES re-export)
-    is pattern-for-pattern, spec-for-spec the pre-refactor table."""
+    is pattern-for-pattern, spec-for-spec the pre-refactor table, after the
+    rules for a ``TrunkSpec`` trunk's leaves (PR 27), which name none of the
+    DALL-E block's."""
     assert DEFAULT_RULES is PARTITION_RULES
-    assert len(PARTITION_RULES) == len(LEGACY_RULES)
-    for (pat, spec), (lpat, lspec) in zip(PARTITION_RULES, LEGACY_RULES):
+    assert PARTITION_RULES[:len(TRUNK_RULES)] == TRUNK_RULES
+    block_rules = PARTITION_RULES[len(TRUNK_RULES):]
+    assert len(block_rules) == len(LEGACY_RULES)
+    for (pat, spec), (lpat, lspec) in zip(block_rules, LEGACY_RULES):
         assert pat == lpat
         assert tuple(spec) == tuple(lspec)
 

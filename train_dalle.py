@@ -229,6 +229,13 @@ def parse_args(argv=None):
     parser.add_argument('--ff_expert_capacity_factor', type=float,
                         default=1.25,
                         help="slot headroom for 'capacity' dispatch")
+    parser.add_argument('--trunk', type=str, default=None,
+                        help="train DALL-E's client over a named trunk "
+                             "(dalle_pytorch_tpu/presets.py, e.g. jamba2-3b, "
+                             "jamba-tiny): dim, depth, heads and the "
+                             "per-layer block spec come from the preset; "
+                             "the text vocabulary, the text length and the "
+                             "image geometry stay this run's")
     parser = distributed_utils.wrap_arg_parser(parser)
     args = parser.parse_args(argv)
     # resolve the declarative ParallelPlan (--plan wins over the individual
@@ -319,7 +326,17 @@ def _main(argv, lr_scale=1.0, skip_past=None):
         LR_DECAY_PATIENCE=5,
         LR_DECAY_COOLDOWN=0,
         LR_DECAY_MIN=1e-7,
+        TRUNK=None,
     )
+    if args.trunk:
+        from dalle_pytorch_tpu.presets import preset_config
+        named = preset_config(args.trunk)
+        assert named.trunk is not None, (
+            f"--trunk {args.trunk}: that preset is the DALL-E block itself")
+        C.update(MODEL_DIM=named.dim, DEPTH=named.depth, HEADS=named.heads,
+                 DIM_HEAD=named.dim_head,
+                 ATTN_TYPES=named.attn_types or ('full',),
+                 TRUNK=named.to_dict()['trunk'])
     import json as _json
     import os as _os
     # graftlint: disable=ENV001 (JSON-valued: presence of any override dict is the signal)
@@ -470,6 +487,7 @@ def _main(argv, lr_scale=1.0, skip_past=None):
             attn_types=ATTN_TYPES,
             ff_experts=args.ff_experts,
             ff_expert_top_k=args.ff_expert_top_k,
+            trunk=C['TRUNK'],
             dtype=dtype,
             **sp_plan,
         )
