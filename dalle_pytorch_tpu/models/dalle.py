@@ -27,6 +27,7 @@ TPU-native redesign:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Optional, Tuple
 
@@ -35,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import metrics, prof, telemetry
+from ..ops.attention import record_kernel_choices
 from ..ops.ssm import normal_init
 from ..ops.transformer import (RMSNorm, Transformer, TrunkSpec,
                                 layer_mixers)
@@ -653,8 +655,12 @@ class DALLE(nn.Module):
         tokens = self.embed_sequence(text, image_codes, onehot)
         n = tokens.shape[1]
 
-        out = self.transformer(tokens, mask=self._pad_mask_for_bos(mask),
-                               deterministic=deterministic)
+        # what each attention layer of this trace runs, said once
+        # (ops/attention.py::record_kernel_choices); not for the shape pass
+        with (contextlib.nullcontext() if self.is_initializing()
+              else record_kernel_choices("dalle")):
+            out = self.transformer(tokens, mask=self._pad_mask_for_bos(mask),
+                                   deterministic=deterministic)
 
         if not return_loss:
             logits = self._head(out)

@@ -276,6 +276,19 @@ def build_report(events: List[dict]) -> dict:
     if traces:
         decode_report = {"traces": traces, **kv, **state}
 
+    # --- attention: which core the model's layers run ------------------------
+    # ops/attention.py::record_kernel_choices emits one `attention.kernel`
+    # record per trace of a model: layers on the flash kernel, layers on the
+    # dense-masked branch, the share of blocks the flash layers compute
+    kernel = [r for r in events if r.get("kind") == "attention"
+              and r.get("name") == "kernel"]
+    attention_report: Optional[dict] = None
+    if kernel:
+        attention_report = {"traces": len(kernel), **{
+            k: kernel[-1].get(k) for k in
+            ("model", "n", "tiles", "flash_layers", "dense_layers",
+             "blocks_computed_share")}}
+
     # --- memory: predicted vs measured --------------------------------------
     # MemTracker emits `mem.watermark` at phase boundaries (obs/mem.py)
     # and trainers emit one `mem.predicted` record (the ledger's memory
@@ -368,6 +381,7 @@ def build_report(events: List[dict]) -> dict:
         "prof": prof_report,
         "compiles": compile_report,
         "decode": decode_report,
+        "attention": attention_report,
         "mem": mem_report,
         "faults": faults,
         "data": data_report,
@@ -573,6 +587,18 @@ def render_text(report: dict) -> str:
                 f"decode state: {dec.get('kv_layers')} layers of keys and "
                 f"values, {dec.get('ssm_layers')} recurrent; "
                 f"{dec.get('state_bytes_per_row')} bytes a row")
+
+    att = report.get("attention")
+    if att:
+        lines.append("-- attention --")
+        lines.append(
+            f"attention core: {att.get('flash_layers')} layers on the flash "
+            f"kernel (tiles {', '.join(att.get('tiles') or []) or '-'}; "
+            f"{100 * (att.get('blocks_computed_share') or 0):.1f}% of their "
+            f"blocks computed), {att.get('dense_layers')} dense "
+            f"(n {att.get('n')}; last of {att.get('traces')} "
+            f"{att.get('model')} traces; the kernel is lowered for a TPU "
+            "only)")
 
     memr = report.get("mem")
     if memr:

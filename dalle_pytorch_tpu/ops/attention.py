@@ -32,15 +32,17 @@ text (incl <bos>) and the rest is the ``fmap x fmap`` image raster.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Any, Optional, Tuple
+import functools
+from typing import Any, Iterator, List, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..obs import prof
+from ..obs import metrics, prof, telemetry
 from ..utils.helpers import max_neg_value
 from .quant import (cache_values, cache_write, cache_write_rows,
                     circular_slice_in_dim, fold_cache, qdense, scaled_qdot,
@@ -241,11 +243,19 @@ def _allowed(pattern: AttnPattern, i, j, xp, layout=None):
 
 def dense_pattern_mask(pattern: AttnPattern, n_q: int, n_k: int) -> np.ndarray:
     """Static [n_q, n_k] boolean mask (True = attend), built with numpy at
-    trace time so it becomes an XLA constant."""
+    trace time so it becomes an XLA constant.  Read-only: the layers of one
+    variant, forward and backward, share one array."""
+    return _pattern_mask(kernel_pattern(pattern), n_q, n_k)
+
+
+@functools.lru_cache(maxsize=16)
+def _pattern_mask(pattern: AttnPattern, n_q: int, n_k: int) -> np.ndarray:
     i = np.arange(n_q)[:, None]
     j = np.arange(n_k)[None, :]
     layout = pattern.block_layout()
-    return np.asarray(_allowed(pattern, i, j, np, layout=layout))
+    mask = np.asarray(_allowed(pattern, i, j, np, layout=layout))
+    mask.setflags(write=False)
+    return mask
 
 
 def pattern_mask_row(pattern: AttnPattern, index, n_k: int,
@@ -360,6 +370,299 @@ def _merge_key_pad_mask(pattern: AttnPattern, allow, key_mask):
     return allow & pad[:, None, None, :]
 
 
+# --- which attention core a forward without a cache runs ----------------------
+
+#: Below this length the dense-masked branch stays: forward + backward of one
+#: layer on the chip, dense against kernel, 0.55 / 0.62 ms at n = 81 and 0.56
+#: / 0.59 at 257, but 1.89 / 1.30 at 592, 7.23 / 2.1-2.8 at 1104, 4.65 / 1.45
+#: at 1280 and 34.7 / 5.5 at 4176 (PERF.md, Findings PR 28).
+FLASH_MIN_LEN = 512
+#: What a computed block costs besides its ``block_q x block_k`` scores, in
+#: scores: the tilings of one pattern rank on the chip as ``computed scores +
+#: this x computed blocks`` does (n = 1104, full: 128-tiles 45 blocks, 2.83
+#: ms, 384-tiles 6 blocks and a fifth more scores, 2.78 ms; axial_row: 24
+#: blocks 2.13 ms against 6 blocks 2.79 ms; n = 1280, full: 256-tiles 1.45
+#: ms, 128-tiles 1.63, 640-tiles 1.65; PERF.md, Findings PR 28).
+FLASH_BLOCK_COST = 4096
+#: Computed blocks one kernel may unroll (its loops are static): past this
+#: the program and its compile (17-29 s at n = 4176 with 66 blocks) grow
+#: out of bounds.
+FLASH_MAX_BLOCKS = 128
+
+
+def flash_tiles(n: int, dim_head: int, dtype, pattern: AttnPattern,
+                kv_heads: Optional[int] = None,
+                ring_axis: Optional[str] = None) -> Optional[Tuple[int, int]]:
+    """``(block_q, block_k)`` for ``ops/attention_pallas.py``'s flash kernel
+    where a forward of this shape should run it, None where the dense-masked
+    branch stays.  Decided from what a trace can see; which of the two a
+    program really holds is settled where it is lowered (the kernel exists
+    for the TPU only: :meth:`MultiHeadAttention._attention_core`).
+
+    Dense stays for grouped keys and the sequence-parallel plans (their own
+    branches), for float32 activations (the kernel's products would run as
+    several bf16 passes where XLA's default precision takes one), for a
+    ``dim_head`` that does not fill a whole number of half-lanes, for
+    sequences under :data:`FLASH_MIN_LEN`, and where no tiling fits.
+    Tiles: the sequence is padded to the lanes only (1104 -> 1152, 1280 ->
+    1280, 4176 -> 4224) and cut into equal square tiles; of the widths that
+    divide it, the one whose computed blocks cost least by
+    :data:`FLASH_BLOCK_COST` (the wider at a tie: less to unroll), among
+    those that leave at most :data:`FLASH_MAX_BLOCKS` blocks to compute and
+    fit VMEM: 384 for ``full`` and ``axial_col`` and 128 for ``axial_row``
+    and ``conv_like`` at 1152, 256 at 1280, 384 at 4224."""
+    if (kv_heads is not None or ring_axis is not None
+            or jnp.dtype(dtype).itemsize != 2 or dim_head % (LANES // 2)
+            or n < FLASH_MIN_LEN):
+        return None
+    return _cheapest_tiles(n, dim_head, kernel_pattern(pattern))
+
+
+def kernel_pattern(pattern: AttnPattern) -> AttnPattern:
+    """``pattern`` as the kernel and its choice of tiles are keyed: the seed
+    draws the sparse layout alone, so without it the layers of one variant
+    are one pattern, decided and traced once."""
+    if pattern.variant == "sparse":
+        return pattern
+    return dataclasses.replace(pattern, layout_seed=0)
+
+
+@functools.lru_cache(maxsize=64)
+def _cheapest_tiles(n: int, dim_head: int,
+                    pattern: AttnPattern) -> Optional[Tuple[int, int]]:
+    from . import attention_pallas as ap
+
+    n_pad = -(-n // LANES) * LANES
+    best = None
+    for tile in range(n_pad, 0, -LANES):
+        if n_pad % tile:
+            continue
+        blocks = ap._pattern_blocks(pattern, n, n_pad, tile, tile)
+        computed = blocks.counts[1] + blocks.counts[2]
+        if (computed > FLASH_MAX_BLOCKS or ap._vmem_resident_bytes(
+                n_pad, dim_head, 2, tile, tile, blocks.tiles.shape[0],
+                has_bias=True) > ap.VMEM_BUDGET_BYTES):
+            continue
+        cost = computed * (tile * tile + FLASH_BLOCK_COST)
+        if best is None or cost < best[0]:
+            best = cost, tile
+    return best and (best[1], best[1])
+
+
+class KernelMesh(NamedTuple):
+    """Where the kernel call is split under a plan: the mesh, the axes the
+    batch is sharded over and the axis the heads are (None: whole)."""
+    mesh: Any
+    batch_axes: Tuple[str, ...]
+    head_axis: Optional[str]
+
+    @property
+    def batch_ways(self) -> int:
+        return int(np.prod([self.mesh.shape[a] for a in self.batch_axes]))
+
+    @property
+    def head_ways(self) -> int:
+        return self.mesh.shape[self.head_axis] if self.head_axis else 1
+
+
+_kernel_mesh: List[KernelMesh] = []
+_choices: List[dict] = []   # per open record: {layer's pattern: choice}
+
+
+@contextlib.contextmanager
+def kernel_mesh(partitioner) -> Iterator[None]:
+    """The step factories open this around the model's trace: a Mosaic
+    kernel cannot be partitioned by GSPMD, so under a plan's mesh the kernel
+    call runs inside a ``shard_map`` with q, k, v, o split over the batch
+    axes and (the ``tp`` rules' head axis) the heads, whole on the sequence
+    and ``dim_head``.  No partitioner, one device: no wrap."""
+    if partitioner is None or partitioner.mesh.size == 1:
+        yield
+        return
+    mesh = partitioner.mesh
+    _kernel_mesh.append(KernelMesh(
+        mesh, tuple(partitioner.batch_axes),
+        "tp" if mesh.shape.get("tp", 1) > 1 else None))
+    try:
+        yield
+    finally:
+        _kernel_mesh.pop()
+
+
+@contextlib.contextmanager
+def record_kernel_choices(model: str) -> Iterator[None]:
+    """Collect every attention layer's choice during one trace of a model
+    and say what was chosen: one ``attention.kernel`` telemetry record and
+    three gauges (flash layers, dense layers, and the share of blocks the
+    flash layers compute, skipped blocks left out).  A layer traced twice
+    (a reversible stack's custom VJP) counts once: its pattern is its key."""
+    _choices.append({})
+    try:
+        yield
+    finally:
+        layers = list(_choices.pop().values())
+        if layers:
+            flash = [c for c in layers if c["tiles"] is not None]
+            computed = sum(c["computed"] for c in flash)
+            blocks = sum(c["blocks"] for c in flash)
+            counts = {
+                "flash_layers": len(flash),
+                "dense_layers": len(layers) - len(flash),
+                "blocks_computed_share":
+                    round(computed / blocks, 4) if blocks else 0.0}
+            telemetry.emit(
+                "attention", "kernel", model=model,
+                n=max(c["n"] for c in layers),
+                tiles=sorted({"x".join(map(str, c["tiles"])) for c in flash}),
+                **counts)
+            reg = metrics.active()
+            if reg is not None:
+                for name, value in counts.items():
+                    reg.gauge(f"graft_attn_{name}",
+                              "the model's last trace (attention.kernel)"
+                              ).set(value)
+
+
+def dense_attention(pattern: AttnPattern, act_dtype, q, k, v, mask,
+                    grouped: bool = False):
+    """Scores, masked softmax and ``attn.v`` as plain XLA ops: the ``[b, h,
+    n, n]`` float32 scores are written out, the probabilities cast to the
+    activations' dtype.  ``grouped``: fewer key heads than query heads."""
+    n = q.shape[2]
+    scale = q.shape[-1] ** -0.5
+    if grouped:
+        dots = grouped_dots(q * scale, k)
+    else:
+        dots = jnp.einsum("bhid,bhjd->bhij", q * scale, k,
+                          preferred_element_type=jnp.float32)
+    allow = jnp.asarray(dense_pattern_mask(pattern, n, n))[None, None]
+    allow = _merge_key_pad_mask(pattern, allow, mask)
+    dots = jnp.where(allow, dots, max_neg_value(dots.dtype))
+    attn = jax.nn.softmax(dots, axis=-1).astype(act_dtype)
+    if grouped:
+        return grouped_values(attn, v)
+    # graftlint: disable=DOT001 (uniform: attn is cast to the activations' dtype above, matching v; parity pinned by tests/attention_refs)
+    return jnp.einsum("bhij,bhjd->bhid", attn, v)
+
+
+class _Core(NamedTuple):
+    """What a switched attention core is built from (static, hashable)."""
+    pattern: AttnPattern
+    act_dtype: Any
+    tiles: Tuple[int, int]
+    mesh: Optional[KernelMesh]
+    forced: bool        # ``use_pallas``: the kernel on any platform
+
+    def halves(self, q, mask):
+        """The kernel's forward and backward for ``q``-shaped arguments
+        (``ops/attention_pallas.py::flash_attention_halves``), taking the
+        key padding mask as the model has it; under a plan's mesh
+        (:func:`kernel_mesh`) each inside a ``shard_map`` over the batch
+        and head axes, whole on the sequence and ``dim_head``."""
+        from .attention_pallas import flash_attention_halves
+
+        pattern, mesh = self.pattern, self.mesh
+        shard = q if mesh is None else jax.ShapeDtypeStruct(
+            (q.shape[0] // mesh.batch_ways, q.shape[1] // mesh.head_ways,
+             *q.shape[2:]), q.dtype)
+        fwd, bwd = flash_attention_halves(
+            shard, pattern, mask is not None, block_q=self.tiles[0],
+            block_k=self.tiles[1], cache_kernels=not self.forced)
+
+        def forward(q, k, v, mask):
+            bias = None
+            if mask is not None:
+                pad = _scope_key_pad(pattern, mask, q.shape[2])
+                bias = jnp.where(pad, 0.0, -1e30).astype(jnp.float32)
+            return fwd(q, k, v, bias)
+
+        def backward(residuals, g):
+            return bwd(residuals, g)[:3]
+
+        if mesh is None:
+            return forward, backward
+        from jax.sharding import PartitionSpec as P
+
+        batch, head = mesh.batch_axes, mesh.head_axis
+        split = P(batch, head, None, None)  # graftlint: disable=PLAN001 (shard_map arg placement of activations q, k, v, o: batch and heads over the plan's own axes; not a param-tree sharding, so the rule table does not apply)
+        rows = P(batch + ((head,) if head else ()), None, None)  # graftlint: disable=PLAN001 (same: the kernel's flat [batch*head, n, dh] residuals)
+        per_sample = P(batch, None, None)  # graftlint: disable=PLAN001 (same: the padded key bias, one row a sample)
+        key_mask = P(batch, None)  # graftlint: disable=PLAN001 (same: the [b, m] key padding mask)
+        # residuals: q, k, v (flat, padded), the padded bias, o, logsumexp
+        res = (rows, rows, rows, None if mask is None else per_sample, rows,
+               rows)
+        wrap = functools.partial(jax.shard_map, mesh=mesh.mesh,
+                                 check_vma=False)
+        return (wrap(forward, in_specs=(split, split, split,
+                                        None if mask is None else key_mask),
+                     out_specs=(split, res)),
+                wrap(backward, in_specs=(res, split),
+                     out_specs=(split, split, split)))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _switched_core(core: _Core, q, k, v, mask):
+    """The attention core as the kernel where the program is lowered for a
+    TPU and as :func:`dense_attention` anywhere else
+    (``jax.lax.platform_dependent``, forward and backward each: no
+    ``jax.default_backend()``, and a compile from a CPU host for a described
+    chip gets the kernel).  One VJP around both, so that differentiation
+    never goes through the switch: the kernel brings its own backward, and
+    the dense branch's is ``jax.vjp`` of the same function on the saved q,
+    k, v (it recomputes its scores; on the platforms that run it, the
+    arithmetic of the dense branch called directly, bit for bit).  Both
+    switches are jitted on the static ``core``: the layers of one variant
+    agree on it and on their shapes, so a model traces and lowers each
+    switch, with both of its branches, once a variant and not once a layer
+    (``lucid1024``'s twelve layers: once)."""
+    return _switched_fwd(core, q, k, v, mask)[0]
+
+
+def _switched_fwd(core: _Core, q, k, v, mask):
+    out, residuals = _forward_switch(core, q, k, v, mask)
+    return out, (q, k, v, mask, residuals)
+
+
+def _switched_bwd(core: _Core, saved, g):
+    return (*_backward_switch(core, *saved, g), None)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _forward_switch(core: _Core, q, k, v, mask):
+    forward, _ = core.halves(q, mask)
+    if core.forced:
+        return forward(q, k, v, mask)
+    blank = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                         jax.eval_shape(forward, q, k, v, mask)[1])
+
+    def dense(q, k, v, mask):
+        return dense_attention(core.pattern, core.act_dtype, q, k, v,
+                               mask), blank
+
+    return jax.lax.platform_dependent(q, k, v, mask, tpu=forward,
+                                      default=dense)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _backward_switch(core: _Core, q, k, v, mask, residuals, g):
+    _, backward = core.halves(q, mask)
+
+    def kernel(q, k, v, mask, residuals, g):
+        return backward(residuals, g)
+
+    def dense(q, k, v, mask, residuals, g):
+        with prof.scope("attn-scores"):
+            return jax.vjp(lambda q, k, v: dense_attention(
+                core.pattern, core.act_dtype, q, k, v, mask), q, k, v)[1](g)
+
+    switch = kernel if core.forced else functools.partial(
+        jax.lax.platform_dependent, tpu=kernel, default=dense)
+    return switch(q, k, v, mask, residuals, g)
+
+
+_switched_core.defvjp(_switched_fwd, _switched_bwd)
+
+
 class MultiHeadAttention(nn.Module):
     """One attention layer of any variant (see module docstring).
 
@@ -436,14 +739,6 @@ class MultiHeadAttention(nn.Module):
             qkv = qkv.transpose(2, 0, 3, 1, 4)  # [3, b, heads, n, dh]
             return qkv[0], qkv[1], qkv[2]
 
-    def _key_pad_bias(self, mask, n):
-        """[b, m] bool key mask -> additive f32 [b, n] bias, same scoping as
-        the dense path (`_scope_key_pad`)."""
-        if mask is None:
-            return None
-        pad = _scope_key_pad(self.pattern, mask, n)
-        return jnp.where(pad, 0.0, -1e30).astype(jnp.float32)
-
     def __call__(self, x, mask=None, deterministic: bool = True,
                  return_kv: bool = False):
         b, n, _ = x.shape
@@ -470,38 +765,10 @@ class MultiHeadAttention(nn.Module):
                 out = sp_attn(q, k, v, axis_name=self.ring_axis,
                               pattern=self.pattern,
                               causal=self.pattern.causal)
-        elif self.use_pallas:
-            from .attention_pallas import flash_pattern_attention
-
-            # always the compiled Mosaic kernel: off-TPU this fails at
-            # lowering instead of silently interpreting (tests that want
-            # the interpreter wrap the call in
-            # ``pltpu.force_tpu_interpret_mode()``)
-            assert self.pallas_block_q >= 1 and self.pallas_block_k >= 1, (
-                f"invalid Pallas block sizes {self.pallas_block_q}x"
-                f"{self.pallas_block_k}")
-            with prof.scope("attn-scores"):
-                out = flash_pattern_attention(
-                    q, k, v, self.pattern,
-                    key_pad_bias=self._key_pad_bias(mask, n),
-                    block_q=self.pallas_block_q, block_k=self.pallas_block_k)
         else:
             with prof.scope("attn-scores"):
-                scale = self.dim_head ** -0.5
-                if self.kv_heads is not None:
-                    dots = grouped_dots(q * scale, k)
-                else:
-                    dots = jnp.einsum("bhid,bhjd->bhij", q * scale, k,
-                                      preferred_element_type=jnp.float32)
-                allow = jnp.asarray(dense_pattern_mask(self.pattern, n, n))[None, None]
-                allow = _merge_key_pad_mask(self.pattern, allow, mask)
-                dots = jnp.where(allow, dots, max_neg_value(dots.dtype))
-                attn = jax.nn.softmax(dots, axis=-1).astype(x.dtype)
-                if self.kv_heads is not None:
-                    out = grouped_values(attn, v)
-                else:
-                    # graftlint: disable=DOT001 (uniform: attn is cast to x.dtype above, matching v; parity pinned by tests/attention_refs)
-                    out = jnp.einsum("bhij,bhjd->bhid", attn, v)
+                out = self._attention_core(q, k, v, mask, x.dtype,
+                                           cached=return_kv)
 
         with prof.scope("attn-out"):
             out = out.astype(x.dtype)
@@ -511,6 +778,53 @@ class MultiHeadAttention(nn.Module):
         if return_kv:
             return out, (k, v)
         return out
+
+    def _attention_core(self, q, k, v, mask, act_dtype,
+                        cached: bool = False):
+        """The attention core of a forward without a cache: the flash kernel
+        where the shape allows (:func:`flash_tiles`) and the program is
+        lowered for a TPU, the dense-masked branch otherwise.  A prefill
+        (``cached``: it returns its keys and values, one batch-1 pass a
+        request) keeps the dense branch, as does flax's shape pass.
+        ``use_pallas`` forces the compiled kernel with the stated tiles on
+        any platform, and fails where it cannot lower."""
+        b, h, n, _ = q.shape
+        if self.use_pallas:
+            # always the compiled Mosaic kernel: off-TPU this fails at
+            # lowering instead of silently interpreting (tests that want
+            # the interpreter wrap the call in
+            # ``pltpu.force_tpu_interpret_mode()``)
+            assert self.pallas_block_q >= 1 and self.pallas_block_k >= 1, (
+                f"invalid Pallas block sizes {self.pallas_block_q}x"
+                f"{self.pallas_block_k}")
+            tiles = (self.pallas_block_q, self.pallas_block_k)
+        else:
+            tiles = None if cached or self.is_initializing() else flash_tiles(
+                n, self.dim_head, q.dtype, self.pattern, self.kv_heads,
+                self.ring_axis)
+        mesh = _kernel_mesh[-1] if _kernel_mesh else None
+        if tiles is not None and mesh is not None:
+            if b % mesh.batch_ways or h % mesh.head_ways:
+                assert not self.use_pallas, (
+                    f"batch {b} x heads {h} do not split over "
+                    f"{mesh.mesh.shape}")
+                tiles = None    # GSPMD's to place: the dense branch
+        if _choices:
+            choice = dict(n=n, tiles=tiles, computed=0, blocks=0)
+            if tiles is not None:
+                from .attention_pallas import block_counts
+
+                skipped, partly, wholly = block_counts(
+                    kernel_pattern(self.pattern), n, *tiles)
+                choice.update(computed=partly + wholly,
+                              blocks=skipped + partly + wholly)
+            _choices[-1][self.pattern] = choice
+        if tiles is None:
+            return dense_attention(self.pattern, act_dtype, q, k, v, mask,
+                                   grouped=self.kv_heads is not None)
+        return _switched_core(
+            _Core(kernel_pattern(self.pattern), jnp.dtype(act_dtype), tiles,
+                  mesh, forced=self.use_pallas), q, k, v, mask)
 
     def _qkv_decode(self, x, qw):
         """Decode-path QKV projection: the f32/bf16 kernel, or — under

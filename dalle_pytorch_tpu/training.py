@@ -28,6 +28,7 @@ import optax
 from jax import shard_map
 
 from .obs import prof
+from .ops.attention import kernel_mesh
 from .utils import guardrails
 
 
@@ -143,7 +144,8 @@ def make_dalle_train_step(dalle, tx, vae=None, donate: bool = True,
     returns the on-device health vector (module docstring).
     ``partitioner`` (the run's mesh Partitioner) pins the updated
     params/opt_state to the input sharding rules so donation survives
-    GSPMD propagation.
+    GSPMD propagation, and tells the attention kernel which mesh axes its
+    call is split over.
     """
 
     def train_step(params, opt_state, vae_params, text, images_or_codes,
@@ -159,7 +161,10 @@ def make_dalle_train_step(dalle, tx, vae=None, donate: bool = True,
             loss = _dalle_loss(dalle, p, text, codes, rng)
             return loss * fault_scale[0] if health else loss
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        # the attention kernel's call is split over the plan's mesh, forward
+        # and backward (ops/attention.py::kernel_mesh)
+        with kernel_mesh(partitioner):
+            loss, grads = jax.value_and_grad(loss_fn)(params)
         if health:
             with prof.scope("optimizer"):
                 params, opt_state, hv = guardrails.guarded_update(
@@ -340,7 +345,10 @@ def make_clip_train_step(clip, tx, donate: bool = True, health: bool = False,
                               text_mask=text_mask, return_loss=True)
             return loss * fault_scale[0] if health else loss
 
-        loss, grads = jax.value_and_grad(loss_fn)(params)
+        # the attention kernel's call is split over the plan's mesh, forward
+        # and backward (ops/attention.py::kernel_mesh)
+        with kernel_mesh(partitioner):
+            loss, grads = jax.value_and_grad(loss_fn)(params)
         if health:
             with prof.scope("optimizer"):
                 params, opt_state, hv = guardrails.guarded_update(

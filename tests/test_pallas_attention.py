@@ -87,10 +87,12 @@ def test_key_padding_bias():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_fully_masked_rows_do_not_leak():
+@pytest.mark.parametrize("variant,causal", [("full", False), ("full", True),
+                                            ("axial_row", True)])
+def test_fully_masked_rows_do_not_leak(variant, causal):
     """A sample whose key_pad_bias drops every key must produce zeros, not a
     uniform average over (causally disallowed) keys."""
-    pattern = make_pattern("full", causal=False)
+    pattern = make_pattern(variant, causal=causal)
     q, k, v = rand_qkv(jax.random.PRNGKey(5))
     bias = jnp.full((B, N), -1e30, jnp.float32)  # drop everything
     out = flash_pattern_attention(q, k, v, pattern, key_pad_bias=bias,
@@ -154,15 +156,16 @@ def test_dalle_use_pallas_matches_dense():
 
 
 def test_block_sparsity_actually_skips():
-    """The block summary must mark disallowed blocks 0 (the compute-skip
+    """The block table must mark disallowed blocks SKIP (the compute-skip
     guarantee: axial patterns touch far fewer blocks than full)."""
-    from dalle_pytorch_tpu.ops.attention_pallas import _pattern_blocks
+    from dalle_pytorch_tpu.ops.attention_pallas import SKIP, _pattern_blocks
 
-    full = _pattern_blocks(make_pattern("full"), N, 24, BLOCK, BLOCK)[1]
-    axial = _pattern_blocks(make_pattern("axial_row"), N, 24, BLOCK, BLOCK)[1]
-    assert axial.sum() <= full.sum()
+    full = _pattern_blocks(make_pattern("full"), N, 24, BLOCK, BLOCK).table
+    axial = _pattern_blocks(make_pattern("axial_row"), N, 24, BLOCK,
+                            BLOCK).table
+    assert (axial != SKIP).sum() <= (full != SKIP).sum()
     # causal: upper-triangle blocks (beyond diagonal) are skipped
-    assert full[0, 1] == 0 and full[0, 2] == 0
+    assert full[0, 1] == SKIP and full[0, 2] == SKIP
 
 
 def test_block_size_config_override(monkeypatch):
@@ -172,14 +175,14 @@ def test_block_size_config_override(monkeypatch):
     from dalle_pytorch_tpu.ops.attention import AttnPattern, MultiHeadAttention
 
     seen = {}
-    orig = ap.flash_pattern_attention
+    orig = ap.flash_attention_halves
 
     def spy(*args, **kwargs):
         seen.update(block_q=kwargs.get("block_q"),
                     block_k=kwargs.get("block_k"))
         return orig(*args, **kwargs)
 
-    monkeypatch.setattr(ap, "flash_pattern_attention", spy)
+    monkeypatch.setattr(ap, "flash_attention_halves", spy)
 
     pattern = AttnPattern(variant="full", seq_len=24, text_len=8, fmap=4)
     attn = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16,
@@ -222,7 +225,7 @@ def test_vmem_budget_guard():
         VMEM_BUDGET_BYTES, _vmem_resident_bytes, flash_pattern_attention)
 
     n = 40960  # ~21 MB of f32 K/V at dh=64: over budget
-    assert _vmem_resident_bytes(n, 64, 4, 128) > VMEM_BUDGET_BYTES
+    assert _vmem_resident_bytes(n, 64, 4, 128, 128) > VMEM_BUDGET_BYTES
     pattern = AttnPattern(variant="full", seq_len=n, text_len=16, fmap=0,
                           causal=True)
     q = jnp.zeros((1, 1, n, 64), jnp.float32)
@@ -237,11 +240,201 @@ def test_vmem_budget_guard():
     try:
         called = {}
         orig = ap._flash_attention
-        ap._flash_attention = lambda *a: called.setdefault("yes", True)
+        ap._flash_attention = lambda *a: called.setdefault("yes", True)  # noqa: E731
         flash_pattern_attention(q, q, q, pattern, interpret=True)
         assert called.get("yes")
     finally:
         ap._flash_attention = orig
 
     # the CUB geometry stays comfortably inside the budget
-    assert _vmem_resident_bytes(1152, 64, 4, 128) < VMEM_BUDGET_BYTES // 4
+    assert _vmem_resident_bytes(1152, 64, 4, 128, 128) < VMEM_BUDGET_BYTES // 4
+
+
+# --- at the train cells' lengths, bf16 (PR 28) ------------------------------
+
+CUB = dict(text=80, fmap=32)        # n = 1104, the cub200 cycle
+LUCID = dict(text=256, fmap=32)     # n = 1280, all full
+AT_WIDTH = [("full", CUB), ("axial_row", CUB), ("axial_col", CUB),
+            ("conv_like", CUB), ("full", LUCID)]
+AT_WIDTH_IDS = ["cub-full", "cub-axial_row", "cub-axial_col",
+                "cub-conv_like", "lucid-full"]
+
+
+def width_pattern(variant, text, fmap):
+    n = text + fmap * fmap
+    return AttnPattern(variant=variant, seq_len=n - 1, text_len=text,
+                       fmap=fmap), n
+
+
+def dense_branch(pattern, dtype):
+    """The model's own dense-masked branch, called directly."""
+    from dalle_pytorch_tpu.ops.attention import dense_attention
+
+    return lambda q, k, v: dense_attention(pattern, dtype, q, k, v, None)
+
+
+@pytest.mark.parametrize("variant,geom", AT_WIDTH, ids=AT_WIDTH_IDS)
+def test_bf16_matches_dense_branch_within_its_own_spread(variant, geom):
+    """bf16 inputs at the train cells' lengths, the tiles the selection
+    gives: forward and gradients lie as close to the float32 dense answer as
+    the dense branch at bf16 does (its spread, measured here, is the
+    yardstick: the kernel rounds where the dense branch rounds)."""
+    from dalle_pytorch_tpu.ops.attention import flash_tiles
+
+    pattern, n = width_pattern(variant, **geom)
+    tiles = flash_tiles(n, 64, jnp.bfloat16, pattern)
+    assert tiles is not None
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k, v = (jax.random.normal(kk, (1, 1, n, 64), jnp.float32)
+               for kk in ks[:3])
+    tangent = jax.random.normal(ks[3], (1, 1, n, 64), jnp.float32)
+
+    def outputs(fn, dtype):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) * tangent), out
+        args = tuple(a.astype(dtype) for a in (q, k, v))
+        grads, out = jax.grad(loss, (0, 1, 2), has_aux=True)(*args)
+        return [np.asarray(a, np.float32) for a in (out, *grads)]
+
+    exact = outputs(dense_branch(pattern, jnp.float32), jnp.float32)
+    dense = outputs(dense_branch(pattern, jnp.bfloat16), jnp.bfloat16)
+    flash = outputs(
+        lambda q, k, v: flash_pattern_attention(
+            q, k, v, pattern, block_q=tiles[0], block_k=tiles[1],
+            interpret=True), jnp.bfloat16)
+    for name, e, d, f in zip(("out", "dq", "dk", "dv"), exact, dense, flash):
+        spread = np.sqrt(np.mean((d - e) ** 2))
+        mine = np.sqrt(np.mean((f - e) ** 2))
+        assert mine <= 1.5 * spread + 1e-6, (name, mine, spread)
+        assert np.abs(f - e).max() <= 2.0 * np.abs(d - e).max() + 1e-6, name
+
+
+@pytest.mark.parametrize("variant,geom", AT_WIDTH, ids=AT_WIDTH_IDS)
+def test_three_block_kinds_counted(variant, geom):
+    """Skipped, partly and wholly allowed blocks of each pattern at the
+    train cells' lengths and the selection's tiles: they add up, a causal
+    pattern skips, ``full`` has wholly allowed blocks below the diagonal,
+    and the distinct mask tiles are far fewer than the partly allowed
+    blocks' rows would be."""
+    from dalle_pytorch_tpu.ops.attention import flash_tiles
+    from dalle_pytorch_tpu.ops.attention_pallas import (
+        PARTIAL, SKIP, WHOLE, _padded_len, _pattern_blocks, block_counts)
+
+    pattern, n = width_pattern(variant, **geom)
+    bq, bk = flash_tiles(n, 64, jnp.bfloat16, pattern)
+    n_pad = _padded_len(n, bq, bk)
+    assert n_pad == -(-n // 128) * 128      # padded to the lanes only
+    blocks = _pattern_blocks(pattern, n, n_pad, bq, bk)
+    skipped, partly, wholly = block_counts(pattern, n, bq, bk)
+    assert skipped + partly + wholly == (n_pad // bq) * (n_pad // bk)
+    assert skipped == (blocks.table == SKIP).sum() > 0
+    assert wholly == (blocks.table == WHOLE).sum()
+    assert partly == (blocks.table >= PARTIAL).sum() > 0
+    assert (wholly > 0) == (variant == "full")
+    assert blocks.tiles.shape[0] <= partly
+    # the table says what the mask says
+    from dalle_pytorch_tpu.ops.attention import dense_pattern_mask
+    mask = np.zeros((n_pad, n_pad), bool)
+    mask[:n, :n] = dense_pattern_mask(pattern, n, n)
+    for qb in range(n_pad // bq):
+        for kb in range(n_pad // bk):
+            blk = mask[qb * bq:(qb + 1) * bq, kb * bk:(kb + 1) * bk]
+            code = blocks.table[qb, kb]
+            if code >= PARTIAL:
+                np.testing.assert_array_equal(
+                    blocks.tiles[code - PARTIAL].astype(bool), blk)
+            else:
+                assert blk.all() if code == WHOLE else not blk.any()
+
+
+@pytest.mark.parametrize("variant", ["full", "axial_col"])
+def test_wholly_allowed_blocks_change_nothing(variant):
+    """Treating every computed block as partly allowed (mask tile + select
+    everywhere) gives the same bits: the wholly allowed kind only leaves
+    work out."""
+    text, fmap = 16, 16
+    pattern, n = width_pattern(variant, text, fmap)
+    q, k, v = (jax.random.normal(kk, (1, 2, n, 16), jnp.float32)
+               for kk in jax.random.split(jax.random.PRNGKey(8), 3))
+
+    def run(all_partial):
+        fn = lambda q, k, v: jnp.sum(flash_pattern_attention(  # noqa: E731
+            q, k, v, pattern, block_q=64, block_k=64, interpret=True,
+            all_partial=all_partial) ** 2)
+        return jax.value_and_grad(fn, (0, 1, 2))(q, k, v)
+
+    for a, b in zip(jax.tree.leaves(run(False)), jax.tree.leaves(run(True))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _layer_pair(variant="axial_row", **kw):
+    from dalle_pytorch_tpu.ops.attention import MultiHeadAttention
+
+    pattern = AttnPattern(variant=variant, seq_len=24, text_len=8, fmap=4)
+    dense = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16)
+    flash = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16,
+                               use_pallas=True, **kw)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
+    return dense, flash, dense.init(jax.random.PRNGKey(1), x), x
+
+
+@pytest.mark.parametrize("variant", ["full", "axial_row"])
+def test_layer_key_padding_mask(variant):
+    """``MultiHeadAttention(mask=)`` reaches the kernel as ``key_pad_bias``
+    with the per-variant scope of ``_scope_key_pad`` (every key for full,
+    the text keys for the sparse variants)."""
+    dense, flash, params, x = _layer_pair(variant)
+    mask = jnp.asarray(np.r_[[[True] * 5 + [False] * 3],
+                             [[True] * 8]])          # text keys, [b, 8]
+    ref = dense.apply(params, x, mask=mask)
+    with pltpu.force_tpu_interpret_mode():
+        out = flash.apply(params, x, mask=mask)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_kernel_under_checkpoint():
+    """The custom VJP survives ``jax.checkpoint`` (``use_remat`` wraps the
+    block in one): the gradients of the dense reference.  On the plain
+    interpreter: the TPU interpreter's callbacks are effects, which
+    ``checkpoint`` does not take; ``tests/test_tpu_compile.py`` compiles the
+    rematerialised layer for the chip."""
+    pattern = make_pattern("axial_row")
+    q, k, v = rand_qkv(jax.random.PRNGKey(6))
+
+    def flash(q, k, v):
+        return jnp.sum(flash_pattern_attention(
+            q, k, v, pattern, block_q=BLOCK, block_k=BLOCK,
+            interpret=True) ** 2)
+
+    got = jax.grad(jax.checkpoint(flash), (0, 1, 2))(q, k, v)
+    ref = jax.grad(lambda q, k, v: jnp.sum(
+        dense_reference(q, k, v, pattern) ** 2), (0, 1, 2))(q, k, v)
+    for a, b in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_backward_kernels_carry_the_forwards_scope():
+    """All three ``pallas_call``s of a differentiated layer sit under
+    ``graftprof:attn-scores`` (a custom VJP's backward does not inherit the
+    forward's name scope by itself)."""
+    _, flash, params, x = _layer_pair()
+    with pltpu.force_tpu_interpret_mode():
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: jnp.sum(flash.apply(p, x) ** 2)))(params)
+
+    def stacks(jaxpr, outer=""):
+        # an equation inside a nested jit names its scopes from that jit on:
+        # the jit's own equation carries the rest
+        for eqn in jaxpr.eqns:
+            here = f"{outer}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "pallas_call":
+                yield here
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from stacks(sub, here)
+
+    found = list(stacks(jaxpr.jaxpr))
+    assert len(found) == 3
+    assert all("graftprof:attn-scores" in s for s in found), found

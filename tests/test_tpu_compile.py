@@ -112,28 +112,51 @@ def test_kernels_compile_at_cub_shape(one_chip, variant, blocks, grad):
 
 
 @pytest.mark.parametrize("blocks,grad", [
-    ((128, 128), False), ((128, 128), True), ((256, 512), False)],
-    ids=["b128x128-fwd", "b128x128-grad", "b256x512-fwd"])
+    ((384, 384), False), ((384, 384), True), ((256, 512), False)],
+    ids=["b384x384-fwd", "b384x384-grad", "b256x512-fwd"])
 def test_kernels_compile_at_fmap64_shape(one_chip, blocks, grad):
-    """The fmap-64 shape (b4, h8, n4176, dh64) of the long-sequence A/B."""
+    """The fmap-64 shape (b4, h8, n4176, dh64) of the long-sequence A/B, at
+    the tiles the selection gives there (384: the kernels' loops are
+    unrolled, and 128 x 128 would leave 561 blocks to each) and one pair
+    more."""
     compiled = _compile_attention(one_chip, "full", *blocks,
                                   (4, 8, 4176, 64), grad, fmap=64)
     assert compiled.as_text().count("tpu_custom_call") == (3 if grad else 1)
 
 
-@pytest.mark.parametrize("blocks,grad", [((256, 512), True),
-                                         ((512, 512), False)],
-                         ids=["b256x512-grad", "b512x512-fwd"])
-def test_compiler_refuses_large_tiles_at_fmap64(one_chip, blocks, grad):
-    """The compiler's answer where the VMEM guard only estimates
-    (ops/attention_pallas.py::_vmem_resident_bytes counts the bool mask
-    rows at 1 byte per element and passes these): at n = 4176 the 512-wide
-    mask tiles do not fit VMEM.  ROADMAP S3 carries the open item; when the
-    kernel or its guard is repaired this test is the one to turn round."""
-    with pytest.raises(Exception, match="vmem"):
-        _compile_attention(one_chip, "full", *blocks, (4, 8, 4176, 64),
-                           grad, fmap=64)
+@pytest.mark.parametrize("blocks,grad,fits", [
+    ((256, 512), True, True), ((512, 512), False, True),
+    ((2304, 2304), True, True), ((4224, 4224), True, False)],
+    ids=["b256x512-grad", "b512x512-fwd", "b2304x2304-grad",
+         "b4224x4224-grad"])
+def test_compiler_refuses_large_tiles_at_fmap64(one_chip, blocks, grad, fits):
+    """Turned round in PR 28: the compiler's answer and the guard's estimate
+    (ops/attention_pallas.py::_vmem_resident_bytes), side by side, at n =
+    4176.  Until PR 28 the kernel held ``[block_q, n_pad]`` bool mask rows
+    that the estimate counted at a byte an element, and the compiler refused
+    the first two pairs while the guard passed them.  The kernel now holds
+    the pattern's distinct mask tiles once and may take 96 MiB: both accept
+    every tiling up to 2304 x 2304, both refuse one 4224 x 4224 block
+    backward (Mosaic wants 113 MiB)."""
+    from dalle_pytorch_tpu.ops import attention_pallas as ap
 
+    n_pad = ap._padded_len(4176, *blocks)
+    pattern = AttnPattern(variant="full", seq_len=4175, text_len=80, fmap=64)
+    tiles = ap._pattern_blocks(pattern, 4176, n_pad, *blocks).tiles.shape[0]
+    estimate = ap._vmem_resident_bytes(n_pad, 64, 2, *blocks, tiles)
+    assert (estimate <= ap.VMEM_BUDGET_BYTES) == fits
+    if fits:
+        _compile_attention(one_chip, "full", *blocks, (4, 8, 4176, 64), grad,
+                           fmap=64)
+        return
+    with pytest.raises(ValueError, match="VMEM"):    # the guard, at the edge
+        _compile_attention(one_chip, "full", *blocks, (4, 8, 4176, 64), grad,
+                           fmap=64)
+    with pytest.MonkeyPatch.context() as patch:      # the compiler, past it
+        patch.setattr(ap, "VMEM_BUDGET_BYTES", 10 ** 12)
+        with pytest.raises(Exception, match="vmem"):
+            _compile_attention(one_chip, "full", *blocks, (4, 8, 4176, 64),
+                               grad, fmap=64)
 
 # --- model level: use_pallas really lowers the kernel -----------------------
 
@@ -152,6 +175,104 @@ def test_model_with_use_pallas_holds_the_kernel(one_chip):
         lambda p, t, c: model.apply({"params": p}, t, c, return_loss=True))
     ).lower(_on(one_chip, shapes), batch, codes).compile()
     assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+# --- the default: the kernel chosen by shape, where the step is lowered ------
+
+def _kernel_calls(hlo_text):
+    return [line for line in hlo_text.splitlines()
+            if "tpu_custom_call" in line and " custom-call(" in line]
+
+
+def _train_cell_step(name, devices):
+    from benchmark import harness
+
+    cell = harness.load_cell(name)
+    dalle_cfg, vae_cfg = harness.build_configs(cell.config)
+    b = harness.load_driver(cell).build(cell, devices[:cell.chips],
+                                        dalle_cfg, vae_cfg)
+    return dalle_cfg, b["step"].lower(*b["abstract"]).compile()
+
+
+def _assert_flash_step(cfg, compiled):
+    """Three kernels a layer, each under ``graftprof:attn-scores`` (or the
+    trace would read the forward alone), and no ``f32[.., n, n]`` left."""
+    text = compiled.as_text()
+    calls = _kernel_calls(text)
+    assert len(calls) == 3 * cfg.depth
+    assert all("graftprof:attn-scores" in line for line in calls)
+    n = cfg.seq_len
+    for side in {n, -(-n // 128) * 128}:
+        assert not re.search(rf"f32\[[\d,]*{side},{side}\]", text), side
+
+
+def test_default_cub200_train_step_holds_the_kernel(topo):
+    """``cub200-train``'s step as the benchmark builds it (batch 16, the VAE
+    inside, no ``use_pallas``): 24 kernels, and the compiler plans under
+    half the 7.57 GB the dense scores took (ledger, PR 27)."""
+    cfg, compiled = _train_cell_step("cub200-train", topo.devices)
+    assert not cfg.use_pallas
+    _assert_flash_step(cfg, compiled)
+    mem = compiled.memory_analysis()
+    assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            < 3.8 * 2 ** 30)
+
+
+def test_default_lucid1024_dp_step_splits_the_kernel(topo):
+    """``lucid1024-train-dp4``'s step under the ``dp`` plan on the described
+    2x2: the kernel calls run inside a ``shard_map`` over the batch axis (a
+    Mosaic kernel cannot be partitioned by GSPMD), so the step compiles, holds
+    no all-gather, and its collectives are the gradient all-reduces alone:
+    559.6 MB of result bytes, ``collective_bytes_per_step`` of the ledger's
+    PR 27."""
+    from benchmark.layer_metrics import collective_bytes_per_step as reader
+
+    cfg, compiled = _train_cell_step("lucid1024-train-dp4", topo.devices)
+    _assert_flash_step(cfg, compiled)
+    text = compiled.as_text()
+    assert " all-gather(" not in text and " all-gather-start(" not in text
+
+    class Run:
+        devices = topo.devices
+        outcome = type("Outcome", (), {
+            "programs": {"step": compiled}, "main_program": "step"})
+
+    assert round(reader.read(Run) / 1e6, 1) == 559.6
+
+
+def test_rematerialised_layer_compiles(one_chip):
+    """``use_remat``: the custom VJP under ``jax.checkpoint`` compiles for
+    the chip (forward, its recomputation, dq, dk/dv: 4 kernels a layer)."""
+    cfg = dataclasses.replace(bench.cub200_config(), depth=1, use_remat=True)
+    model, shapes = _param_shapes(cfg)
+    batch = jax.ShapeDtypeStruct((16, cfg.text_seq_len), jnp.int32,
+                                 sharding=one_chip)
+    codes = jax.ShapeDtypeStruct((16, cfg.image_seq_len), jnp.int32,
+                                 sharding=one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda p, t, c: model.apply({"params": p}, t, c, return_loss=True))
+    ).lower(_on(one_chip, shapes), batch, codes).compile()
+    assert len(_kernel_calls(compiled.as_text())) == 4
+
+
+@pytest.mark.parametrize("cell", ["lucid1024-generate", "cub200-generate",
+                                  "jamba2-3b-generate"])
+def test_generate_prefill_holds_no_kernel(one_chip, cell):
+    """The generate cells' prefill (one batch-1 pass a request, over the
+    whole padded sequence) keeps the dense branch: lowered for the chip, the
+    program holds no custom call."""
+    from benchmark import harness
+    from dalle_pytorch_tpu.models.dalle import prefill_codes
+
+    cell = harness.load_cell(cell)
+    cfg = harness.build_configs(cell.config)[0]
+    model, shapes = _param_shapes(cfg)
+    lowered = jax.jit(
+        lambda v, t: prefill_codes(model, v, t)).lower(
+        {"params": _on(one_chip, shapes)},
+        jax.ShapeDtypeStruct((1, cfg.text_seq_len), jnp.int32,
+                             sharding=one_chip))
+    assert "tpu_custom_call" not in lowered.as_text()
 
 
 # --- the serving entry points at CUB width ----------------------------------
