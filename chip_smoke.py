@@ -578,10 +578,10 @@ def phase_pallas(size: SmokeSize, platform: str) -> list:
 # --- --chips 4: the sharded step --------------------------------------------
 
 def cub_config(**overrides):
-    """The CUB-200 model the trainer builds (``bench.cub200_config``)."""
-    import bench
+    """The CUB-200 model the trainer builds (``presets.cub200_config``)."""
+    from dalle_pytorch_tpu.presets import cub200_config
 
-    return dataclasses.replace(bench.cub200_config(), **overrides)
+    return dataclasses.replace(cub200_config(), **overrides)
 
 
 def plan_step(spec: str, devices, cfg, batch: int):

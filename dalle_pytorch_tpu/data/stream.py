@@ -37,7 +37,7 @@ module replaces the *storage* layer while keeping the *iteration* contract:
 :class:`DevicePrefetcher` is the last host stall remover: it pulls (and
 optionally device-places) the next batch while the current step runs, and
 meters the time the step loop actually waited on the input pipeline — the
-``loader_stall_s`` metric the heartbeat/monitor/bench surfaces report, so
+``loader_stall_s`` metric the heartbeat and the monitor report, so
 an input-bound run is visible instead of mislabeled "slow chip".
 """
 from __future__ import annotations

@@ -67,13 +67,6 @@ class DALLEConfig:
     image_fmap_size: int = 32
     # TPU-native extras
     use_remat: bool = False
-    use_pallas: bool = False   # Pallas flash/block-sparse attention
-    pallas_block_q: int = 128  # Pallas tile sizes (perf_ab sweeps these)
-    pallas_block_k: int = 128
-    logits_bf16: bool = False  # head matmul in bf16 (f32 accumulate)
-    onehot_embed: bool = False  # loss-path embeds via one-hot matmul (MXU
-    #                             backward instead of scatter-add); inference
-    #                             forwards keep the gather
     # MoE feed-forward (model hyperparameters — they change the param tree)
     ff_experts: int = 0        # >1: MoE FF with this many experts
     ff_expert_top_k: int = 2
@@ -91,17 +84,6 @@ class DALLEConfig:
     ring_axis: Optional[str] = None  # mesh axis name, e.g. "sp"
     sp_impl: str = "ring"            # 'ring' | 'ulysses'
     sp_size: int = 1                 # ways of the sp axis (static shard count)
-    # Training-loss head strategy: True runs one matmul per vocab phase
-    # (text positions x text head, image positions x image head — skips the
-    # cross-phase half of the compute, bit-identical loss).  False computes
-    # both phases for every position then slices (the A/B control).  The
-    # head is stored per-phase either way (PhaseLogits), so tp meshes keep
-    # the sliced path: each phase kernel tp-shards on its own vocab dim.
-    head_phase_sliced: bool = True
-    # Decode-time cache-read strategy (ops/attention.py::decode_key_positions):
-    # True gathers only the reachable keys per step, False streams the full
-    # cache — the measured A/B control (tools/perf_ab.py `gen-dense`).
-    sliced_kv_decode: bool = True
     # Decode-time KV-cache STORAGE dtype: True keeps the caches in bf16 even
     # when activations are f32 (checkpoint-loaded eval models default to
     # f32).  The decode loop is measured HBM-bound on cache traffic
@@ -109,8 +91,8 @@ class DALLEConfig:
     # cut to its dominant stream; attention still *accumulates* in f32
     # (ops/attention.py::decode_step computes all q·k dots with
     # preferred_element_type=f32 and softmaxes in f32), so only the stored
-    # k/v values round through bf16.  False is the A/B control
-    # (tools/perf_ab.py `gen_f32cache`).  No-op when dtype is already bf16.
+    # k/v values round through bf16.  False is the control no cell has
+    # judged yet.  No-op when dtype is already bf16.
     kv_cache_bf16: bool = True
     # Int8 cache storage (takes precedence over kv_cache_bf16): the caches
     # become (int8 values, f32 per-head scale) pairs — ops/quant.py layout
@@ -168,10 +150,14 @@ class DALLEConfig:
     # they select how the same params are computed, not what the model is
     _PLAN_FIELDS = ("ring_axis", "sp_impl", "sp_size",
                     "ff_expert_dispatch", "ff_expert_capacity_factor",
-                    "head_phase_sliced", "sliced_kv_decode", "kv_cache_bf16",
-                    "kv_cache_int8", "weights_int8", "aligned_span_decode",
-                    "spec_decode", "spec_draft_depth", "spec_k",
-                    "spec_force_reject")
+                    "kv_cache_bf16", "kv_cache_int8", "weights_int8",
+                    "aligned_span_decode", "spec_decode", "spec_draft_depth",
+                    "spec_k", "spec_force_reject")
+    # execution switches that no longer exist; checkpoints written while
+    # they did carry them in their hparams (from_dict drops them)
+    _RETIRED_FIELDS = ("use_pallas", "pallas_block_q", "pallas_block_k",
+                       "logits_bf16", "onehot_embed", "head_phase_sliced",
+                       "sliced_kv_decode")
 
     def __post_init__(self):
         if isinstance(self.trunk, dict):
@@ -179,7 +165,7 @@ class DALLEConfig:
         if self.trunk is not None:
             # a recurrent layer has no meaning yet on these paths
             for field in ("reversible", "spec_decode", "weights_int8",
-                          "kv_cache_int8", "use_pallas", "sparse_attn"):
+                          "kv_cache_int8", "sparse_attn"):
                 assert not getattr(self, field), (
                     f"{field} is not supported over a TrunkSpec trunk (its "
                     "state-space layers carry a recurrent state, not keys)")
@@ -248,8 +234,8 @@ class DALLEConfig:
 
     @classmethod
     def from_dict(cls, d: dict, **overrides) -> "DALLEConfig":
-        d = {k: v for k, v in d.items()
-             if k not in cls._PLAN_FIELDS}  # tolerate old ckpts carrying them
+        d = {k: v for k, v in d.items()  # tolerate old ckpts carrying them
+             if k not in cls._PLAN_FIELDS + cls._RETIRED_FIELDS}
         if d.get("attn_types") is not None:
             d["attn_types"] = tuple(d["attn_types"])
         d.update(overrides)
@@ -282,8 +268,7 @@ class PhaseLogits(nn.Module):
       vocab dim, so the phase boundary is a parameter boundary, never an
       interior slice.  A slice at ``total_text`` (7880 at CUB geometry)
       inside a single tp-sharded kernel can't align with the equal-width
-      shard boundaries GSPMD requires, forcing a per-step reshard — the
-      round-2 reason ``head_phase_sliced`` auto-disabled under tp.
+      shard boundaries GSPMD requires, forcing a per-step reshard.
 
     Joint-vocab callers get ``concat(text, image)`` — XLA folds a
     downstream phase slice of that concat back to the operand, so the
@@ -291,15 +276,10 @@ class PhaseLogits(nn.Module):
 
     Legacy single-kernel checkpoints are upgraded by
     ``utils.checkpoint.migrate_head_kernels`` (an exact column split).
-
-    ``bf16_matmul`` runs the matmuls with bf16 inputs and f32 accumulation
-    (the MXU's native mode, ~4x the f32 rate); params and the returned
-    logits stay f32.
     """
 
     total_text: int
     total: int
-    bf16_matmul: bool = False
 
     @nn.compact
     def __call__(self, x, image_only: bool = False, text_only: bool = False):
@@ -330,12 +310,7 @@ class PhaseLogits(nn.Module):
         outs = []
         for phase in wanted:
             kernel, bias = phases[phase]
-            if self.bf16_matmul:
-                outs.append(jnp.dot(x.astype(jnp.bfloat16),
-                                    kernel.astype(jnp.bfloat16),
-                                    preferred_element_type=jnp.float32) + bias)
-            else:
-                outs.append(x @ kernel + bias)
+            outs.append(x @ kernel + bias)
         return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=-1)
 
 
@@ -376,11 +351,8 @@ def transformer_kwargs(cfg: DALLEConfig) -> dict:
         attn_dropout=cfg.attn_dropout, ff_dropout=cfg.ff_dropout,
         attn_types=tuple(attn_types), image_fmap_size=cfg.image_fmap_size,
         text_len=cfg.text_seq_len + 1, reversible=cfg.reversible,
-        use_remat=cfg.use_remat, use_pallas=cfg.use_pallas,
-        pallas_block_q=cfg.pallas_block_q,
-        pallas_block_k=cfg.pallas_block_k,
+        use_remat=cfg.use_remat,
         ring_axis=cfg.ring_axis, sp_impl=cfg.sp_impl,
-        sliced_kv_decode=cfg.sliced_kv_decode,
         aligned_span_decode=cfg.aligned_span_decode,
         ff_experts=cfg.ff_experts, ff_expert_top_k=cfg.ff_expert_top_k,
         ff_expert_dispatch=cfg.ff_expert_dispatch,
@@ -429,7 +401,6 @@ class DALLE(nn.Module):
         self.final_norm = nn.LayerNorm(dtype=jnp.float32, name="final_norm")
         self.to_logits_dense = PhaseLogits(cfg.total_text_tokens,
                                            cfg.total_tokens,
-                                           bf16_matmul=cfg.logits_bf16,
                                            name="to_logits_dense")
 
     # --- embedding helpers ---
@@ -442,45 +413,28 @@ class DALLE(nn.Module):
             cfg.total_text_tokens - cfg.text_seq_len)
         return jnp.where(text == 0, text_range, text)
 
-    def _lookup(self, table: nn.Embed, ids, onehot: bool):
-        """Token lookup; with ``onehot`` the gather becomes a one-hot matmul
-        whose transpose (the embedding gradient) is a plain matmul on the
-        MXU instead of a scatter-add.  HIGHEST precision keeps the forward
-        bit-exact with the gather — TPU's default f32 matmul precision would
-        round the selected rows through bf16."""
-        if onehot:
-            oh = jax.nn.one_hot(ids, table.num_embeddings,
-                                dtype=table.embedding.dtype)
-            # graftlint: disable=DOT001 (uniform: oh is built in the table dtype; HIGHEST precision pins the f32-exact product)
-            return jnp.dot(oh, table.embedding,
-                           precision=jax.lax.Precision.HIGHEST)
-        return table(ids)
-
-    def _embed_text(self, text, onehot: bool = False):
+    def _embed_text(self, text):
         """Unique-pad remap + <bos> + token/pos embeddings (ref :440-448)."""
         cfg = self.cfg
         assert text.shape[-1] == cfg.text_seq_len, (
             f"text length {text.shape[-1]} != text_seq_len {cfg.text_seq_len}"
         )
         text = jnp.pad(self._remap_pad_tokens(text), ((0, 0), (1, 0)))  # <bos> id 0
-        tokens = self._lookup_ids(text, False, onehot)
+        tokens = self._lookup_ids(text, False)
         tokens = tokens + self.text_pos_emb(jnp.arange(text.shape[1]))
         return tokens.astype(cfg.dtype)
 
-    def _lookup_ids(self, ids, image: bool, onehot: bool = False):
+    def _lookup_ids(self, ids, image: bool):
         """Token embeddings of text ids or image codes: each phase's own
         table, or its rows of the tied table (image codes after the text
         vocabulary, as the joint logits order them)."""
         cfg = self.cfg
         if cfg.trunk is not None:
-            return self._lookup(
-                self.table, ids + cfg.total_text_tokens if image else ids,
-                onehot)
-        return self._lookup(self.image_emb if image else self.text_emb, ids,
-                            onehot)
+            return self.table(ids + cfg.total_text_tokens if image else ids)
+        return (self.image_emb if image else self.text_emb)(ids)
 
-    def _embed_image_codes(self, codes, onehot: bool = False):
-        emb = self._lookup_ids(codes, True, onehot)
+    def _embed_image_codes(self, codes):
+        emb = self._lookup_ids(codes, True)
         emb = emb + self.image_pos_emb(codes.shape[1])
         return emb.astype(self.cfg.dtype)
 
@@ -508,16 +462,16 @@ class DALLE(nn.Module):
 
     # --- main forward (ref :428-500) ---
 
-    def embed_sequence(self, text, image_codes=None, onehot: bool = False):
+    def embed_sequence(self, text, image_codes=None):
         """[bos+text | image] token embeddings, truncated to seq_len (ref
         :440-475) — the input to the transformer stack.  Exposed as a
         method so the pipeline-parallel trainer can run embeddings outside
         the pipelined stack (training.py::make_dalle_pp_train_step)."""
         cfg = self.cfg
         with prof.scope("embed"):
-            tokens = self._embed_text(text, onehot)
+            tokens = self._embed_text(text)
             if image_codes is not None and image_codes.shape[1] > 0:
-                image_emb = self._embed_image_codes(image_codes, onehot)
+                image_emb = self._embed_image_codes(image_codes)
                 tokens = jnp.concatenate([tokens, image_emb], axis=1)
             # drop the final token when the sequence overflows (ref :473-475)
             if tokens.shape[1] > cfg.seq_len:
@@ -576,21 +530,15 @@ class DALLE(nn.Module):
         # this sliced head).
         T = cfg.text_seq_len
         # labels: next-token over [text[1:], image codes] (ref :489-499)
-        if cfg.head_phase_sliced:
-            text_logits = self._head(out[:, :T], text_only=True)
-            img_logits = self._head(out[:, T:], image_only=True)
-        else:  # full head then slice — for tp meshes (see DALLEConfig)
-            logits = self._head(out)
-            V_text = cfg.total_text_tokens
-            text_logits = logits[:, :T, :V_text]
-            img_logits = logits[:, T:, V_text:]
+        text_logits = self._head(out[:, :T], text_only=True)
+        img_logits = self._head(out[:, T:], image_only=True)
         with prof.scope("logits-head"):
             loss_text = self._phase_nll(text_logits,
                                         self._remap_pad_tokens(text)).mean()
             loss_img = self._phase_nll(img_logits, image_codes).mean()
             return (loss_text + cfg.loss_img_weight * loss_img) / (cfg.loss_img_weight + 1)
 
-    def _sp_loss(self, text, image_codes, onehot: bool, deterministic: bool):
+    def _sp_loss(self, text, image_codes, deterministic: bool):
         """Sequence-parallel training loss — runs INSIDE a shard_map over
         ``cfg.ring_axis`` (training.py::make_dalle_sp_train_step).
 
@@ -603,7 +551,7 @@ class DALLE(nn.Module):
         """
         cfg = self.cfg
         S = cfg.sp_size
-        tokens = self.embed_sequence(text, image_codes, onehot)
+        tokens = self.embed_sequence(text, image_codes)
         n = tokens.shape[1]
         assert n % S == 0, f"seq_len {n} not divisible by sp_size {S}"
         L = n // S
@@ -640,19 +588,15 @@ class DALLE(nn.Module):
     def __call__(self, text, image_codes=None, mask=None, return_loss: bool = False,
                  deterministic: bool = True):
         cfg = self.cfg
-        # one-hot embeds only pay off through their backward — inference
-        # forwards (return_loss=False, prefill, decode) keep the gather
-        onehot = cfg.onehot_embed and return_loss
-
         if return_loss and cfg.ring_axis is not None and cfg.sp_size > 1 \
                 and not self.is_initializing():
             assert image_codes is not None, (
                 "when training, image codes must be supplied")
             assert mask is None, (
                 "sequence-parallel training does not take a key padding mask")
-            return self._sp_loss(text, image_codes, onehot, deterministic)
+            return self._sp_loss(text, image_codes, deterministic)
 
-        tokens = self.embed_sequence(text, image_codes, onehot)
+        tokens = self.embed_sequence(text, image_codes)
         n = tokens.shape[1]
 
         # what each attention layer of this trace runs, said once

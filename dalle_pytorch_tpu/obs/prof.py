@@ -335,8 +335,8 @@ def fingerprint_payload(config, **extra) -> dict:
     the run point (``target=``, ``plan=``, ``batch=``, ...): dataclass
     fields stringified and sorted, sweep knobs appended raw.  Every
     producer — tools/graftprof.py predicted rows, the trainers'
-    ``prof.predicted`` lookup, bench.py / tools/perf_ab.py measured
-    appends — builds this SAME dict so their rows meet on one key."""
+    ``prof.predicted`` lookup, :func:`append_measured` — builds this SAME
+    dict so their rows meet on one key."""
     import dataclasses
 
     d = dict(config) if isinstance(config, dict) else dataclasses.asdict(config)
@@ -429,7 +429,7 @@ def append_measured(measured: dict, *, fingerprint: Optional[str] = None,
     """Append one measured row (tok/s / img/s + MFU from a real run)
     under the prediction's fingerprint — read-modify-write, atomic
     publish.  A fingerprint with no predicted row still lands (stub row)
-    so a bench round never loses data waiting for a sweep."""
+    so a measurement never waits for a sweep."""
     if fingerprint is None:
         if config is None:
             raise ProfError("append_measured needs fingerprint or config")

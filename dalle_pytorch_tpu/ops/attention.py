@@ -551,7 +551,6 @@ class _Core(NamedTuple):
     act_dtype: Any
     tiles: Tuple[int, int]
     mesh: Optional[KernelMesh]
-    forced: bool        # ``use_pallas``: the kernel on any platform
 
     def halves(self, q, mask):
         """The kernel's forward and backward for ``q``-shaped arguments
@@ -567,7 +566,7 @@ class _Core(NamedTuple):
              *q.shape[2:]), q.dtype)
         fwd, bwd = flash_attention_halves(
             shard, pattern, mask is not None, block_q=self.tiles[0],
-            block_k=self.tiles[1], cache_kernels=not self.forced)
+            block_k=self.tiles[1], cache_kernels=True)
 
         def forward(q, k, v, mask):
             bias = None
@@ -630,8 +629,6 @@ def _switched_bwd(core: _Core, saved, g):
 @functools.partial(jax.jit, static_argnums=(0,))
 def _forward_switch(core: _Core, q, k, v, mask):
     forward, _ = core.halves(q, mask)
-    if core.forced:
-        return forward(q, k, v, mask)
     blank = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
                          jax.eval_shape(forward, q, k, v, mask)[1])
 
@@ -655,9 +652,8 @@ def _backward_switch(core: _Core, q, k, v, mask, residuals, g):
             return jax.vjp(lambda q, k, v: dense_attention(
                 core.pattern, core.act_dtype, q, k, v, mask), q, k, v)[1](g)
 
-    switch = kernel if core.forced else functools.partial(
-        jax.lax.platform_dependent, tpu=kernel, default=dense)
-    return switch(q, k, v, mask, residuals, g)
+    return jax.lax.platform_dependent(q, k, v, mask, residuals, g,
+                                      tpu=kernel, default=dense)
 
 
 _switched_core.defvjp(_switched_fwd, _switched_bwd)
@@ -676,19 +672,12 @@ class MultiHeadAttention(nn.Module):
     heads: int = 8
     dim_head: int = 64
     dropout: float = 0.0
-    use_pallas: bool = False
-    pallas_block_q: int = 128   # Pallas tile sizes; sweep via
-    pallas_block_k: int = 128   # tools/perf_ab.py pallas-b* variants
     ring_axis: Optional[str] = None  # sequence-parallel axis (inside shard_map)
     sp_impl: str = "ring"            # 'ring' (k/v rotation) | 'ulysses' (all-to-all)
-    sliced_kv_decode: bool = True    # decode reads only reachable keys
-    #   (decode_key_positions); False streams the full cache — the A/B
-    #   control for the sliced path, selectable per-build so the choice is
-    #   part of the traced config, never a monkeypatch around the compile
     aligned_span_decode: bool = True  # serve-path sliced reads as circular
     #   dynamic_slice spans (<=2 per row) instead of the per-key vmapped
-    #   gather; bit-identical (same key order/masks), False is the A/B
-    #   control — again part of the traced config
+    #   gather; bit-identical (same key order/masks), False is the control
+    #   no cell has judged yet — part of the traced config
     # ``kv_heads`` keys and values serve ``heads`` queries (1: multi-query);
     # None is one each, the fused ``to_qkv`` kernel.  The dense paths take
     # it (``__call__``, ``decode_step``, the arena's aligned read), in the
@@ -702,7 +691,7 @@ class MultiHeadAttention(nn.Module):
         self.drop = nn.Dropout(self.dropout)
         if self.kv_heads is not None:
             assert self.heads % self.kv_heads == 0, (self.heads, self.kv_heads)
-            assert not self.use_pallas and self.ring_axis is None, (
+            assert self.ring_axis is None, (
                 "grouped keys run the dense attention paths only")
             proj = dict(axis=-1, use_bias=False, dtype=self.dtype,
                         param_dtype=self.param_dtype,
@@ -785,30 +774,15 @@ class MultiHeadAttention(nn.Module):
         where the shape allows (:func:`flash_tiles`) and the program is
         lowered for a TPU, the dense-masked branch otherwise.  A prefill
         (``cached``: it returns its keys and values, one batch-1 pass a
-        request) keeps the dense branch, as does flax's shape pass.
-        ``use_pallas`` forces the compiled kernel with the stated tiles on
-        any platform, and fails where it cannot lower."""
+        request) keeps the dense branch, as does flax's shape pass."""
         b, h, n, _ = q.shape
-        if self.use_pallas:
-            # always the compiled Mosaic kernel: off-TPU this fails at
-            # lowering instead of silently interpreting (tests that want
-            # the interpreter wrap the call in
-            # ``pltpu.force_tpu_interpret_mode()``)
-            assert self.pallas_block_q >= 1 and self.pallas_block_k >= 1, (
-                f"invalid Pallas block sizes {self.pallas_block_q}x"
-                f"{self.pallas_block_k}")
-            tiles = (self.pallas_block_q, self.pallas_block_k)
-        else:
-            tiles = None if cached or self.is_initializing() else flash_tiles(
-                n, self.dim_head, q.dtype, self.pattern, self.kv_heads,
-                self.ring_axis)
+        tiles = None if cached or self.is_initializing() else flash_tiles(
+            n, self.dim_head, q.dtype, self.pattern, self.kv_heads,
+            self.ring_axis)
         mesh = _kernel_mesh[-1] if _kernel_mesh else None
-        if tiles is not None and mesh is not None:
-            if b % mesh.batch_ways or h % mesh.head_ways:
-                assert not self.use_pallas, (
-                    f"batch {b} x heads {h} do not split over "
-                    f"{mesh.mesh.shape}")
-                tiles = None    # GSPMD's to place: the dense branch
+        if tiles is not None and mesh is not None and (
+                b % mesh.batch_ways or h % mesh.head_ways):
+            tiles = None    # GSPMD's to place: the dense branch
         if _choices:
             choice = dict(n=n, tiles=tiles, computed=0, blocks=0)
             if tiles is not None:
@@ -824,7 +798,7 @@ class MultiHeadAttention(nn.Module):
                                    grouped=self.kv_heads is not None)
         return _switched_core(
             _Core(kernel_pattern(self.pattern), jnp.dtype(act_dtype), tiles,
-                  mesh, forced=self.use_pallas), q, k, v, mask)
+                  mesh), q, k, v, mask)
 
     def _qkv_decode(self, x, qw):
         """Decode-path QKV projection: the f32/bf16 kernel, or — under
@@ -918,8 +892,7 @@ class MultiHeadAttention(nn.Module):
             v_vals, v_scale = split_cache(cache_v)
         n_k = k_vals.shape[2]
         scale = self.dim_head ** -0.5
-        sliced = (decode_key_positions(self.pattern, index)
-                  if self.sliced_kv_decode else None)
+        sliced = decode_key_positions(self.pattern, index)
         assert fold == 1 or sliced is None, (
             "only the dense read path takes a head-folded cache")
         if sliced is not None:
@@ -999,9 +972,8 @@ class MultiHeadAttention(nn.Module):
         :func:`kv_fold_factor`) where :meth:`decode_step` reads the whole
         cache, as given where it reads slices (they touch a tenth of it).
         The arena and the span pass never come here."""
-        if self.kv_heads is not None or (
-                self.sliced_kv_decode and decode_key_positions(
-                    self.pattern, jnp.int32(0)) is not None):
+        if self.kv_heads is not None or decode_key_positions(
+                self.pattern, jnp.int32(0)) is not None:
             return cache    # grouped keys' reads have no folded form
         values = cache_values(cache)
         return fold_cache(cache, kv_fold_factor(
@@ -1063,8 +1035,7 @@ class MultiHeadAttention(nn.Module):
         extend the greedy harness unchanged."""
         n_k = k_vals.shape[2]
         scale = self.dim_head ** -0.5
-        sliced = (decode_key_positions(self.pattern, jnp.int32(0))
-                  if self.sliced_kv_decode else None)
+        sliced = decode_key_positions(self.pattern, jnp.int32(0))
         if sliced is not None:
             # batched positions: every row computes its own reachable set
             # (decode_key_positions is shape-static over index, so the

@@ -98,12 +98,8 @@ class AttnBlock(nn.Module):
     heads: int = 8
     dim_head: int = 64
     dropout: float = 0.0
-    use_pallas: bool = False
-    pallas_block_q: int = 128
-    pallas_block_k: int = 128
     ring_axis: Optional[str] = None
     sp_impl: str = "ring"
-    sliced_kv_decode: bool = True
     aligned_span_decode: bool = True
     dtype: Any = jnp.float32
 
@@ -112,12 +108,8 @@ class AttnBlock(nn.Module):
         self.attn = MultiHeadAttention(
             pattern=self.pattern, dim=self.dim, heads=self.heads,
             dim_head=self.dim_head, dropout=self.dropout,
-            use_pallas=self.use_pallas,
-            pallas_block_q=self.pallas_block_q,
-            pallas_block_k=self.pallas_block_k,
             ring_axis=self.ring_axis,
             sp_impl=self.sp_impl,
-            sliced_kv_decode=self.sliced_kv_decode,
             aligned_span_decode=self.aligned_span_decode, dtype=self.dtype,
             name="attn",
         )
@@ -373,12 +365,8 @@ class Transformer(nn.Module):
     reversible: bool = False
     reversible_naive: bool = False  # test hook: plain-autodiff two-stream
     use_remat: bool = False
-    use_pallas: bool = False   # Pallas flash/block-sparse attention kernels
-    pallas_block_q: int = 128
-    pallas_block_k: int = 128
     ring_axis: Optional[str] = None  # sequence-parallel axis (inside shard_map)
     sp_impl: str = "ring"            # 'ring' | 'ulysses' (all-to-all)
-    sliced_kv_decode: bool = True    # decode gathers only reachable keys
     aligned_span_decode: bool = True  # serve-path circular reads as spans
     ff_experts: int = 0        # >1: MoE feed-forward with this many experts
     ff_expert_top_k: int = 2
@@ -428,11 +416,8 @@ class Transformer(nn.Module):
             attn_blocks.append(AttnBlock(
                 pattern=pattern, dim=self.dim, layer_index=ind + 1,
                 heads=self.heads, dim_head=self.dim_head,
-                dropout=self.attn_dropout, use_pallas=self.use_pallas,
-                pallas_block_q=self.pallas_block_q,
-                pallas_block_k=self.pallas_block_k,
+                dropout=self.attn_dropout,
                 ring_axis=self.ring_axis, sp_impl=self.sp_impl,
-                sliced_kv_decode=self.sliced_kv_decode,
                 aligned_span_decode=self.aligned_span_decode,
                 dtype=self.dtype,
                 name=f"layers_{ind}_attn",

@@ -181,7 +181,7 @@ def pipeline_transformer(tf, params: dict, *, mesh: Mesh,
         "pipeline stages apply without mutable collections, so the MoE "
         "load-balance aux losses would silently vanish")
 
-    # clone so every other field (dtype, use_pallas, remat, ...) carries over
+    # clone so every other field (dtype, remat, ...) carries over
     stage = tf.clone(depth=per, name=None)
     stacked = stack_stage_params(params, tf.depth, pp)
 

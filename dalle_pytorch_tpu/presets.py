@@ -70,9 +70,25 @@ def tiny_config(**overrides):
     return DALLEConfig(**base)
 
 
+def cub200_config():
+    """The CUB-200 model as the reference trains it (ref train_dalle.py:
+    74-97): the four-pattern attention cycle, 8192 image tokens, bf16."""
+    import jax.numpy as jnp
+
+    from dalle_pytorch_tpu import DALLEConfig
+
+    return DALLEConfig(
+        dim=256, num_text_tokens=7800, text_seq_len=80, depth=8, heads=8,
+        dim_head=64, attn_types=("full", "axial_row", "axial_col", "conv_like"),
+        num_image_tokens=8192, image_size=256, image_fmap_size=32,
+        dtype=jnp.bfloat16,
+    )
+
+
 def cub_config(**overrides):
-    """The production CUB-200 geometry (bench.py::cub200_config shapes)
-    at the checkpoint-eval dtype (f32 activations)."""
+    """The production CUB-200 geometry (:func:`cub200_config`'s widths,
+    all-``full`` attention, 1024 image tokens) at the checkpoint-eval
+    dtype (f32 activations)."""
     from dalle_pytorch_tpu import DALLEConfig
 
     base = dict(dim=256, depth=8, heads=8, dim_head=64,

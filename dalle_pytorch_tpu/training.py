@@ -290,7 +290,7 @@ def make_dalle_pp_train_step(dalle, tx, params, mesh, *,
 
     def loss_fn(p, text, codes):
         tokens = dalle.apply({"params": p["outer"]}, text, codes,
-                             cfg.onehot_embed, method=DALLE.embed_sequence)
+                             method=DALLE.embed_sequence)
         # "pipeline" charges the schedule machinery (microbatch buffers,
         # ppermute shifts); the blocks' own scopes win inside (innermost
         # graftprof frame takes the eqn)

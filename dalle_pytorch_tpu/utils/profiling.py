@@ -157,7 +157,7 @@ def compiled_cost_summary(fn, *args, donate_argnums=(),
     This is the chip-independent half of the perf story: the same numbers
     XLA computes on any backend, so FLOPs/traffic/memory regressions are
     caught by CPU-only CI runs without a TPU in the loop (the wall-clock
-    half lives in bench.py / tools/perf_ab.py).  The analytic
+    half is ``BENCHMARK.json`` + ``benchmark/``).  The analytic
     ``dalle_train_flops`` is validated against this path (96.4% agreement
     at the CUB geometry, tests/test_perf_model.py)."""
     compiled = jax.jit(fn, donate_argnums=donate_argnums,
@@ -185,7 +185,7 @@ class StepTimer:
     time the step loop spent waiting on the input pipeline for this step
     (``DevicePrefetcher.last_wait_s``): the reported EMA and
     ``loader_stall_frac`` (stall over step time) make an *input-bound* run
-    readable as such in monitor/bench output instead of masquerading as a
+    readable as such in the monitor's output instead of masquerading as a
     slow chip — at ~0 the step is device-bound, near 1 the chip is idling
     on the loader.
 

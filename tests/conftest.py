@@ -3,7 +3,7 @@
 The TPU-native analog of "multi-node testing without a cluster" (SURVEY.md
 §4): all distributed/sharding tests run on 8 virtual CPU devices via
 ``--xla_force_host_platform_device_count``.  The suite is a CPU suite by
-design — the chip is exercised by ``chip_smoke.py`` and ``bench.py`` — so
+design — the chip is exercised by ``chip_smoke.py`` and ``benchmark/run.py`` — so
 ``JAX_PLATFORMS=cpu`` is put into the environment before jax is imported:
 jax honours it, and the CLI-subprocess tests inherit it.
 

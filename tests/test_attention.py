@@ -102,53 +102,56 @@ def test_sparse_layout_deterministic():
     assert not np.array_equal(a, c)
 
 
-def test_decode_row_matches_dense_mask():
+VARIANTS = ("full", "axial_row", "axial_col", "conv_like", "sparse")
+
+
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_decode_row_matches_dense_mask(variant):
     """pattern_mask_row(i) must equal row i of the dense mask, for all
     variants — this is what makes KV-cache decode output-equivalent."""
-    for variant in ("full", "axial_row", "axial_col", "conv_like", "sparse"):
-        pattern = make_pattern(variant)
-        dense = dense_pattern_mask(pattern, pattern.padded_len, SEQ_LEN)
-        layout = pattern.block_layout()
-        layout_j = jnp.asarray(layout) if layout is not None else None
-        for i in range(TEXT_LEN, pattern.padded_len):
-            row = np.asarray(pattern_mask_row(pattern, jnp.asarray(i), SEQ_LEN,
-                                              layout=layout_j))
-            assert np.array_equal(row, dense[i]), (variant, i)
+    pattern = make_pattern(variant)
+    dense = dense_pattern_mask(pattern, pattern.padded_len, SEQ_LEN)
+    layout = pattern.block_layout()
+    layout_j = jnp.asarray(layout) if layout is not None else None
+    for i in range(TEXT_LEN, pattern.padded_len):
+        row = np.asarray(pattern_mask_row(pattern, jnp.asarray(i), SEQ_LEN,
+                                          layout=layout_j))
+        assert np.array_equal(row, dense[i]), (variant, i)
 
 
-def test_attention_forward_decode_equivalence():
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "keymask"])
+@pytest.mark.parametrize("variant", VARIANTS)
+def test_attention_forward_decode_equivalence(variant, masked):
     """Full-sequence forward vs token-by-token decode with KV cache — for
-    every variant, from a TEXT-region start (decode_step is a public
-    position-agnostic API: aliased negative-row candidates must not
-    double-count text keys in the sliced-cache path), and both without and
-    with a partial key-padding mask (the sliced branch gathers its scoped
-    pad mask and could drift from the dense path unobserved otherwise)."""
+    every variant (``full`` and ``sparse`` read the whole cache, the other
+    three only their reachable keys: ``decode_key_positions``), from a
+    TEXT-region start (decode_step is a public position-agnostic API:
+    aliased negative-row candidates must not double-count text keys in the
+    sliced-cache path), and both without and with a partial key-padding
+    mask (the sliced branch gathers its scoped pad mask and could drift
+    from the dense path unobserved otherwise)."""
     rng = jax.random.PRNGKey(0)
-    key_mask = jnp.asarray(
-        np.arange(SEQ_LEN)[None, :] < np.asarray([[3], [SEQ_LEN]]))
-    for variant in ("full", "axial_row", "axial_col", "conv_like", "sparse"):
-        for mask in (None, key_mask):
-            pattern = make_pattern(variant)
-            attn = MultiHeadAttention(pattern=pattern, dim=32, heads=2,
-                                      dim_head=8)
-            x = jax.random.normal(rng, (2, SEQ_LEN, 32))
-            params = attn.init(rng, x)
-            out_full, (k, v) = attn.apply(params, x, mask, return_kv=True)
+    mask = jnp.asarray(np.arange(SEQ_LEN)[None, :] < np.asarray(
+        [[3], [SEQ_LEN]])) if masked else None
+    pattern = make_pattern(variant)
+    attn = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=8)
+    x = jax.random.normal(rng, (2, SEQ_LEN, 32))
+    params = attn.init(rng, x)
+    out_full, (k, v) = attn.apply(params, x, mask, return_kv=True)
 
-            # decode from INSIDE the text region using prefilled caches
-            ck = jnp.zeros((2, 2, SEQ_LEN, 8))
-            cv = jnp.zeros((2, 2, SEQ_LEN, 8))
-            start = 2
-            ck = ck.at[:, :, :start].set(k[:, :, :start])
-            cv = cv.at[:, :, :start].set(v[:, :, :start])
-            for i in range(start, SEQ_LEN):
-                out_i, ck, cv = attn.apply(
-                    params, x[:, i : i + 1], ck, cv, jnp.asarray(i),
-                    mask=mask, method=MultiHeadAttention.decode_step)
-                np.testing.assert_allclose(
-                    np.asarray(out_i[:, 0]), np.asarray(out_full[:, i]),
-                    rtol=2e-4, atol=2e-5,
-                    err_msg=f"{variant} pos {i} mask={mask is not None}")
+    # decode from INSIDE the text region using prefilled caches
+    ck = jnp.zeros((2, 2, SEQ_LEN, 8))
+    cv = jnp.zeros((2, 2, SEQ_LEN, 8))
+    start = 2
+    ck = ck.at[:, :, :start].set(k[:, :, :start])
+    cv = cv.at[:, :, :start].set(v[:, :, :start])
+    for i in range(start, SEQ_LEN):
+        out_i, ck, cv = attn.apply(
+            params, x[:, i : i + 1], ck, cv, jnp.asarray(i),
+            mask=mask, method=MultiHeadAttention.decode_step)
+        np.testing.assert_allclose(
+            np.asarray(out_i[:, 0]), np.asarray(out_full[:, i]),
+            rtol=2e-4, atol=2e-5, err_msg=f"pos {i}")
 
 
 def test_decode_equivalence_window_taller_than_raster():

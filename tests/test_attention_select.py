@@ -190,11 +190,11 @@ def test_jamba_trace_reports_no_flash_layer(tmp_path):
 # --- under a plan's mesh ----------------------------------------------------
 
 def test_kernel_call_is_split_over_the_plans_mesh(monkeypatch):
-    """Inside ``kernel_mesh`` the kernel call runs in a ``shard_map`` over
-    the batch and head axes of the plan (here ``dp2.tp2`` on four virtual
-    devices; the kernel's two halves replaced by the dense reference and
-    its VJP, on the same per-shard arguments), and a batch the mesh does not divide
-    keeps the dense branch."""
+    """Under a plan's mesh the switched core's two halves run in a
+    ``shard_map`` over the batch and head axes of the plan (here ``dp2.tp2``
+    on four virtual devices; the kernel's two halves replaced by the dense
+    reference and its VJP, on the same per-shard arguments), and a batch
+    the mesh does not divide keeps the dense branch."""
     from attention_refs import dense_reference
     from dalle_pytorch_tpu.ops import attention_pallas
     from dalle_pytorch_tpu.parallel.plan import ParallelPlan
@@ -228,29 +228,36 @@ def test_kernel_call_is_split_over_the_plans_mesh(monkeypatch):
 
     monkeypatch.setattr(attention_pallas, "flash_attention_halves",
                         fake_halves)
-    layer = attention.MultiHeadAttention(pattern=pattern, dim=32, heads=2,
-                                         dim_head=16, use_pallas=True)
-    dense = attention.MultiHeadAttention(pattern=pattern, dim=32, heads=2,
-                                         dim_head=16)
-    x = jax.random.normal(jax.random.PRNGKey(0), (4, 24, 32))
-    params = dense.init(jax.random.PRNGKey(1), x)
-    def loss(layer):
-        return lambda p, x: jnp.sum(layer.apply(p, x) ** 2)
-
+    q, k, v, g = jax.random.normal(jax.random.PRNGKey(0), (4, 4, 2, 24, 16))
     with attention.kernel_mesh(part):
-        out = jax.jit(layer.apply)(params, x)
-        got = jax.jit(jax.grad(loss(layer)))(params, x)
+        mesh = attention._kernel_mesh[-1]
+    core = attention._Core(attention.kernel_pattern(pattern), jnp.dtype(F32),
+                           (128, 128), mesh)
+    forward, backward = core.halves(q, None)
+    out, residuals = jax.jit(forward)(q, k, v, None)
+    got = jax.jit(backward)(residuals, g)
     assert set(seen) == {(2, 1, 24, 16)}    # batch over dp, heads over tp
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(dense.apply(params, x)),
+    ref, vjp = jax.vjp(lambda q, k, v: dense_reference(q, k, v, pattern),
+                       q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
-    for a, b in zip(jax.tree.leaves(got),
-                    jax.tree.leaves(jax.grad(loss(dense))(params, x))):
+    for a, b in zip(got, vjp(g)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=5e-4)
-    with attention.kernel_mesh(part), pytest.raises(AssertionError,
-                                                    match="do not split"):
-        layer.apply(params, x[:3])
+
+    # a layer whose shape asks for the kernel, at a batch of 3: no kernel
+    # call is built, the dense branch's output
+    monkeypatch.setattr(attention, "flash_tiles", lambda *a: (128, 128))
+    layer = attention.MultiHeadAttention(pattern=pattern, dim=32, heads=2,
+                                         dim_head=16)
+    x = jax.random.normal(jax.random.PRNGKey(2), (3, 24, 32))
+    params = layer.init(jax.random.PRNGKey(1), x)
+    del seen[:]
+    with attention.kernel_mesh(part):
+        odd = jax.jit(layer.apply)(params, x)
+    assert not seen
+    np.testing.assert_array_equal(np.asarray(odd),
+                                  np.asarray(layer.apply(params, x)))
 
 
 # --- the kernels, kept between processes ------------------------------------

@@ -61,10 +61,6 @@ def test_strategy_shardings_resolve(cc, strategy):
     cc.check_strategy(strategy)
 
 
-def test_pallas_variant_instantiates(cc):
-    cc.check_pallas_variant(128, make_cfg=cc.tiny_config)
-
-
 def test_run_all_quick_exits_zero(cc, capsys):
     assert cc.run_all(quick=True) == 0
     out = capsys.readouterr().out

@@ -166,46 +166,6 @@ def test_top_k_filter_sliced_vs_joint_vocab():
                                       err_msg=f"thres={thres}")
 
 
-def test_onehot_embed_equivalent():
-    """cfg.onehot_embed changes the embedding gradient from scatter-add to
-    matmul but must leave outputs exactly equal (HIGHEST-precision one-hot
-    matmul is exact row selection); it only engages on the loss path —
-    inference forwards keep the gather."""
-    import dataclasses
-
-    cfg, dalle, params, text, codes = build()
-    dalle_oh = DALLE(dataclasses.replace(cfg, onehot_embed=True))
-    # jitted: the unjitted op-by-op dispatch of a full-DALLE grad costs 3x
-    # the compile (measured on the 1-core box); the cache makes reruns free
-    a = np.asarray(jax.jit(dalle.apply)(params, text, codes))
-    b = np.asarray(jax.jit(dalle_oh.apply)(params, text, codes))
-    np.testing.assert_array_equal(a, b)
-
-    la = float(jax.jit(lambda p: dalle.apply(p, text, codes,
-                                             return_loss=True))(params))
-    lb = float(jax.jit(lambda p: dalle_oh.apply(p, text, codes,
-                                                return_loss=True))(params))
-    assert la == lb
-    g = jax.jit(jax.grad(
-        lambda p: dalle_oh.apply(p, text, codes, return_loss=True)))(params)
-    total = jax.tree.reduce(lambda a, x: a + float(jnp.abs(x).sum()), g, 0.0)
-    assert np.isfinite(total) and total > 0
-
-
-def test_bf16_logits_close():
-    """cfg.logits_bf16 keeps params/logits f32 and stays numerically close
-    to the f32 matmul (MXU-native bf16 inputs, f32 accumulation)."""
-    import dataclasses
-
-    cfg, dalle, params, text, codes = build()
-    dalle_bf = DALLE(dataclasses.replace(cfg, logits_bf16=True))
-    a = np.asarray(dalle.apply(params, text, codes))
-    b = np.asarray(dalle_bf.apply(params, text, codes))
-    assert b.dtype == np.float32
-    finite = np.isfinite(a)
-    np.testing.assert_allclose(a[finite], b[finite], atol=0.05, rtol=0.05)
-
-
 def test_top_p_filter_semantics():
     from dalle_pytorch_tpu.utils.helpers import top_p_filter
 
@@ -238,40 +198,6 @@ def test_generate_with_top_p(small):
                                      jax.random.PRNGKey(0), filter_thres=0.9,
                                      top_p=1.0))
     np.testing.assert_array_equal(plain, full)
-
-
-def test_full_head_loss_matches_sliced():
-    """head_phase_sliced=False (the A/B control: both phases computed for
-    every position, then sliced) must produce the same loss as the default
-    sliced-head path — same math, different matmul partitioning."""
-    import dataclasses
-
-    cfg, dalle, params, text, codes = build()
-    assert cfg.head_phase_sliced
-    dalle_full = type(dalle)(dataclasses.replace(cfg, head_phase_sliced=False))
-    a = float(dalle.apply(params, text, codes, return_loss=True))
-    b = float(dalle_full.apply(params, text, codes, return_loss=True))
-    assert np.allclose(a, b, rtol=1e-6), (a, b)
-
-
-def test_dense_decode_control_matches_sliced():
-    """sliced_kv_decode=False (the perf A/B control: decode streams the
-    full cache every step) must sample the identical greedy tokens as the
-    default sliced-cache decode — the flag selects the cache-read strategy,
-    never the math.  This is the config-level control tools/perf_ab.py's
-    ``gen-dense`` measures."""
-    import dataclasses
-
-    cfg, dalle, params, text, _ = build(
-        attn_types=("full", "axial_row", "axial_col", "conv_like"), depth=4)
-    assert cfg.sliced_kv_decode
-    dalle_dense = DALLE(dataclasses.replace(cfg, sliced_kv_decode=False))
-    thres = 1.0 - 1.0 / cfg.total_tokens  # greedy: k=1
-    a = np.asarray(generate_codes(dalle, params, text, jax.random.PRNGKey(0),
-                                  filter_thres=thres))
-    b = np.asarray(generate_codes(dalle_dense, params, text,
-                                  jax.random.PRNGKey(0), filter_thres=thres))
-    np.testing.assert_array_equal(a, b)
 
 
 def test_tile_prefill_matches_batched_prefill(small):
@@ -399,3 +325,119 @@ def test_phase_head_init_call_path_independent():
         "text_kernel", "text_bias", "image_kernel", "image_bias"}
     for k in full_head:
         assert pre_head[k].shape == full_head[k].shape, k
+
+
+def test_env_flag_semantics(monkeypatch):
+    """Boolean env knobs must be OFF-able: X=0/false/no/off (any case)
+    parse as False; bool(os.environ.get(X)) treats '0' as ON."""
+    from dalle_pytorch_tpu.utils.helpers import env_flag
+
+    monkeypatch.delenv("X_FLAG", raising=False)
+    assert env_flag("X_FLAG") is False
+    assert env_flag("X_FLAG", default=True) is True
+    for off in ("0", "false", "no", "off", "", "False", " 0 ", "OFF"):
+        monkeypatch.setenv("X_FLAG", off)
+        assert env_flag("X_FLAG") is False, repr(off)
+        assert env_flag("X_FLAG", default=True) is False, repr(off)
+    for on in ("1", "true", "yes", "512", "on"):
+        monkeypatch.setenv("X_FLAG", on)
+        assert env_flag("X_FLAG") is True, repr(on)
+
+
+#: what checkpoints written before the seven execution switches were
+#: retired carry in their hparams, at the values every one of them had
+RETIRED_HPARAMS = dict(use_pallas=False, pallas_block_q=128,
+                       pallas_block_k=128, logits_bf16=False,
+                       onehot_embed=False, head_phase_sliced=True,
+                       sliced_kv_decode=True)
+
+
+@pytest.mark.parametrize("key", sorted(RETIRED_HPARAMS))
+def test_from_dict_drops_a_retired_switch(key):
+    """Hparams are input from outside the program: an older checkpoint's
+    carry a retired switch, and ``from_dict`` must build the same config
+    as without it instead of failing in ``cls(**d)``."""
+    cfg = DALLEConfig(dim=32, attn_types=("full", "axial_row"))
+    assert not hasattr(cfg, key)
+    old = {**cfg.to_dict(), key: RETIRED_HPARAMS[key]}
+    assert DALLEConfig.from_dict(old) == DALLEConfig.from_dict(cfg.to_dict())
+    with pytest.raises(TypeError):      # an unknown key still fails
+        DALLEConfig.from_dict({**cfg.to_dict(), "no_such_field": 1})
+
+
+def test_checkpoint_with_every_retired_switch_loads_and_generates(tmp_path):
+    from dalle_pytorch_tpu import DiscreteVAE
+    from dalle_pytorch_tpu.cli import load_dalle_checkpoint
+    from dalle_pytorch_tpu.utils.checkpoint import save_checkpoint
+
+    cfg, dalle, params, text, _ = build(
+        attn_types=("full", "axial_row", "axial_col", "conv_like"), depth=4)
+    vae_params = DiscreteVAE(VCFG).init(
+        {"params": jax.random.PRNGKey(1), "gumbel": jax.random.PRNGKey(1)},
+        jnp.zeros((1, 16, 16, 3)))["params"]
+    path = tmp_path / "old.pt"
+    save_checkpoint(path, {
+        "hparams": {**cfg.to_dict(), **RETIRED_HPARAMS},
+        "vae_params": VCFG.to_dict(), "vae_weights": vae_params,
+        "weights": params["params"]})
+    dalle2, cfg2, params2, _, _ = load_dalle_checkpoint(path)
+    assert cfg2 == cfg
+    thres = 1.0 - 1.0 / cfg.total_tokens  # greedy: k=1
+    want = generate_codes(dalle, params, text, jax.random.PRNGKey(0),
+                          filter_thres=thres)
+    got = generate_codes(dalle2, {"params": params2}, text,
+                         jax.random.PRNGKey(0), filter_thres=thres)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+#: ``DALLEConfig``'s fields that select how the same parameters are
+#: computed and not what the model is (ROADMAP D4 counts them; ``dtype``
+#: apart).  A new one is an option: it needs two callers that differ.
+EXECUTION_FIELDS = {
+    "use_remat", "ff_expert_dispatch", "ff_expert_capacity_factor",
+    "ring_axis", "sp_impl", "sp_size", "kv_cache_bf16", "kv_cache_int8",
+    "weights_int8", "aligned_span_decode", "spec_decode",
+    "spec_draft_depth", "spec_k", "spec_force_reject"}
+
+MODEL_FIELDS = {
+    "dim", "num_text_tokens", "text_seq_len", "depth", "heads", "dim_head",
+    "reversible", "attn_dropout", "ff_dropout", "sparse_attn", "attn_types",
+    "loss_img_weight", "num_image_tokens", "image_size", "image_fmap_size",
+    "ff_experts", "ff_expert_top_k", "ff_aux_weight", "trunk"}
+
+
+def test_execution_fields_are_exactly_the_fourteen():
+    import dataclasses
+
+    names = {f.name for f in dataclasses.fields(DALLEConfig)}
+    assert names - MODEL_FIELDS - {"dtype"} == EXECUTION_FIELDS
+    assert len(EXECUTION_FIELDS) == 14
+    # every plan field is an execution field; use_remat is the one that a
+    # checkpoint still records
+    assert set(DALLEConfig._PLAN_FIELDS) == EXECUTION_FIELDS - {"use_remat"}
+    assert not set(DALLEConfig._RETIRED_FIELDS) & names
+
+
+def test_cub200_preset_is_the_benchmarks_configuration():
+    """``presets.cub200_config`` (the tests' and ``chip_smoke.py``'s CUB-200
+    model) against the benchmark's ``cub200`` configuration: every
+    hyperparameter of its ``dalle`` section and the geometry its ``vae``
+    section implies.  The one allowed difference is the text vocabulary:
+    the preset keeps the 7800 of the BPE file's name, the benchmark the
+    7740 entries the file holds."""
+    import json
+    from pathlib import Path
+
+    from dalle_pytorch_tpu.presets import cub200_config
+
+    conf = json.loads((Path(__file__).resolve().parent.parent / "benchmark"
+                       / "configs" / "cub200.json").read_text())
+    cfg = cub200_config()
+    want = dict(conf["dalle"], attn_types=tuple(conf["dalle"]["attn_types"]))
+    differ = {k for k, v in want.items() if getattr(cfg, k) != v}
+    assert differ == {"num_text_tokens"}
+    assert (cfg.num_text_tokens, want["num_text_tokens"]) == (7800, 7740)
+    vae = conf["vae"]
+    assert cfg.num_image_tokens == vae["num_tokens"]
+    assert cfg.image_fmap_size == vae["image_size"] // 2 ** vae["num_layers"]
+    assert jnp.dtype(cfg.dtype).name == conf["dtype"]

@@ -160,9 +160,9 @@ def test_int8_equivalence_bounds_cub_geometry():
     XLA:CPU with random init: 0.991 / 0.868 — greedy sequences compound
     any single-token divergence, so these are sequence-level bounds, far
     above what a broken scale layout produces, ~1/8192 ≈ 0)."""
-    import bench
+    from dalle_pytorch_tpu.presets import cub200_config
 
-    cfg = dataclasses.replace(bench.cub200_config(), dtype=jnp.float32,
+    cfg = dataclasses.replace(cub200_config(), dtype=jnp.float32,
                               kv_cache_bf16=False)
     model = DALLE(cfg)
     rng = jax.random.PRNGKey(0)

@@ -403,7 +403,7 @@ def test_obs002_monotonic_and_out_of_scope_clean():
     assert lint(mono, select=("OBS002",),
                 path="dalle_pytorch_tpu/utils/x.py") == []
     wall = "import time\nd = time.time() - t0\n"
-    for path in ("tools/monitor.py", "train_dalle.py", "bench.py"):
+    for path in ("tools/monitor.py", "train_dalle.py", "chip_smoke.py"):
         assert lint_source(wall, select=("OBS002",), path=path) == [], path
 
 
@@ -433,7 +433,7 @@ def test_obs003_direct_profiler_calls_flagged():
         with jax.profiler.trace(logdir):
             work()
     """
-    for path in ("train_dalle.py", "tools/perf_ab.py",
+    for path in ("train_dalle.py", "tools/loss_curve.py",
                  "dalle_pytorch_tpu/utils/profiling.py"):
         assert rules_of(lint(src, select=("OBS003",),
                              path=path)) == ["OBS003"] * 3, path
@@ -1094,7 +1094,7 @@ def test_fix_env001_no_duplicate_import():
 
 # --- the repo gate -------------------------------------------------------
 
-LINT_TARGETS = ["dalle_pytorch_tpu", "tools", "bench.py", "chip_smoke.py",
+LINT_TARGETS = ["dalle_pytorch_tpu", "tools", "chip_smoke.py",
                 "train_dalle.py", "genrank.py", "train_vae.py"]
 
 

@@ -142,15 +142,16 @@ def test_folded_decode_step_matches_plain(heads, dim_head, rows, cache,
                                rtol=2e-5, atol=2e-6)
 
 
-def test_sliced_layers_keep_the_plain_layout():
-    pattern = AttnPattern(variant="axial_row", seq_len=N,
-                          text_len=TEXT + 1, fmap=FMAP)
+@pytest.mark.parametrize("variant,shape", [
+    ("axial_row", (4, 4, N, 64)),       # reads slices: as given
+    ("full", (4, 2, N, 128))])          # reads the whole cache: head-folded
+def test_sliced_layers_keep_the_plain_layout(variant, shape):
+    pattern = AttnPattern(variant=variant, seq_len=N, text_len=TEXT + 1,
+                          fmap=FMAP)
     k = jnp.zeros((4, 4, N, 64), jnp.bfloat16)
-    for sliced, shape in ((True, (4, 4, N, 64)), (False, (4, 2, N, 128))):
-        attn = MultiHeadAttention(pattern=pattern, dim=32, heads=4,
-                                  dim_head=64, sliced_kv_decode=sliced)
-        got = attn.apply({}, k, method=MultiHeadAttention.lane_dense_cache)
-        assert got.shape == shape
+    attn = MultiHeadAttention(pattern=pattern, dim=32, heads=4, dim_head=64)
+    got = attn.apply({}, k, method=MultiHeadAttention.lane_dense_cache)
+    assert got.shape == shape
 
 
 # --- the model: teacher-forced logits and sampled codes ---------------------

@@ -471,53 +471,6 @@ def test_serve_tick_sampling_aggregates_preserve_report(tmp_path,
         assert r["active_sum"] == pytest.approx(r["active"] * r["ticks"])
 
 
-def test_bench_events_make_history_derivable(tmp_path, capsys):
-    """bench.record_history emits the exact bench-history.jsonl payload
-    as a `bench` event (CPU runs included — marked by device kind), and
-    ``obs_report --bench-jsonl`` extracts the lines back out: the
-    committed perf history is derivable from telemetry alone."""
-    import importlib.util
-    import json as _json
-
-    from dalle_pytorch_tpu.obs import telemetry
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_for_obs_test", REPO / "bench.py")
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    telemetry.init(tmp_path / "tel", run_id="bench-test")
-    record = {"metric": "dalle_cub200_train_throughput", "value": 42.5,
-              "unit": "images/sec/chip", "vs_baseline": None,
-              "meta": {"steps": 5, "batch": 16}}
-    bench.record_history(dict(record))
-    bench.record_history({"metric": "dalle_cub200_gen_throughput",
-                          "value": 1000.0, "unit": "image_tokens/sec",
-                          "meta": {"batch": 8}})
-    telemetry.shutdown()
-
-    recs = [r for r in telemetry.read_events(tmp_path / "tel")
-            if r.get("kind") == "bench"]
-    assert [r["name"] for r in recs] == ["dalle_cub200_train_throughput",
-                                         "dalle_cub200_gen_throughput"]
-    assert recs[0]["value"] == 42.5 and recs[0]["meta"]["batch"] == 16
-    assert "ts" in recs[0] and "device" in recs[0]  # the history envelope
-
-    spec2 = importlib.util.spec_from_file_location(
-        "obs_report_for_bench_test", REPO / "tools" / "obs_report.py")
-    obs_report = importlib.util.module_from_spec(spec2)
-    spec2.loader.exec_module(obs_report)
-    assert obs_report.main([str(tmp_path / "tel"), "--bench-jsonl"]) == 0
-    lines = [l for l in capsys.readouterr().out.splitlines() if l.strip()]
-    assert len(lines) == 2
-    derived = _json.loads(lines[0])
-    # payload only — envelope stripped — and the record rides intact
-    assert derived["metric"] == record["metric"]
-    assert derived["value"] == record["value"]
-    assert derived["meta"] == record["meta"]
-    assert "seq" not in derived and "run" not in derived
-
-
 # --- read side: fixture stream, report, Perfetto --------------------------
 
 

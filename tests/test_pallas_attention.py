@@ -4,9 +4,9 @@ Runs the kernels in interpret mode (CPU), checking forward outputs and
 gradients for every attention variant against the plain XLA dense-with-mask
 computation that `MultiHeadAttention` uses (SURVEY.md §4: 'sparse-attention
 equivalence vs dense-with-mask').  Direct kernel calls pass
-``interpret=True``; the model never does (``use_pallas`` always asks for the
-compiled kernel), so the model-level tests steer the interpreter from here,
-with Pallas' own ``force_tpu_interpret_mode`` context.
+``interpret=True``; the model never does (its switched core always asks for
+the compiled kernel), so the tests of that core's two halves steer the
+interpreter from here, with Pallas' own ``force_tpu_interpret_mode`` context.
 """
 from __future__ import annotations
 
@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
+from dalle_pytorch_tpu.ops import attention
 from dalle_pytorch_tpu.ops.attention import AttnPattern
 from dalle_pytorch_tpu.ops.attention_pallas import flash_pattern_attention
 
@@ -117,44 +118,6 @@ def test_bf16_forward_close():
                                rtol=5e-2, atol=5e-2)
 
 
-@pytest.mark.slow
-def test_dalle_use_pallas_matches_dense():
-    """Full DALLE forward loss with the Pallas kernels == dense path."""
-    from dalle_pytorch_tpu import DALLE, DALLEConfig
-
-    def make(use_pallas):
-        cfg = DALLEConfig(
-            dim=32, num_text_tokens=32, text_seq_len=4, depth=2, heads=2,
-            dim_head=16, attn_types=("full", "axial_row", "conv_like",
-                                     "sparse"),
-            num_image_tokens=16, image_size=16, image_fmap_size=4,
-            use_pallas=use_pallas)
-        return DALLE(cfg), cfg
-
-    dalle_d, cfg = make(False)
-    dalle_p, _ = make(True)
-    rng = jax.random.PRNGKey(0)
-    text = jax.random.randint(rng, (2, cfg.text_seq_len), 0, 32)
-    codes = jax.random.randint(rng, (2, cfg.image_seq_len), 0, 16)
-    params = dalle_d.init(rng, text, codes)["params"]
-
-    loss_d = dalle_d.apply({"params": params}, text, codes, return_loss=True)
-    with pltpu.force_tpu_interpret_mode():
-        loss_p = dalle_p.apply({"params": params}, text, codes,
-                               return_loss=True)
-    np.testing.assert_allclose(float(loss_d), float(loss_p), rtol=1e-4)
-
-    gd = jax.grad(lambda p: dalle_d.apply({"params": p}, text, codes,
-                                          return_loss=True))(params)
-    with pltpu.force_tpu_interpret_mode():
-        gp = jax.grad(lambda p: dalle_p.apply({"params": p}, text, codes,
-                                              return_loss=True))(params)
-    flat_d, flat_p = jax.tree.leaves(gd), jax.tree.leaves(gp)
-    for a, b in zip(flat_d, flat_p):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=5e-3, atol=5e-4)
-
-
 def test_block_sparsity_actually_skips():
     """The block table must mark disallowed blocks SKIP (the compute-skip
     guarantee: axial patterns touch far fewer blocks than full)."""
@@ -168,53 +131,14 @@ def test_block_sparsity_actually_skips():
     assert full[0, 1] == SKIP and full[0, 2] == SKIP
 
 
-def test_block_size_config_override(monkeypatch):
-    """pallas_block_q/k thread from the layer config to the kernel launch
-    (perf_ab's pallas-b* variants sweep them) and results stay equivalent."""
-    import dalle_pytorch_tpu.ops.attention_pallas as ap
-    from dalle_pytorch_tpu.ops.attention import AttnPattern, MultiHeadAttention
-
-    seen = {}
-    orig = ap.flash_attention_halves
-
-    def spy(*args, **kwargs):
-        seen.update(block_q=kwargs.get("block_q"),
-                    block_k=kwargs.get("block_k"))
-        return orig(*args, **kwargs)
-
-    monkeypatch.setattr(ap, "flash_attention_halves", spy)
-
-    pattern = AttnPattern(variant="full", seq_len=24, text_len=8, fmap=4)
-    attn = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16,
-                              use_pallas=True, pallas_block_q=256,
-                              pallas_block_k=256)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
-    dense = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16)
-    params = dense.init(jax.random.PRNGKey(1), x)
-    with pltpu.force_tpu_interpret_mode():
-        out = attn.apply(params, x)
-    assert seen == {"block_q": 256, "block_k": 256}
-
-    ref = dense.apply(params, x)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-
-
-def test_use_pallas_off_tpu_fails_loudly():
-    """``use_pallas`` asks for the compiled Mosaic kernel whatever the
-    backend: off-TPU that is an error at lowering, never a silent drop to
-    the interpreter (which would report interpreter results — and
-    interpreter speed — under the kernel's name)."""
-    from dalle_pytorch_tpu.ops.attention import AttnPattern, MultiHeadAttention
-
-    pattern = AttnPattern(variant="full", seq_len=24, text_len=8, fmap=4)
-    attn = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16,
-                              use_pallas=True)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
-    params = MultiHeadAttention(pattern=pattern, dim=32, heads=2,
-                                dim_head=16).init(jax.random.PRNGKey(1), x)
+def test_compiled_kernel_off_tpu_fails_loudly():
+    """Without ``interpret`` the call asks for the compiled Mosaic kernel
+    whatever the backend: off-TPU that is an error at lowering, never a
+    silent drop to the interpreter (which would report interpreter results
+    — and interpreter speed — under the kernel's name)."""
+    q, k, v = rand_qkv(jax.random.PRNGKey(0))
     with pytest.raises(ValueError, match="[Ii]nterpret"):
-        attn.apply(params, x)
+        flash_pattern_attention(q, k, v, make_pattern("full"))
 
 
 def test_vmem_budget_guard():
@@ -368,30 +292,42 @@ def test_wholly_allowed_blocks_change_nothing(variant):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def _layer_pair(variant="axial_row", **kw):
-    from dalle_pytorch_tpu.ops.attention import MultiHeadAttention
+@pytest.fixture
+def no_kernel_files():
+    """The model's switched core keeps its traced kernels as files where
+    the program keeps a compile cache (for the TPU only): off for a test
+    that builds the core's kernels here."""
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
 
+
+def _core(variant="axial_row"):
+    """The static part of a layer's switched core at toy width, with the
+    pattern the dense branch takes."""
     pattern = AttnPattern(variant=variant, seq_len=24, text_len=8, fmap=4)
-    dense = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16)
-    flash = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16,
-                               use_pallas=True, **kw)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
-    return dense, flash, dense.init(jax.random.PRNGKey(1), x), x
+    return pattern, attention._Core(attention.kernel_pattern(pattern),
+                                    jnp.dtype(jnp.float32), (128, 128), None)
 
 
 @pytest.mark.parametrize("variant", ["full", "axial_row"])
-def test_layer_key_padding_mask(variant):
-    """``MultiHeadAttention(mask=)`` reaches the kernel as ``key_pad_bias``
-    with the per-variant scope of ``_scope_key_pad`` (every key for full,
-    the text keys for the sparse variants)."""
-    dense, flash, params, x = _layer_pair(variant)
+def test_layer_key_padding_mask(variant, no_kernel_files):
+    """The layer's ``mask=`` reaches the kernel as ``key_pad_bias`` with the
+    per-variant scope of ``_scope_key_pad`` (every key for full, the text
+    keys for the sparse variants): the core's kernel half against its dense
+    branch, on the mask as the model has it."""
+    pattern, core = _core(variant)
+    q, k, v = jax.random.normal(jax.random.PRNGKey(0), (3, 2, 2, 24, 16))
     mask = jnp.asarray(np.r_[[[True] * 5 + [False] * 3],
                              [[True] * 8]])          # text keys, [b, 8]
-    ref = dense.apply(params, x, mask=mask)
+    ref = attention.dense_attention(pattern, jnp.float32, q, k, v, mask)
     with pltpu.force_tpu_interpret_mode():
-        out = flash.apply(params, x, mask=mask)
+        out, _ = core.halves(q, mask)[0](q, k, v, mask)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+    unmasked = attention.dense_attention(pattern, jnp.float32, q, k, v, None)
+    assert not np.allclose(np.asarray(ref[0]), np.asarray(unmasked[0]))
 
 
 def test_kernel_under_checkpoint():
@@ -416,14 +352,22 @@ def test_kernel_under_checkpoint():
                                    rtol=2e-4, atol=2e-4)
 
 
-def test_backward_kernels_carry_the_forwards_scope():
+def test_backward_kernels_carry_the_forwards_scope(monkeypatch,
+                                                    no_kernel_files):
     """All three ``pallas_call``s of a differentiated layer sit under
     ``graftprof:attn-scores`` (a custom VJP's backward does not inherit the
-    forward's name scope by itself)."""
-    _, flash, params, x = _layer_pair()
-    with pltpu.force_tpu_interpret_mode():
-        jaxpr = jax.make_jaxpr(jax.grad(
-            lambda p: jnp.sum(flash.apply(p, x) ** 2)))(params)
+    forward's name scope by itself).  Traced, not lowered: the layer's
+    default path with a shape that asks for the kernel, whose TPU branch the
+    jaxpr holds beside the dense one."""
+    from dalle_pytorch_tpu.ops.attention import MultiHeadAttention
+
+    monkeypatch.setattr(attention, "flash_tiles", lambda *a: (128, 128))
+    pattern, _ = _core()
+    layer = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
+    params = layer.init(jax.random.PRNGKey(1), x)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda p: jnp.sum(layer.apply(p, x) ** 2)))(params)
 
     def stacks(jaxpr, outer=""):
         # an equation inside a nested jit names its scopes from that jit on:

@@ -4,24 +4,19 @@ Chip time is scarce and budgeted, so the structural perf invariants are
 pinned here against XLA's own cost model (``utils.profiling.compiled_cost_summary``), which is identical
 math on every backend — a regression that lands in the production step,
 the candidate stack, or the sliced-KV decode fails in CPU-only CI, no
-chip required.  The wall-clock half of the story stays in bench.py /
-tools/perf_ab.py; PERF.md records these numbers as "compiler-model, not
-wall-clock".
+chip required.  The wall-clock half is the benchmark (``BENCHMARK.json`` +
+``benchmark/``, measured on the chip); these numbers are compiler-model,
+not wall-clock.
 
 Calibration (XLA:CPU, jax 0.8.x, 2026-08; PERF.md "Compiler-model
 gates" table):
 
 * production train step (CUB geometry, batch 16):
   flops 2.380e12, bytes 1.981e11, temp 14.46 GiB; analytic/xla = 0.964
-* candidate stack (batch 64 + bf16 head + one-hot embeds):
-  flops 1.011e13 (4.25x the b16 step: 4x batch + the one-hot embed
-  matmuls), analytic/xla = 0.907
-* full-head control (head_phase_sliced=False):
-  flops 2.596e12 (sliced head saves 8.3%), temp 18.67 GiB (+4.2 GiB —
-  the [b, n, total_vocab] logits/grads the sliced head never builds)
 * decode step (batch 8): the sliced-KV path's bytes-per-cache-key
   derivative is variant-independent update plumbing (~114.7 kB/key);
-  the dense control adds ~35.4 kB/key of cache *streaming* on top.
+  the ``full`` layers' dense read adds ~35.4 kB/key of cache *streaming*
+  on top.
   At n=1105 that streaming is ~21x the sliced path's whole reachable
   read set ((81 text + 32 row) keys) — the cache-traffic claim behind
   the sliced decode (ops/attention.py::decode_key_positions), asserted
@@ -49,10 +44,10 @@ GiB = 2 ** 30
 
 
 def cub_train_costs(batch=16, **overrides):
-    """Cost summary of the production train step at the bench geometry."""
-    import bench
+    """Cost summary of the production train step at the CUB-200 geometry."""
+    from dalle_pytorch_tpu.presets import cub200_config
 
-    cfg = bench.cub200_config()
+    cfg = cub200_config()
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
     model = DALLE(cfg)
@@ -70,10 +65,12 @@ def cub_train_costs(batch=16, **overrides):
                                  rng), cfg
 
 
-def layer_decode_costs(variant, sliced, n_cache, batch=8, fmap=32, text=81,
+def layer_decode_costs(variant, n_cache, batch=8, fmap=32, text=81,
                        dtype=jnp.bfloat16, cache_dtype=None,
                        cache_int8=False):
-    """Cost summary of ONE attention layer's KV-cache decode step.
+    """Cost summary of ONE attention layer's KV-cache decode step
+    (``full`` reads the whole cache, the axial and conv variants only
+    their reachable keys: ``decode_key_positions``).
 
     ``n_cache`` can exceed the pattern's padded length: extra keys are
     mask-dead, so growing it isolates d(bytes)/d(cache key) — the pure
@@ -85,7 +82,7 @@ def layer_decode_costs(variant, sliced, n_cache, batch=8, fmap=32, text=81,
     n = text - 1 + fmap * fmap
     pat = AttnPattern(variant=variant, seq_len=n, text_len=text, fmap=fmap)
     m = MultiHeadAttention(pattern=pat, dim=256, heads=8, dim_head=64,
-                           sliced_kv_decode=sliced, dtype=dtype)
+                           dtype=dtype)
     x = jnp.zeros((batch, 1, 256), dtype)
     if cache_int8:
         ck = (jnp.zeros((batch, 8, n_cache, 64), jnp.int8),
@@ -144,34 +141,6 @@ def test_production_step_regression_bands(prod):
 
 
 @pytest.mark.slow
-def test_candidate_stack_scales_clean(prod):
-    """The candidate production config (batch 64 + bf16 head + one-hot
-    embeds) must cost ~4x the b16 step plus the embed matmuls — if batch
-    scaling stops being linear (a shape blow-up, a quadratic term), the
-    candidate flip would silently lose its projected MFU win."""
-    costs16, _ = prod
-    costs64, cfg64 = cub_train_costs(64, logits_bf16=True, onehot_embed=True)
-    assert 0.85 <= dalle_train_flops(cfg64, 64) / costs64["flops"] <= 1.0
-    ratio = costs64["flops"] / costs16["flops"]
-    assert 4.0 <= ratio <= 4.5, ratio  # 4x batch + one-hot embed matmuls
-
-
-@pytest.mark.slow
-def test_phase_sliced_head_saves_flops_and_memory(prod):
-    """head_phase_sliced=True must keep both its wins over the full-head
-    control: ~8% step FLOPs and the multi-GiB temp allocation for the
-    [b, n, total_vocab] logits tensor the sliced head never materializes
-    (models/dalle.py::loss_from_hidden)."""
-    sliced, _ = prod
-    full, _ = cub_train_costs(16, head_phase_sliced=False)
-    ratio = sliced["flops"] / full["flops"]
-    assert 0.88 <= ratio <= 0.95, ratio
-    if "temp_bytes" in sliced:
-        saved = full["temp_bytes"] - sliced["temp_bytes"]
-        assert saved >= 3 * GiB, saved / GiB
-
-
-@pytest.mark.slow
 @pytest.mark.parametrize("variant,reachable", [
     ("axial_row", 81 + 32),        # all text + the query's raster row
     ("conv_like", 81 + 3 * 32),    # all text + kernel//2+1 rows (k=5, d=1)
@@ -182,20 +151,19 @@ def test_sliced_decode_eliminates_cache_streaming(variant, reachable):
     XLA's bytes-accessed totals double-count fixed overhead, so the gate
     differentiates with respect to cache length: extra keys are mask-dead,
     and only *streamed* cache reads scale with them.  The sliced path's
-    derivative must be pure update plumbing (identical to the full
-    variant's fixed writes — no read term), while the dense control pays
-    at least the true k+v row reads (2 caches x batch x heads x dh x 2B
+    derivative must be pure update plumbing (no read term), while the
+    ``full`` variant's dense read pays at least the true k+v row reads (2 caches x batch x heads x dh x 2B
     = 16 kB/key) on top.  At the CUB cache length, the streaming the
     sliced path eliminates must be >= 8x its whole reachable read set —
     the "~10x less cache traffic" line in PERF.md, made falsifiable."""
     n_k, n_k2 = 1105, 2210
     key_row_bytes = 2 * 8 * 8 * 64 * 2  # k+v rows: batch x heads x dh, bf16
 
-    d_sliced = (layer_decode_costs(variant, True, n_k2)["bytes_accessed"]
-                - layer_decode_costs(variant, True, n_k)["bytes_accessed"]
+    d_sliced = (layer_decode_costs(variant, n_k2)["bytes_accessed"]
+                - layer_decode_costs(variant, n_k)["bytes_accessed"]
                 ) / (n_k2 - n_k)
-    d_dense = (layer_decode_costs(variant, False, n_k2)["bytes_accessed"]
-               - layer_decode_costs(variant, False, n_k)["bytes_accessed"]
+    d_dense = (layer_decode_costs("full", n_k2)["bytes_accessed"]
+               - layer_decode_costs("full", n_k)["bytes_accessed"]
                ) / (n_k2 - n_k)
 
     streaming = (d_dense - d_sliced) * n_k      # what slicing eliminates
@@ -213,8 +181,8 @@ def test_bf16_cache_cuts_decode_cache_bytes():
     at — the decode step's cache I/O footprint (memory_analysis argument +
     output bytes: what the decode scan must stream through HBM every step
     just to carry the caches in and out) with a bf16 cache must be ≤ 0.6x
-    the f32-cache sliced baseline, for the sliced path and the dense
-    control alike.
+    the f32-cache baseline, for the sliced read (``axial_row``) and the
+    dense read (``full``) alike.
 
     ``bytes_accessed`` cannot carry this gate on the CPU test backend:
     XLA:CPU has no native bf16 dynamic-update-slice and round-trips bf16
@@ -224,25 +192,24 @@ def test_bf16_cache_cuts_decode_cache_bytes():
     backend and is exactly the quantity the HBM-bound loop streams."""
     n_k = 1105
 
-    def io_bytes(sliced, cache_dtype):
-        costs = layer_decode_costs("axial_row", sliced, n_k,
-                                   dtype=jnp.float32,
+    def io_bytes(variant, cache_dtype):
+        costs = layer_decode_costs(variant, n_k, dtype=jnp.float32,
                                    cache_dtype=cache_dtype)
         if "argument_bytes" not in costs:  # pragma: no cover
             pytest.skip("backend lacks memory_analysis")
         return costs["argument_bytes"] + costs["output_bytes"]
 
-    for sliced in (True, False):
-        io16 = io_bytes(sliced, jnp.bfloat16)
-        io32 = io_bytes(sliced, jnp.float32)
-        assert io16 <= 0.6 * io32, (sliced, io16, io32)
+    for variant in ("axial_row", "full"):
+        io16 = io_bytes(variant, jnp.bfloat16)
+        io32 = io_bytes(variant, jnp.float32)
+        assert io16 <= 0.6 * io32, (variant, io16, io32)
 
 
 def test_int8_cache_cuts_decode_cache_bytes():
     """The kv_cache_int8 byte cut (ISSUE 7 acceptance): the int8-cache
     decode step's arg/out CACHE bytes must be ≤ 0.55x the bf16-cache
-    program's at CUB geometry, sliced path and dense control alike (fast
-    tier, single layer — the model-level twin is slow-tier).
+    program's at CUB geometry on the sliced read path (fast tier, single
+    layer — the model-level twin is slow-tier).
 
     The cache component is isolated exactly: argument/output bytes are
     deterministic buffer sums, and the two builds differ ONLY in cache
@@ -257,8 +224,8 @@ def test_int8_cache_cuts_decode_cache_bytes():
         + 2 * batch * heads * 4                       # int8 + scale planes
 
     def io(**kw):
-        costs = layer_decode_costs("axial_row", True, n_k,
-                                   dtype=jnp.float32, **kw)
+        costs = layer_decode_costs("axial_row", n_k, dtype=jnp.float32,
+                                   **kw)
         if "argument_bytes" not in costs:  # pragma: no cover
             pytest.skip("backend lacks memory_analysis")
         return costs["argument_bytes"], costs["output_bytes"]
@@ -326,10 +293,10 @@ def test_model_decode_step_bf16_cache_cheaper():
     bf16-cache build's per-step cache I/O must shrink by the full k+v
     cache byte delta — i.e. every one of depth x 2 caches really is stored
     (and therefore carried through the scan) at half the bytes."""
-    import bench
+    from dalle_pytorch_tpu.presets import cub200_config
 
     def decode_costs(cache_bf16: bool, batch=8):
-        cfg = dataclasses.replace(bench.cub200_config(), dtype=jnp.float32,
+        cfg = dataclasses.replace(cub200_config(), dtype=jnp.float32,
                                   kv_cache_bf16=cache_bf16)
         model = DALLE(cfg)
         rng = jax.random.PRNGKey(0)
@@ -379,13 +346,12 @@ def test_model_decode_step_int8_quantized_serving():
     the weight stream drops by ≥ 0.7x the f32 decode-kernel footprint
     (jit prunes the unreferenced f32 kernels once the int8 copies ride
     the argument list)."""
-    import bench
-
     from dalle_pytorch_tpu.models.dalle import quantize_decode_weights
+    from dalle_pytorch_tpu.presets import cub200_config
     from dalle_pytorch_tpu.utils.profiling import dalle_decode_cache_bytes
 
     def decode_costs(cache_int8: bool, qw_params=None, batch=8):
-        cfg = dataclasses.replace(bench.cub200_config(), dtype=jnp.float32,
+        cfg = dataclasses.replace(cub200_config(), dtype=jnp.float32,
                                   kv_cache_int8=cache_int8,
                                   weights_int8=qw_params is not None)
         model = DALLE(cfg)
@@ -438,17 +404,6 @@ def test_model_decode_step_int8_quantized_serving():
     w_bytes = _tree_bytes(kernels)
     saved_w = int8["argument_bytes"] - quant["argument_bytes"]
     assert saved_w >= 0.70 * w_bytes, (saved_w, w_bytes)
-
-
-@pytest.mark.slow
-def test_full_variant_ignores_decode_flag():
-    """The full pattern has no reachable-subset structure: both flag
-    settings must compile to the same costs (decode_key_positions returns
-    None), so flipping the flag can never change full-attention layers."""
-    a = layer_decode_costs("full", True, 1105)
-    b = layer_decode_costs("full", False, 1105)
-    assert a["flops"] == b["flops"]
-    assert a["bytes_accessed"] == b["bytes_accessed"]
 
 
 @pytest.mark.slow
@@ -595,47 +550,3 @@ def test_expert_parallel_per_device_costs():
         "above = expert kernels replicating instead of ep-sharding; below "
         "= the compiler's loop accounting changed (re-calibrate if "
         "intentional)")
-
-
-@pytest.mark.slow
-def test_model_decode_step_sliced_cheaper():
-    """End-to-end decode step (8-layer CUB stack, 6 sliced-eligible
-    layers): the sliced build must read measurably less than the dense
-    control — at least 6 layers' worth of (1 - reachable fraction) cache
-    reads (~90 MB at this geometry)."""
-    import bench
-
-    def decode_costs(sliced: bool, batch=8):
-        cfg = dataclasses.replace(bench.cub200_config(),
-                                  sliced_kv_decode=sliced)
-        model = DALLE(cfg)
-        rng = jax.random.PRNGKey(0)
-        text = jax.random.randint(rng, (batch, cfg.text_seq_len), 0,
-                                  cfg.num_text_tokens)
-        params = jax.jit(lambda r: model.init(
-            r, text[:1],
-            jnp.zeros((1, cfg.image_seq_len), jnp.int32))["params"])(rng)
-        caches = [(jnp.zeros((batch, cfg.heads, cfg.seq_len, cfg.dim_head),
-                             cfg.dtype),
-                   jnp.zeros((batch, cfg.heads, cfg.seq_len, cfg.dim_head),
-                             cfg.dtype))
-                  for _ in range(cfg.depth)]
-        code = jnp.zeros((batch,), jnp.int32)
-        idx = jnp.asarray(cfg.text_seq_len + 5)
-
-        def step(params, code, caches, idx):
-            return model.apply({"params": params}, code, caches, idx,
-                               method=DALLE.decode_step)
-
-        return compiled_cost_summary(step, params, code, caches, idx,
-                                     donate_argnums=(2,)), cfg
-
-    sliced, cfg = decode_costs(True)
-    dense, _ = decode_costs(False)
-    cache_bytes = 8 * cfg.heads * cfg.seq_len * cfg.dim_head * 2  # bf16
-    # 6 of 8 CUB layers are sliced-eligible; each stops streaming ~90% of
-    # its k+v caches
-    expected_floor = 6 * 2 * cache_bytes * 0.8
-    saved = dense["bytes_accessed"] - sliced["bytes_accessed"]
-    assert saved >= expected_floor, (saved, expected_floor)
-    assert sliced["flops"] <= dense["flops"]

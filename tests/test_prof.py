@@ -14,7 +14,7 @@ Four promises are pinned here:
 * the graftscope join works end to end on CPU: trainers' `prof.predicted`
   events render in obs_report's predicted-vs-measured section, the
   mfu_vs_predicted alert fires against the ledger reference, and
-  bench.record_history lands measured rows under the prediction's
+  ``prof.append_measured`` lands measured rows under the prediction's
   fingerprint;
 * the chip-spec table cannot drift from lint/spmd.py's HBM budget table.
 """
@@ -444,35 +444,10 @@ def test_mfu_vs_predicted_alert_fires_against_ledger_ref():
     assert [a["rule"] for a in fired] == ["mfu_vs_predicted"]
 
 
-def test_bench_record_history_joins_ledger(tmp_path, monkeypatch):
-    import bench
-
-    p = tmp_path / "ledger.json"
-    row = _seed_ledger(p)
-    monkeypatch.setenv("GRAFT_PERF_LEDGER", str(p))
-    keys = {"ledger_fingerprint": row["fingerprint"],
-            "ledger_target": "dalle/dp"}
-    bench.record_history({"metric": "dalle_cub200_train_throughput",
-                          "value": 123.4, "unit": "images/sec/chip",
-                          "mfu": 0.41, **keys})
-    led = prof.load_ledger(p)
-    hist = led["rows"][row["fingerprint"]]["measured"]
-    assert hist[-1]["value"] == 123.4 and hist[-1]["mfu"] == 0.41
-    # ledger_keys hashes the same payload graftprof's sweep hashes
-    from dalle_pytorch_tpu import DALLEConfig
-
-    cfg = DALLEConfig(dim=32, depth=2, heads=4, dim_head=8,
-                      num_text_tokens=50, text_seq_len=8,
-                      num_image_tokens=32, image_size=64, image_fmap_size=4)
-    keys2 = bench.ledger_keys(cfg, target="vae", plan="single", batch=8)
-    assert keys2["ledger_fingerprint"] == prof.row_fingerprint(
-        prof.fingerprint_payload(cfg, target="vae", plan="single", batch=8))
-
-
 def test_graftprof_report_cli(tmp_path):
     p = tmp_path / "ledger.json"
     row = _seed_ledger(p)
-    prof.append_measured({"metric": "perf_ab:baseline", "value": 50.0,
+    prof.append_measured({"metric": "train_images_per_s", "value": 50.0,
                           "unit": "img/s", "mfu": 0.3},
                          fingerprint=row["fingerprint"], path=p)
     out = subprocess.run(

@@ -92,7 +92,7 @@ def test_step_timer(monkeypatch):
 
 def test_step_timer_loader_stall():
     """stall_s feeds the loader-stall EMA and the stall fraction — the
-    surface monitor/bench use to tell an input-bound run from a slow
+    surface the monitor uses to tell an input-bound run from a slow
     chip.  Fraction is clamped to 1 (a stall can't exceed the step)."""
     t = StepTimer()
     t.tick(8, stall_s=0.0)

@@ -83,8 +83,8 @@ def test_full_occupancy_throughput_vs_static_batch(served_model):
         return dt
 
     serve_dt()  # compile + warm
-    # interleaved best-of-3 (tools/perf_ab.py's drift policy: ambient load
-    # hits both sides of a round roughly equally)
+    # interleaved best-of-3: ambient load hits both sides of a round
+    # roughly equally
     s_dts, v_dts = [], []
     for _ in range(3):
         s_dts.append(static_dt())

@@ -67,7 +67,7 @@ for m in pkgutil.walk_packages(dalle_pytorch_tpu.__path__,
     targets.append(("pkg", m.name))
 for f in sorted(Path(repo, "tools").glob("*.py")):
     targets.append(("tool", str(f)))
-for f in ("chip_smoke.py", "bench.py", "train_vae.py", "train_dalle.py",
+for f in ("chip_smoke.py", "train_vae.py", "train_dalle.py",
           "generate.py", "genrank.py"):
     targets.append(("tool", str(Path(repo, f))))
 

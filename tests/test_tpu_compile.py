@@ -27,13 +27,13 @@ import pytest
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-import bench  # noqa: E402
 import chip_smoke  # noqa: E402
 from dalle_pytorch_tpu import DALLE  # noqa: E402
 from dalle_pytorch_tpu.lint.spmd import fresh_stats_compile  # noqa: E402
 from dalle_pytorch_tpu.ops.attention import AttnPattern  # noqa: E402
 from dalle_pytorch_tpu.ops.attention_pallas import (  # noqa: E402
     flash_pattern_attention)
+from dalle_pytorch_tpu.presets import cub200_config  # noqa: E402
 
 V5E_HBM_BYTES = 16 * 2 ** 30
 
@@ -158,25 +158,6 @@ def test_compiler_refuses_large_tiles_at_fmap64(one_chip, blocks, grad, fits):
             _compile_attention(one_chip, "full", *blocks, (4, 8, 4176, 64),
                                grad, fmap=64)
 
-# --- model level: use_pallas really lowers the kernel -----------------------
-
-def test_model_with_use_pallas_holds_the_kernel(one_chip):
-    """``use_pallas=True`` asks for the compiled kernel whatever backend the
-    process runs on, so a model-level compile for the TPU holds
-    ``tpu_custom_call`` (one depth-1 "full" layer: fwd + dq + dk/dv)."""
-    cfg = dataclasses.replace(bench.cub200_config(use_pallas=True), depth=1,
-                              pallas_block_q=256, pallas_block_k=512)
-    model, shapes = _param_shapes(cfg)
-    batch = jax.ShapeDtypeStruct((16, cfg.text_seq_len), jnp.int32,
-                                 sharding=one_chip)
-    codes = jax.ShapeDtypeStruct((16, cfg.image_seq_len), jnp.int32,
-                                 sharding=one_chip)
-    compiled = jax.jit(jax.value_and_grad(
-        lambda p, t, c: model.apply({"params": p}, t, c, return_loss=True))
-    ).lower(_on(one_chip, shapes), batch, codes).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 3
-
-
 # --- the default: the kernel chosen by shape, where the step is lowered ------
 
 def _kernel_calls(hlo_text):
@@ -208,10 +189,9 @@ def _assert_flash_step(cfg, compiled):
 
 def test_default_cub200_train_step_holds_the_kernel(topo):
     """``cub200-train``'s step as the benchmark builds it (batch 16, the VAE
-    inside, no ``use_pallas``): 24 kernels, and the compiler plans under
-    half the 7.57 GB the dense scores took (ledger, PR 27)."""
+    inside): 24 kernels, and the compiler plans under half the 7.57 GB the
+    dense scores took (ledger, PR 27)."""
     cfg, compiled = _train_cell_step("cub200-train", topo.devices)
-    assert not cfg.use_pallas
     _assert_flash_step(cfg, compiled)
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
@@ -243,7 +223,7 @@ def test_default_lucid1024_dp_step_splits_the_kernel(topo):
 def test_rematerialised_layer_compiles(one_chip):
     """``use_remat``: the custom VJP under ``jax.checkpoint`` compiles for
     the chip (forward, its recomputation, dq, dk/dv: 4 kernels a layer)."""
-    cfg = dataclasses.replace(bench.cub200_config(), depth=1, use_remat=True)
+    cfg = dataclasses.replace(cub200_config(), depth=1, use_remat=True)
     model, shapes = _param_shapes(cfg)
     batch = jax.ShapeDtypeStruct((16, cfg.text_seq_len), jnp.int32,
                                  sharding=one_chip)
@@ -284,7 +264,7 @@ def serve_arena():
     over the bf16 KV cache (checkpoints carry no dtype)."""
     from dalle_pytorch_tpu.serve import SlotArena
 
-    cfg = dataclasses.replace(bench.cub200_config(), dtype=jnp.float32)
+    cfg = dataclasses.replace(cub200_config(), dtype=jnp.float32)
     model, shapes = _param_shapes(cfg)
     return cfg, SlotArena(model, {"params": shapes}, num_slots=4,
                           filter_thres=1.0)
@@ -382,7 +362,7 @@ def test_dense_train_step_compiles_and_fits(topo):
     compiler's 2026-09-26 answer was temp 7.3 GB + arguments 0.23 GB of
     16 GB, in ~13 s."""
     _, _, step, abstract = chip_smoke.plan_step(
-        "dp", topo.devices[:1], bench.cub200_config(), 16)
+        "dp", topo.devices[:1], cub200_config(), 16)
     mem = step.lower(*abstract).compile().memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             < V5E_HBM_BYTES)
@@ -393,7 +373,7 @@ def test_generate_b8_compiles(one_chip):
     """Prefill + the 1024-step decode scan at batch 8 (~18 s)."""
     from dalle_pytorch_tpu.models.dalle import decode_codes, prefill_codes
 
-    cfg = dataclasses.replace(bench.cub200_config(), dtype=jnp.float32)
+    cfg = dataclasses.replace(cub200_config(), dtype=jnp.float32)
     model, shapes = _param_shapes(cfg)
 
     def generate(variables, text, key):
@@ -415,7 +395,7 @@ def test_sharded_step_compiles_for_four_chips(topo, spec):
     the four described devices: collectives present, per-device bytes
     inside one chip."""
     _, _, step, abstract = chip_smoke.plan_step(
-        spec, topo.devices, bench.cub200_config(), 16)
+        spec, topo.devices, cub200_config(), 16)
     compiled = step.lower(*abstract).compile()
     assert chip_smoke.collectives_in(compiled.as_text())
     mem = compiled.memory_analysis()

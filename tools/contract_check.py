@@ -31,9 +31,6 @@ ISSUE 7 "int8 quantized serving"):
 * C4 shardings resolve — for all five parallel strategies (dp, fsdp, tp,
   sp-ring, sp-ulysses) the strategy's step traces and its shardings
   lower/partition on a virtual mesh.
-* C5 config variants instantiate — the pallas tile ladder (128/256/512)
-  and all three KV-cache storage layouts prefill to the expected shapes
-  at the production CUB geometry.
 
 Usage:
     JAX_PLATFORMS=cpu python tools/contract_check.py [--quick]
@@ -90,7 +87,7 @@ def tiny_config(**overrides) -> DALLEConfig:
 
 
 def cub_config(**overrides) -> DALLEConfig:
-    """The production CUB-200 geometry (bench.py::cub200_config shapes)."""
+    """The production CUB-200 geometry (``presets.cub_config``'s)."""
     base = dict(dim=256, depth=8, heads=8, dim_head=64,
                 num_text_tokens=7800, text_seq_len=80,
                 num_image_tokens=1024, image_size=256, image_fmap_size=32)
@@ -478,19 +475,6 @@ def check_preset(name: str, batch: int = 8) -> None:
             f"{type(e).__name__}: {e}") from e
 
 
-# --- C5: config variants ------------------------------------------------
-
-PALLAS_TILES = (128, 256, 512)
-
-
-def check_pallas_variant(block: int, make_cfg=cub_config) -> None:
-    """The pallas tile config instantiates and prefills to the contract
-    shapes (abstract eval only — Mosaic never lowers here)."""
-    cfg = make_cfg(use_pallas=True, pallas_block_q=block,
-                   pallas_block_k=block)
-    check_cache_dtype(cfg)
-
-
 # --- driver --------------------------------------------------------------
 
 
@@ -545,9 +529,6 @@ def run_all(quick: bool = False) -> int:
         check_spec_verify_no_dequant, make_cfg(spec_decode=True))
     for name in STRATEGIES:
         run(f"C4 shardings resolve [{name}]", check_strategy, name)
-    for block in PALLAS_TILES if not quick else PALLAS_TILES[:1]:
-        run(f"C5 pallas tiles [block={block}]", check_pallas_variant, block,
-            make_cfg)
     if not quick:
         from dalle_pytorch_tpu.presets import SCALE_PRESETS
         for name in sorted(SCALE_PRESETS):

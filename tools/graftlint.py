@@ -9,7 +9,7 @@ backend init, no device calls, milliseconds per file once imported — so it
 gates in CI and before any chip call without costing chip time.
 
 Usage:
-    python tools/graftlint.py dalle_pytorch_tpu tools bench.py \
+    python tools/graftlint.py dalle_pytorch_tpu tools chip_smoke.py \
         train_dalle.py genrank.py
     python tools/graftlint.py --select ENV001 --fix dalle_pytorch_tpu
     python tools/graftlint.py --write-baseline ...   # grandfather findings

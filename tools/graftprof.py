@@ -207,7 +207,7 @@ def _vae_cfg(quick: bool) -> VAEConfig:
     if quick:
         return VAEConfig(image_size=16, num_tokens=16, codebook_dim=16,
                          num_layers=1, hidden_dim=16)
-    # bench.py::vae128_config — the reference stage-1 geometry
+    # the reference stage-1 geometry (ref train_vae.py:42-59)
     return VAEConfig(image_size=128, num_tokens=8192, codebook_dim=512,
                      num_layers=2, num_resnet_blocks=2, hidden_dim=256)
 
@@ -248,7 +248,7 @@ def _clip_cfg(quick: bool) -> CLIPConfig:
                           num_visual_tokens=64, visual_enc_depth=1,
                           visual_heads=2, visual_image_size=16,
                           visual_patch_size=8)
-    # the CUB-shaped ViT-B/32 ranker geometry (bench.py genrank stand-in)
+    # the CUB-shaped ViT-B/32 ranker geometry
     return CLIPConfig(dim_text=256, dim_image=256, dim_latent=256,
                       num_text_tokens=7800, text_enc_depth=4,
                       text_seq_len=80, text_heads=8, num_visual_tokens=512,
@@ -482,7 +482,7 @@ def render_report(ledger: dict) -> str:
             f"{roof.get('bound', '-'):>5} {meas_txt[:24]:>24} {gap:>6}")
     lines.append("")
     lines.append("gap = measured MFU / predicted ceiling; measured rows "
-                 "append via bench.record_history / tools/perf_ab.py")
+                 "append via prof.append_measured")
     return "\n".join(lines)
 
 

@@ -15,10 +15,6 @@ trainers / serve scheduler via ``dalle_pytorch_tpu.obs`` and renders:
   spans from every thread of every host on one zoomable timeline.
 * ``--tail N``       — just the last N records per host (the monitor
   uses this to carry a dead run's final moments into its own log).
-* ``--bench-jsonl``  — extract the ``bench`` events back into
-  bench-history.jsonl lines (bench.py's ``record_history`` emits the
-  exact history payload as the event), so the committed perf history is
-  derivable from a run's telemetry stream alone.
 * ``--merge DIR1 DIR2 …`` — the FLEET view: treat each path as one
   host's stream, solve the cross-host clock model from its beacons /
   matched step anchors (``obs/align.py``), rewrite every timestamp onto
@@ -70,11 +66,6 @@ def main(argv=None) -> int:
     parser.add_argument("--tail", type=int, default=0,
                         help="print only the last N records per host "
                              "(one line each) instead of the report")
-    parser.add_argument("--bench-jsonl", action="store_true",
-                        help="emit the stream's `bench` events as "
-                             "bench-history.jsonl lines (payload only, "
-                             "envelope stripped) — the history file is "
-                             "derivable from telemetry")
     args = parser.parse_args(argv)
     if not args.paths and not args.merge:
         parser.error("give stream paths, or --merge DIR1 DIR2 ...")
@@ -89,14 +80,7 @@ def main(argv=None) -> int:
         print(f"no readable events under {srcs}", file=sys.stderr)
         return 2
 
-    if args.bench_jsonl:
-        from dalle_pytorch_tpu.obs.telemetry import ENVELOPE_KEYS
-
-        lines = [json.dumps({k: v for k, v in r.items()
-                             if k not in ENVELOPE_KEYS})
-                 for r in events if r.get("kind") == "bench"]
-        out = "\n".join(lines) + ("\n" if lines else "")
-    elif args.tail > 0:
+    if args.tail > 0:
         hosts = sorted({(r.get("run"), r.get("host", 0)) for r in events})
         lines = []
         for run, host in hosts:
