@@ -40,7 +40,8 @@ from ..ops.attention import record_kernel_choices
 from ..ops.ssm import normal_init
 from ..ops.transformer import (RMSNorm, Transformer, TrunkSpec,
                                 layer_mixers)
-from ..utils.helpers import max_neg_value, top_k_filter, top_p_filter
+from ..utils.helpers import (TOP_K_PASSES, max_neg_value, top_k_count,
+                             top_k_filter, top_p_filter)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -813,8 +814,26 @@ def sample_image_code(logits, key, *, k_vocab: int,
     the p-mass set of the distribution actually sampled.  ``temperature``
     may be a traced scalar/array (the serve path carries it per request),
     ``filter_thres``/``top_p`` stay static (``top_k_filter`` derives a
-    static k).  Its own ``sample`` scope: the top-k filter's sort is a
-    quarter of a decode tick's device time at CUB width."""
+    static k).  Its own ``sample`` scope.  The top-k cut-off is found by
+    exact selection (``utils/helpers.py::kth_largest``: 32 counting passes
+    over keys, no ordering; as a sort it was a quarter of a decode tick's
+    device time at CUB width, PERF.md PR 31), a static choice, so it is
+    counted per trace: every traced sampler emits one ``sample.top_k``
+    record (rows as traced, so 1 under a ``vmap``) and sets two gauges.  A
+    ``decode_codes`` program holds two samplers, the first code's and the
+    scan body's."""
+    k = top_k_count(logits.shape[-1], filter_thres, k_vocab)
+    telemetry.emit("sample", "top_k", rows=logits.size // logits.shape[-1],
+                   vocab=logits.shape[-1], k=k, passes=TOP_K_PASSES,
+                   method="select")
+    reg = metrics.active()
+    if reg is not None:
+        reg.gauge("graft_sample_topk_k",
+                  "logits the last traced sampler's top-k filter keeps"
+                  ).set(k)
+        reg.gauge("graft_sample_topk_passes",
+                  "counting passes of the last traced sampler's k-th-largest "
+                  "selection").set(TOP_K_PASSES)
     with prof.scope("sample"):
         # a static temperature of exactly 1 emits no divide (x / 1 is x bit
         # for bit, and XLA drops it anyway)

@@ -275,6 +275,17 @@ def build_report(events: List[dict]) -> dict:
     decode_report: Optional[dict] = None
     if traces:
         decode_report = {"traces": traces, **kv, **state}
+    # models/dalle.py::sample_image_code emits one `sample.top_k` record per
+    # traced sampler (a decode_codes program holds two, a serve tick its
+    # own): how many logits the top-k filter keeps and how it finds the
+    # cut-off; the last one speaks
+    top_k = [r for r in events
+             if r.get("kind") == "sample" and r.get("name") == "top_k"]
+    sampler_report: Optional[dict] = None
+    if top_k:
+        sampler_report = {"traces": len(top_k), **{
+            k: top_k[-1].get(k)
+            for k in ("rows", "vocab", "k", "passes", "method")}}
 
     # --- attention: which core the model's layers run ------------------------
     # ops/attention.py::record_kernel_choices emits one `attention.kernel`
@@ -381,6 +392,7 @@ def build_report(events: List[dict]) -> dict:
         "prof": prof_report,
         "compiles": compile_report,
         "decode": decode_report,
+        "sampler": sampler_report,
         "attention": attention_report,
         "mem": mem_report,
         "faults": faults,
@@ -575,8 +587,10 @@ def render_text(report: dict) -> str:
                      f"{comp['traces_after_first_step']}")
 
     dec = report.get("decode")
-    if dec:
+    sam = report.get("sampler")
+    if dec or sam:
         lines.append("-- decode --")
+    if dec:
         lines.append(
             f"kv cache layout: {dec.get('kv_lane_dense_layers')} layers "
             f"lane-dense, {dec.get('kv_plain_layers')} plain "
@@ -587,6 +601,12 @@ def render_text(report: dict) -> str:
                 f"decode state: {dec.get('kv_layers')} layers of keys and "
                 f"values, {dec.get('ssm_layers')} recurrent; "
                 f"{dec.get('state_bytes_per_row')} bytes a row")
+    if sam:
+        lines.append(
+            f"sampler top-k: keeps {sam.get('k')} of {sam.get('vocab')} "
+            f"logits, cut-off by {sam.get('method')} in "
+            f"{sam.get('passes')} passes ({sam.get('rows')} rows; last of "
+            f"{sam.get('traces')} sampler traces)")
 
     att = report.get("attention")
     if att:

@@ -166,6 +166,231 @@ def test_top_k_filter_sliced_vs_joint_vocab():
                                       err_msg=f"thres={thres}")
 
 
+# --- the top-k cut-off by exact selection (PR 31): bit for bit the filter a
+# sort gives.  The oracles live here only; the program keeps no sort.
+
+# (k_vocab, thres) as the three generate cells derive k over 8,192 image
+# logits: cub200 1601, lucid1024 1844, jamba2-3b 6553
+CELL_K = {1601: (16012, 0.9), 1844: (18448, 0.9), 6553: (65536, 0.9)}
+
+
+def _thres_for(k, width):
+    """A threshold at which ``top_k_filter`` keeps exactly ``k`` of
+    ``width`` logits."""
+    thres = 1.0 - (k + 0.5) / width
+    assert max(int((1 - thres) * width), 1) == k
+    return thres
+
+
+def _filter_args(k, width):
+    if k in CELL_K and width == 8192:
+        k_vocab, thres = CELL_K[k]
+        assert int((1 - thres) * k_vocab) == k
+        return {"thres": thres, "k_vocab": k_vocab}
+    return {"thres": _thres_for(k, width)}
+
+
+def _logits(content, rows, width, seed=0):
+    """f32 rows that try the selection: Gaussian values; heavy ties (a few
+    dozen distinct values, so the cut-off is a duplicated value); runs of +0
+    and -0; +inf and -inf entries; all-equal rows."""
+    rng = np.random.default_rng(seed)
+    x = rng.standard_normal((rows, width)).astype(np.float32)
+    if content == "ties":
+        x = np.round(x * 4) / 4
+    elif content == "zeros":
+        x[:, : width // 4] = 0.0
+        x[:, width // 4: width // 2] = -0.0
+        x = rng.permuted(x, axis=-1)
+    elif content == "infs":
+        x[:, ::5] = np.inf
+        x[:, 1::7] = -np.inf
+    elif content == "equal":
+        x[:] = rng.standard_normal((rows, 1)).astype(np.float32)
+        x[0] = -np.inf
+    else:
+        assert content == "normal"
+    return x
+
+
+def _oracle_filter(x, k):
+    """``np.partition`` finds the k-th largest; the comparison runs on the
+    widened values (exact for bf16), the output keeps the input's dtype."""
+    wide = np.asarray(x, np.float32)
+    kth = np.partition(wide, wide.shape[-1] - k, axis=-1)[
+        ..., wide.shape[-1] - k, None]
+    return np.where(wide < kth, np.asarray(-np.inf, x.dtype), x)
+
+
+def _sorting_filter(logits, thres=0.5, k_vocab=None):
+    """``top_k_filter`` as it was until PR 31: the cut-off from
+    ``lax.top_k``.  The oracle of the sampler tests; the program keeps none."""
+    from dalle_pytorch_tpu.utils.helpers import top_k_count
+
+    k = top_k_count(logits.shape[-1], thres, k_vocab)
+    kth = jax.lax.top_k(logits, k)[0][..., -1:]
+    return jnp.where(logits < kth, -jnp.inf, logits)
+
+
+def _bits(a):
+    a = np.asarray(a)
+    return a.view({2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
+def _assert_bit_equal(got, x, k):
+    want = _oracle_filter(np.asarray(x), k)
+    assert got.dtype == x.dtype and got.shape == x.shape
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+
+
+SHAPES_K = [(rows, 64, k) for rows in (1, 4, 32, 128) for k in (1, 7, 64)] + [
+    (rows, 8192, k) for rows in (1, 4, 32, 128)
+    for k in (1, 7, 1601, 1844, 6553, 8192)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("rows,width,k", SHAPES_K)
+def test_top_k_filter_bit_equal_to_partition(rows, width, k, dtype):
+    """Every shape and k in use (rows 1 to 128; the tests' 64 logits and the
+    cells' 8,192; k = 1, 7, the three cells' and the whole row), on rows with
+    ties: the filtered logits are the oracle's, bit for bit."""
+    x = jnp.asarray(_logits("ties" if k % 2 else "normal", rows, width,
+                            seed=k), dtype)
+    got = top_k_filter(x, **_filter_args(k, width))
+    _assert_bit_equal(got, x, k)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("width,k", [(64, 1), (64, 7), (64, 33), (8192, 7),
+                                     (8192, 1601), (8192, 6553)])
+@pytest.mark.parametrize("content", ["ties", "zeros", "infs", "equal"])
+def test_top_k_filter_bit_equal_on_hard_rows(content, width, k, dtype):
+    x = jnp.asarray(_logits(content, 4, width, seed=width + k), dtype)
+    got = top_k_filter(x, **_filter_args(k, width))
+    _assert_bit_equal(got, x, k)
+
+
+@pytest.mark.parametrize("width,k", [(64, 7), (8192, 1601)])
+@pytest.mark.parametrize("how", ["jit", "vmap", "scan"])
+def test_top_k_filter_bit_equal_under_transforms(how, width, k):
+    """Under ``jit``, per row under ``vmap`` (the serve tick and the
+    speculative sampler) and inside a ``lax.scan`` (``decode_codes``)."""
+    x = jnp.asarray(_logits("ties", 8, width, seed=3))
+    args = _filter_args(k, width)
+    one = lambda a: top_k_filter(a, **args)  # noqa: E731
+    if how == "jit":
+        got = jax.jit(one)(x)
+    elif how == "vmap":
+        got = jax.jit(jax.vmap(one))(x)
+    else:
+        pairs = x.reshape(4, 2, width)
+        _, got = jax.lax.scan(lambda c, a: (c, one(a)), 0, pairs)
+        got = got.reshape(x.shape)
+    _assert_bit_equal(got, x, k)
+
+
+@pytest.mark.parametrize("top_p", [None, 0.8], ids=["top_k", "top_k+top_p"])
+@pytest.mark.parametrize("temperature", [1.0, 0.7])
+def test_sample_image_code_traced_temperature_matches_sort_oracle(
+        temperature, top_p):
+    """The sampler with a traced per-row temperature (the serve tick's):
+    the same codes as the same sampler over a ``lax.top_k`` cut-off."""
+    from dalle_pytorch_tpu.models.dalle import sample_image_code
+    from dalle_pytorch_tpu.utils.helpers import top_p_filter
+
+    x = jnp.asarray(_logits("ties", 16, 64, seed=5))
+    keys = jax.random.split(jax.random.PRNGKey(2), 16)
+    temps = jnp.full((16,), temperature, jnp.float32)
+
+    def oracle(logits, key, temp):
+        filtered = _sorting_filter(logits / temp, thres=0.9, k_vocab=64)
+        if top_p is not None:
+            filtered = top_p_filter(filtered, top_p)
+        return jax.random.categorical(key, filtered).astype(jnp.int32)
+
+    got = jax.jit(jax.vmap(lambda a, key, t: sample_image_code(
+        a, key, k_vocab=64, filter_thres=0.9, temperature=t,
+        top_p=top_p)))(x, keys, temps)
+    want = jax.jit(jax.vmap(oracle))(x, keys, temps)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.fixture(scope="module")
+def cub200_twin():
+    """``cub200-generate``'s tiny twin with a prefill tiled to 4 rows, and a
+    threshold that keeps 6 of its 64 logits (the cell's own 0.9 keeps them
+    all, so the cut-off would decide nothing)."""
+    from benchmark import harness
+    from dalle_pytorch_tpu.models.dalle import prefill_codes, tile_prefill
+
+    cell = harness.load_cell("cub200-generate", rehearse=True)
+    cfg = harness.build_configs(cell.config)[0]
+    model = DALLE(cfg)
+    text = jax.random.randint(jax.random.PRNGKey(1), (1, cfg.text_seq_len),
+                              1, cfg.num_text_tokens)
+    params = model.init(jax.random.PRNGKey(0), text,
+                        jnp.zeros((1, cfg.image_seq_len), jnp.int32))
+    first, caches = tile_prefill(*prefill_codes(model, params, text), 4)
+    thres = _thres_for(6, cfg.total_tokens)
+
+    def draw(key):
+        from dalle_pytorch_tpu.models.dalle import decode_codes
+
+        # a new jit each call: the sampler is traced again
+        return np.asarray(jax.jit(lambda p, f, c, k: decode_codes(
+            model, p, f, c, k, filter_thres=thres))(
+            params, first, caches, key))
+
+    return cfg, draw
+
+
+def test_decode_codes_draws_the_sort_oracles_codes(cub200_twin, monkeypatch):
+    """With one key, ``decode_codes`` over the selection draws exactly the
+    codes it draws over a sort-based cut-off."""
+    from dalle_pytorch_tpu.models import dalle as dalle_module
+
+    cfg, draw = cub200_twin
+    selected = draw(jax.random.PRNGKey(31))
+    monkeypatch.setattr(dalle_module, "top_k_filter", _sorting_filter)
+    np.testing.assert_array_equal(selected, draw(jax.random.PRNGKey(31)))
+    assert selected.shape == (4, cfg.image_seq_len)
+    assert len(np.unique(selected)) > 4  # a draw, not a constant
+
+
+def test_sampler_trace_reports_its_top_k(cub200_twin, tmp_path):
+    """One trace of ``decode_codes`` holds two samplers (the first code's and
+    the scan body's): a ``sample.top_k`` record each, the two gauges, and the
+    line ``tools/obs_report.py`` prints under ``-- decode --``."""
+    from dalle_pytorch_tpu.obs import metrics, telemetry
+    from dalle_pytorch_tpu.obs.report import build_report, render_text
+
+    _, draw = cub200_twin
+    reg = metrics.init()
+    tel = telemetry.init(tmp_path, run_id="top-k")
+    try:
+        draw(jax.random.PRNGKey(0))
+        rendered = reg.render()
+    finally:
+        telemetry.shutdown()
+        metrics.shutdown()
+    events = telemetry.read_events(tel.path)
+    records = [{f: e[f] for f in ("rows", "vocab", "k", "passes", "method")}
+               for e in events
+               if e["kind"] == "sample" and e["name"] == "top_k"]
+    assert records == 2 * [{"rows": 4, "vocab": 64, "k": 6, "passes": 32,
+                            "method": "select"}]
+    assert "graft_sample_topk_k 6" in rendered
+    assert "graft_sample_topk_passes 32" in rendered
+    report = build_report(events)
+    assert report["sampler"] == {"traces": 2, **records[-1]}
+    text_report = render_text(report)
+    assert "-- decode --" in text_report
+    assert ("sampler top-k: keeps 6 of 64 logits, cut-off by select in 32 "
+            "passes (4 rows; last of 2 sampler traces)") in text_report
+
+
 def test_top_p_filter_semantics():
     from dalle_pytorch_tpu.utils.helpers import top_p_filter
 

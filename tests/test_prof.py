@@ -526,12 +526,15 @@ def test_sampler_has_its_own_scope_and_totals_do_not_move(monkeypatch):
     from dalle_pytorch_tpu.models import dalle as dalle_mod
 
     decode, args = _tiny_decode_and_args()
-    # the compiled program's sort carries the scope: what the benchmark's
-    # trace_reduce.scopes_of reads to name `sample/sort` in a breakdown
+    # the compiled program's counting loops (the top-k cut-off's, one in the
+    # first code's sampler and one in the scan body's) carry the scope: what
+    # the benchmark's trace_reduce.scopes_of reads to name `sample/...` in a
+    # breakdown; nothing sorts (the cut-off was a `sample/sort` until PR 31)
     hlo = jax.jit(decode).lower(*args).compile().as_text()
-    sorts = [line for line in hlo.splitlines()
-             if " sort(" in line and "op_name=" in line]
-    assert sorts and all("graftprof:sample" in line for line in sorts), sorts
+    loops = [line for line in hlo.splitlines()
+             if " while(" in line and 'graftprof:sample/while"' in line]
+    assert len(loops) == 2, loops
+    assert not [line for line in hlo.splitlines() if " sort(" in line]
     assert "sample" in prof.SCOPES
 
     attr = prof.attribute(jax.make_jaxpr(decode)(*args))

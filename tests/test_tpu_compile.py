@@ -354,6 +354,49 @@ def test_decode_scan_carries_unpadded_caches(one_chip, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.3 * cache_bytes
 
 
+# --- the sampler's top-k cut-off: selected, not sorted -----------------------
+
+def _sampler_sorts(hlo_text):
+    """The instructions under the ``sample`` scope that order a row: a
+    ``sort``, or a ``top_k`` under either of its names."""
+    return [line.split(" = ")[0].strip() for line in hlo_text.splitlines()
+            if "graftprof:sample" in line
+            and re.search(r"\bsort\(|top_?k", line.split("metadata=")[0],
+                          re.IGNORECASE)]
+
+
+@pytest.mark.parametrize("top_p", [None, 0.9], ids=["top_k", "top_k+top_p"])
+def test_decode_program_sorts_only_for_the_nucleus(one_chip, top_p):
+    """``decode_codes`` of ``cub200-generate``'s tiny twin, compiled for the
+    chip: the top-k filter finds its cut-off by counting passes, so nothing
+    under the ``sample`` scope sorts; the nucleus filter, which needs the
+    sorted cumulative mass, still does when ``top_p`` is set."""
+    from benchmark import harness
+    from dalle_pytorch_tpu.models.dalle import (decode_codes, prefill_codes,
+                                                tile_prefill)
+
+    cell = harness.load_cell("cub200-generate", rehearse=True)
+    cfg = harness.build_configs(cell.config)[0]
+    model, shapes = _param_shapes(cfg)
+    variables = {"params": shapes}
+    first, caches = jax.eval_shape(
+        lambda v, t: tile_prefill(*prefill_codes(model, v, t),
+                                  int(cell.traffic["fanout"])),
+        variables, jnp.zeros((1, cfg.text_seq_len), jnp.int32))
+    # 6 of the twin's 64 logits: the cell's own 0.9 would keep them all
+    thres = 1.0 - 6.5 / cfg.total_tokens
+    compiled = jax.jit(
+        lambda v, f, c, k: decode_codes(model, v, f, c, k,
+                                        filter_thres=thres, top_p=top_p)
+    ).lower(_on(one_chip, variables), _on(one_chip, first),
+            _on(one_chip, caches),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+            ).compile()
+    text = compiled.as_text()
+    assert "graftprof:sample" in text
+    assert bool(_sampler_sorts(text)) == (top_p is not None)
+
+
 # --- the long compiles: kept, but outside the quick tier --------------------
 
 @pytest.mark.slow
