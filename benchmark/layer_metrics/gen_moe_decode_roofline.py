@@ -1,0 +1,20 @@
+"""Least time of a whole decode tick of a routed, windowed trunk (the touched
+expert banks, the attention, router and norm weights, the head's image rows,
+each layer's reachable keys and values, window-bounded and averaged over the
+traced ticks' positions, over the memory bandwidth; or its FLOPs if longer)
+over the measured device time of a tick."""
+from benchmark import rooflines_smallthinker_21ba3b as rooflines
+from benchmark.layer_metrics._common import decode_tick_s, pct
+from benchmark.layer_metrics._moe import routed
+
+
+def read(run):
+    host = run.outcome.host
+    tick_s = decode_tick_s(run, "jit_bench_decode",
+                           host["decode_steps_traced"])
+    if tick_s is None or run.peaks is None or not routed(run):
+        return None
+    least = rooflines.tick_least_s(
+        run.dalle_cfg, host["rows"], host.get("n_prime", 0),
+        host["decode_steps_traced"], run.peaks)
+    return pct(least["seconds"] / tick_s)

@@ -37,9 +37,9 @@ import jax.numpy as jnp
 
 from ..obs import metrics, prof, telemetry
 from ..ops.attention import record_kernel_choices
-from ..ops.ssm import normal_init
+from ..ops.ssm import fan_in_normal, normal_init
 from ..ops.transformer import (RMSNorm, Transformer, TrunkSpec,
-                                layer_mixers)
+                                layer_cache_lens, layer_mixers)
 from ..utils.helpers import (TOP_K_PASSES, max_neg_value, top_k_count,
                              top_k_filter, top_p_filter)
 
@@ -140,10 +140,12 @@ class DALLEConfig:
     # layout, phase mask, sampler, loss): ops/transformer.py::TrunkSpec, or
     # the plain dict it is built from (a checkpoint's hparams, a benchmark
     # configuration).  A model hyperparameter: it changes the parameter
-    # tree (RMSNorm / Mamba / multi-query / SwiGLU layers, and ONE table of
-    # ``total_tokens`` rows tied between the embedding and the head in
-    # place of text_emb / image_emb / to_logits_dense).  None is the 2021
-    # DALL-E block, its parameter names and its programs.
+    # tree (RMSNorm / Mamba / grouped-query / SwiGLU or routed-expert
+    # layers, and ONE table of ``total_tokens`` rows, tied between the
+    # embedding and the head or with a separate ``head``, in place of
+    # text_emb / image_emb / to_logits_dense; no position embeddings over a
+    # rotary trunk).  None is the 2021 DALL-E block, its parameter names and
+    # its programs.
     trunk: Optional[TrunkSpec] = None
     dtype: Any = jnp.float32
 
@@ -164,15 +166,21 @@ class DALLEConfig:
         if isinstance(self.trunk, dict):
             object.__setattr__(self, "trunk", TrunkSpec(**self.trunk))
         if self.trunk is not None:
-            # a recurrent layer has no meaning yet on these paths
+            # what a trunk's layers have no form for yet: a recurrent state
+            # or a ring of keys cannot be recomputed backwards (reversible)
+            # or rolled back (spec_decode); the trunk's blocks have no int8
+            # kernels (weights_int8: expert banks least of all) and its
+            # grouped, rotated or ring caches no int8 layout (kv_cache_int8)
             for field in ("reversible", "spec_decode", "weights_int8",
                           "kv_cache_int8", "sparse_attn"):
                 assert not getattr(self, field), (
                     f"{field} is not supported over a TrunkSpec trunk (its "
-                    "state-space layers carry a recurrent state, not keys)")
+                    "layers carry a recurrent state, grouped keys or a ring "
+                    "of them, and routed experts; none has that form)")
             assert self.ring_axis is None and self.ff_experts <= 1, (
-                "a TrunkSpec trunk runs unsharded in sequence and with a "
-                "dense feed-forward")
+                "a TrunkSpec trunk runs unsharded in sequence (no ring or "
+                "Ulysses form of a windowed or grouped layer) and routes "
+                "through its own ff = 'moe_reglu', not ff_experts")
             assert self.attn_dropout == 0 and self.ff_dropout == 0, (
                 "a TrunkSpec trunk has no dropout")
             assert self.heads % self.trunk.kv_heads == 0, (
@@ -219,8 +227,21 @@ class DALLEConfig:
     @property
     def mixers(self) -> Tuple[str, ...]:
         """Each layer's mixer, and so the kind of its decode state:
-        "attention" carries ``(k, v)``, "mamba" ``(window, h)``."""
+        "attention" carries ``(k, v)`` over every position, "window" over a
+        ring of the window's length, "mamba" ``(window, h)``."""
         return layer_mixers(self.trunk, self.depth)
+
+    @property
+    def cache_lens(self) -> Tuple[int, ...]:
+        """Slots of each layer's key/value cache (0: it keeps none)."""
+        return layer_cache_lens(self.trunk, self.depth, self.seq_len)
+
+    @property
+    def rotary(self) -> bool:
+        """The trunk rotates queries and keys itself, so the client adds no
+        learned position embedding (upstream: ``rotary_emb`` makes
+        ``text_pos_emb`` / ``image_pos_emb`` ``always(0)``)."""
+        return self.trunk is not None and self.trunk.rotary
 
     def to_dict(self) -> dict:
         d = dataclasses.asdict(self)
@@ -368,20 +389,27 @@ class DALLE(nn.Module):
         cfg = self.cfg
         if cfg.trunk is not None:
             # one table over the joint vocabulary [text ids | per-position
-            # pad ids | image codes], embedding and head tied; the learned
-            # position embeddings are DALL-E's, outside the trunk, at the
-            # table's scale
+            # pad ids | image codes], embedding and head tied, or a separate
+            # head beside it (stored [vocab, dim] like the table, so that a
+            # phase's logits read whole rows); the learned position
+            # embeddings are DALL-E's, outside the trunk, at the table's
+            # scale, and absent over a trunk that rotates
+            param_dtype = jnp.dtype(cfg.trunk.param_dtype)
             self.table = nn.Embed(
-                cfg.total_tokens, cfg.dim,
-                param_dtype=jnp.dtype(cfg.trunk.param_dtype),
+                cfg.total_tokens, cfg.dim, param_dtype=param_dtype,
                 embedding_init=normal_init(TABLE_STD), name="table")
-            self.text_pos_emb = nn.Embed(
-                cfg.text_seq_len + 1, cfg.dim,
-                embedding_init=nn.initializers.normal(TABLE_STD),
-                name="text_pos_emb")
-            self.image_pos_emb = AxialPositionalEmbedding(
-                cfg.dim, cfg.image_fmap_size, std=TABLE_STD,
-                name="image_pos_emb")
+            if not cfg.trunk.tied_table:
+                self.head = self.param(
+                    "head", fan_in_normal(cfg.dim),
+                    (cfg.total_tokens, cfg.dim), param_dtype)
+            if not cfg.rotary:
+                self.text_pos_emb = nn.Embed(
+                    cfg.text_seq_len + 1, cfg.dim,
+                    embedding_init=nn.initializers.normal(TABLE_STD),
+                    name="text_pos_emb")
+                self.image_pos_emb = AxialPositionalEmbedding(
+                    cfg.dim, cfg.image_fmap_size, std=TABLE_STD,
+                    name="image_pos_emb")
             self.transformer = Transformer(name="transformer",
                                            **transformer_kwargs(cfg))
             self.final_norm = RMSNorm(cfg.trunk.norm_eps, name="final_norm")
@@ -422,7 +450,8 @@ class DALLE(nn.Module):
         )
         text = jnp.pad(self._remap_pad_tokens(text), ((0, 0), (1, 0)))  # <bos> id 0
         tokens = self._lookup_ids(text, False)
-        tokens = tokens + self.text_pos_emb(jnp.arange(text.shape[1]))
+        if not cfg.rotary:
+            tokens = tokens + self.text_pos_emb(jnp.arange(text.shape[1]))
         return tokens.astype(cfg.dtype)
 
     def _lookup_ids(self, ids, image: bool):
@@ -436,7 +465,8 @@ class DALLE(nn.Module):
 
     def _embed_image_codes(self, codes):
         emb = self._lookup_ids(codes, True)
-        emb = emb + self.image_pos_emb(codes.shape[1])
+        if not self.cfg.rotary:
+            emb = emb + self.image_pos_emb(codes.shape[1])
         return emb.astype(self.cfg.dtype)
 
     @staticmethod
@@ -494,10 +524,12 @@ class DALLE(nn.Module):
                 from ..ops.quant import qdense
                 return qdense(h, *qhead)  # f32 logits
             if self.cfg.trunk is not None:
-                # the tied table's rows of the wanted phase (DALL-E's phase
-                # mask), multiplicands in the table's dtype, f32 logits
+                # the wanted phase's rows (DALL-E's phase mask) of the tied
+                # table or of the separate head, multiplicands in their
+                # dtype, f32 logits
                 split = self.cfg.total_text_tokens
-                rows = self.table.embedding
+                rows = (self.table.embedding if self.cfg.trunk.tied_table
+                        else self.head)
                 rows = (rows[split:] if image_only else
                         rows[:split] if text_only else rows)
                 return jnp.einsum("...d,vd->...v", h.astype(rows.dtype),
@@ -640,14 +672,23 @@ class DALLE(nn.Module):
         if cfg.trunk is not None:
             # the prompt's positions only: a recurrent layer's state is the
             # one after the last of them, and an attention layer's keys and
-            # values are padded out to the cache's static length
+            # values are padded out to the cache's static length; a window
+            # layer's cache is a ring (position p in slot p mod slots), so
+            # a prompt longer than it leaves its last ``slots`` positions,
+            # rolled to their slots
+            def stored(a, slots):
+                a = a.astype(jnp.bfloat16) if cfg.kv_cache_bf16 else a
+                if n_pre <= slots:
+                    return jnp.pad(a, ((0, 0), (0, 0), (0, slots - n_pre),
+                                       (0, 0)))
+                return jnp.roll(a[:, :, n_pre - slots:],
+                                (n_pre - slots) % slots, axis=2)
+
             with prof.scope("attn-cache"):
-                room = ((0, 0), (0, 0), (0, pad), (0, 0))
                 kvs = [kv if kind == "mamba" else
-                       tuple(jnp.pad(a.astype(jnp.bfloat16)
-                                     if cfg.kv_cache_bf16 else a, room)
-                             for a in kv)
-                       for kind, kv in zip(cfg.mixers, kvs)]
+                       tuple(stored(a, slots) for a in kv)
+                       for kind, slots, kv in zip(cfg.mixers, cfg.cache_lens,
+                                                  kvs)]
         elif cfg.kv_cache_int8:
             # int8 cache storage: per-head symmetric scales computed HERE,
             # at prefill write time — the one place the whole sequence is
@@ -704,14 +745,17 @@ class DALLE(nn.Module):
             with prof.scope("embed"):
                 emb = self._lookup_ids(code[:, None], True)
                 img_index = index - (cfg.text_seq_len + 1)
-                pos_grid = self.image_pos_emb(cfg.image_seq_len)
-                if jnp.ndim(index) > 0:
+                if cfg.rotary:
+                    pass    # the trunk's own layers take the position
+                elif jnp.ndim(index) > 0:
                     # per-row positions: gather each row's pos-emb (clipped
                     # like dynamic_slice clamps — idle serve slots park out
                     # of range)
+                    pos_grid = self.image_pos_emb(cfg.image_seq_len)
                     rows = jnp.clip(img_index, 0, cfg.image_seq_len - 1)
                     emb = emb + jnp.take(pos_grid, rows, axis=0)[:, None]
                 else:
+                    pos_grid = self.image_pos_emb(cfg.image_seq_len)
                     emb = emb + jax.lax.dynamic_slice_in_dim(
                         pos_grid, img_index, 1, axis=0)[None]
                 x = emb.astype(cfg.dtype)
@@ -899,13 +943,16 @@ def _lane_dense_caches(dalle: DALLE, params, caches):
     layers' caches were folded and how many kept plain, a
     ``decode.state_layout`` record and three gauges how many layers carry
     keys and values, how many a recurrent state, and the bytes of decode
-    state one row holds."""
+    state one row holds; over a routed trunk a ``decode.moe_layout`` record
+    and three gauges besides: the expert layers, the window layers, and the
+    key/value slots one row holds over all layers (a window layer holds its
+    ring, not ``seq_len``)."""
     from ..ops.quant import cache_values
 
     cfg = dalle.cfg
     with prof.scope("attn-cache"):
         folded = dalle.apply(params, caches, method=DALLE.lane_dense_caches)
-    attn = [i for i, kind in enumerate(cfg.mixers) if kind == "attention"]
+    attn = [i for i, kind in enumerate(cfg.mixers) if kind != "mamba"]
     dense = sum(cache_values(folded[i][0]).shape
                 != cache_values(caches[i][0]).shape for i in attn)
     rows = int(jax.tree.leaves(caches)[0].shape[0])
@@ -924,6 +971,22 @@ def _lane_dense_caches(dalle: DALLE, params, caches):
             for name, value in counts.items():
                 reg.gauge(f"graft_decode_{name}",
                           f"decode_codes' last trace ({record})").set(value)
+    if cfg.trunk is not None and cfg.trunk.routed:
+        t = cfg.trunk
+        counts = {"moe_layers": cfg.depth,
+                  "window_layers": cfg.mixers.count("window"),
+                  "kv_slots_per_row": sum(cfg.cache_lens)}
+        telemetry.emit(
+            "decode", "moe_layout", rows=rows, layers=cfg.depth,
+            experts=t.experts, experts_per_token=t.experts_per_token,
+            expert_bytes_per_layer=3 * t.experts * cfg.dim * t.expert_dim
+            * jnp.dtype(t.param_dtype).itemsize,
+            window_layers=counts["window_layers"],
+            kv_slots_per_row=counts["kv_slots_per_row"])
+        if reg is not None:
+            for name, value in counts.items():
+                reg.gauge(f"graft_decode_{name}",
+                          "decode_codes' last trace (moe_layout)").set(value)
     return folded
 
 

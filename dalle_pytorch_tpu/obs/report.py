@@ -272,9 +272,17 @@ def build_report(events: List[dict]) -> dict:
         "kv_layout", ("rows", "kv_lane_dense_layers", "kv_plain_layers"))
     _, state = last_decode(
         "state_layout", ("kv_layers", "ssm_layers", "state_bytes_per_row"))
+    # over a routed trunk a `decode.moe_layout` record besides: the expert
+    # layers, their banks' bytes, the window layers and the key slots a row
+    # holds over all layers
+    _, routed = last_decode(
+        "moe_layout", ("layers", "experts", "experts_per_token",
+                       "expert_bytes_per_layer", "window_layers",
+                       "kv_slots_per_row"))
     decode_report: Optional[dict] = None
     if traces:
-        decode_report = {"traces": traces, **kv, **state}
+        decode_report = {"traces": traces, **kv, **state,
+                         **({"moe": routed} if routed else {})}
     # models/dalle.py::sample_image_code emits one `sample.top_k` record per
     # traced sampler (a decode_codes program holds two, a serve tick its
     # own): how many logits the top-k filter keeps and how it finds the
@@ -601,6 +609,15 @@ def render_text(report: dict) -> str:
                 f"decode state: {dec.get('kv_layers')} layers of keys and "
                 f"values, {dec.get('ssm_layers')} recurrent; "
                 f"{dec.get('state_bytes_per_row')} bytes a row")
+        if "moe" in dec:
+            m = dec["moe"]
+            lines.append(
+                f"routed experts: {m.get('layers')} layers of "
+                f"{m.get('experts')}, {m.get('experts_per_token')} a token, "
+                f"at {dec.get('rows')} rows "
+                f"({m.get('expert_bytes_per_layer')} bytes of banks a "
+                f"layer); {m.get('window_layers')} window layers, "
+                f"{m.get('kv_slots_per_row')} key slots a row")
     if sam:
         lines.append(
             f"sampler top-k: keeps {sam.get('k')} of {sam.get('vocab')} "

@@ -109,6 +109,33 @@ def _block_diag_q(q, fold: int):
         b, h // fold, fold * dh, fold)
 
 
+def apply_rope(x, positions, theta: float):
+    """Rotary position embedding over the whole last axis of ``x`` ``[b, h,
+    n, d]`` at integer ``positions`` ``[n]`` or ``[b, n]``: dimension ``i <
+    d / 2`` pairs with ``i + d / 2`` (rotate-half) and turns by ``p *
+    theta^(-2i / d)``.  Angles, sines and the rotation in float32; the
+    result in ``x``'s dtype."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.asarray(positions, jnp.float32)[..., None] * freq
+    if angle.ndim == 3:                 # per-row positions: [b, 1, n, d / 2]
+        angle = angle[:, None]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    lo, hi = x[..., :half].astype(jnp.float32), x[..., half:].astype(
+        jnp.float32)
+    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def ring_positions(index, slots: int):
+    """The position each slot of a ring cache holds once position ``index``
+    (traced scalar or ``[b]``) is written: the largest ``p <= index`` with
+    ``p mod slots == slot``; negative where the slot was never written."""
+    index = jnp.asarray(index, jnp.int32)
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    return index[..., None] - jnp.remainder(index[..., None] - slot, slots)
+
+
 def make_variable_sparse_layout(
     num_blocks: int,
     global_blocks: int,
@@ -166,11 +193,24 @@ class AttnPattern:
     block: int = 16       # sparse block size (ref attention.py:292)
     num_random_blocks: Optional[int] = None
     layout_seed: int = 0
+    # sliding window of a causal ``full`` layer: query i reaches keys j in
+    # (i - window, i]; 0 is unbounded.  No flash kernel bounds its keys yet:
+    # a windowed layer takes the dense core (:func:`flash_tiles`).
+    window: int = 0
 
     def __post_init__(self):
         assert self.variant in VARIANTS, f"unknown attention variant {self.variant}"
         if self.variant == "conv_like":
             assert self.kernel % 2 == 1, "kernel size must be odd"
+        assert self.window >= 0 and (not self.window or (
+            self.variant == "full" and self.causal)), (
+            "a sliding window bounds a causal 'full' layer only")
+
+    @property
+    def cache_len(self) -> int:
+        """Slots of this layer's decode cache: every position, or a ring of
+        the window's length (position p lives in slot ``p mod window``)."""
+        return min(self.window, self.seq_len) if self.window else self.seq_len
 
     @property
     def padded_len(self) -> int:
@@ -202,6 +242,8 @@ def _allowed(pattern: AttnPattern, i, j, xp, layout=None):
     """
     T, W = pattern.text_len, pattern.fmap
     causal = (j <= i) if pattern.causal else (j == j)
+    if pattern.window:
+        causal = causal & (j > i - pattern.window)
     v = pattern.variant
 
     if v == "full":
@@ -399,8 +441,8 @@ def flash_tiles(n: int, dim_head: int, dtype, pattern: AttnPattern,
     program really holds is settled where it is lowered (the kernel exists
     for the TPU only: :meth:`MultiHeadAttention._attention_core`).
 
-    Dense stays for grouped keys and the sequence-parallel plans (their own
-    branches), for float32 activations (the kernel's products would run as
+    Dense stays for grouped keys, a sliding window and the sequence-parallel
+    plans (their own branches), for float32 activations (the kernel's products would run as
     several bf16 passes where XLA's default precision takes one), for a
     ``dim_head`` that does not fill a whole number of half-lanes, for
     sequences under :data:`FLASH_MIN_LEN`, and where no tiling fits.
@@ -411,7 +453,7 @@ def flash_tiles(n: int, dim_head: int, dtype, pattern: AttnPattern,
     those that leave at most :data:`FLASH_MAX_BLOCKS` blocks to compute and
     fit VMEM: 384 for ``full`` and ``axial_col`` and 128 for ``axial_row``
     and ``conv_like`` at 1152, 256 at 1280, 384 at 4224."""
-    if (kv_heads is not None or ring_axis is not None
+    if (kv_heads is not None or ring_axis is not None or pattern.window
             or jnp.dtype(dtype).itemsize != 2 or dim_head % (LANES // 2)
             or n < FLASH_MIN_LEN):
         return None
@@ -684,6 +726,10 @@ class MultiHeadAttention(nn.Module):
     # plain cache layouts; the Pallas, ring and int8 paths do not.
     kv_heads: Optional[int] = None
     use_bias: bool = True             # the output projection's
+    # rotate queries and keys by their position (:func:`apply_rope`, the
+    # index in the sequence the layer sees); None: no position encoding.
+    # Keys enter the cache rotated, so a ring's slot order does not matter.
+    rope_theta: Optional[float] = None
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -718,12 +764,22 @@ class MultiHeadAttention(nn.Module):
         self.to_out = nn.Dense(self.dim, use_bias=self.use_bias,
                                dtype=self.dtype, name="to_out")
 
-    def _qkv(self, x):
+    def _qkv(self, x, positions=None):
+        """``positions`` (``[n]`` or ``[b, n]``; None: 0..n-1) matter to a
+        rotary layer only."""
         with prof.scope("attn-qkv"):
             if self.kv_heads is not None:
                 q = self.to_q(x).transpose(0, 2, 1, 3)      # [b, heads, n, dh]
                 kv = self.to_kv(x).transpose(2, 0, 3, 1, 4)  # [2, b, g, n, dh]
-                return q, kv[0], kv[1]
+                k, v = kv[0], kv[1]
+                if self.rope_theta is not None:
+                    if positions is None:
+                        positions = jnp.arange(x.shape[1])
+                    q = apply_rope(q, positions, self.rope_theta)
+                    k = apply_rope(k, positions, self.rope_theta)
+                return q, k, v
+            assert self.rope_theta is None, (
+                "rotary layers are built with kv_heads (the trunk's blocks)")
             qkv = self.to_qkv(x)  # [b, n, 3, heads, dh]
             qkv = qkv.transpose(2, 0, 3, 1, 4)  # [3, b, heads, n, dh]
             return qkv[0], qkv[1], qkv[2]
@@ -800,13 +856,15 @@ class MultiHeadAttention(nn.Module):
             _Core(kernel_pattern(self.pattern), jnp.dtype(act_dtype), tiles,
                   mesh), q, k, v, mask)
 
-    def _qkv_decode(self, x, qw):
+    def _qkv_decode(self, x, qw, index=None):
         """Decode-path QKV projection: the f32/bf16 kernel, or — under
         ``weights_int8`` — the session-quantized int8 kernel as a direct
         dot multiplicand (ops/quant.py::qdense; per-output-channel scales
-        applied to the small product, never to the kernel)."""
+        applied to the small product, never to the kernel).  ``index`` is
+        the token's position, which a rotary layer turns q and k by."""
         if qw is None:
-            return self._qkv(x)
+            return self._qkv(x, None if self.rope_theta is None else
+                             jnp.asarray(index, jnp.int32)[..., None])
         with prof.scope("attn-qkv"):
             q8, s = qw["qkv"]                   # [dim, 3, h, dh] int8
             qkv = qdense(x, q8, s).astype(self.dtype)
@@ -878,7 +936,10 @@ class MultiHeadAttention(nn.Module):
         once per generate/serve session.
         """
         b = x.shape[0]
-        q, k, v = self._qkv_decode(x, qw)  # [b, h, 1, dh]
+        q, k, v = self._qkv_decode(x, qw, index)  # [b, h, 1, dh]
+        if self.pattern.window:
+            return self._decode_step_ring(x, q, k, v, cache_k, cache_v,
+                                          index, mask, qw)
         if write_pos is not None:
             return self._decode_step_aligned(x, q, k, v, cache_k, cache_v,
                                              index, write_pos, mask, qw)
@@ -959,6 +1020,55 @@ class MultiHeadAttention(nn.Module):
                 layout=jnp.asarray(layout) if layout is not None else None,
             )[None, None, None, :]
             row = _merge_key_pad_mask(self.pattern, row, mask)
+            dots = jnp.where(row, dots, max_neg_value(dots.dtype))
+            attn = jax.nn.softmax(dots, axis=-1)  # f32
+            out = self._cache_values(attn, v_vals, v_scale, x.dtype)
+            out = out.transpose(0, 2, 1, 3).reshape(
+                b, 1, self.heads * self.dim_head)
+        return self._out_proj(out, qw), cache_k, cache_v
+
+    def _decode_step_ring(self, x, q, k, v, cache_k, cache_v, index, mask,
+                          qw=None):
+        """Decode against a sliding-window layer's ring cache ``[b, kv
+        heads, slots, dh]`` (``AttnPattern.cache_len`` slots): position p
+        lives in slot ``p mod slots``, so the step overwrites the key that
+        just left the window.  ``index`` is a traced scalar (the static
+        sampler: one ``dynamic_update_slice``) or per-row ``[b]`` (the
+        serving arena, whose rows sit at different depths: a per-row write,
+        and no rotation, since each row's slots follow its own positions).
+        Validity comes from the position each slot holds
+        (:func:`ring_positions`) through the same ``_allowed`` as every other
+        path; keys are stored rotated, so slot order is of no account."""
+        b = x.shape[0]
+        slots = split_cache(cache_k)[0].shape[2]
+        index = jnp.asarray(index, jnp.int32)
+        with prof.scope("attn-cache"):
+            if index.ndim == 0:
+                at = (0, 0, jnp.remainder(index, slots), 0)
+                cache_k = cache_write(cache_k, k, at)
+                cache_v = cache_write(cache_v, v, at)
+            else:
+                at = jnp.remainder(index, slots)[:, None]          # [b, 1]
+                keep = jnp.ones((b, 1), bool)
+                cache_k = cache_write_rows(cache_k, k, at, keep)
+                cache_v = cache_write_rows(cache_v, v, at, keep)
+            k_vals, k_scale = split_cache(cache_k)
+            v_vals, v_scale = split_cache(cache_v)
+        with prof.scope("attn-scores"):
+            dots = self._cache_dots(q * self.dim_head ** -0.5, k_vals,
+                                    k_scale)
+            held = ring_positions(index, slots)       # [slots] or [b, slots]
+            row = _allowed(self.pattern, index[..., None], held, jnp) & (
+                held >= 0)
+            row = row[:, None, None, :] if index.ndim else row[None, None,
+                                                               None, :]
+            if mask is not None:
+                assert index.ndim == 0, (
+                    "per-row decode takes no key padding mask")
+                pad = _scope_key_pad(self.pattern, mask, self.pattern.seq_len)
+                row = row & jnp.take(
+                    pad, jnp.clip(held, 0, self.pattern.seq_len - 1),
+                    axis=1)[:, None, None, :]
             dots = jnp.where(row, dots, max_neg_value(dots.dtype))
             attn = jax.nn.softmax(dots, axis=-1)  # f32
             out = self._cache_values(attn, v_vals, v_scale, x.dtype)

@@ -33,6 +33,12 @@ TPU-native design choices:
 * **router in f32**, switch-style load-balance auxiliary loss (mean
   fraction x mean probability per expert), returned separately so callers
   weight it.
+
+Two layers share one routing rule (:func:`route`): :class:`MoEFeedForward`
+above, the 2021 block's GEGLU experts, and :class:`ExpertsReGLU`, the
+dropless bias-free ReGLU experts of a ``TrunkSpec`` trunk
+(ops/transformer.py::TrunkMoEBlock), whose router logits come from the
+caller.
 """
 from __future__ import annotations
 
@@ -41,6 +47,104 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from ..obs import prof
+
+
+def route(logits, k: int):
+    """The one routing rule: float32 softmax over ALL experts, the ``k``
+    largest probabilities, renormalised over the chosen.
+
+    ``logits`` ``[..., e]`` -> ``(probs [..., e], top_idx [..., k], combine
+    [..., e])``, all float32 but the indices: ``combine`` holds each chosen
+    expert's weight at its own column and exact zeros elsewhere (rows sum to
+    1).  ``jax.lax.top_k`` breaks exact ties towards the lower index."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    e = probs.shape[-1]
+    top_p, top_idx = jax.lax.top_k(probs, k)
+    onehot = jax.nn.one_hot(top_idx, e, dtype=probs.dtype)  # [..., k, e]
+    combine = (top_p[..., None] * onehot).sum(axis=-2)      # [..., e]
+    combine = combine / jnp.clip(
+        combine.sum(axis=-1, keepdims=True), 1e-9)
+    return probs, top_idx, combine
+
+
+class ExpertsReGLU(nn.Module):
+    """Dropless top-k mixture of ReGLU experts without bias:
+    ``y = sum_{e in S} c_e (relu(m W_gate_e) * (m W_up_e)) W_down_e`` with
+    ``S`` and ``c`` from :func:`route` over router logits the CALLER hands
+    in (``router_logits``: a trunk's router reads its layer's input, before
+    the mixer, ops/transformer.py::TrunkMoEBlock).  Every token gets all
+    ``k`` of its experts whatever the imbalance.
+
+    One form for a tick's rows and a sequence's tokens: every expert on every
+    token, the ``combine`` weights' exact zeros cancelling the unchosen before
+    one contraction over experts x width into the model width.  Settled on
+    the chip (PERF.md, Findings PR 32; ms a layer at dim 2560, 64 experts of
+    768, bf16): 1.01 at a tick's 64 rows, where the 755 MB of banks are read
+    whole either way (750 GB/s), and 8.2 at a prompt's 2,049; tokens sorted
+    by expert into ``jax.lax.ragged_dot`` read 4.01 and 12.0, because XLA:TPU
+    lowers it to every expert on all ``tokens x k`` sorted rows under a mask.
+    A grouped product that skips the unchosen needs a kernel of its own
+    (ROADMAP R1).  Scopes: ``moe-route`` (router product, softmax, top-k,
+    renormalise, combine) and ``moe-experts`` (the three products and the
+    gate)."""
+
+    dim: int
+    experts: int
+    k: int
+    expert_dim: int
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        from .ssm import fan_in_normal
+
+        e, d, f = self.experts, self.dim, self.expert_dim
+        bank = dict(dtype=self.param_dtype)
+        self.w_router = self.param("w_router", fan_in_normal(d), (d, e),
+                                   **bank)
+        self.w_gate = self.param("w_gate", fan_in_normal(d), (e, d, f),
+                                 **bank)
+        self.w_up = self.param("w_up", fan_in_normal(d), (e, d, f), **bank)
+        self.w_down = self.param("w_down", fan_in_normal(f), (e, f, d),
+                                 **bank)
+
+    def router_logits(self, x):
+        """``x @ W_r`` with float32 sums: ``[..., e]`` float32."""
+        with prof.scope("moe-route"):
+            kernel = self.w_router.astype(self.dtype)
+            return jnp.einsum("...d,de->...e", x.astype(self.dtype), kernel,
+                              preferred_element_type=jnp.float32)
+
+    def __call__(self, m, router_logits):
+        """``m`` ``[b, n, dim]`` (the normed hidden state), ``router_logits``
+        ``[b, n, e]`` -> ``[b, n, dim]`` in ``m``'s dtype."""
+        b, n, d = m.shape
+        tokens = b * n
+        with prof.scope("moe-route"):
+            _, top_idx, combine = route(router_logits.reshape(tokens, -1),
+                                        self.k)
+            # what this layer's routing chose, for tests and the benchmark's
+            # comparison (a no-op unless "intermediates" is mutable)
+            self.sow("intermediates", "top_idx", top_idx.reshape(b, n, -1))
+        x = m.reshape(tokens, d).astype(self.dtype)
+        w_gate, w_up, w_down = (self.w_gate.astype(self.dtype),
+                                self.w_up.astype(self.dtype),
+                                self.w_down.astype(self.dtype))
+        with prof.scope("moe-experts"):
+            # graftlint: disable=DOT001 (uniform: x and the banks are both cast to self.dtype)
+            gate = jnp.einsum("td,edf->tef", x, w_gate)
+            # graftlint: disable=DOT001 (uniform: x and the banks are both cast to self.dtype)
+            up = jnp.einsum("td,edf->tef", x, w_up)
+            act = jax.nn.relu(gate) * up
+        with prof.scope("moe-route"):
+            act = (act.astype(jnp.float32)
+                   * combine[:, :, None]).astype(self.dtype)
+        with prof.scope("moe-experts"):
+            y = jnp.einsum("tef,efd->td", act, w_down,
+                           preferred_element_type=jnp.float32)
+        return y.reshape(b, n, d).astype(m.dtype)
 
 
 class MoEFeedForward(nn.Module):
@@ -106,14 +210,7 @@ class MoEFeedForward(nn.Module):
         # --- router (f32 for a stable softmax) ---
         router = nn.Dense(e, dtype=jnp.float32, name="router")
         logits = router(x.astype(jnp.float32))  # [b, n, e]
-        probs = jax.nn.softmax(logits, axis=-1)
-
-        # top-k combine weights, renormalized over the selected experts
-        top_p, top_idx = jax.lax.top_k(probs, k)               # [b, n, k]
-        onehot = jax.nn.one_hot(top_idx, e, dtype=probs.dtype)  # [b, n, k, e]
-        combine = (top_p[..., None] * onehot).sum(axis=-2)      # [b, n, e]
-        combine = combine / jnp.clip(
-            combine.sum(axis=-1, keepdims=True), 1e-9)
+        probs, top_idx, combine = route(logits, k)
 
         # --- switch-style load-balance loss (f32) ---
         # fraction of tokens whose top-1 lands on each expert x mean prob

@@ -24,13 +24,14 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..obs import prof
+from ..obs import prof, telemetry
 from ..utils.helpers import cast_tuple, default
 from .attention import AttnPattern, MultiHeadAttention
 from .reversible import reversible_sequence, reversible_sequence_naive
 from .ssm import MambaMixer, fan_in_normal, rms_norm
 
-MIXERS = ("attention", "mamba")
+MIXERS = ("attention", "mamba", "window")
+FFS = ("swiglu", "moe_reglu")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,14 +42,23 @@ class TrunkSpec:
     Absent (``DALLEConfig.trunk`` None) the stack is LayerScale(PreNorm(
     attention)) + LayerScale(PreNorm(GEGLU x4)) as before.
 
-    Taken as far as the Jamba family needs: RMSNorm, multi-query or
-    multi-head attention without position encoding, Mamba-1 with normed
-    ``dt``/``B``/``C``, a dense gated-SiLU feed-forward, and one table tied
-    between the embedding and the head (``models/dalle.py``).  Built from a
-    plain dict (a checkpoint's hparams, a benchmark configuration)."""
+    Every field is a model field (it changes the parameter tree or the
+    mathematics).  Mixers: ``"attention"`` is global grouped-query attention
+    without position encoding, ``"window"`` the same attention rotated
+    (``rope_theta``, ops/attention.py::apply_rope) and bounded to the last
+    ``window`` keys, ``"mamba"`` Mamba-1 with normed ``dt``/``B``/``C``.
+    Feed-forward: ``"swiglu"`` a dense gated SiLU of width ``ff_dim``;
+    ``"moe_reglu"`` ``experts`` routed ReGLU experts of width ``expert_dim``,
+    ``experts_per_token`` a token, dropless (ops/moe.py::ExpertsReGLU), whose
+    router reads the layer's INPUT (before the norm and the mixer, so the
+    two halves of a layer are no longer independent).  ``tied_table``: one
+    table for the embedding and the head, or (False) a table and a separate
+    ``head`` (``models/dalle.py``).  A rotary trunk takes no position
+    embedding from DALL-E's client.  Built from a plain dict (a checkpoint's
+    hparams, a benchmark configuration)."""
 
     mixers: Tuple[str, ...]
-    ff_dim: int
+    ff_dim: int = 0
     kv_heads: int = 1
     norm: str = "rms"
     norm_eps: float = 1e-6
@@ -58,26 +68,63 @@ class TrunkSpec:
     ssm_conv: int = 4
     ssm_dt_rank: int = 160
     param_dtype: str = "bfloat16"     # matrices and the table; gains stay f32
+    window: int = 0
+    rope_theta: float = 10000.0
+    experts: int = 0
+    experts_per_token: int = 0
+    expert_dim: int = 0
+    tied_table: bool = True
 
     def __post_init__(self):
         object.__setattr__(self, "mixers", tuple(self.mixers))
         assert self.mixers and set(self.mixers) <= set(MIXERS), (
             f"trunk mixers {self.mixers} outside {MIXERS}")
-        assert self.norm == "rms" and self.ff == "swiglu", (
+        assert self.norm == "rms" and self.ff in FFS, (
             f"trunk norm {self.norm!r} / ff {self.ff!r}: only 'rms' and "
-            "'swiglu' blocks exist")
+            f"{FFS} blocks exist")
         assert self.param_dtype in ("bfloat16", "float32"), self.param_dtype
+        assert ("window" in self.mixers) == (self.window > 0), (
+            f"'window' layers need a window and a window needs them: "
+            f"{self.mixers}, window {self.window}")
+        if self.ff == "swiglu":
+            assert self.ff_dim > 0, "a swiglu feed-forward needs ff_dim"
+        else:
+            assert (0 < self.experts_per_token <= self.experts
+                    and self.expert_dim > 0), (
+                f"moe_reglu needs experts >= experts_per_token > 0 and an "
+                f"expert_dim: {self.experts}, {self.experts_per_token}, "
+                f"{self.expert_dim}")
 
     def mixer(self, layer: int) -> str:
         return self.mixers[layer % len(self.mixers)]
 
+    @property
+    def rotary(self) -> bool:
+        """Some layer rotates its queries and keys."""
+        return "window" in self.mixers
+
+    @property
+    def routed(self) -> bool:
+        return self.ff == "moe_reglu"
+
 
 def layer_mixers(trunk: Optional[TrunkSpec], depth: int) -> Tuple[str, ...]:
     """Each layer's mixer kind, which is also the kind of its decode state:
-    ``(k, v)`` for "attention", ``(window, h)`` for "mamba"."""
+    ``(k, v)`` over every position for "attention", ``(k, v)`` over a ring
+    of the window's length for "window", ``(window, h)`` for "mamba"."""
     if trunk is None:
         return ("attention",) * depth
     return tuple(trunk.mixer(i) for i in range(depth))
+
+
+def layer_cache_lens(trunk: Optional[TrunkSpec], depth: int,
+                     seq_len: int) -> Tuple[int, ...]:
+    """Slots of each layer's key/value cache: ``seq_len``, or ``min(window,
+    seq_len)`` for a "window" layer (a ring: position p in slot ``p mod
+    window``); 0 for a layer that keeps no keys."""
+    return tuple(0 if kind == "mamba" else
+                 min(trunk.window, seq_len) if kind == "window" else seq_len
+                 for kind in layer_mixers(trunk, depth))
 
 
 def layerscale_init(layer_index: int) -> float:
@@ -221,7 +268,8 @@ class TrunkAttnBlock(nn.Module):
     dim_head: int
     kv_heads: int
     eps: float = 1e-6
-    dtype: Any = jnp.float32
+    rope_theta: Optional[float] = None   # a "window" layer's; its window
+    dtype: Any = jnp.float32             # is the pattern's
     param_dtype: Any = jnp.float32
 
     def setup(self):
@@ -229,7 +277,8 @@ class TrunkAttnBlock(nn.Module):
         self.attn = MultiHeadAttention(
             pattern=self.pattern, dim=self.dim, heads=self.heads,
             dim_head=self.dim_head, kv_heads=self.kv_heads, use_bias=False,
-            dtype=self.dtype, param_dtype=self.param_dtype, name="attn")
+            rope_theta=self.rope_theta, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="attn")
 
     def _normed(self, x):
         with prof.scope("attn-qkv"):
@@ -304,6 +353,36 @@ class SwiGLUBlock(nn.Module):
             return self.down(jax.nn.silu(self.gate(h)) * self.up(h))
 
 
+class TrunkMoEBlock(nn.Module):
+    """PreNorm(routed ReGLU experts) of a :class:`TrunkSpec` trunk
+    (ops/moe.py::ExpertsReGLU): RMSNorm, no bias, no LayerScale, no
+    auxiliary loss.  The router's logits come from the layer's input
+    (:meth:`router_logits`, called before the mixer) and are handed back
+    past it, so the call takes them beside the hidden state."""
+
+    dim: int
+    spec: TrunkSpec
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        from .moe import ExpertsReGLU
+
+        self.norm = RMSNorm(self.spec.norm_eps, name="norm")
+        self.moe = ExpertsReGLU(
+            dim=self.dim, experts=self.spec.experts,
+            k=self.spec.experts_per_token, expert_dim=self.spec.expert_dim,
+            dtype=self.dtype, param_dtype=self.param_dtype, name="moe")
+
+    def router_logits(self, x):
+        return self.moe.router_logits(x)
+
+    def __call__(self, x, router_logits):
+        with prof.scope("moe-route"):
+            normed = self.norm(x).astype(x.dtype)
+        return self.moe(normed, router_logits)
+
+
 class MoEFFBlock(nn.Module):
     """LayerScale(PreNorm(MoE feed-forward)) — the FFBlock with its GEGLU
     swapped for a top-k routed expert mixture (ops/moe.py).  The switch
@@ -348,7 +427,8 @@ class MoEFFBlock(nn.Module):
 class Transformer(nn.Module):
     """Depth x (attn, ff) residual stack with cycled attention variants
     (ref transformer.py:71-123); with a ``trunk`` (:class:`TrunkSpec`),
-    depth x (mixer, SwiGLU) with each layer's mixer attention or Mamba."""
+    depth x (mixer, feed-forward) with each layer's mixer global or windowed
+    attention or Mamba and its feed-forward a SwiGLU or routed experts."""
 
     dim: int
     depth: int
@@ -381,6 +461,10 @@ class Transformer(nn.Module):
     def mixers(self) -> Tuple[str, ...]:
         return layer_mixers(self.trunk, self.depth)
 
+    @property
+    def cache_lens(self) -> Tuple[int, ...]:
+        return layer_cache_lens(self.trunk, self.depth, self.seq_len)
+
     def setup(self):
         attn_types = cast_tuple(default(self.attn_types, ("full",)))
         fmap = default(self.image_fmap_size, 0)
@@ -401,17 +485,27 @@ class Transformer(nn.Module):
                 spec = self.trunk
                 kw = dict(dim=self.dim, dtype=self.dtype,
                           param_dtype=jnp.dtype(spec.param_dtype))
-                if spec.mixer(ind) == "mamba":
+                kind = spec.mixer(ind)
+                if kind == "mamba":
                     attn_blocks.append(TrunkSSMBlock(
                         spec=spec, name=f"layers_{ind}_ssm", **kw))
                 else:
+                    windowed = kind == "window"
                     attn_blocks.append(TrunkAttnBlock(
-                        pattern=pattern, heads=self.heads,
+                        pattern=dataclasses.replace(
+                            pattern, window=spec.window) if windowed
+                        else pattern, heads=self.heads,
                         dim_head=self.dim_head, kv_heads=spec.kv_heads,
-                        eps=spec.norm_eps, name=f"layers_{ind}_attn", **kw))
-                ff_blocks.append(SwiGLUBlock(
-                    ff_dim=spec.ff_dim, eps=spec.norm_eps,
-                    name=f"layers_{ind}_ff", **kw))
+                        eps=spec.norm_eps,
+                        rope_theta=spec.rope_theta if windowed else None,
+                        name=f"layers_{ind}_attn", **kw))
+                if spec.routed:
+                    ff_blocks.append(TrunkMoEBlock(
+                        spec=spec, name=f"layers_{ind}_ff", **kw))
+                else:
+                    ff_blocks.append(SwiGLUBlock(
+                        ff_dim=spec.ff_dim, eps=spec.norm_eps,
+                        name=f"layers_{ind}_ff", **kw))
                 continue
             attn_blocks.append(AttnBlock(
                 pattern=pattern, dim=self.dim, layer_index=ind + 1,
@@ -446,15 +540,37 @@ class Transformer(nn.Module):
         (nn.remat) can thread params AND mutable collections (MoE's sown
         aux losses) through it; a raw jax.checkpoint closure would leak
         tracers out of any sown value."""
+        routed = self._router_logits(ind, x)
         x = x + self.attn_blocks[ind](x, mask=mask, deterministic=deterministic)
-        x = x + self.ff_blocks[ind](x, deterministic=deterministic)
-        return x
+        return x + self._ff(ind, x, routed, deterministic=deterministic)
+
+    def _router_logits(self, ind: int, x):
+        """A routed layer's router logits, read from the layer's input
+        before its mixer runs; None for every other layer."""
+        ff = self.ff_blocks[ind]
+        return ff.router_logits(x) if isinstance(ff, TrunkMoEBlock) else None
+
+    def _ff(self, ind: int, x, routed, qw=None, deterministic: bool = True):
+        """Layer ``ind``'s feed-forward half on the hidden state after its
+        mixer, with the logits :meth:`_router_logits` took before it."""
+        ff = self.ff_blocks[ind]
+        if routed is not None:
+            return ff(x, routed)
+        if qw is not None:
+            return ff(x, qw=qw)
+        return ff(x, deterministic=deterministic)
 
     def __call__(self, x, mask=None, deterministic: bool = True,
                  return_kv: bool = False):
         if self.reversible and not self.is_initializing():
             return self._reversible_call(x, mask, deterministic, return_kv)
 
+        if (self.trunk is not None and self.trunk.routed
+                and not self.is_initializing()):
+            telemetry.emit("moe", "route", tokens=x.shape[0] * x.shape[1],
+                           experts=self.trunk.experts,
+                           k=self.trunk.experts_per_token,
+                           layers=self.depth)
         use_remat = (self.use_remat and not self.is_initializing()
                      and not return_kv)
         remat_block = nn.remat(
@@ -463,11 +579,12 @@ class Transformer(nn.Module):
         kvs = []
         for ind in range(self.depth):
             if return_kv:
+                routed = self._router_logits(ind, x)
                 h, kv = self.attn_blocks[ind](
                     x, mask=mask, deterministic=deterministic, return_kv=True)
                 kvs.append(kv)
                 x = x + h
-                x = x + self.ff_blocks[ind](x, deterministic=deterministic)
+                x = x + self._ff(ind, x, routed, deterministic=deterministic)
             elif use_remat:
                 x = remat_block(self, x, ind, mask, deterministic)
             else:
@@ -532,15 +649,20 @@ class Transformer(nn.Module):
 
     def decode_init_cache(self, batch: int, dtype=None):
         """Zeroed decode state, one pair per layer: ``(k, v)`` ``[b, kv
-        heads, seq_len, dh]`` for an attention layer, ``(window, h)``
-        (ops/ssm.py) for a state-space one."""
+        heads, slots, dh]`` for an attention layer, ``slots`` its own
+        (:attr:`cache_lens`: ``seq_len``, or a ring of the window's length),
+        ``(window, h)`` (ops/ssm.py) for a state-space one."""
         dtype = dtype or self.dtype
         kv_heads = self.heads if self.trunk is None else self.trunk.kv_heads
-        shape = (batch, kv_heads, self.seq_len, self.dim_head)
+
+        def pair(slots):
+            shape = (batch, kv_heads, slots, self.dim_head)
+            return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
         return [
-            blk.ssm.init_state(batch) if kind == "mamba"
-            else (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for blk, kind in zip(self.attn_blocks, self.mixers)
+            blk.ssm.init_state(batch) if kind == "mamba" else pair(slots)
+            for blk, kind, slots in zip(self.attn_blocks, self.mixers,
+                                        self.cache_lens)
         ]
 
     def lane_dense_caches(self, caches):
@@ -582,12 +704,13 @@ class Transformer(nn.Module):
                 x2 = x2 + (ff(x1, qw=qw) if qw is not None else ff(x1))
                 new_caches.append((ck, cv))
             return (x1 + x2) / 2, new_caches
-        for attn, ff, (ck, cv), qw in zip(self.attn_blocks, self.ff_blocks,
-                                          caches, qws):
+        for ind, (attn, (ck, cv), qw) in enumerate(zip(self.attn_blocks,
+                                                       caches, qws)):
+            routed = self._router_logits(ind, x)
             h, ck, cv = attn.decode_step(x, ck, cv, index, mask=mask,
                                          write_pos=write_pos, qw=qw)
             x = x + h
-            x = x + (ff(x, qw=qw) if qw is not None else ff(x))
+            x = x + self._ff(ind, x, routed, qw=qw)
             new_caches.append((ck, cv))
         return x, new_caches
 
@@ -618,10 +741,11 @@ class Transformer(nn.Module):
         qws = qweights if qweights is not None else [None] * self.depth
         new_caches = list(caches)
         for ind in range(depth):
-            attn, ff, qw = self.attn_blocks[ind], self.ff_blocks[ind], qws[ind]
+            attn, qw = self.attn_blocks[ind], qws[ind]
             ck, cv = new_caches[ind]
+            routed = self._router_logits(ind, x)
             h, ck, cv = attn.decode_span(x, ck, cv, qpos, rot, valid, qw=qw)
             x = x + h
-            x = x + (ff(x, qw=qw) if qw is not None else ff(x))
+            x = x + self._ff(ind, x, routed, qw=qw)
             new_caches[ind] = (ck, cv)
         return x, new_caches

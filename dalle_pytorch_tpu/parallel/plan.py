@@ -68,8 +68,15 @@ TRUNK_RULES: Tuple[Tuple[str, P], ...] = (
     # SwiGLU: gate and up column-parallel, down row-parallel
     (r".*ff/(gate|up)/kernel$", P("fsdp", "tp")),
     (r".*ff/down/kernel$", P("tp", "fsdp")),
-    # the tied table [vocab, dim]: as the token embeddings below
+    # routed experts: the banks [experts, in, out] and the router whole on
+    # every device (no ``ep`` plan yet: ROADMAP R1), so ``dp`` replicates
+    # them like everything else
+    (r".*ff/moe/(w_gate|w_up|w_down)$", P(None, None, None)),
+    (r".*ff/moe/w_router$", P(None, None)),
+    # the table [vocab, dim], tied or with its separate head of the same
+    # shape: as the token embeddings below
     (r".*table/embedding$", P("fsdp", "tp")),
+    (r"head$", P("fsdp", "tp")),
 )
 
 # Default partition rules for our models' flax param trees.  Matched against

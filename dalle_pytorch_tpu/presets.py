@@ -24,6 +24,13 @@ jamba-tiny  ~0.05M  dim-32    a ``TrunkSpec`` trunk (Mamba + one
 jamba2-3b   3.03B   dim-2560  DALL-E over AI21-Jamba2-3B's trunk: 26
                               Mamba-1 + 2 multi-query attention
                               layers, bf16; one chip generates
+smallthinker-tiny  (~0.1M, dim-32)  routed ReGLU experts, one global and
+                              three rotated sliding-window layers, an
+                              untied head, at toy width (tests)
+smallthinker-21ba3b  (2.37B, dim-2560)  DALL-E over the first period (4
+                              of 52 layers) of SmallThinker-21BA3B's
+                              trunk, all 64 experts of each, bf16; one
+                              chip generates
 ==========  ======  ========  =======================================
 
 ``cub-512`` and ``cub-1024`` are ALSO :data:`~dalle_pytorch_tpu.parallel.
@@ -55,6 +62,8 @@ PARAM_BANDS = {
     "cub-1024": (1.15e9, 1.45e9),
     "jamba-tiny": (0.01e6, 1e6),
     "jamba2-3b": (2.9e9, 3.2e9),
+    "smallthinker-tiny": (0.01e6, 1e6),
+    "smallthinker-21ba3b": (2.3e9, 2.45e9),
 }
 
 
@@ -189,6 +198,56 @@ def jamba2_3b_config(**overrides):
     return DALLEConfig(**base)
 
 
+#: SmallThinker-21BA3B-Instruct's trunk (huggingface.co/PowerInfer/
+#: SmallThinker-21BA3B-Instruct, config.json): layer i of 52 is global
+#: attention without rotation iff i mod 4 == 0, else rotated (theta 1.5e6)
+#: and bounded to 4,096 keys; 28 queries over 4 keys of 128; 64 ReGLU experts
+#: of 768, 6 a token, the router on the layer's input; an untied head.
+SMALLTHINKER_21BA3B_TRUNK = dict(
+    mixers=("attention", "window", "window", "window"), kv_heads=4,
+    norm="rms", norm_eps=1e-6, ff="moe_reglu", window=4096,
+    rope_theta=1500000.0, experts=64, experts_per_token=6, expert_dim=768,
+    tied_table=False, param_dtype="bfloat16")
+
+
+def smallthinker_tiny_config(**overrides):
+    """The same period at toy width, its window (8) shorter than its prompt
+    (9 positions) and its sequence (24) (tests)."""
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=32, depth=4, heads=4, dim_head=8, num_text_tokens=50,
+                text_seq_len=8, num_image_tokens=32, image_size=64,
+                image_fmap_size=4,
+                trunk=dict(SMALLTHINKER_21BA3B_TRUNK, kv_heads=2, window=8,
+                           experts=8, experts_per_token=3, expert_dim=24,
+                           param_dtype="float32"))
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
+def smallthinker_21ba3b_config(**overrides):
+    """DALL-E's client over one whole period (4 of 52 layers: global,
+    window, window, window) of the SmallThinker-21BA3B-Instruct trunk, every
+    width as published and all 64 experts of each layer: 2.37B parameters,
+    4.75 GB in bfloat16.  The 151,936 rows of the embedding and of the
+    separate head are 143,488 text ids + 256 per-position pad ids + 8,192
+    image codes of a 512 px, 64 x 64 code grid (n = 4,352, so that the
+    4,096 window cuts keys).  ``benchmark/configs/smallthinker-21ba3b.json``
+    is the same model as the benchmark runs it; the other 48 layers would
+    lie on further chips."""
+    import jax.numpy as jnp
+
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=2560, depth=4, heads=28, dim_head=128,
+                num_text_tokens=143488, text_seq_len=256,
+                num_image_tokens=8192, image_size=512, image_fmap_size=64,
+                attn_types=("full",), trunk=SMALLTHINKER_21BA3B_TRUNK,
+                dtype=jnp.bfloat16)
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
 #: Every named config geometry (CLI ``--preset`` surface).
 CONFIG_PRESETS = {
     "tiny": tiny_config,
@@ -197,6 +256,8 @@ CONFIG_PRESETS = {
     "cub-1024": cub1024_config,
     "jamba-tiny": jamba_tiny_config,
     "jamba2-3b": jamba2_3b_config,
+    "smallthinker-tiny": smallthinker_tiny_config,
+    "smallthinker-21ba3b": smallthinker_21ba3b_config,
 }
 
 #: The scale rungs that are ALSO plan-registry entries: registry name ->

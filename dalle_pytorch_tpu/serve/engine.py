@@ -40,6 +40,11 @@ This module is the device half of the fix:
   translate physical -> logical per slot (``ops/attention.py::
   MultiHeadAttention._decode_step_aligned``), which also hides the
   previous resident's stale keys.
+* **Rings beside the rotated caches.**  A sliding-window layer's slot
+  holds ``min(window, seq_len)`` keys (``DALLEConfig.cache_lens``), position
+  p in slot ``p mod ring``: tied to the row's own positions, not to the
+  arena clock, so it is written per row and read whole
+  (``MultiHeadAttention._decode_step_ring``); ``admit`` installs it unrolled.
 * **Recurrent entries beside the caches.**  A state-space layer
   (``DALLEConfig.trunk``) keeps ``(window, h)`` per slot, ``[num_slots,
   ...]`` with no position axis: nothing to rotate and nothing a mask could
@@ -116,11 +121,16 @@ class SlotArena:
                              else jnp.bfloat16 if cfg.kv_cache_bf16
                              else cfg.dtype)
         S = num_slots
-        cache_shape = (S, cfg.kv_heads, cfg.seq_len, cfg.dim_head)
         recurrent = [kind == "mamba" for kind in cfg.mixers]
+        # a window layer's slot holds a ring of its own length, position p
+        # in slot p mod ring whatever the arena's clock: rows at different
+        # depths cannot share a write column there, so such a layer takes
+        # ops/attention.py's per-row ring step and no rotation
+        ring = [kind == "window" for kind in cfg.mixers]
 
-        def fresh_entry():
-            values = jnp.zeros(cache_shape, self._cache_dtype)
+        def fresh_entry(slots):
+            values = jnp.zeros((S, cfg.kv_heads, slots, cfg.dim_head),
+                               self._cache_dtype)
             if not cfg.kv_cache_int8:
                 return values
             return (values, jnp.ones((S, cfg.heads, 1, 1), jnp.float32))
@@ -140,8 +150,10 @@ class SlotArena:
             zero = (dalle.apply(variables, S, method=DALLE.decode_init_state)
                     if any(recurrent) else [None] * cfg.depth)
             return dict(
-                caches=[entry if rec else (fresh_entry(), fresh_entry())
-                        for rec, entry in zip(recurrent, zero)],
+                caches=[entry if rec else (fresh_entry(slots),
+                                           fresh_entry(slots))
+                        for rec, entry, slots in zip(recurrent, zero,
+                                                     cfg.cache_lens)],
                 code=jnp.zeros((S,), jnp.int32),
                 index=jnp.zeros((S,), jnp.int32),
                 pos=jnp.zeros((S,), jnp.int32),
@@ -212,15 +224,17 @@ class SlotArena:
                     scale, new_scale, (slot, 0, 0, 0)))
 
             def install_whole(arena_entry, new_entry):
-                """A recurrent entry has no position axis: the slot's row
-                is replaced whole."""
+                """A recurrent entry has no position axis, and a window
+                layer's ring is prefilled in its own slot order: the slot's
+                row is replaced whole."""
                 return jax.lax.dynamic_update_slice(
                     arena_entry, new_entry.astype(arena_entry.dtype),
                     (slot,) + (0,) * (arena_entry.ndim - 1))
 
-            caches = [tuple(map(install_whole if rec else install, old, new))
-                      for rec, old, new in zip(recurrent, state["caches"],
-                                               caches1)]
+            caches = [tuple(map(install_whole if rec or rng else install,
+                                old, new))
+                      for rec, rng, old, new in zip(
+                          recurrent, ring, state["caches"], caches1)]
             ks = jax.random.split(key, self.geometry.image_seq_len)
             code0 = sample_one(first_logits[0], ks[0], temp)
 

@@ -279,7 +279,8 @@ def make_dalle_pp_train_step(dalle, tx, params, mesh, *,
     cfg = dalle.cfg
     assert cfg.trunk is None, (
         "the pipeline step stacks identical (attn, ff) stages; a TrunkSpec "
-        "trunk's layers differ in kind")
+        "trunk's layers differ in kind (mixers, cache lengths) and a routed "
+        "layer's two halves share its router logits")
     tf = Transformer(**transformer_kwargs(cfg))
     _, stacked, apply_fn = pipeline_transformer(
         tf, params["transformer"], mesh=mesh, pp_axis=pp_axis,

@@ -321,6 +321,71 @@ def test_train_then_generate_over_a_named_trunk(trained_vae, tiny_dataset,
     assert len(images) == 2
 
 
+def test_train_generate_and_serve_a_routed_windowed_trunk(
+        trained_vae, tiny_dataset, tiny_tokenizer_json, tmp_path_factory):
+    """`train_dalle.py --trunk smallthinker-tiny` trains two steps of DALL-E
+    over routed experts and ring-cached window layers; the spec rides in the
+    checkpoint's hparams, so `generate.py` rebuilds the model from the
+    checkpoint alone; and a `SlotArena` built from the same checkpoint
+    serves it to the static path's logits."""
+    import jax
+    import jax.numpy as jnp
+
+    wd = tmp_path_factory.mktemp("routed_cli")
+    _run_train_dalle(wd, dict(BATCH_SIZE=6, TEXT_SEQ_LEN=8),
+                     ["--trunk", "smallthinker-tiny"], trained_vae,
+                     tiny_dataset, tiny_tokenizer_json)
+    from dalle_pytorch_tpu.utils.checkpoint import load_checkpoint
+
+    ckpt = load_checkpoint(wd / "dalle-final.pt")
+    trunk = ckpt["hparams"]["trunk"]
+    assert trunk["mixers"] == ["attention", "window", "window", "window"]
+    assert (trunk["ff"], trunk["experts"], trunk["window"],
+            trunk["tied_table"]) == ("moe_reglu", 8, 8, False)
+    weights = ckpt["weights"]
+    assert "head" in weights and "text_pos_emb" not in weights
+    assert weights["transformer"]["layers_1_ff"]["moe"]["w_gate"].shape[0] == 8
+    log = next(wd.glob("dalle_tpu_train_transformer-*.txt"))
+    assert len(log.read_text().strip().splitlines()) == 2      # two steps
+    assert np.isfinite(_first_loss(wd))
+    cwd = os.getcwd()
+    os.chdir(wd)
+    try:
+        import generate
+
+        generate.main(["--dalle_path", str(wd / "dalle-final.pt"),
+                       "--text", "red bird", "--num_images", "2",
+                       "--batch_size", "2",
+                       "--bpe_path", str(tiny_tokenizer_json),
+                       "--outputs_dir", str(wd / "outputs")])
+    finally:
+        os.chdir(cwd)
+    images = list((wd / "outputs").rglob("*.jpg")) + list(
+        (wd / "outputs").rglob("*.png"))
+    assert len(images) == 2
+
+    from dalle_pytorch_tpu import DALLE
+    from dalle_pytorch_tpu.cli import load_dalle_checkpoint
+    from dalle_pytorch_tpu.serve.engine import SlotArena
+
+    dalle, cfg, params = load_dalle_checkpoint(str(wd / "dalle-final.pt"))[:3]
+    assert cfg.cache_lens == (24, 8, 8, 8)
+    variables = {"params": params}
+    arena = SlotArena(dalle, variables, 2, filter_thres=1.0)
+    text = jnp.asarray([[3, 7, 0, 0, 0, 0, 0, 0]], jnp.int32)
+    first, caches = arena.prefill(text)
+    arena.admit(1, first, caches, jax.random.PRNGKey(0), 1.0, clock=3)
+    want, _ = dalle.apply(variables, arena.state["code"][1:2], caches,
+                          jnp.asarray(cfg.text_seq_len + 1),
+                          method=DALLE.decode_step)
+    got, _ = dalle.apply(variables, arena.state["code"],
+                         arena.state["caches"], arena.state["index"], None,
+                         jnp.int32(3), None, method=DALLE.decode_step)
+    np.testing.assert_allclose(np.asarray(got[1], np.float32),
+                               np.asarray(want[0], np.float32), rtol=2e-2,
+                               atol=2e-2)
+
+
 def test_generate_cli(trained_dalle, tiny_tokenizer_json, workdir):
     cwd = os.getcwd()
     os.chdir(workdir)
