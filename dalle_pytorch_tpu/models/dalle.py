@@ -34,6 +34,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..obs import metrics, prof, telemetry
 from ..ops.attention import record_kernel_choices
@@ -722,6 +723,11 @@ class DALLE(nn.Module):
         (ops/transformer.py::Transformer.lane_dense_caches)."""
         return self.transformer.lane_dense_caches(caches)
 
+    def dense_read_bounds(self):
+        """Per layer, the prefixes the decode step's dense cache read
+        chooses among (ops/transformer.py::Transformer.dense_read_bounds)."""
+        return self.transformer.dense_read_bounds()
+
     def decode_step(self, code, caches, index, mask=None, write_pos=None,
                     qweights=None):
         """One sampled image code in, next-position logits out.
@@ -933,7 +939,38 @@ def tile_prefill(first_logits, caches, reps: int):
     return broadcast_prefill(first_logits, caches, reps)
 
 
-def _lane_dense_caches(dalle: DALLE, params, caches):
+def _kv_reach(dalle: DALLE, params, caches, n_pre: int) -> dict:
+    """What a ``decode_codes`` call's bounded cache reads come to
+    (ops/attention.py::MultiHeadAttention._masked_read), from static
+    shapes: the attention layers whose dense read chooses among several
+    prefixes and those that read as before (slices, or a cache of one
+    bucket), the prefixes over all layers, and ``read_share``: over the
+    dense-read layers, weighted by their caches' bytes, the mean over the
+    call's ticks (positions ``n_pre`` to the last) of slots read over slots
+    held; 1.0 where no layer reads densely."""
+    cfg = dalle.cfg
+    bounds = dalle.apply(params, method=DALLE.dense_read_bounds)
+    ticks = np.arange(n_pre, cfg.seq_len)
+    read = held = 0.0
+    for layer, cache in zip(bounds, caches):
+        if layer is None:
+            continue
+        slots = layer[-1]
+        filled = np.minimum(ticks + 1, slots)   # a ring fills, then wraps
+        chosen = np.asarray(layer)[np.searchsorted(layer, filled)]
+        nbytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(cache))
+        read += nbytes * (chosen.mean() / slots if ticks.size else 1.0)
+        held += nbytes
+    bounded = [layer for layer in bounds if layer and len(layer) > 1]
+    return {"bounded_layers": len(bounded),
+            "unbounded_layers": sum(kind != "mamba" for kind in cfg.mixers)
+            - len(bounded),
+            "buckets": sum(map(len, bounded)),
+            "read_share": read / held if held else 1.0}
+
+
+def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
     """``caches`` with the dense-read layers' entries head-folded wherever
     XLA:TPU would pad the plain layout to the lanes
     (ops/attention.py::kv_fold_factor): one relayout a call, so that every
@@ -946,7 +983,10 @@ def _lane_dense_caches(dalle: DALLE, params, caches):
     state one row holds; over a routed trunk a ``decode.moe_layout`` record
     and three gauges besides: the expert layers, the window layers, and the
     key/value slots one row holds over all layers (a window layer holds its
-    ring, not ``seq_len``)."""
+    ring, not ``seq_len``); and a ``decode.kv_reach`` record (:func:`_kv_reach`)
+    with gauges ``graft_decode_kv_bounded_layers`` / ``_kv_read_share``: how
+    many layers' dense reads the position bounds, and the share of their
+    slots a tick of this call (``n_pre`` positions prefilled) reads."""
     from ..ops.quant import cache_values
 
     cfg = dalle.cfg
@@ -971,6 +1011,12 @@ def _lane_dense_caches(dalle: DALLE, params, caches):
             for name, value in counts.items():
                 reg.gauge(f"graft_decode_{name}",
                           f"decode_codes' last trace ({record})").set(value)
+    reach = _kv_reach(dalle, params, caches, n_pre)
+    telemetry.emit("decode", "kv_reach", rows=rows, **reach)
+    if reg is not None:
+        for name in ("bounded_layers", "read_share"):
+            reg.gauge(f"graft_decode_kv_{name}",
+                      "decode_codes' last trace (kv_reach)").set(reach[name])
     if cfg.trunk is not None and cfg.trunk.routed:
         t = cfg.trunk
         counts = {"moe_layers": cfg.depth,
@@ -1035,7 +1081,7 @@ def decode_codes(dalle: DALLE, params, first_logits, caches, rng, *,
                     if cfg.weights_int8 else None)
         rng, key0 = jax.random.split(rng)
         first_code = sample(first_logits, key0)
-        caches = _lane_dense_caches(dalle, params, caches)
+        caches = _lane_dense_caches(dalle, params, caches, n_pre)
 
         num_steps = cfg.seq_len - n_pre  # remaining image positions
         keys = (jax.random.split(rng, num_steps) if num_steps > 0

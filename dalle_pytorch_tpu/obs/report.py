@@ -279,9 +279,15 @@ def build_report(events: List[dict]) -> dict:
         "moe_layout", ("layers", "experts", "experts_per_token",
                        "expert_bytes_per_layer", "window_layers",
                        "kv_slots_per_row"))
+    # and a `decode.kv_reach` record: the layers whose dense cache read the
+    # position bounds, and the share of their slots a tick reads
+    _, reach = last_decode(
+        "kv_reach", ("bounded_layers", "unbounded_layers", "buckets",
+                     "read_share"))
     decode_report: Optional[dict] = None
     if traces:
         decode_report = {"traces": traces, **kv, **state,
+                         **({"reach": reach} if reach else {}),
                          **({"moe": routed} if routed else {})}
     # models/dalle.py::sample_image_code emits one `sample.top_k` record per
     # traced sampler (a decode_codes program holds two, a serve tick its
@@ -604,6 +610,14 @@ def render_text(report: dict) -> str:
             f"lane-dense, {dec.get('kv_plain_layers')} plain "
             f"({dec.get('rows')} rows; last of {dec.get('traces')} "
             f"decode_codes traces)")
+        if "reach" in dec:
+            r = dec["reach"]
+            lines.append(
+                f"kv cache reach: {r.get('bounded_layers')} layers' dense "
+                f"reads bounded by the position ({r.get('buckets')} "
+                f"prefixes), {r.get('unbounded_layers')} as before; "
+                f"{100 * (r.get('read_share') or 0):.1f}% of their slots "
+                f"read a tick")
         if "kv_layers" in dec:
             lines.append(
                 f"decode state: {dec.get('kv_layers')} layers of keys and "

@@ -55,7 +55,7 @@ VARIANTS = ("full", "axial_row", "axial_col", "conv_like", "sparse")
 LANES = 128
 
 
-def kv_fold_factor(heads: int, dim_head: int, rows: int, dtype) -> int:
+def kv_fold_factor(heads: int, dim_head: int, dtype) -> int:
     """How many heads share the minor dimension of a lane-dense decode cache
     (``quant.fold_heads``); 1 keeps the plain ``[b, heads, n, dh]``.
 
@@ -65,14 +65,15 @@ def kv_fold_factor(heads: int, dim_head: int, rows: int, dtype) -> int:
     (PERF.md, Findings PR 26).  Folding ``128 / dim_head`` heads into the
     minor dimension fills the lanes exactly.  Decided from what the trace
     can see: nothing to fold at a ``dim_head`` that already fills the lanes
-    or does not divide them, at a head count the fold does not divide, or at
-    ``rows`` a multiple of 128 (the batch fills the lanes, measured at the
-    memory rate); 4-byte caches stay plain too, since the folded reads are
-    dots and a dot on f32 multiplicands rounds them unless it runs six
-    passes."""
+    or does not divide them, or at a head count the fold does not divide;
+    4-byte caches stay plain too, since the folded reads are dots and a dot
+    on f32 multiplicands rounds them unless it runs six passes.  The row
+    count has no say: a batch that fills the lanes could carry the plain
+    layout at the memory rate, but the bounded read's conditional
+    (:meth:`MultiHeadAttention._masked_read`) takes its operands row-major,
+    ``dim_head`` on the lanes again (PERF.md, Findings PR 33)."""
     fold = LANES // dim_head if LANES % dim_head == 0 else 1
-    if (fold == 1 or heads % fold or rows % LANES == 0
-            or jnp.dtype(dtype).itemsize >= 4):
+    if fold == 1 or heads % fold or jnp.dtype(dtype).itemsize >= 4:
         return 1
     return fold
 
@@ -134,6 +135,25 @@ def ring_positions(index, slots: int):
     index = jnp.asarray(index, jnp.int32)
     slot = jnp.arange(slots, dtype=jnp.int32)
     return index[..., None] - jnp.remainder(index[..., None] - slot, slots)
+
+
+#: most static prefixes one cache's bounded decode read chooses among
+READ_BUCKETS = 8
+
+
+def read_bounds(slots: int) -> Tuple[int, ...]:
+    """The static prefix lengths a decode tick's dense read of a cache of
+    ``slots`` chooses among (:meth:`MultiHeadAttention._masked_read`), the
+    last one the whole cache.  Buckets are the smallest multiple of the 128
+    lanes that gives at most :data:`READ_BUCKETS` of them: 1280 and 1104 ->
+    256 (5 buckets), 4096 -> 512 (8), 4352 -> 640 (7); a cache under two
+    lane widths keeps the single read."""
+    if slots < 2 * LANES:
+        return (slots,)
+    width = LANES
+    while -(-slots // width) > READ_BUCKETS:
+        width += LANES
+    return tuple(range(width, slots, width)) + (slots,)
 
 
 def make_variable_sparse_layout(
@@ -879,13 +899,18 @@ class MultiHeadAttention(nn.Module):
             return qdense(out, q8, s, bias).astype(self.dtype)
 
     def _cache_dots(self, q_scaled, k_sub, k_scale):
+        """:meth:`_dots` by this layer's grouping."""
+        return self._dots(q_scaled, k_sub, k_scale, self.kv_heads is not None)
+
+    @staticmethod
+    def _dots(q_scaled, k_sub, k_scale, grouped: bool):
         """q·k over a cache read of either storage layout.  Plain caches
         keep the calibrated form (multiplicands in the cache dtype, f32
         accumulation); int8 caches keep the int8 tensor as the
         multiplicand and apply the per-head scale to the f32 dots —
         either way no full-precision cache copy ever exists for XLA to
-        hoist (contract_check C2/C3)."""
-        if self.kv_heads is not None:
+        hoist (contract_check C2/C3).  ``grouped``: ``kv_heads`` is set."""
+        if grouped:
             assert k_scale is None, "grouped keys take no int8 cache"
             return grouped_dots(q_scaled, k_sub)
         fold = q_scaled.shape[1] // k_sub.shape[1]
@@ -1013,19 +1038,48 @@ class MultiHeadAttention(nn.Module):
                     b, 1, self.heads * self.dim_head)
             return self._out_proj(out, qw), cache_k, cache_v
         with prof.scope("attn-scores"):
-            dots = self._cache_dots(q * scale, k_vals, k_scale)
             layout = self.pattern.block_layout()
             row = pattern_mask_row(
                 self.pattern, index, n_k,
                 layout=jnp.asarray(layout) if layout is not None else None,
             )[None, None, None, :]
             row = _merge_key_pad_mask(self.pattern, row, mask)
-            dots = jnp.where(row, dots, max_neg_value(dots.dtype))
-            attn = jax.nn.softmax(dots, axis=-1)  # f32
-            out = self._cache_values(attn, v_vals, v_scale, x.dtype)
+            # a causal row is False past ``index``: the slots written so far
+            out = self._masked_read(
+                q * scale, k_vals, k_scale, v_vals, v_scale, row, x.dtype,
+                filled=index + 1 if self.pattern.causal else None)
             out = out.transpose(0, 2, 1, 3).reshape(
                 b, 1, self.heads * self.dim_head)
         return self._out_proj(out, qw), cache_k, cache_v
+
+    def _masked_read(self, q_scaled, k_vals, k_scale, v_vals, v_scale, row,
+                     out_dtype, filled=None):
+        """The dense read of a decode cache: softmax of ``q_scaled`` against
+        every key under the mask ``row`` (broadcastable to ``[b, h, 1,
+        slots]``), over the values: ``[b, h, 1, dh]``.
+
+        ``filled`` (a traced scalar: the static sampler's position) says
+        that the slots written so far are the prefix ``[0, filled)`` and
+        that ``row`` is False past it.  The read then runs over a static
+        prefix ``[:bound]`` of keys, values and mask, ``bound >= filled``
+        chosen per tick among :func:`read_bounds` by a ``lax.switch`` around
+        the read alone (:func:`_read_prefix`, one branch a prefix): the
+        decode loop is bound by the cache's bytes, and the slots left out
+        were masked to ``exp(...) = 0`` (the same mathematics, no key left
+        out).  ``None`` (rows at their own positions, a non-causal row)
+        reads the whole cache."""
+        bounds = read_bounds(k_vals.shape[2])
+        reads = [functools.partial(
+            _read_prefix, bound=bound, grouped=self.kv_heads is not None,
+            attn_v=self._attn_v, out_dtype=jnp.dtype(out_dtype))
+            for bound in bounds]
+        operands = (q_scaled, k_vals, k_scale, v_vals, v_scale, row)
+        if filled is None or len(bounds) == 1:
+            return reads[-1](*operands)
+        # the first bound to hold ``filled`` slots (a compare and a sum:
+        # ``searchsorted`` lowers to a loop of its own)
+        bucket = jnp.sum(filled > jnp.asarray(bounds[:-1], jnp.int32))
+        return jax.lax.switch(bucket, reads, *operands)
 
     def _decode_step_ring(self, x, q, k, v, cache_k, cache_v, index, mask,
                           qw=None):
@@ -1055,8 +1109,6 @@ class MultiHeadAttention(nn.Module):
             k_vals, k_scale = split_cache(cache_k)
             v_vals, v_scale = split_cache(cache_v)
         with prof.scope("attn-scores"):
-            dots = self._cache_dots(q * self.dim_head ** -0.5, k_vals,
-                                    k_scale)
             held = ring_positions(index, slots)       # [slots] or [b, slots]
             row = _allowed(self.pattern, index[..., None], held, jnp) & (
                 held >= 0)
@@ -1069,12 +1121,22 @@ class MultiHeadAttention(nn.Module):
                 row = row & jnp.take(
                     pad, jnp.clip(held, 0, self.pattern.seq_len - 1),
                     axis=1)[:, None, None, :]
-            dots = jnp.where(row, dots, max_neg_value(dots.dtype))
-            attn = jax.nn.softmax(dots, axis=-1)  # f32
-            out = self._cache_values(attn, v_vals, v_scale, x.dtype)
+            # until the wrap a ring fills from the left (slot = p mod slots)
+            out = self._masked_read(
+                q * self.dim_head ** -0.5, k_vals, k_scale, v_vals, v_scale,
+                row, x.dtype,
+                filled=None if index.ndim else jnp.minimum(index + 1, slots))
             out = out.transpose(0, 2, 1, 3).reshape(
                 b, 1, self.heads * self.dim_head)
         return self._out_proj(out, qw), cache_k, cache_v
+
+    def dense_read_bounds(self) -> Optional[Tuple[int, ...]]:
+        """The prefixes the static sampler's :meth:`decode_step` chooses
+        among where it reads this layer's whole cache (:func:`read_bounds`
+        of its slots); None where it reads slices."""
+        if decode_key_positions(self.pattern, jnp.int32(0)) is not None:
+            return None
+        return read_bounds(self.pattern.cache_len)
 
     def lane_dense_cache(self, cache):
         """One of this layer's decode caches in the layout the static
@@ -1087,7 +1149,7 @@ class MultiHeadAttention(nn.Module):
             return cache    # grouped keys' reads have no folded form
         values = cache_values(cache)
         return fold_cache(cache, kv_fold_factor(
-            self.heads, self.dim_head, values.shape[0], values.dtype))
+            self.heads, self.dim_head, values.dtype))
 
     def _decode_step_aligned(self, x, q, k, v, cache_k, cache_v, index,
                              write_pos, mask, qw=None):
@@ -1307,3 +1369,24 @@ class MultiHeadAttention(nn.Module):
         return jnp.einsum("bhij,bhjd->bhid", attn.astype(v.dtype), v,
                           preferred_element_type=jnp.float32
                           ).astype(out_dtype)
+
+
+
+@functools.partial(jax.jit, static_argnames=("bound", "grouped", "attn_v",
+                                             "out_dtype"))
+def _read_prefix(q_scaled, k_vals, k_scale, v_vals, v_scale, row, *,
+                 bound: int, grouped: bool, attn_v, out_dtype):
+    """One branch of :meth:`MultiHeadAttention._masked_read`: the masked
+    softmax read over the first ``bound`` slots.  Jitted, with everything a
+    layer brings static (``attn_v`` is its ``_attn_v``), so that the layers
+    of one shape share one traced and lowered function per prefix: 12 layers
+    x 5 prefixes traced inline cost `lucid1024-generate` 1.6 s of set-up
+    (PERF.md, Findings PR 33)."""
+    dots = MultiHeadAttention._dots(q_scaled, k_vals[:, :, :bound], k_scale,
+                                    grouped)
+    dots = jnp.where(row[..., :bound], dots, max_neg_value(dots.dtype))
+    attn = jax.nn.softmax(dots, axis=-1)  # f32
+    v = v_vals[:, :, :bound]
+    if grouped:
+        return grouped_values(attn, v).astype(out_dtype)
+    return attn_v(attn, v, v_scale, out_dtype)

@@ -674,6 +674,13 @@ class Transformer(nn.Module):
                 for blk, kind, (ck, cv) in zip(self.attn_blocks, self.mixers,
                                                caches)]
 
+    def dense_read_bounds(self):
+        """Per layer, the prefixes its decode step's dense cache read chooses
+        among (MultiHeadAttention.dense_read_bounds); None for a layer that
+        reads slices or carries a recurrent state."""
+        return [None if kind == "mamba" else blk.attn.dense_read_bounds()
+                for blk, kind in zip(self.attn_blocks, self.mixers)]
+
     def decode_step(self, x, caches, index, mask=None, write_pos=None,
                     qweights=None):
         """Single-token pass: x [b, 1, dim], the per-layer decode state

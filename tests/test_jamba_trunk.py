@@ -221,8 +221,8 @@ def test_grouped_attention_matches_the_reference(kv_heads):
 def test_grouped_caches_stay_plain_at_every_shape():
     """``dim_head`` 128 fills the lanes; and where the fold would apply to
     ungrouped heads (4 x 64 at 32 rows), a grouped layer keeps its cache."""
-    assert kv_fold_factor(20, 128, 128, jnp.bfloat16) == 1
-    assert kv_fold_factor(4, 64, 32, jnp.bfloat16) == 2
+    assert kv_fold_factor(20, 128, jnp.bfloat16) == 1
+    assert kv_fold_factor(4, 64, jnp.bfloat16) == 2
     pattern = AttnPattern("full", seq_len=10, text_len=4, fmap=0)
     cache = jnp.zeros((32, 2, 10, 64), jnp.bfloat16)
     for kv_heads, want in ((2, cache.shape), (None, (32, 2, 10, 128))):

@@ -40,22 +40,22 @@ N = TEXT + FMAP * FMAP              # seq_len: the cache's length
 
 # --- the choice ------------------------------------------------------------
 
-@pytest.mark.parametrize("heads,dim_head,rows,dtype,want", [
-    (16, 64, 32, jnp.bfloat16, 2),    # lucid1024-generate: padded today
-    (16, 64, 32, jnp.int8, 2),
-    (8, 32, 4, jnp.bfloat16, 4),
-    (8, 64, 128, jnp.bfloat16, 1),    # cub200-generate: the batch fills them
-    (16, 64, 256, jnp.bfloat16, 1),
-    (16, 64, 160, jnp.bfloat16, 2),   # not a multiple of 128: padded to 256
-    (3, 64, 32, jnp.bfloat16, 1),     # an odd head count
-    (6, 32, 32, jnp.bfloat16, 1),     # 4 does not divide 6
-    (16, 128, 32, jnp.bfloat16, 1),   # dim_head fills the lanes
-    (16, 96, 32, jnp.bfloat16, 1),    # dim_head does not divide them
-    (16, 64, 32, jnp.float32, 1),     # a dot would round f32 multiplicands
+@pytest.mark.parametrize("heads,dim_head,dtype,want", [
+    (16, 64, jnp.bfloat16, 2),    # lucid1024-generate: padded before PR 26
+    (16, 64, jnp.int8, 2),
+    (8, 32, jnp.bfloat16, 4),
+    (8, 64, jnp.bfloat16, 2),     # cub200-generate: at any rows since PR 33
+    (2, 64, jnp.int8, 2),
+    (4, 32, jnp.float32, 1),
+    (3, 64, jnp.bfloat16, 1),     # an odd head count
+    (6, 32, jnp.bfloat16, 1),     # 4 does not divide 6
+    (16, 128, jnp.bfloat16, 1),   # dim_head fills the lanes
+    (16, 96, jnp.bfloat16, 1),    # dim_head does not divide them
+    (16, 64, jnp.float32, 1),     # a dot would round f32 multiplicands
 ])
-def test_fold_factor_is_decided_by_shape_and_dtype(heads, dim_head, rows,
-                                                   dtype, want):
-    assert kv_fold_factor(heads, dim_head, rows, dtype) == want
+def test_fold_factor_is_decided_by_shape_and_dtype(heads, dim_head, dtype,
+                                                   want):
+    assert kv_fold_factor(heads, dim_head, dtype) == want
 
 
 def test_fold_heads_puts_a_group_side_by_side():
@@ -107,8 +107,8 @@ def test_folded_decode_step_matches_plain(heads, dim_head, rows, cache,
                                           with_mask):
     """``decode_step`` on the layout ``lane_dense_cache`` chooses against
     the plain layout: the same attended output and the same cache after the
-    write.  Where the choice is plain (odd heads, 128 rows, an f32 cache)
-    the arithmetic of the folded read is still held to the plain one, on a
+    write.  Where the choice is plain (odd heads, an f32 cache) the
+    arithmetic of the folded read is still held to the plain one, on a
     cache folded by hand."""
     attn, params, x, k, v, mask = _attn_and_state(heads, dim_head, rows,
                                                   cache, with_mask)
@@ -122,11 +122,10 @@ def test_folded_decode_step_matches_plain(heads, dim_head, rows, cache,
 
     chosen_k = attn.apply(params, k,
                           method=MultiHeadAttention.lane_dense_cache)
-    want = kv_fold_factor(heads, dim_head, rows, cache_values(k).dtype)
+    want = kv_fold_factor(heads, dim_head, cache_values(k).dtype)
     assert cache_values(chosen_k).shape == (
         rows, heads // want, N, want * dim_head)
-    assert (want > 1) == (cache in ("bf16", "int8")
-                          and heads % 2 == 0 and rows == 4)
+    assert (want > 1) == (cache in ("bf16", "int8") and heads % 2 == 0)
 
     fold = 128 // dim_head
     if heads % fold:
@@ -279,6 +278,11 @@ def test_decode_trace_reports_its_cache_layout(tmp_path):
                                 "kv_plain_layers": 1, "kv_layers": 2,
                                 "ssm_layers": 0,
                                 "state_bytes_per_row": report["decode"][
-                                    "state_bytes_per_row"]}
+                                    "state_bytes_per_row"],
+                                # 23 slots: one read (PR 33; its own test
+                                # is in tests/test_kv_bounded_read.py)
+                                "reach": {"bounded_layers": 0,
+                                          "unbounded_layers": 2,
+                                          "buckets": 0, "read_share": 1.0}}
     assert ("kv cache layout: 1 layers lane-dense, 1 plain (4 rows; last of 1 "
             "decode_codes traces)") in render_text(report)

@@ -722,11 +722,14 @@ def _programs(cfg):
 
 
 #: sha256[:16] of each program's StableHLO text at PR 32's parent (a59357f),
-#: written by this very function run in a checkout of it.
+#: written by this very function run in a checkout of it.  ``decode``: as
+#: PR 33 left it, which moved ``decode_step``'s masked read into one jitted
+#: function the layers share (the same operations at these widths, one read
+#: a cache; fd8f1b4a8f8bcc36 / 97c4b3e90ce5d854 / 9c92037e888ce7ad before).
 PARENT_PROGRAMS = {
-    "jamba-tiny": {"grad": "538fdfe5a0388221", "prefill": "fd637bdb401f3a54", "decode": "fd8f1b4a8f8bcc36"},
-    "cub200-tiny": {"grad": "060a49282343e35c", "prefill": "b6c3bf8521b2e723", "decode": "97c4b3e90ce5d854"},
-    "lucid1024-tiny": {"grad": "a49d823ab2963c63", "prefill": "7599dad17aa699e1", "decode": "9c92037e888ce7ad"},
+    "jamba-tiny": {"grad": "538fdfe5a0388221", "prefill": "fd637bdb401f3a54", "decode": "bafb4c6346238832"},
+    "cub200-tiny": {"grad": "060a49282343e35c", "prefill": "b6c3bf8521b2e723", "decode": "37a99c20f70ee99c"},
+    "lucid1024-tiny": {"grad": "a49d823ab2963c63", "prefill": "7599dad17aa699e1", "decode": "4aed9037a547f015"},
 }
 
 
