@@ -39,7 +39,7 @@ import numpy as np
 from ..obs import metrics, prof, telemetry
 from ..ops.attention import record_kernel_choices
 from ..ops.ssm import fan_in_normal, normal_init
-from ..ops.transformer import (RMSNorm, Transformer, TrunkSpec,
+from ..ops.transformer import (RMSNorm, Transformer, TrunkSpec, is_recurrent,
                                 layer_cache_lens, layer_mixers)
 from ..utils.helpers import (TOP_K_PASSES, max_neg_value, top_k_count,
                              top_k_filter, top_p_filter)
@@ -168,16 +168,18 @@ class DALLEConfig:
             object.__setattr__(self, "trunk", TrunkSpec(**self.trunk))
         if self.trunk is not None:
             # what a trunk's layers have no form for yet: a recurrent state
-            # or a ring of keys cannot be recomputed backwards (reversible)
-            # or rolled back (spec_decode); the trunk's blocks have no int8
+            # (a state-space vector or a linear-attention matrix a head) or
+            # a ring of keys cannot be recomputed backwards (reversible) or
+            # rolled back (spec_decode); the trunk's blocks have no int8
             # kernels (weights_int8: expert banks least of all) and its
             # grouped, rotated or ring caches no int8 layout (kv_cache_int8)
             for field in ("reversible", "spec_decode", "weights_int8",
                           "kv_cache_int8", "sparse_attn"):
                 assert not getattr(self, field), (
                     f"{field} is not supported over a TrunkSpec trunk (its "
-                    "layers carry a recurrent state, grouped keys or a ring "
-                    "of them, and routed experts; none has that form)")
+                    "layers carry a recurrent state-space or linear-"
+                    "attention state, grouped keys or a ring of them, and "
+                    "routed experts; none has that form)")
             assert self.ring_axis is None and self.ff_experts <= 1, (
                 "a TrunkSpec trunk runs unsharded in sequence (no ring or "
                 "Ulysses form of a windowed or grouped layer) and routes "
@@ -229,7 +231,8 @@ class DALLEConfig:
     def mixers(self) -> Tuple[str, ...]:
         """Each layer's mixer, and so the kind of its decode state:
         "attention" carries ``(k, v)`` over every position, "window" over a
-        ring of the window's length, "mamba" ``(window, h)``."""
+        ring of the window's length, "mamba" ``(window, h)``, "gdn"
+        ``(window, S)`` (ops/transformer.py::is_recurrent)."""
         return layer_mixers(self.trunk, self.depth)
 
     @property
@@ -686,7 +689,7 @@ class DALLE(nn.Module):
                                 (n_pre - slots) % slots, axis=2)
 
             with prof.scope("attn-cache"):
-                kvs = [kv if kind == "mamba" else
+                kvs = [kv if is_recurrent(kind) else
                        tuple(stored(a, slots) for a in kv)
                        for kind, slots, kv in zip(cfg.mixers, cfg.cache_lens,
                                                   kvs)]
@@ -964,8 +967,8 @@ def _kv_reach(dalle: DALLE, params, caches, n_pre: int) -> dict:
         held += nbytes
     bounded = [layer for layer in bounds if layer and len(layer) > 1]
     return {"bounded_layers": len(bounded),
-            "unbounded_layers": sum(kind != "mamba" for kind in cfg.mixers)
-            - len(bounded),
+            "unbounded_layers": sum(not is_recurrent(kind)
+                                    for kind in cfg.mixers) - len(bounded),
             "buckets": sum(map(len, bounded)),
             "read_share": read / held if held else 1.0}
 
@@ -978,9 +981,10 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
     they are.  A static choice, so its counters are per trace: a
     ``decode.kv_layout`` record and two gauges say how many attention
     layers' caches were folded and how many kept plain, a
-    ``decode.state_layout`` record and three gauges how many layers carry
-    keys and values, how many a recurrent state, and the bytes of decode
-    state one row holds; over a routed trunk a ``decode.moe_layout`` record
+    ``decode.state_layout`` record and four gauges how many layers carry
+    keys and values, how many a state-space state, how many a
+    linear-attention state (with the shape a row of it is carried in), and
+    the bytes of decode state one row holds; over a routed trunk a ``decode.moe_layout`` record
     and three gauges besides: the expert layers, the window layers, and the
     key/value slots one row holds over all layers (a window layer holds its
     ring, not ``seq_len``); and a ``decode.kv_reach`` record (:func:`_kv_reach`)
@@ -992,7 +996,8 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
     cfg = dalle.cfg
     with prof.scope("attn-cache"):
         folded = dalle.apply(params, caches, method=DALLE.lane_dense_caches)
-    attn = [i for i, kind in enumerate(cfg.mixers) if kind != "mamba"]
+    attn = [i for i, kind in enumerate(cfg.mixers) if not is_recurrent(kind)]
+    linear = [i for i, kind in enumerate(cfg.mixers) if kind == "gdn"]
     dense = sum(cache_values(folded[i][0]).shape
                 != cache_values(caches[i][0]).shape for i in attn)
     rows = int(jax.tree.leaves(caches)[0].shape[0])
@@ -1000,13 +1005,19 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
         "kv_layout": {"kv_lane_dense_layers": dense,
                       "kv_plain_layers": len(attn) - dense},
         "state_layout": {
-            "ssm_layers": len(caches) - len(attn), "kv_layers": len(attn),
+            "ssm_layers": len(caches) - len(attn) - len(linear),
+            "kv_layers": len(attn), "linear_layers": len(linear),
             "state_bytes_per_row": sum(
                 a.size * a.dtype.itemsize
                 for a in jax.tree.leaves(caches)) // rows}}
+    # the shape one row of a linear-attention state is carried in: said in
+    # the state_layout record, no gauge
+    state_shape = ({"linear_state_shape": list(caches[linear[0]][1].shape[1:])}
+                   if linear else {})
     reg = metrics.active()
     for record, counts in records.items():
-        telemetry.emit("decode", record, rows=rows, **counts)
+        telemetry.emit("decode", record, rows=rows, **counts,
+                       **(state_shape if record == "state_layout" else {}))
         if reg is not None:
             for name, value in counts.items():
                 reg.gauge(f"graft_decode_{name}",

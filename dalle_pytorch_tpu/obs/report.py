@@ -272,6 +272,10 @@ def build_report(events: List[dict]) -> dict:
         "kv_layout", ("rows", "kv_lane_dense_layers", "kv_plain_layers"))
     _, state = last_decode(
         "state_layout", ("kv_layers", "ssm_layers", "state_bytes_per_row"))
+    # where some of the recurrent layers are linear attention, how many and
+    # the shape a row of their matrix state is carried in
+    _, linear = last_decode(
+        "state_layout", ("linear_layers", "linear_state_shape"))
     # over a routed trunk a `decode.moe_layout` record besides: the expert
     # layers, their banks' bytes, the window layers and the key slots a row
     # holds over all layers
@@ -288,6 +292,8 @@ def build_report(events: List[dict]) -> dict:
     if traces:
         decode_report = {"traces": traces, **kv, **state,
                          **({"reach": reach} if reach else {}),
+                         **({"linear": linear}
+                            if linear.get("linear_layers") else {}),
                          **({"moe": routed} if routed else {})}
     # models/dalle.py::sample_image_code emits one `sample.top_k` record per
     # traced sampler (a decode_codes program holds two, a serve tick its
@@ -619,10 +625,17 @@ def render_text(report: dict) -> str:
                 f"{100 * (r.get('read_share') or 0):.1f}% of their slots "
                 f"read a tick")
         if "kv_layers" in dec:
+            lin = dec.get("linear", {})
             lines.append(
                 f"decode state: {dec.get('kv_layers')} layers of keys and "
-                f"values, {dec.get('ssm_layers')} recurrent; "
-                f"{dec.get('state_bytes_per_row')} bytes a row")
+                f"values, "
+                f"{(dec.get('ssm_layers') or 0) + lin.get('linear_layers', 0)}"
+                f" recurrent; {dec.get('state_bytes_per_row')} bytes a row")
+            if lin:
+                lines.append(
+                    f"linear attention: {lin.get('linear_layers')} of the "
+                    f"recurrent layers, a float32 state of "
+                    f"{lin.get('linear_state_shape')} a row")
         if "moe" in dec:
             m = dec["moe"]
             lines.append(

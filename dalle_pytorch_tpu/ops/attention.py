@@ -47,7 +47,7 @@ from ..utils.helpers import max_neg_value
 from .quant import (cache_values, cache_write, cache_write_rows,
                     circular_slice_in_dim, fold_cache, qdense, scaled_qdot,
                     split_cache)
-from .ssm import fan_in_normal
+from .ssm import fan_in_normal, rms_norm
 
 VARIANTS = ("full", "axial_row", "axial_col", "conv_like", "sparse")
 
@@ -750,15 +750,31 @@ class MultiHeadAttention(nn.Module):
     # index in the sequence the layer sees); None: no position encoding.
     # Keys enter the cache rotated, so a ring's slot order does not matter.
     rope_theta: Optional[float] = None
+    # RMS-norm the projected queries and keys, each over its projection's
+    # whole width (all heads together, before the split into heads and
+    # before any rotation), with a float32 gain of that width: ``q_norm``,
+    # ``k_norm``.  Keys enter the cache normed.
+    qk_norm: bool = False
+    norm_eps: float = 1e-6
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
     def setup(self):
         self.drop = nn.Dropout(self.dropout)
+        assert not self.qk_norm or self.kv_heads is not None, (
+            "normed queries and keys are built with kv_heads (the trunk's "
+            "blocks)")
         if self.kv_heads is not None:
             assert self.heads % self.kv_heads == 0, (self.heads, self.kv_heads)
             assert self.ring_axis is None, (
                 "grouped keys run the dense attention paths only")
+            if self.qk_norm:
+                self.q_norm = self.param(
+                    "q_norm", nn.initializers.ones,
+                    (self.heads * self.dim_head,), jnp.float32)
+                self.k_norm = self.param(
+                    "k_norm", nn.initializers.ones,
+                    (self.kv_heads * self.dim_head,), jnp.float32)
             proj = dict(axis=-1, use_bias=False, dtype=self.dtype,
                         param_dtype=self.param_dtype,
                         kernel_init=fan_in_normal(self.dim))
@@ -784,13 +800,27 @@ class MultiHeadAttention(nn.Module):
         self.to_out = nn.Dense(self.dim, use_bias=self.use_bias,
                                dtype=self.dtype, name="to_out")
 
+    def _width_norm(self, a, gain):
+        """RMS norm of a projection ``[b, n, heads, dh]`` over all its
+        heads' width at once (``qk_norm``), in the projection's dtype."""
+        flat = a.reshape(a.shape[:2] + (-1,))
+        return rms_norm(flat, gain, self.norm_eps).astype(a.dtype).reshape(
+            a.shape)
+
     def _qkv(self, x, positions=None):
         """``positions`` (``[n]`` or ``[b, n]``; None: 0..n-1) matter to a
         rotary layer only."""
         with prof.scope("attn-qkv"):
             if self.kv_heads is not None:
-                q = self.to_q(x).transpose(0, 2, 1, 3)      # [b, heads, n, dh]
-                kv = self.to_kv(x).transpose(2, 0, 3, 1, 4)  # [2, b, g, n, dh]
+                q = self.to_q(x)                            # [b, n, heads, dh]
+                if self.qk_norm:
+                    q = self._width_norm(q, self.q_norm)
+                q = q.transpose(0, 2, 1, 3)                 # [b, heads, n, dh]
+                kv = self.to_kv(x)                          # [b, n, 2, g, dh]
+                if self.qk_norm:
+                    kv = kv.at[:, :, 0].set(
+                        self._width_norm(kv[:, :, 0], self.k_norm))
+                kv = kv.transpose(2, 0, 3, 1, 4)            # [2, b, g, n, dh]
                 k, v = kv[0], kv[1]
                 if self.rope_theta is not None:
                     if positions is None:

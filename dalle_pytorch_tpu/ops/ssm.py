@@ -139,7 +139,7 @@ def fan_in_normal(fan_in: int):
     return normal_init(fan_in ** -0.5)
 
 
-def _dt_bias_init(key, shape, dtype=F32):
+def dt_bias_init(key, shape, dtype=F32):
     """``b_dt`` such that ``softplus(b_dt)`` is log-uniform in [1e-3, 1e-1]
     (Mamba's initialisation)."""
     lo, hi = jnp.log(1e-3), jnp.log(1e-1)
@@ -191,7 +191,7 @@ class MambaMixer(nn.Module):
         self.b_norm = self.param("b_norm", nn.initializers.ones, (N,), F32)
         self.c_norm = self.param("c_norm", nn.initializers.ones, (N,), F32)
         self.dt_proj = dense(d_in, R, "dt_proj")
-        self.dt_bias = self.param("dt_bias", _dt_bias_init, (d_in,), F32)
+        self.dt_bias = self.param("dt_bias", dt_bias_init, (d_in,), F32)
         self.A_log = self.param(
             "A_log", lambda key, shape: jnp.broadcast_to(
                 jnp.log(jnp.arange(1, shape[1] + 1, dtype=F32)), shape),

@@ -27,26 +27,38 @@ import jax.numpy as jnp
 from ..obs import prof, telemetry
 from ..utils.helpers import cast_tuple, default
 from .attention import AttnPattern, MultiHeadAttention
+from .linear_attention import GatedDeltaMixer
 from .reversible import reversible_sequence, reversible_sequence_naive
 from .ssm import MambaMixer, fan_in_normal, rms_norm
 
-MIXERS = ("attention", "mamba", "window")
+MIXERS = ("attention", "gdn", "mamba", "window")
+#: the mixers whose decode state is a recurrent state (two leaves with the
+#: rows on axis 0 and no position axis), not keys and values
+RECURRENT_MIXERS = ("gdn", "mamba")
 FFS = ("swiglu", "moe_reglu")
+NORM_AT = ("input", "output")
 
 
 @dataclasses.dataclass(frozen=True)
 class TrunkSpec:
     """Per-layer block spec of a trunk that is not the 2021 DALL-E block:
-    layer ``i`` is ``x += Mixer_i(Norm(x)); x += FF(Norm(x))`` with no
-    LayerScale, bias or dropout, its mixer ``mixers[i % len(mixers)]``.
-    Absent (``DALLEConfig.trunk`` None) the stack is LayerScale(PreNorm(
+    layer ``i`` is ``x += Mixer_i(Norm(x)); x += FF(Norm(x))`` (``norm_at``
+    "input") or ``x += Norm(Mixer_i(x)); x += Norm(FF(x))`` ("output": the
+    sublayers read the un-normed stream) with no LayerScale, bias or
+    dropout, its mixer ``mixers[i % len(mixers)]``.  Absent
+    (``DALLEConfig.trunk`` None) the stack is LayerScale(PreNorm(
     attention)) + LayerScale(PreNorm(GEGLU x4)) as before.
 
     Every field is a model field (it changes the parameter tree or the
     mathematics).  Mixers: ``"attention"`` is global grouped-query attention
     without position encoding, ``"window"`` the same attention rotated
     (``rope_theta``, ops/attention.py::apply_rope) and bounded to the last
-    ``window`` keys, ``"mamba"`` Mamba-1 with normed ``dt``/``B``/``C``.
+    ``window`` keys, ``"mamba"`` Mamba-1 with normed ``dt``/``B``/``C``,
+    ``"gdn"`` gated-delta-rule linear attention (ops/linear_attention.py):
+    ``DALLEConfig.heads`` heads of ``lin_key_dim`` x ``lin_value_dim`` state
+    behind ``lin_conv``-tap convolutions.  ``qk_norm``: attention layers
+    RMS-norm their projected queries and keys over the projection's whole
+    width, before the split into heads.
     Feed-forward: ``"swiglu"`` a dense gated SiLU of width ``ff_dim``;
     ``"moe_reglu"`` ``experts`` routed ReGLU experts of width ``expert_dim``,
     ``experts_per_token`` a token, dropless (ops/moe.py::ExpertsReGLU), whose
@@ -74,6 +86,11 @@ class TrunkSpec:
     experts_per_token: int = 0
     expert_dim: int = 0
     tied_table: bool = True
+    norm_at: str = "input"
+    qk_norm: bool = False
+    lin_key_dim: int = 0
+    lin_value_dim: int = 0
+    lin_conv: int = 4
 
     def __post_init__(self):
         object.__setattr__(self, "mixers", tuple(self.mixers))
@@ -86,6 +103,15 @@ class TrunkSpec:
         assert ("window" in self.mixers) == (self.window > 0), (
             f"'window' layers need a window and a window needs them: "
             f"{self.mixers}, window {self.window}")
+        assert ("gdn" in self.mixers) == (
+            self.lin_key_dim > 0 and self.lin_value_dim > 0), (
+            f"'gdn' layers need lin_key_dim and lin_value_dim, which need "
+            f"them: {self.mixers}, {self.lin_key_dim}, {self.lin_value_dim}")
+        assert self.norm_at in NORM_AT, self.norm_at
+        assert self.norm_at == "input" or (
+            "mamba" not in self.mixers and self.ff == "swiglu"), (
+            "norm_at = 'output' closes attention, 'gdn' and swiglu "
+            f"sublayers only: {self.mixers}, ff {self.ff!r}")
         if self.ff == "swiglu":
             assert self.ff_dim > 0, "a swiglu feed-forward needs ff_dim"
         else:
@@ -111,10 +137,19 @@ class TrunkSpec:
 def layer_mixers(trunk: Optional[TrunkSpec], depth: int) -> Tuple[str, ...]:
     """Each layer's mixer kind, which is also the kind of its decode state:
     ``(k, v)`` over every position for "attention", ``(k, v)`` over a ring
-    of the window's length for "window", ``(window, h)`` for "mamba"."""
+    of the window's length for "window", ``(window, h)`` for "mamba",
+    ``(window, S)`` for "gdn" (:func:`is_recurrent` tells the last two from
+    the first two)."""
     if trunk is None:
         return ("attention",) * depth
     return tuple(trunk.mixer(i) for i in range(depth))
+
+
+def is_recurrent(kind: str) -> bool:
+    """A layer of this mixer kind carries a recurrent state through decode
+    (``(window, state)``: rows on axis 0, no position axis, replaced whole
+    at every step), not a cache of keys and values."""
+    return kind in RECURRENT_MIXERS
 
 
 def layer_cache_lens(trunk: Optional[TrunkSpec], depth: int,
@@ -122,7 +157,7 @@ def layer_cache_lens(trunk: Optional[TrunkSpec], depth: int,
     """Slots of each layer's key/value cache: ``seq_len``, or ``min(window,
     seq_len)`` for a "window" layer (a ring: position p in slot ``p mod
     window``); 0 for a layer that keeps no keys."""
-    return tuple(0 if kind == "mamba" else
+    return tuple(0 if is_recurrent(kind) else
                  min(trunk.window, seq_len) if kind == "window" else seq_len
                  for kind in layer_mixers(trunk, depth))
 
@@ -260,7 +295,9 @@ class RMSNorm(nn.Module):
 class TrunkAttnBlock(nn.Module):
     """PreNorm(attention) of a :class:`TrunkSpec` trunk: RMSNorm, ``heads``
     queries over ``kv_heads`` keys and values, no bias, no LayerScale.  Same
-    calls as :class:`AttnBlock`."""
+    calls as :class:`AttnBlock`.  Without ``prenorm`` the attention reads
+    its input as it comes (the trunk norms the output:
+    :meth:`Transformer._residual`)."""
 
     pattern: AttnPattern
     dim: int
@@ -269,18 +306,24 @@ class TrunkAttnBlock(nn.Module):
     kv_heads: int
     eps: float = 1e-6
     rope_theta: Optional[float] = None   # a "window" layer's; its window
-    dtype: Any = jnp.float32             # is the pattern's
+    prenorm: bool = True                 # is the pattern's.  prenorm: the
+    qk_norm: bool = False                # block norms its own input
+    dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
     def setup(self):
-        self.norm = RMSNorm(self.eps, name="norm")
+        if self.prenorm:
+            self.norm = RMSNorm(self.eps, name="norm")
         self.attn = MultiHeadAttention(
             pattern=self.pattern, dim=self.dim, heads=self.heads,
             dim_head=self.dim_head, kv_heads=self.kv_heads, use_bias=False,
-            rope_theta=self.rope_theta, dtype=self.dtype,
+            rope_theta=self.rope_theta, qk_norm=self.qk_norm,
+            norm_eps=self.eps, dtype=self.dtype,
             param_dtype=self.param_dtype, name="attn")
 
     def _normed(self, x):
+        if not self.prenorm:
+            return x
         with prof.scope("attn-qkv"):
             return self.norm(x).astype(x.dtype)
 
@@ -325,14 +368,60 @@ class TrunkSSMBlock(nn.Module):
                     qw=None):
         return self.ssm.decode_step(self._normed(x), window, h)
 
+    def init_state(self, batch: int):
+        return self.ssm.init_state(batch)
+
+
+class TrunkLinearBlock(nn.Module):
+    """PreNorm(gated-delta-rule mixer) of a :class:`TrunkSpec` trunk
+    (ops/linear_attention.py), or the mixer on its input as it comes where
+    the trunk norms the output.  Its decode state ``(window, S)`` rides
+    where a state-space layer's ``(window, h)`` does, and takes the same
+    calls as :class:`TrunkSSMBlock`."""
+
+    dim: int
+    heads: int
+    spec: TrunkSpec
+    prenorm: bool = True
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        if self.prenorm:
+            self.norm = RMSNorm(self.spec.norm_eps, name="norm")
+        self.gdn = GatedDeltaMixer(
+            dim=self.dim, heads=self.heads, key_dim=self.spec.lin_key_dim,
+            value_dim=self.spec.lin_value_dim, conv=self.spec.lin_conv,
+            eps=self.spec.norm_eps, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="gdn")
+
+    def _normed(self, x):
+        if not self.prenorm:
+            return x
+        with prof.scope("gdn-proj"):
+            return self.norm(x).astype(x.dtype)
+
+    def __call__(self, x, mask=None, deterministic: bool = True,
+                 return_kv: bool = False):
+        return self.gdn(self._normed(x), return_state=return_kv)
+
+    def decode_step(self, x, window, S, index, mask=None, write_pos=None,
+                    qw=None):
+        return self.gdn.decode_step(self._normed(x), window, S)
+
+    def init_state(self, batch: int):
+        return self.gdn.init_state(batch)
+
 
 class SwiGLUBlock(nn.Module):
     """PreNorm(``W_down(silu(W_gate h) * (W_up h))``) of a
-    :class:`TrunkSpec` trunk: RMSNorm, no bias, no LayerScale."""
+    :class:`TrunkSpec` trunk: RMSNorm, no bias, no LayerScale; without
+    ``prenorm`` on its input as it comes (the trunk norms the output)."""
 
     dim: int
     ff_dim: int
     eps: float = 1e-6
+    prenorm: bool = True
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -342,14 +431,15 @@ class SwiGLUBlock(nn.Module):
                             param_dtype=self.param_dtype,
                             kernel_init=fan_in_normal(fan_in), name=name)
 
-        self.norm = RMSNorm(self.eps, name="norm")
+        if self.prenorm:
+            self.norm = RMSNorm(self.eps, name="norm")
         self.gate = dense(self.ff_dim, self.dim, "gate")
         self.up = dense(self.ff_dim, self.dim, "up")
         self.down = dense(self.dim, self.ff_dim, "down")
 
     def __call__(self, x, deterministic: bool = True):
         with prof.scope("ff"):
-            h = self.norm(x).astype(x.dtype)
+            h = self.norm(x).astype(x.dtype) if self.prenorm else x
             return self.down(jax.nn.silu(self.gate(h)) * self.up(h))
 
 
@@ -428,7 +518,9 @@ class Transformer(nn.Module):
     """Depth x (attn, ff) residual stack with cycled attention variants
     (ref transformer.py:71-123); with a ``trunk`` (:class:`TrunkSpec`),
     depth x (mixer, feed-forward) with each layer's mixer global or windowed
-    attention or Mamba and its feed-forward a SwiGLU or routed experts."""
+    attention, Mamba or gated-delta-rule linear attention and its
+    feed-forward a SwiGLU or routed experts, the norm on each sublayer's
+    input or on its output."""
 
     dim: int
     depth: int
@@ -474,6 +566,7 @@ class Transformer(nn.Module):
         )
         attn_blocks = []
         ff_blocks = []
+        mixer_norms, ff_norms = [], []
         for ind in range(self.depth):
             variant = attn_types[ind % len(attn_types)]
             pattern = AttnPattern(
@@ -486,9 +579,19 @@ class Transformer(nn.Module):
                 kw = dict(dim=self.dim, dtype=self.dtype,
                           param_dtype=jnp.dtype(spec.param_dtype))
                 kind = spec.mixer(ind)
+                prenorm = spec.norm_at == "input"
+                if not prenorm:
+                    mixer_norms.append(RMSNorm(
+                        spec.norm_eps, name=f"layers_{ind}_mixer_norm"))
+                    ff_norms.append(RMSNorm(
+                        spec.norm_eps, name=f"layers_{ind}_ff_norm"))
                 if kind == "mamba":
                     attn_blocks.append(TrunkSSMBlock(
                         spec=spec, name=f"layers_{ind}_ssm", **kw))
+                elif kind == "gdn":
+                    attn_blocks.append(TrunkLinearBlock(
+                        heads=self.heads, spec=spec, prenorm=prenorm,
+                        name=f"layers_{ind}_gdn", **kw))
                 else:
                     windowed = kind == "window"
                     attn_blocks.append(TrunkAttnBlock(
@@ -498,6 +601,7 @@ class Transformer(nn.Module):
                         dim_head=self.dim_head, kv_heads=spec.kv_heads,
                         eps=spec.norm_eps,
                         rope_theta=spec.rope_theta if windowed else None,
+                        prenorm=prenorm, qk_norm=spec.qk_norm,
                         name=f"layers_{ind}_attn", **kw))
                 if spec.routed:
                     ff_blocks.append(TrunkMoEBlock(
@@ -505,7 +609,7 @@ class Transformer(nn.Module):
                 else:
                     ff_blocks.append(SwiGLUBlock(
                         ff_dim=spec.ff_dim, eps=spec.norm_eps,
-                        name=f"layers_{ind}_ff", **kw))
+                        prenorm=prenorm, name=f"layers_{ind}_ff", **kw))
                 continue
             attn_blocks.append(AttnBlock(
                 pattern=pattern, dim=self.dim, layer_index=ind + 1,
@@ -534,6 +638,22 @@ class Transformer(nn.Module):
                 ))
         self.attn_blocks = attn_blocks
         self.ff_blocks = ff_blocks
+        # a trunk that norms each sublayer's OUTPUT: one gain per sublayer,
+        # owned here and applied in :meth:`_residual` alone
+        self.mixer_norms = mixer_norms
+        self.ff_norms = ff_norms
+
+    def _residual(self, x, ind: int, h, ff: bool = False):
+        """The stream after layer ``ind``'s mixer (or, ``ff``, feed-forward)
+        output ``h``: ``x + h``, or ``x + Norm(h)`` where the trunk norms
+        outputs, under the scope of the sublayer the norm closes."""
+        norms = self.ff_norms if ff else self.mixer_norms
+        if not norms:
+            return x + h
+        scope = ("ff" if ff else
+                 "gdn-proj" if self.mixers[ind] == "gdn" else "attn-out")
+        with prof.scope(scope):
+            return x + norms[ind](h).astype(x.dtype)
 
     def _block_apply(self, x, ind: int, mask, deterministic: bool):
         """One (attn, ff) residual block — a method so lifted transforms
@@ -541,8 +661,11 @@ class Transformer(nn.Module):
         aux losses) through it; a raw jax.checkpoint closure would leak
         tracers out of any sown value."""
         routed = self._router_logits(ind, x)
-        x = x + self.attn_blocks[ind](x, mask=mask, deterministic=deterministic)
-        return x + self._ff(ind, x, routed, deterministic=deterministic)
+        x = self._residual(x, ind, self.attn_blocks[ind](
+            x, mask=mask, deterministic=deterministic))
+        return self._residual(
+            x, ind, self._ff(ind, x, routed, deterministic=deterministic),
+            ff=True)
 
     def _router_logits(self, ind: int, x):
         """A routed layer's router logits, read from the layer's input
@@ -583,8 +706,10 @@ class Transformer(nn.Module):
                 h, kv = self.attn_blocks[ind](
                     x, mask=mask, deterministic=deterministic, return_kv=True)
                 kvs.append(kv)
-                x = x + h
-                x = x + self._ff(ind, x, routed, deterministic=deterministic)
+                x = self._residual(x, ind, h)
+                x = self._residual(
+                    x, ind, self._ff(ind, x, routed,
+                                     deterministic=deterministic), ff=True)
             elif use_remat:
                 x = remat_block(self, x, ind, mask, deterministic)
             else:
@@ -651,7 +776,8 @@ class Transformer(nn.Module):
         """Zeroed decode state, one pair per layer: ``(k, v)`` ``[b, kv
         heads, slots, dh]`` for an attention layer, ``slots`` its own
         (:attr:`cache_lens`: ``seq_len``, or a ring of the window's length),
-        ``(window, h)`` (ops/ssm.py) for a state-space one."""
+        the block's own ``(window, state)`` for a recurrent one (ops/ssm.py,
+        ops/linear_attention.py)."""
         dtype = dtype or self.dtype
         kv_heads = self.heads if self.trunk is None else self.trunk.kv_heads
 
@@ -660,7 +786,7 @@ class Transformer(nn.Module):
             return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
         return [
-            blk.ssm.init_state(batch) if kind == "mamba" else pair(slots)
+            blk.init_state(batch) if is_recurrent(kind) else pair(slots)
             for blk, kind, slots in zip(self.attn_blocks, self.mixers,
                                         self.cache_lens)
         ]
@@ -669,7 +795,7 @@ class Transformer(nn.Module):
         """Per-layer caches as ``decode_codes``' scan should carry them
         (MultiHeadAttention.lane_dense_cache): :meth:`decode_step` takes
         either layout, told by the shape."""
-        return [(ck, cv) if kind == "mamba" else
+        return [(ck, cv) if is_recurrent(kind) else
                 (blk.attn.lane_dense_cache(ck), blk.attn.lane_dense_cache(cv))
                 for blk, kind, (ck, cv) in zip(self.attn_blocks, self.mixers,
                                                caches)]
@@ -678,14 +804,14 @@ class Transformer(nn.Module):
         """Per layer, the prefixes its decode step's dense cache read chooses
         among (MultiHeadAttention.dense_read_bounds); None for a layer that
         reads slices or carries a recurrent state."""
-        return [None if kind == "mamba" else blk.attn.dense_read_bounds()
+        return [None if is_recurrent(kind) else blk.attn.dense_read_bounds()
                 for blk, kind in zip(self.attn_blocks, self.mixers)]
 
     def decode_step(self, x, caches, index, mask=None, write_pos=None,
                     qweights=None):
         """Single-token pass: x [b, 1, dim], the per-layer decode state
-        (``(k, v)`` caches, or ``(window, h)`` for a state-space layer of a
-        ``trunk``: :attr:`mixers` says which), traced absolute position
+        (``(k, v)`` caches, or ``(window, state)`` for a recurrent layer of
+        a ``trunk``: :attr:`mixers` says which), traced absolute position
         `index`.  Returns (out, new_caches).
 
         ``write_pos`` enables the phase-aligned serving mode (``index``
@@ -716,8 +842,9 @@ class Transformer(nn.Module):
             routed = self._router_logits(ind, x)
             h, ck, cv = attn.decode_step(x, ck, cv, index, mask=mask,
                                          write_pos=write_pos, qw=qw)
-            x = x + h
-            x = x + self._ff(ind, x, routed, qw=qw)
+            x = self._residual(x, ind, h)
+            x = self._residual(x, ind, self._ff(ind, x, routed, qw=qw),
+                               ff=True)
             new_caches.append((ck, cv))
         return x, new_caches
 

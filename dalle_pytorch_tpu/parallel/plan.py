@@ -65,6 +65,16 @@ TRUNK_RULES: Tuple[Tuple[str, P], ...] = (
     (r".*ssm/out_proj/kernel$", P("tp", "fsdp")),
     (r".*ssm/conv_kernel$", P(None, "tp")),
     (r".*ssm/A_log$", P("tp", None)),
+    # gated-delta-rule mixer: heads are independent through the
+    # convolutions and the rule, so they split over tp — the four wide
+    # projections [dim, heads, d] column-parallel, o_proj [heads, d_v, dim]
+    # row-parallel, the two per-head projections [dim, heads], the
+    # convolution taps [conv, heads, d] and the per-head vectors with them
+    (r".*gdn/(q_proj|k_proj|v_proj|g_proj)/kernel$", P("fsdp", "tp", None)),
+    (r".*gdn/o_proj/kernel$", P("tp", None, "fsdp")),
+    (r".*gdn/(a_proj|b_proj)/kernel$", P("fsdp", "tp")),
+    (r".*gdn/conv_(q|k|v)$", P(None, "tp", None)),
+    (r".*gdn/(A_log|dt_bias)$", P("tp")),
     # SwiGLU: gate and up column-parallel, down row-parallel
     (r".*ff/(gate|up)/kernel$", P("fsdp", "tp")),
     (r".*ff/down/kernel$", P("tp", "fsdp")),

@@ -64,6 +64,8 @@ PARAM_BANDS = {
     "jamba2-3b": (2.9e9, 3.2e9),
     "smallthinker-tiny": (0.01e6, 1e6),
     "smallthinker-21ba3b": (2.3e9, 2.45e9),
+    "olmo-hybrid-tiny": (0.01e6, 1e6),
+    "olmo-hybrid-7b": (2.4e9, 2.47e9),
 }
 
 
@@ -248,6 +250,56 @@ def smallthinker_21ba3b_config(**overrides):
     return DALLEConfig(**base)
 
 
+#: Olmo-Hybrid-7B's trunk (huggingface.co/allenai/Olmo-Hybrid-7B,
+#: config.json): layer i of 32 is full attention iff i mod 4 == 3 (30 heads
+#: of 128 over 30 keys, no rotation: ``rope_theta`` null), else
+#: gated-delta-rule linear attention (30 heads, a 96 x 192 float32 state a
+#: head behind 4-tap convolutions); SwiGLU 11008; the norm on each
+#: sublayer's output, queries and keys normed; an untied head.
+OLMO_HYBRID_7B_TRUNK = dict(
+    mixers=("gdn", "gdn", "gdn", "attention"), ff_dim=11008, kv_heads=30,
+    norm="rms", norm_eps=1e-6, ff="swiglu", norm_at="output", qk_norm=True,
+    lin_key_dim=96, lin_value_dim=192, lin_conv=4, tied_table=False,
+    param_dtype="bfloat16")
+
+
+def olmo_hybrid_tiny_config(**overrides):
+    """The same period at toy width (tests)."""
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=64, depth=4, heads=4, dim_head=16, num_text_tokens=50,
+                text_seq_len=8, num_image_tokens=32, image_size=64,
+                image_fmap_size=4,
+                trunk=dict(OLMO_HYBRID_7B_TRUNK, ff_dim=96, kv_heads=4,
+                           lin_key_dim=8, lin_value_dim=16,
+                           param_dtype="float32"))
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
+def olmo_hybrid_7b_config(**overrides):
+    """DALL-E's client over two whole periods (8 of 32 layers: linear,
+    linear, linear, full, twice) of the Olmo-Hybrid-7B trunk, every width
+    as published: 2.44B parameters, 4.87 GB in bfloat16.  The 100,352 rows
+    of the embedding and of the separate head are 91,904 text ids + 256
+    per-position pad ids + 8,192 image codes of a 256 px, 32 x 32 code grid
+    (n = 1280); the trunk has no position encoding, so the client's learned
+    position embeddings are added before it.
+    ``benchmark/configs/olmo-hybrid-7b.json`` is the same model as the
+    benchmark runs it; the other 24 layers would lie on further chips."""
+    import jax.numpy as jnp
+
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=3840, depth=8, heads=30, dim_head=128,
+                num_text_tokens=91904, text_seq_len=256,
+                num_image_tokens=8192, image_size=256, image_fmap_size=32,
+                attn_types=("full",), trunk=OLMO_HYBRID_7B_TRUNK,
+                dtype=jnp.bfloat16)
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
 #: Every named config geometry (CLI ``--preset`` surface).
 CONFIG_PRESETS = {
     "tiny": tiny_config,
@@ -258,6 +310,8 @@ CONFIG_PRESETS = {
     "jamba2-3b": jamba2_3b_config,
     "smallthinker-tiny": smallthinker_tiny_config,
     "smallthinker-21ba3b": smallthinker_21ba3b_config,
+    "olmo-hybrid-tiny": olmo_hybrid_tiny_config,
+    "olmo-hybrid-7b": olmo_hybrid_7b_config,
 }
 
 #: The scale rungs that are ALSO plan-registry entries: registry name ->

@@ -71,6 +71,7 @@ from ..models.dalle import (DALLE, prefill_codes, quantize_decode_weights,
                             sample_image_code)
 from ..obs import prof
 from ..ops.quant import split_cache
+from ..ops.transformer import is_recurrent
 
 
 @dataclasses.dataclass(frozen=True)
@@ -121,7 +122,7 @@ class SlotArena:
                              else jnp.bfloat16 if cfg.kv_cache_bf16
                              else cfg.dtype)
         S = num_slots
-        recurrent = [kind == "mamba" for kind in cfg.mixers]
+        recurrent = [is_recurrent(kind) for kind in cfg.mixers]
         # a window layer's slot holds a ring of its own length, position p
         # in slot p mod ring whatever the arena's clock: rows at different
         # depths cannot share a write column there, so such a layer takes
@@ -145,7 +146,8 @@ class SlotArena:
 
         def fresh_state():
             # a recurrent layer's zero state comes from the model itself
-            # (shapes and dtypes of ops/ssm.py), an attention layer's cache
+            # (shapes and dtypes of ops/ssm.py and ops/linear_attention.py),
+            # an attention layer's cache
             # from the geometry above
             zero = (dalle.apply(variables, S, method=DALLE.decode_init_state)
                     if any(recurrent) else [None] * cfg.depth)

@@ -321,6 +321,49 @@ def test_train_then_generate_over_a_named_trunk(trained_vae, tiny_dataset,
     assert len(images) == 2
 
 
+def test_train_then_generate_over_a_linear_attention_trunk(
+        trained_vae, tiny_dataset, tiny_tokenizer_json, tmp_path_factory):
+    """`train_dalle.py --trunk olmo-hybrid-tiny` trains DALL-E over
+    gated-delta-rule layers and a full-attention layer with the norm on each
+    sublayer's output; the spec (its new fields too) rides in the
+    checkpoint's hparams, so `generate.py` rebuilds the model from the
+    checkpoint alone and samples through the matrix-state carry."""
+    wd = tmp_path_factory.mktemp("linear_cli")
+    _run_train_dalle(wd, dict(BATCH_SIZE=4, TEXT_SEQ_LEN=8),
+                     ["--trunk", "olmo-hybrid-tiny"], trained_vae,
+                     tiny_dataset, tiny_tokenizer_json)
+    from dalle_pytorch_tpu.utils.checkpoint import load_checkpoint
+
+    ckpt = load_checkpoint(wd / "dalle-final.pt")
+    trunk = ckpt["hparams"]["trunk"]
+    assert trunk["mixers"] == ["gdn", "gdn", "gdn", "attention"]
+    assert (trunk["norm_at"], trunk["qk_norm"], trunk["lin_key_dim"],
+            trunk["lin_value_dim"], trunk["tied_table"]) == (
+        "output", True, 8, 16, False)
+    layers = ckpt["weights"]["transformer"]
+    assert "A_log" in layers["layers_0_gdn"]["gdn"]
+    assert "q_norm" in layers["layers_3_attn"]["attn"]
+    assert "layers_0_mixer_norm" in layers and "norm" not in layers[
+        "layers_0_ff"]
+    assert "head" in ckpt["weights"] and "text_pos_emb" in ckpt["weights"]
+    assert np.isfinite(_first_loss(wd))
+    cwd = os.getcwd()
+    os.chdir(wd)
+    try:
+        import generate
+
+        generate.main(["--dalle_path", str(wd / "dalle-final.pt"),
+                       "--text", "red bird", "--num_images", "2",
+                       "--batch_size", "2",
+                       "--bpe_path", str(tiny_tokenizer_json),
+                       "--outputs_dir", str(wd / "outputs")])
+    finally:
+        os.chdir(cwd)
+    images = list((wd / "outputs").rglob("*.jpg")) + list(
+        (wd / "outputs").rglob("*.png"))
+    assert len(images) == 2
+
+
 def test_train_generate_and_serve_a_routed_windowed_trunk(
         trained_vae, tiny_dataset, tiny_tokenizer_json, tmp_path_factory):
     """`train_dalle.py --trunk smallthinker-tiny` trains two steps of DALL-E
