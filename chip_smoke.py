@@ -520,7 +520,10 @@ def phase_pallas(size: SmokeSize, platform: str) -> list:
     """The compiled kernel against the dense path: four variants x the tile
     settings, forward and gradients, and the kernel really is in the HLO
     (``tpu_custom_call``) exactly when this runs on a TPU — under the
-    interpreter (the CPU rehearsal) it must not be."""
+    interpreter (the CPU rehearsal) it must not be.  Through
+    ``flash_pattern_attention``, the wrapper that takes q, k and v apart and
+    stacks them into the one array the kernel reads (the model hands it
+    ``to_qkv``'s result and never transposes)."""
     from dalle_pytorch_tpu.ops.attention import AttnPattern
     from dalle_pytorch_tpu.ops.attention_pallas import flash_pattern_attention
 

@@ -310,7 +310,8 @@ def build_report(events: List[dict]) -> dict:
     # --- attention: which core the model's layers run ------------------------
     # ops/attention.py::record_kernel_choices emits one `attention.kernel`
     # record per trace of a model: layers on the flash kernel, layers on the
-    # dense-masked branch, the share of blocks the flash layers compute
+    # dense-masked branch, the share of blocks the flash layers compute, and
+    # the kernel's operand contract (heads a program, padding it adds in HBM)
     kernel = [r for r in events if r.get("kind") == "attention"
               and r.get("name") == "kernel"]
     attention_report: Optional[dict] = None
@@ -318,7 +319,7 @@ def build_report(events: List[dict]) -> dict:
         attention_report = {"traces": len(kernel), **{
             k: kernel[-1].get(k) for k in
             ("model", "n", "tiles", "flash_layers", "dense_layers",
-             "blocks_computed_share")}}
+             "blocks_computed_share", "heads_per_program", "hbm_pad_rows")}}
 
     # --- memory: predicted vs measured --------------------------------------
     # MemTracker emits `mem.watermark` at phase boundaries (obs/mem.py)
@@ -659,7 +660,9 @@ def render_text(report: dict) -> str:
             f"attention core: {att.get('flash_layers')} layers on the flash "
             f"kernel (tiles {', '.join(att.get('tiles') or []) or '-'}; "
             f"{100 * (att.get('blocks_computed_share') or 0):.1f}% of their "
-            f"blocks computed), {att.get('dense_layers')} dense "
+            f"blocks computed; {att.get('heads_per_program')} heads a "
+            f"program, {att.get('hbm_pad_rows')} rows of padding in HBM), "
+            f"{att.get('dense_layers')} dense "
             f"(n {att.get('n')}; last of {att.get('traces')} "
             f"{att.get('model')} traces; the kernel is lowered for a TPU "
             "only)")

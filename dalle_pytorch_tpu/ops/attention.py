@@ -452,32 +452,53 @@ FLASH_BLOCK_COST = 4096
 FLASH_MAX_BLOCKS = 128
 
 
-def flash_tiles(n: int, dim_head: int, dtype, pattern: AttnPattern,
-                kv_heads: Optional[int] = None,
+def lane_block(heads: int, dim_head: int) -> Optional[int]:
+    """Columns of the block one program of the flash kernel takes of the
+    projections' arrays ``[b, n, .. heads * dim_head]``: ``dim_head`` where
+    that is whole lane widths (one head a program), else the 128 lanes with
+    ``128 / dim_head`` heads side by side; None where ``heads * dim_head``
+    does not cut into such blocks of whole heads."""
+    if dim_head % LANES == 0:
+        return dim_head
+    if LANES % dim_head or (heads * dim_head) % LANES:
+        return None
+    return LANES
+
+
+def flash_tiles(n: int, heads: int, dim_head: int, dtype,
+                pattern: AttnPattern, kv_heads: Optional[int] = None,
                 ring_axis: Optional[str] = None) -> Optional[Tuple[int, int]]:
     """``(block_q, block_k)`` for ``ops/attention_pallas.py``'s flash kernel
     where a forward of this shape should run it, None where the dense-masked
     branch stays.  Decided from what a trace can see; which of the two a
     program really holds is settled where it is lowered (the kernel exists
-    for the TPU only: :meth:`MultiHeadAttention._attention_core`).
+    for the TPU only: :meth:`MultiHeadAttention._kernel_core`).
 
     Dense stays for grouped keys, a sliding window and the sequence-parallel
     plans (their own branches), for float32 activations (the kernel's products would run as
     several bf16 passes where XLA's default precision takes one), for a
     ``dim_head`` that does not fill a whole number of half-lanes, for
-    sequences under :data:`FLASH_MIN_LEN`, and where no tiling fits.
-    Tiles: the sequence is padded to the lanes only (1104 -> 1152, 1280 ->
-    1280, 4176 -> 4224) and cut into equal square tiles; of the widths that
-    divide it, the one whose computed blocks cost least by
-    :data:`FLASH_BLOCK_COST` (the wider at a tie: less to unroll), among
-    those that leave at most :data:`FLASH_MAX_BLOCKS` blocks to compute and
-    fit VMEM: 384 for ``full`` and ``axial_col`` and 128 for ``axial_row``
-    and ``conv_like`` at 1152, 256 at 1280, 384 at 4224."""
+    ``heads`` (a shard's, under a plan that splits them) that do not fill
+    whole lane blocks (:func:`lane_block`: the kernel reads ``to_qkv``'s
+    own array, 128 columns a program), for a length off the 16-row sublane
+    tiles (a tail block starts at ``n - tile``), for sequences under
+    :data:`FLASH_MIN_LEN`, and where no tiling fits.
+    Tiles: nothing is padded in HBM; the kernel takes the last block of a
+    length that is no multiple of the tile as the last full tile (1104: rows
+    720-1103 at 384, 976-1103 at 128).  The sequence is cut into equal
+    square tiles of a width that divides its length rounded up to the lanes
+    (1104 -> 1152, 1280, 4176 -> 4224); of those, the one whose computed
+    blocks cost least by :data:`FLASH_BLOCK_COST` (the wider at a tie: less
+    to unroll), among those that leave at most :data:`FLASH_MAX_BLOCKS`
+    blocks to compute and fit VMEM: 384 for ``full`` and ``axial_col`` and
+    128 for ``axial_row`` and ``conv_like`` at 1104, 256 at 1280, 384 at
+    4176."""
+    lanes = lane_block(heads, dim_head)
     if (kv_heads is not None or ring_axis is not None or pattern.window
             or jnp.dtype(dtype).itemsize != 2 or dim_head % (LANES // 2)
-            or n < FLASH_MIN_LEN):
+            or lanes is None or n % 16 or n < FLASH_MIN_LEN):
         return None
-    return _cheapest_tiles(n, dim_head, kernel_pattern(pattern))
+    return _cheapest_tiles(n, lanes, kernel_pattern(pattern))
 
 
 def kernel_pattern(pattern: AttnPattern) -> AttnPattern:
@@ -490,19 +511,19 @@ def kernel_pattern(pattern: AttnPattern) -> AttnPattern:
 
 
 @functools.lru_cache(maxsize=64)
-def _cheapest_tiles(n: int, dim_head: int,
+def _cheapest_tiles(n: int, lanes: int,
                     pattern: AttnPattern) -> Optional[Tuple[int, int]]:
     from . import attention_pallas as ap
 
     n_pad = -(-n // LANES) * LANES
     best = None
     for tile in range(n_pad, 0, -LANES):
-        if n_pad % tile:
+        if n_pad % tile or tile > n:
             continue
-        blocks = ap._pattern_blocks(pattern, n, n_pad, tile, tile)
+        blocks = ap._pattern_blocks(pattern, n, tile, tile)
         computed = blocks.counts[1] + blocks.counts[2]
         if (computed > FLASH_MAX_BLOCKS or ap._vmem_resident_bytes(
-                n_pad, dim_head, 2, tile, tile, blocks.tiles.shape[0],
+                n, lanes, 2, tile, tile, blocks.tiles.shape[0],
                 has_bias=True) > ap.VMEM_BUDGET_BYTES):
             continue
         cost = computed * (tile * tile + FLASH_BLOCK_COST)
@@ -555,9 +576,11 @@ def kernel_mesh(partitioner) -> Iterator[None]:
 def record_kernel_choices(model: str) -> Iterator[None]:
     """Collect every attention layer's choice during one trace of a model
     and say what was chosen: one ``attention.kernel`` telemetry record and
-    three gauges (flash layers, dense layers, and the share of blocks the
-    flash layers compute, skipped blocks left out).  A layer traced twice
-    (a reversible stack's custom VJP) counts once: its pattern is its key."""
+    five gauges (flash layers, dense layers, the share of blocks the flash
+    layers compute, skipped blocks left out, and the kernel's operand
+    contract: heads a program shares the lanes between, positions of
+    padding a call adds to a sequence in HBM).  A layer traced twice (a
+    reversible stack's custom VJP) counts once: its pattern is its key."""
     _choices.append({})
     try:
         yield
@@ -571,7 +594,11 @@ def record_kernel_choices(model: str) -> Iterator[None]:
                 "flash_layers": len(flash),
                 "dense_layers": len(layers) - len(flash),
                 "blocks_computed_share":
-                    round(computed / blocks, 4) if blocks else 0.0}
+                    round(computed / blocks, 4) if blocks else 0.0,
+                "heads_per_program":
+                    max((c["heads_per_program"] for c in flash), default=0),
+                "hbm_pad_rows":
+                    max((c["hbm_pad_rows"] for c in flash), default=0)}
             telemetry.emit(
                 "attention", "kernel", model=model,
                 n=max(c["n"] for c in layers),
@@ -613,109 +640,132 @@ class _Core(NamedTuple):
     act_dtype: Any
     tiles: Tuple[int, int]
     mesh: Optional[KernelMesh]
+    heads: int
+    dim_head: int
 
-    def halves(self, q, mask):
-        """The kernel's forward and backward for ``q``-shaped arguments
-        (``ops/attention_pallas.py::flash_attention_halves``), taking the
-        key padding mask as the model has it; under a plan's mesh
+    @property
+    def shard_heads(self) -> int:
+        """The heads one shard of the plan's mesh holds."""
+        return self.heads // (self.mesh.head_ways if self.mesh else 1)
+
+    @property
+    def fused(self) -> bool:
+        """Whether the core takes ``to_qkv``'s result as one ``[b, n, 3 *
+        heads * dim_head]`` array (no axis of it is a mesh axis's to split)
+        or as ``[b, n, 3, heads, dim_head]`` (the plan splits the heads)."""
+        return self.mesh is None or self.mesh.head_axis is None
+
+    def halves(self, qkv, mask):
+        """The kernel's forward and backward for ``to_qkv``'s result ``qkv``
+        (:attr:`fused`) (``ops/attention_pallas.py::flash_attention_halves``),
+        taking the key padding mask as the model has it; under a plan's mesh
         (:func:`kernel_mesh`) each inside a ``shard_map`` over the batch
         and head axes, whole on the sequence and ``dim_head``."""
         from .attention_pallas import flash_attention_halves
 
         pattern, mesh = self.pattern, self.mesh
-        shard = q if mesh is None else jax.ShapeDtypeStruct(
-            (q.shape[0] // mesh.batch_ways, q.shape[1] // mesh.head_ways,
-             *q.shape[2:]), q.dtype)
+        n = qkv.shape[1]
         fwd, bwd = flash_attention_halves(
-            shard, pattern, mask is not None, block_q=self.tiles[0],
+            n, self.shard_heads, self.dim_head, qkv.dtype, pattern,
+            mask is not None, block_q=self.tiles[0],
             block_k=self.tiles[1], cache_kernels=True)
 
-        def forward(q, k, v, mask):
+        def forward(qkv, mask):
             bias = None
             if mask is not None:
-                pad = _scope_key_pad(pattern, mask, q.shape[2])
+                pad = _scope_key_pad(pattern, mask, n)
                 bias = jnp.where(pad, 0.0, -1e30).astype(jnp.float32)
-            return fwd(q, k, v, bias)
+            return fwd(qkv, bias)
 
         def backward(residuals, g):
-            return bwd(residuals, g)[:3]
+            return bwd(residuals, g)[0]
 
         if mesh is None:
             return forward, backward
         from jax.sharding import PartitionSpec as P
 
         batch, head = mesh.batch_axes, mesh.head_axis
-        split = P(batch, head, None, None)  # graftlint: disable=PLAN001 (shard_map arg placement of activations q, k, v, o: batch and heads over the plan's own axes; not a param-tree sharding, so the rule table does not apply)
-        rows = P(batch + ((head,) if head else ()), None, None)  # graftlint: disable=PLAN001 (same: the kernel's flat [batch*head, n, dh] residuals)
-        per_sample = P(batch, None, None)  # graftlint: disable=PLAN001 (same: the padded key bias, one row a sample)
+        fused = P(batch, None, None) if self.fused else P(batch, None, None, head, None)  # graftlint: disable=PLAN001 (shard_map arg placement of the activations qkv and dqkv, [b, n, 3 * heads * dh] or [b, n, 3, heads, dh]: batch and heads over the plan's own axes; not a param-tree sharding, so the rule table does not apply)
+        wide = P(batch, None, head)  # graftlint: disable=PLAN001 (same: o and its cotangent, [b, n, heads * dh])
+        stats = P(batch, head, None, None)  # graftlint: disable=PLAN001 (same: logsumexp [b, heads, blocks, tile])
+        per_sample = P(batch, None, None)  # graftlint: disable=PLAN001 (same: the key bias in the key blocks' layout, a sample's)
         key_mask = P(batch, None)  # graftlint: disable=PLAN001 (same: the [b, m] key padding mask)
-        # residuals: q, k, v (flat, padded), the padded bias, o, logsumexp
-        res = (rows, rows, rows, None if mask is None else per_sample, rows,
-               rows)
+        # residuals: qkv, the blocked bias, o, logsumexp
+        res = (fused, None if mask is None else per_sample, wide, stats)
         wrap = functools.partial(jax.shard_map, mesh=mesh.mesh,
                                  check_vma=False)
-        return (wrap(forward, in_specs=(split, split, split,
-                                        None if mask is None else key_mask),
-                     out_specs=(split, res)),
-                wrap(backward, in_specs=(res, split),
-                     out_specs=(split, split, split)))
+        return (wrap(forward,
+                     in_specs=(fused, None if mask is None else key_mask),
+                     out_specs=(wide, res)),
+                wrap(backward, in_specs=(res, wide), out_specs=fused))
+
+
+def _dense_core(core: _Core, qkv, mask):
+    """The dense branch on the kernel's operands: its own transpositions to
+    head-major and back, around :func:`dense_attention`."""
+    b, n = qkv.shape[:2]
+    q, k, v = qkv.reshape(b, n, 3, core.heads, core.dim_head).transpose(
+        2, 0, 3, 1, 4)
+    out = dense_attention(core.pattern, core.act_dtype, q, k, v, mask)
+    return out.transpose(0, 2, 1, 3).reshape(b, n, -1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _switched_core(core: _Core, q, k, v, mask):
-    """The attention core as the kernel where the program is lowered for a
-    TPU and as :func:`dense_attention` anywhere else
-    (``jax.lax.platform_dependent``, forward and backward each: no
+def _switched_core(core: _Core, qkv, mask):
+    """The attention core, ``to_qkv``'s result (:attr:`_Core.fused`) to
+    ``to_out``'s input ``[b, n, heads * dim_head]``, as the kernel where
+    the program is lowered for a TPU and as :func:`dense_attention` anywhere
+    else (``jax.lax.platform_dependent``, forward and backward each: no
     ``jax.default_backend()``, and a compile from a CPU host for a described
     chip gets the kernel).  One VJP around both, so that differentiation
     never goes through the switch: the kernel brings its own backward, and
-    the dense branch's is ``jax.vjp`` of the same function on the saved q,
-    k, v (it recomputes its scores; on the platforms that run it, the
+    the dense branch's is ``jax.vjp`` of the same function on the saved
+    ``qkv`` (it recomputes its scores; on the platforms that run it, the
     arithmetic of the dense branch called directly, bit for bit).  Both
     switches are jitted on the static ``core``: the layers of one variant
     agree on it and on their shapes, so a model traces and lowers each
     switch, with both of its branches, once a variant and not once a layer
     (``lucid1024``'s twelve layers: once)."""
-    return _switched_fwd(core, q, k, v, mask)[0]
+    return _switched_fwd(core, qkv, mask)[0]
 
 
-def _switched_fwd(core: _Core, q, k, v, mask):
-    out, residuals = _forward_switch(core, q, k, v, mask)
-    return out, (q, k, v, mask, residuals)
+def _switched_fwd(core: _Core, qkv, mask):
+    out, residuals = _forward_switch(core, qkv, mask)
+    return out, (mask, residuals)
 
 
 def _switched_bwd(core: _Core, saved, g):
-    return (*_backward_switch(core, *saved, g), None)
+    return _backward_switch(core, *saved, g), None
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _forward_switch(core: _Core, q, k, v, mask):
-    forward, _ = core.halves(q, mask)
+def _forward_switch(core: _Core, qkv, mask):
+    """``(out, residuals)``; the residuals are the kernel's (``qkv`` first)
+    on either branch, the statistics blank on the dense one."""
+    forward, _ = core.halves(qkv, mask)
     blank = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                         jax.eval_shape(forward, q, k, v, mask)[1])
+                         jax.eval_shape(forward, qkv, mask)[1][1:])
 
-    def dense(q, k, v, mask):
-        return dense_attention(core.pattern, core.act_dtype, q, k, v,
-                               mask), blank
+    def dense(qkv, mask):
+        return _dense_core(core, qkv, mask), (qkv, *blank)
 
-    return jax.lax.platform_dependent(q, k, v, mask, tpu=forward,
-                                      default=dense)
+    return jax.lax.platform_dependent(qkv, mask, tpu=forward, default=dense)
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _backward_switch(core: _Core, q, k, v, mask, residuals, g):
-    _, backward = core.halves(q, mask)
+def _backward_switch(core: _Core, mask, residuals, g):
+    _, backward = core.halves(residuals[0], mask)
 
-    def kernel(q, k, v, mask, residuals, g):
+    def kernel(mask, residuals, g):
         return backward(residuals, g)
 
-    def dense(q, k, v, mask, residuals, g):
+    def dense(mask, residuals, g):
         with prof.scope("attn-scores"):
-            return jax.vjp(lambda q, k, v: dense_attention(
-                core.pattern, core.act_dtype, q, k, v, mask), q, k, v)[1](g)
+            return jax.vjp(lambda qkv: _dense_core(core, qkv, mask),
+                           residuals[0])[1](g)[0]
 
-    return jax.lax.platform_dependent(q, k, v, mask, residuals, g,
-                                      tpu=kernel, default=dense)
+    return jax.lax.platform_dependent(mask, residuals, g, tpu=kernel,
+                                      default=dense)
 
 
 _switched_core.defvjp(_switched_fwd, _switched_bwd)
@@ -834,12 +884,38 @@ class MultiHeadAttention(nn.Module):
             qkv = qkv.transpose(2, 0, 3, 1, 4)  # [3, b, heads, n, dh]
             return qkv[0], qkv[1], qkv[2]
 
+    def _kernel_qkv(self, x, core: _Core):
+        """The fused projection as the flash kernel reads it.  Where no
+        mesh axis splits the heads, one product onto ``[b, n, 3 * heads *
+        dh]`` (``to_qkv``'s kernel seen as ``[dim, 3 * heads * dh]``: the
+        weights are reshaped, never the activations): on the TPU a ``[..,
+        heads, dh]`` array is tiled over its last two axes, ``dh`` 64 padded
+        to the 128 lanes, and XLA can reach the kernel's ``[.., 3 * heads *
+        dh]`` from it only through a copy (PERF.md, Findings PR 35)."""
+        with prof.scope("attn-qkv"):
+            if not core.fused:
+                return self.to_qkv(x)                   # [b, n, 3, heads, dh]
+            kernel = self.to_qkv.variables["params"]["kernel"]
+            x, kernel = nn.dtypes.promote_dtype(x, kernel, dtype=self.dtype)
+            # graftlint: disable=DOT001 (uniform: x and the kernel are promoted to self.dtype above, nn.DenseGeneral's own product)
+            return jnp.dot(x, kernel.reshape(kernel.shape[0], -1))
+
     def __call__(self, x, mask=None, deterministic: bool = True,
                  return_kv: bool = False):
         b, n, _ = x.shape
-        q, k, v = self._qkv(x)
+        ring = self.ring_axis is not None and not self.is_initializing()
+        core = None if ring else self._kernel_core(
+            b, n, self.dtype or x.dtype, x.dtype, cached=return_kv)
+        if core is not None:
+            # q, k, v stay where the projection wrote them and the kernel
+            # writes to_out's input: no transposition on this path
+            qkv = self._kernel_qkv(x, core)
+            with prof.scope("attn-scores"):
+                out = _switched_core(core, qkv, mask)
+            return self._project_out(out, x.dtype, deterministic)
 
-        if self.ring_axis is not None and not self.is_initializing():
+        q, k, v = self._qkv(x)
+        if ring:
             # sequence parallelism: x is this device's sequence shard and we
             # are inside a shard_map over `ring_axis` — exact attention via
             # k/v ring rotation (parallel/ring.py) or head<->sequence
@@ -862,49 +938,64 @@ class MultiHeadAttention(nn.Module):
                               causal=self.pattern.causal)
         else:
             with prof.scope("attn-scores"):
-                out = self._attention_core(q, k, v, mask, x.dtype,
-                                           cached=return_kv)
+                out = dense_attention(self.pattern, x.dtype, q, k, v, mask,
+                                      grouped=self.kv_heads is not None)
 
-        with prof.scope("attn-out"):
-            out = out.astype(x.dtype)
-            out = out.transpose(0, 2, 1, 3).reshape(b, n, self.heads * self.dim_head)
-            out = self.to_out(out)
-            out = self.drop(out, deterministic=deterministic)
+        out = self._project_out(out, x.dtype, deterministic)
         if return_kv:
             return out, (k, v)
         return out
 
-    def _attention_core(self, q, k, v, mask, act_dtype,
-                        cached: bool = False):
-        """The attention core of a forward without a cache: the flash kernel
-        where the shape allows (:func:`flash_tiles`) and the program is
-        lowered for a TPU, the dense-masked branch otherwise.  A prefill
-        (``cached``: it returns its keys and values, one batch-1 pass a
-        request) keeps the dense branch, as does flax's shape pass."""
-        b, h, n, _ = q.shape
-        tiles = None if cached or self.is_initializing() else flash_tiles(
-            n, self.dim_head, q.dtype, self.pattern, self.kv_heads,
-            self.ring_axis)
-        mesh = _kernel_mesh[-1] if _kernel_mesh else None
-        if tiles is not None and mesh is not None and (
-                b % mesh.batch_ways or h % mesh.head_ways):
-            tiles = None    # GSPMD's to place: the dense branch
-        if _choices:
-            choice = dict(n=n, tiles=tiles, computed=0, blocks=0)
-            if tiles is not None:
-                from .attention_pallas import block_counts
+    def _project_out(self, out, dtype, deterministic: bool):
+        """``to_out`` and its dropout on the core's result: ``[b, heads, n,
+        dh]`` of the dense branches, ``[b, n, heads * dh]`` of the kernel."""
+        with prof.scope("attn-out"):
+            out = out.astype(dtype)
+            if out.ndim == 4:
+                b, _, n, _ = out.shape
+                out = out.transpose(0, 2, 1, 3).reshape(b, n, self.heads * self.dim_head)
+            out = self.to_out(out)
+            return self.drop(out, deterministic=deterministic)
 
-                skipped, partly, wholly = block_counts(
-                    kernel_pattern(self.pattern), n, *tiles)
-                choice.update(computed=partly + wholly,
-                              blocks=skipped + partly + wholly)
+    def _kernel_core(self, b: int, n: int, dtype, act_dtype,
+                     cached: bool = False) -> Optional[_Core]:
+        """The switched core of a forward without a cache over ``b``
+        sequences of ``n`` positions projected to ``dtype``: the flash
+        kernel where the shape allows (:func:`flash_tiles`, on the heads a
+        shard of the plan's mesh holds) and the program is lowered for a
+        TPU, the dense-masked branch otherwise; None where the dense branch
+        stays whatever the platform.  A prefill (``cached``: it returns its
+        keys and values, one batch-1 pass a request) keeps it, as does
+        flax's shape pass."""
+        mesh = _kernel_mesh[-1] if _kernel_mesh else None
+        core = None
+        # a batch or heads the mesh does not divide: GSPMD's to place, dense
+        if not (cached or self.is_initializing() or mesh is not None and (
+                b % mesh.batch_ways or self.heads % mesh.head_ways)):
+            tiles = flash_tiles(
+                n, self.heads // (mesh.head_ways if mesh else 1),
+                self.dim_head, dtype, self.pattern, self.kv_heads,
+                self.ring_axis)
+            if tiles is not None:
+                core = _Core(kernel_pattern(self.pattern),
+                             jnp.dtype(act_dtype), tiles, mesh, self.heads,
+                             self.dim_head)
+        if _choices:
+            choice = dict(n=n, tiles=core and core.tiles, computed=0,
+                          blocks=0)
+            if core is not None:
+                from .attention_pallas import HBM_PAD_ROWS, block_counts
+
+                skipped, partly, wholly = block_counts(core.pattern, n,
+                                                       *core.tiles)
+                choice.update(
+                    computed=partly + wholly,
+                    blocks=skipped + partly + wholly,
+                    heads_per_program=lane_block(
+                        core.shard_heads, self.dim_head) // self.dim_head,
+                    hbm_pad_rows=HBM_PAD_ROWS)
             _choices[-1][self.pattern] = choice
-        if tiles is None:
-            return dense_attention(self.pattern, act_dtype, q, k, v, mask,
-                                   grouped=self.kv_heads is not None)
-        return _switched_core(
-            _Core(kernel_pattern(self.pattern), jnp.dtype(act_dtype), tiles,
-                  mesh), q, k, v, mask)
+        return core
 
     def _qkv_decode(self, x, qw, index=None):
         """Decode-path QKV projection: the f32/bf16 kernel, or — under

@@ -12,6 +12,27 @@ Design (TPU-first):
   attention matrix is never materialized in HBM, forward or backward.  At
   the reference's CUB geometry (b16 h8 n1104) the dense f32 scores alone are
   624 MB a layer; this kernel keeps them in VMEM tiles.
+* **the projections' own arrays** (PR 35): the kernels read q, k and v
+  straight out of ``to_qkv``'s result ``[b, n, 3 * heads * dim_head]`` (the
+  same array under three block specs), write ``o`` as ``[b, n, heads *
+  dim_head]``, which is ``to_out``'s input as it stands, and write dq and
+  dk into one ``[b, n, 3 * heads * dim_head]`` buffer where q and k lie in
+  ``qkv`` (the dk/dv call takes the dq call's buffer through
+  ``input_output_aliases``); dv, the dk/dv call's second output, is written
+  into the buffer's last third by one in-place update.  A program's block
+  is the whole sequence, at its own length, by one lane block
+  (:func:`~.attention.lane_block`: 128 lanes, two heads of 64 side by side,
+  or one head of 128); the grid is ``(batch, lane blocks)``, and a program
+  goes through its heads in a loop: each trip takes its head's lanes of the
+  operands with a select and writes its lanes of the block's outputs.
+  Nothing is padded, sliced or transposed in HBM around a call.
+* **the ragged tail is the kernel's**: where the length is no multiple of
+  the tile, the last block of queries (and of keys) is the *last full tile*,
+  rows ``[n - tile, n)``; its mask tile disallows the rows and columns the
+  block before it already covered, and only its new rows are stored.  So
+  every shape inside the kernel is a whole tile.  Logsumexp and delta are
+  kept in that block layout, ``[b, heads, blocks, tile]``, and the key bias
+  as ``[b, blocks, tile]``: no statistic is cut at an unaligned lane offset.
 * **three kinds of block**: a static table derived from the pattern
   predicate marks each (query block, key block) *skipped* (no pair allowed:
   no work at all), *wholly allowed* (no mask tile, no select) or *partly
@@ -23,17 +44,16 @@ Design (TPU-first):
   probabilities enter every ``dot_general`` in ``q.dtype`` with float32
   accumulation — with bf16 inputs the rounding the dense path has (it casts
   the probabilities to the activation dtype before ``attn.v``), with f32
-  inputs nothing is rounded.  Running max, sum, ``exp``, logsumexp, delta
-  and the accumulators are float32 whatever the inputs.
-* **keys/values stay VMEM-resident** per (batch*head) program: at n≈1104,
-  dh=64 they fit comfortably, so the inner loop does no HBM traffic at all.
+  inputs nothing is rounded.  ``q * dim_head ** -0.5`` is taken in the input
+  dtype, before the product, as the dense path does.  Running max, sum,
+  ``exp``, logsumexp, delta (computed in the dq kernel from ``do`` and
+  ``o``) and the accumulators are float32 whatever the inputs.
+* **keys/values stay VMEM-resident** per program: at n≈1104 a lane block's
+  q, k, v fit comfortably, so the inner loop does no HBM traffic at all.
 * full custom VJP: flash backward (dq, then dk/dv on transposed tiles so
   that every product is in the MXU's native form) with the same block
   table, using the saved logsumexp rows; both backward kernels are traced
   under the forward's ``graftprof:attn-scores`` scope.
-
-All shapes are padded to block multiples with masked-off (never-attended)
-positions.
 """
 from __future__ import annotations
 
@@ -41,7 +61,7 @@ import functools
 import hashlib
 import os
 from pathlib import Path
-from typing import NamedTuple, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +69,7 @@ import numpy as np
 
 from ..obs import prof
 from .attention import (LANES, AttnPattern, dense_pattern_mask,
-                        kernel_pattern)
+                        kernel_pattern, lane_block)
 
 NEG_INF = -1e30  # finite mask value: keeps (s - lse) well-defined everywhere
 
@@ -57,12 +77,25 @@ NEG_INF = -1e30  # finite mask value: keeps (s - lse) well-defined everywhere
 #: partly allowed block whose mask is tile ``value - PARTIAL``
 SKIP, WHOLE, PARTIAL = 0, 1, 2
 
+#: positions of padding a call adds to a sequence in HBM (48 at n = 1104
+#: until PR 35; the ``attention.kernel`` record carries it)
+HBM_PAD_ROWS = 0
+
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def block_starts(n: int, block: int) -> List[int]:
+    """First row of each block of a length-``n`` sequence: multiples of
+    ``block``, the last one pulled back to ``n - block`` (the last full
+    tile) where ``n`` is no multiple.  Block ``i``'s *new* rows, those no
+    block before it holds, start at ``i * block``."""
+    assert 0 < block <= n, (block, n)
+    return [min(i * block, n - block) for i in range(-(-n // block))]
 
 
 class PatternBlocks(NamedTuple):
@@ -74,27 +107,24 @@ class PatternBlocks(NamedTuple):
     #                     allowed key yet but will meet one further on
 
 
-@functools.lru_cache(maxsize=8)
-def _padded_mask(pattern: AttnPattern, n: int, n_pad: int) -> np.ndarray:
-    """The pattern's mask with padded queries and keys allowed nothing
-    (read-only: the tilings of one pattern share it)."""
-    mask = np.zeros((n_pad, n_pad), dtype=bool)
-    mask[:n, :n] = dense_pattern_mask(pattern, n, n)
-    mask.setflags(write=False)
-    return mask
-
-
 @functools.lru_cache(maxsize=64)
-def _pattern_blocks(pattern: AttnPattern, n: int, n_pad: int,
-                    block_q: int, block_k: int,
+def _pattern_blocks(pattern: AttnPattern, n: int, block_q: int, block_k: int,
                     all_partial: bool = False) -> PatternBlocks:
     """Static (trace-time) block table and mask tiles for a pattern at
-    length ``n`` padded to ``n_pad``.  Padded queries and keys are allowed
-    nothing.  ``all_partial`` (a test hook) treats wholly allowed blocks as
-    partly allowed: the result must not change."""
-    mask = _padded_mask(pattern, n, n_pad)
-    nq, nk = n_pad // block_q, n_pad // block_k
-    blocks = mask.reshape(nq, block_q, nk, block_k).transpose(0, 2, 1, 3)
+    length ``n``.  A tail block (:func:`block_starts`) is allowed nothing in
+    the rows and columns it shares with the block before it: each pair of
+    positions lies in exactly one block.  ``all_partial`` (a test hook)
+    treats wholly allowed blocks as partly allowed: the result must not
+    change."""
+    mask = np.broadcast_to(dense_pattern_mask(pattern, n, n), (n, n))
+    q_starts, k_starts = block_starts(n, block_q), block_starts(n, block_k)
+    nq, nk = len(q_starts), len(k_starts)
+    blocks = np.zeros((nq, nk, block_q, block_k), bool)
+    for qb, q0 in enumerate(q_starts):
+        for kb, k0 in enumerate(k_starts):
+            new_q, new_k = qb * block_q, kb * block_k
+            blocks[qb, kb, new_q - q0:, new_k - k0:] = mask[
+                new_q:q0 + block_q, new_k:k0 + block_k]
     some, every = blocks.any((2, 3)), blocks.all((2, 3))
     if all_partial:
         every = np.zeros_like(every)
@@ -110,32 +140,79 @@ def _pattern_blocks(pattern: AttnPattern, n: int, n_pad: int,
         tiles.append(np.zeros((block_q, block_k), bool))
     partial = int((table >= PARTIAL).sum())
     whole = int((table == WHOLE).sum())
-    # keys each query has met after each key block, and in the end
-    met = mask.reshape(n_pad, nk, block_k).any(2).cumsum(1) > 0
+    # keys each row of a query block has met after each key block, and in
+    # the end
+    met = blocks.any(3).cumsum(1) > 0                       # [nq, nk, bq]
     waiting = ~met & met[:, -1:]
     return PatternBlocks(table, np.stack(tiles).astype(np.int8),
                          (nq * nk - partial - whole, partial, whole),
-                         waiting.reshape(nq, block_q, nk).any(1))
+                         waiting.any(2))
 
 
 def block_counts(pattern: AttnPattern, n: int, block_q: int,
                  block_k: int) -> Tuple[int, int, int]:
     """(skipped, partly allowed, wholly allowed) blocks of one layer."""
-    n_pad = _padded_len(n, block_q, block_k)
-    return _pattern_blocks(pattern, n, n_pad, block_q, block_k).counts
+    return _pattern_blocks(pattern, n, block_q, block_k).counts
 
 
 # ---------------------------------------------------------------------------
 # kernels
 # ---------------------------------------------------------------------------
 #
-# One program holds one (batch, head)'s whole padded sequence, and every loop
-# over blocks is unrolled at trace time from the static table: no branch, no
-# dynamic slice, no carried loop state, so the scheduler overlaps one block's
-# MXU passes with the next one's VPU work.  On the chip (PERF.md, Findings
-# PR 28) this form runs the CUB shape in 2.1-2.8 ms a layer where the same
-# arithmetic under ``fori_loop`` + ``cond`` over a (batch*head, block) grid
-# took 5.5-9.5 ms and the dense branch 7.2 ms.  ``q`` arrives scaled.
+# One program holds one sample's whole sequence by one lane block of heads,
+# and every loop over blocks is unrolled at trace time from the static table:
+# no branch, no dynamic slice, no carried loop state, so the scheduler
+# overlaps one block's MXU passes with the next one's VPU work.  On the chip
+# (PERF.md, Findings PR 28) this form runs the CUB shape in 2.1-2.8 ms a
+# layer where the same arithmetic under ``fori_loop`` + ``cond`` over a
+# (batch*head, block) grid took 5.5-9.5 ms and the dense branch 7.2 ms.  The
+# heads of a program are the trips of one ``fori_loop`` around that body
+# (:func:`_each_head`), not a second unrolled copy of it: the trace and
+# Mosaic's lowering stay the size they were.  Nor are they a grid axis: the
+# pipeline fetches the next step's blocks during the current step, and a
+# program that is one step hides the next program's q, k, v behind all of
+# its work, not behind its last head's (PERF.md, Findings PR 35: as a grid
+# axis the dk/dv kernel waited 4.7 us a program for them).
+#
+# Two heads share the 128 lanes of a block.  A step has its head's operand
+# by a select over the lanes (:func:`_only`), never by a slice: a product
+# that contracts over all 128 lanes with the other head's zeroed costs the
+# MXU what the 64-deep product padded to 128 cost, and ``p @ v`` gives
+# ``[rows, 128]`` whose other half is never stored (:func:`_store`).
+
+
+def _each_head(static: "_Static", body) -> None:
+    """``body(head)`` for every head of the program's lane block."""
+    if static.heads_per_program == 1:
+        body(0)
+    else:
+        jax.lax.fori_loop(0, static.heads_per_program,
+                          lambda head, _: body(head), None)
+
+
+def _own_lanes(static: "_Static", head):
+    """``[1, lanes]`` bool: the lanes of head number ``head`` (traced: the
+    loop's trip) of the program's block; None where one head fills it."""
+    if static.heads_per_program == 1:
+        return None
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, static.lanes), 1)
+    first = head * static.dim_head
+    return (lane >= first) & (lane < first + static.dim_head)
+
+
+def _only(x, own):
+    """``x`` with the other heads' lanes zeroed."""
+    return x if own is None else jnp.where(own, x, jnp.zeros_like(x))
+
+
+def _store(ref, at: tuple, first: int, value, own):
+    """Rows ``[first, first + len(value))`` of ``ref[at]``: this head's lanes
+    of the float32 ``value``, the other heads' left as they are."""
+    rows = slice(first, first + value.shape[0])
+    if own is not None:
+        value = jnp.where(own, value, ref[(*at, rows, slice(None))].astype(
+            jnp.float32))
+    ref[(*at, rows, slice(None))] = value.astype(ref.dtype)
 
 
 def _scores(a, b_blk, bias, tile):
@@ -209,76 +286,108 @@ def _computed(blocks: PatternBlocks, qb=None, kb=None):
             for i, c in enumerate(codes) if c != SKIP]
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest, blocks: PatternBlocks,
-                block_q: int, block_k: int, has_bias: bool):
+def _fwd_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest, static: "_Static",
+                has_bias: bool):
     bias_ref = rest[0] if has_bias else None
     o_ref, lse_ref = rest[-2:]
-    for qb in range(blocks.table.shape[0]):
-        rows = slice(qb * block_q, (qb + 1) * block_q)
-        q = q_ref[0, rows, :]
-        carry = None
-        for kb, number in _computed(blocks, qb=qb):
-            cols = slice(kb * block_k, (kb + 1) * block_k)
-            carry = _fwd_block(
-                q, k_ref[0, cols, :], v_ref[0, cols, :],
-                bias_ref[0, :, cols] if has_bias else None,
-                None if number is None else tiles_ref[number], carry,
-                guard=has_bias or bool(blocks.guard[qb, kb]))
-        if carry is None:       # a block row of padding only
-            o_ref[0, rows, :] = jnp.zeros((block_q, q.shape[1]), o_ref.dtype)
-            lse_ref[0, 0, rows] = jnp.full((block_q,), jnp.inf, jnp.float32)
-            continue
-        m, l, acc = carry
-        # rows with no attendable key (padding, a sample whose every key is
-        # dropped) give zeros, and lse = +inf so that the backward's
-        # exp(s - lse) is exactly 0
-        dead = m <= NEG_INF * 0.5
-        o_ref[0, rows, :] = jnp.where(dead, 0.0, acc / l).astype(o_ref.dtype)
-        lse_ref[0, 0, rows] = jnp.where(dead, jnp.inf, m + jnp.log(l))[:, 0]
+    blocks, bq, bk = static.blocks, static.block_q, static.block_k
+
+    def one_head(head):
+        own = _own_lanes(static, head)
+        for qb, q0 in enumerate(static.q_starts):
+            q = _only(q_ref[0, q0:q0 + bq, :], own) * static.scale
+            carry = None
+            for kb, number in _computed(blocks, qb=qb):
+                cols = slice(static.k_starts[kb], static.k_starts[kb] + bk)
+                carry = _fwd_block(
+                    q, k_ref[0, cols, :], v_ref[0, cols, :],
+                    bias_ref[0, kb:kb + 1, :] if has_bias else None,
+                    None if number is None else tiles_ref[number], carry,
+                    guard=has_bias or bool(blocks.guard[qb, kb]))
+            new = qb * bq - q0      # the rows before it are the last block's
+            if carry is None:       # no key allowed to any query of the block
+                out = jnp.zeros((bq, static.lanes), jnp.float32)
+                lse = jnp.full((bq,), jnp.inf, jnp.float32)
+            else:
+                m, l, acc = carry
+                # rows with no attendable key (a tail block's shared rows, a
+                # sample whose every key is dropped) give zeros, and lse =
+                # +inf so that the backward's exp(s - lse) is exactly 0
+                dead = m <= NEG_INF * 0.5
+                out = jnp.where(dead, 0.0, acc / l)
+                lse = jnp.where(dead, jnp.inf, m + jnp.log(l))[:, 0]
+            _store(o_ref, (0,), qb * bq, out[new:], own)
+            lse_ref[0, head, qb, :] = lse
+
+    _each_head(static, one_head)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest,
-                   blocks: PatternBlocks, block_q: int, block_k: int,
+def _bwd_dq_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest, static: "_Static",
                    has_bias: bool):
+    """dq a query block at a time, and delta (the row sums of ``do * o``
+    over the head's lanes) on the way, for the dk/dv kernel."""
     bias_ref = rest[0] if has_bias else None
-    do_ref, lse_ref, delta_ref, dq_ref = rest[-4:]
-    for qb in range(blocks.table.shape[0]):
-        rows = slice(qb * block_q, (qb + 1) * block_q)
-        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
-        lse = lse_ref[0, 0, rows][:, None]      # [bq, 1]
-        delta = delta_ref[0, 0, rows][:, None]
-        dq = jnp.zeros(q.shape, jnp.float32)
-        for kb, number in _computed(blocks, qb=qb):
-            cols = slice(kb * block_k, (kb + 1) * block_k)
-            dq = _dq_block(
-                q, do, lse, delta, k_ref[0, cols, :], v_ref[0, cols, :],
-                bias_ref[0, :, cols] if has_bias else None,
-                None if number is None else tiles_ref[number], dq)
-        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
+    do_ref, o_ref, lse_ref, dq_ref, delta_ref = rest[-5:]
+    blocks, bq, bk = static.blocks, static.block_q, static.block_k
+
+    def one_head(head):
+        own = _own_lanes(static, head)
+        for qb, q0 in enumerate(static.q_starts):
+            rows = slice(q0, q0 + bq)
+            q = _only(q_ref[0, rows, :], own) * static.scale
+            do = _only(do_ref[0, rows, :], own)
+            delta = jnp.sum(do.astype(jnp.float32)
+                            * o_ref[0, rows, :].astype(jnp.float32),
+                            axis=1, keepdims=True)              # [bq, 1]
+            lse = lse_ref[0, head, qb, :][:, None]
+            dq = jnp.zeros((bq, static.lanes), jnp.float32)
+            for kb, number in _computed(blocks, qb=qb):
+                cols = slice(static.k_starts[kb], static.k_starts[kb] + bk)
+                dq = _dq_block(
+                    q, do, lse, delta, k_ref[0, cols, :], v_ref[0, cols, :],
+                    bias_ref[0, kb:kb + 1, :] if has_bias else None,
+                    None if number is None else tiles_ref[number], dq)
+            _store(dq_ref, (0,), qb * bq, (dq * static.scale)[qb * bq - q0:],
+                   own)
+            delta_ref[0, head, qb, :] = delta[:, 0]
+
+    _each_head(static, one_head)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest,
-                    blocks: PatternBlocks, block_q: int, block_k: int,
-                    has_bias: bool):
-    """dk and dv, a key block at a time (:func:`_dkv_block`);
-    ``tiles_ref`` holds the mask tiles transposed."""
+                    static: "_Static", has_bias: bool):
+    """dk and dv, a key block at a time (:func:`_dkv_block`); ``tiles_ref``
+    holds the mask tiles transposed.  The scaled, selected q and the
+    selected do are made once a head: the inner loop reads them as often as
+    it has blocks."""
+    from jax.experimental import pallas as pl
+
     bias_ref = rest[0] if has_bias else None
-    do_ref, lse_ref, delta_ref, dk_ref, dv_ref = rest[-5:]
-    for kb in range(blocks.table.shape[1]):
-        cols = slice(kb * block_k, (kb + 1) * block_k)
-        k_blk, v_blk = k_ref[0, cols, :], v_ref[0, cols, :]
-        # the bias over this key block, as a column
-        bias = bias_ref[0, 0, cols][:, None] if has_bias else None
-        dk = jnp.zeros(k_blk.shape, jnp.float32)
-        dv = jnp.zeros(v_blk.shape, jnp.float32)
-        for qb, number in _computed(blocks, kb=kb):
-            rows = slice(qb * block_q, (qb + 1) * block_q)
-            dk, dv = _dkv_block(
-                k_blk, v_blk, q_ref[0, rows, :], do_ref[0, rows, :],
-                lse_ref[0, :, rows], delta_ref[0, :, rows], bias,
-                None if number is None else tiles_ref[number], dk, dv)
-        dk_ref[0, cols, :] = dk.astype(dk_ref.dtype)
-        dv_ref[0, cols, :] = dv.astype(dv_ref.dtype)
+    do_ref, lse_ref, delta_ref, _, dk_ref, dv_ref, q_own, do_own = rest[-8:]
+    blocks, bq, bk = static.blocks, static.block_q, static.block_k
+
+    def one_head(head):
+        own = _own_lanes(static, head)
+        q_own[...] = _only(q_ref[0], own) * static.scale
+        do_own[...] = _only(do_ref[0], own)
+        for kb, k0 in enumerate(static.k_starts):
+            k_blk, v_blk = k_ref[0, k0:k0 + bk, :], v_ref[0, k0:k0 + bk, :]
+            # the bias over this key block, as a column
+            bias = bias_ref[0, kb, :][:, None] if has_bias else None
+            dk = jnp.zeros((bk, static.lanes), jnp.float32)
+            dv = jnp.zeros((bk, static.lanes), jnp.float32)
+            for qb, number in _computed(blocks, kb=kb):
+                rows = slice(static.q_starts[qb], static.q_starts[qb] + bq)
+                dk, dv = _dkv_block(
+                    k_blk, v_blk, q_own[rows, :], do_own[rows, :],
+                    lse_ref[0, pl.ds(head, 1), qb, :],
+                    delta_ref[0, pl.ds(head, 1), qb, :], bias,
+                    None if number is None else tiles_ref[number], dk, dv)
+            new = kb * bk - k0
+            _store(dk_ref, (0,), kb * bk, dk[new:], own)
+            _store(dv_ref, (0,), kb * bk, dv[new:], own)
+
+    _each_head(static, one_head)
 
 
 # ---------------------------------------------------------------------------
@@ -290,49 +399,71 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest,
 VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 
 
-def _pallas(kernel, blocks: PatternBlocks, tiles, bias, heads: int, q, k, v,
-            extra, n_out: int, stats_out: int, *, block_q, block_k,
-            interpret):
-    """One of the three kernels over a ``(batch*head,)`` grid: q, k, v, every
-    ``extra`` operand and each of the ``n_out`` outputs one whole sequence a
-    program (``extra``'s last two and the ``stats_out`` last outputs: one
-    row of float32 statistics), the mask tiles one block for the whole
-    call, the key bias one row a sample.  Every program writes its own
-    (batch, head)'s outputs exactly once, so the grid axis is parallel.
+def _pallas(kernel, static: "_Static", tiles, bias, qkv, wide, stats, outs,
+            *, scratch=0, aliased=None):
+    """One of the three kernels over the grid ``(batch, lane blocks)``: q, k
+    and v as column blocks of ``qkv`` (``[b, n, 3 * heads * dim_head]``,
+    passed three times), each ``wide`` operand (``[b, n, heads *
+    dim_head]``: do, o) the same column block of its array, each of
+    ``stats`` the ``[blocks, tile]`` float32 statistics of the block's
+    heads, the mask tiles one block for the whole call, the key bias one
+    sample's.  ``outs``: ``("wide", thirds, third)`` is an array of
+    ``thirds * heads * dim_head`` columns of which the program writes its
+    lane block of third ``third``; ``("stat",)`` statistics.  ``aliased``:
+    an array left in HBM that the first output overwrites in place (the
+    blocks no program writes keep what it held).  Every program writes its
+    own blocks exactly once, so both grid axes are parallel.
 
     Pallas is imported here, not with the module: a process that finds its
     kernels in the cache (:func:`_kernels`) never pays that second."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    bh, n_pad, dh = q.shape
+    b, n, _ = qkv.shape
+    lanes, per, groups = (static.lanes, static.heads_per_program,
+                          static.lane_blocks)
+    nq = len(static.q_starts)
 
     def spec(shape, index_map):
         return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
 
-    seq = spec((1, n_pad, dh), lambda ib: (ib, 0, 0))
-    stat = spec((1, 1, n_pad), lambda ib: (ib, 0, 0))   # along the lanes
-    in_specs = [seq] * 3 + [spec(tiles.shape, lambda ib: (0, 0, 0))]
-    args = [q, k, v, tiles]
+    def column(third):
+        return spec((1, n, lanes), lambda ib, j: (ib, 0, third * groups + j))
+
+    stat = spec((1, per, nq, static.block_q), lambda ib, j: (ib, j, 0, 0))
+    in_specs = [column(0), column(1), column(2),
+                spec(tiles.shape, lambda ib, j: (0, 0, 0))]
+    args = [qkv, qkv, qkv, tiles]
     if bias is not None:
-        in_specs.append(spec((1, 1, n_pad),
-                             lambda ib: (jax.lax.div(ib, heads), 0, 0)))
+        in_specs.append(spec((1,) + bias.shape[1:], lambda ib, j: (ib, 0, 0)))
         args.append(bias)
-    if extra:
-        in_specs += [seq] * (len(extra) - 2) + [stat] * 2
-    out_specs = [seq] * (n_out - stats_out) + [stat] * stats_out
-    out_shape = (
-        [jax.ShapeDtypeStruct((bh, n_pad, dh), q.dtype)] * (n_out - stats_out)
-        + [jax.ShapeDtypeStruct((bh, 1, n_pad), jnp.float32)] * stats_out)
+    in_specs += [column(0)] * len(wide) + [stat] * len(stats)
+    args += [*wide, *stats]
+    aliases = {}
+    if aliased is not None:
+        aliases[len(args)] = 0
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        args.append(aliased)
+    out_specs, out_shape = [], []
+    for kind, *columns in outs:
+        if kind == "wide":
+            thirds, third = columns
+            out_specs.append(column(third))
+            out_shape.append(jax.ShapeDtypeStruct(
+                (b, n, thirds * static.heads * static.dim_head), qkv.dtype))
+        else:
+            out_specs.append(stat)
+            out_shape.append(jax.ShapeDtypeStruct(
+                (b, static.heads, nq, static.block_q), jnp.float32))
     return pl.pallas_call(
-        functools.partial(kernel, blocks=blocks, block_q=block_q,
-                          block_k=block_k, has_bias=bias is not None),
-        grid=(bh,), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape,
+        functools.partial(kernel, static=static, has_bias=bias is not None),
+        grid=(b, groups), in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, input_output_aliases=aliases,
+        scratch_shapes=[pltpu.VMEM((n, lanes), qkv.dtype)] * scratch,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",),
+            dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
-        interpret=interpret)(*args, *extra)
+        interpret=static.interpret)(*args)
 
 
 class _Static(NamedTuple):
@@ -341,7 +472,9 @@ class _Static(NamedTuple):
     calls below are jitted on it, so a model traces each kernel's unrolled
     body once a pattern, not once a layer and differentiation pass."""
     pattern: AttnPattern
-    n: int              # the sequence's own length, before padding
+    n: int
+    heads: int          # of the arrays the call is given (a shard's)
+    dim_head: int
     block_q: int
     block_k: int
     interpret: bool
@@ -350,37 +483,61 @@ class _Static(NamedTuple):
 
     @property
     def blocks(self) -> PatternBlocks:
-        return _pattern_blocks(
-            self.pattern, self.n,
-            _padded_len(self.n, self.block_q, self.block_k), self.block_q,
-            self.block_k, self.all_partial)
+        return _pattern_blocks(self.pattern, self.n, self.block_q,
+                               self.block_k, self.all_partial)
 
     @property
-    def tiling(self) -> dict:
-        return dict(block_q=self.block_q, block_k=self.block_k,
-                    interpret=self.interpret)
+    def q_starts(self) -> List[int]:
+        return block_starts(self.n, self.block_q)
+
+    @property
+    def k_starts(self) -> List[int]:
+        return block_starts(self.n, self.block_k)
+
+    @property
+    def lanes(self) -> int:
+        """Columns of one program's block: :func:`~.attention.lane_block`,
+        or every head's at once where the width cuts into no lane blocks
+        (the interpreter's toy shapes; the compiler is never asked)."""
+        return (lane_block(self.heads, self.dim_head)
+                or self.heads * self.dim_head)
+
+    @property
+    def heads_per_program(self) -> int:
+        return self.lanes // self.dim_head
+
+    @property
+    def lane_blocks(self) -> int:
+        return self.heads * self.dim_head // self.lanes
+
+    @property
+    def scale(self) -> float:
+        return self.dim_head ** -0.5
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _call_fwd(static: _Static, q, k, v, bias):
-    blocks = static.blocks
-    heads = 1 if bias is None else q.shape[0] // bias.shape[0]
-    return _pallas(_fwd_kernel, blocks, jnp.asarray(blocks.tiles), bias,
-                   heads, q, k, v, [], 2, 1, **static.tiling)
+def _call_fwd(static: _Static, qkv, bias):
+    """``(o [b, n, heads * dim_head], logsumexp [b, heads, blocks, tile])``."""
+    return _pallas(_fwd_kernel, static, jnp.asarray(static.blocks.tiles),
+                   bias, qkv, [], [], [("wide", 1, 0), ("stat",)])
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
-def _call_bwd(static: _Static, q, k, v, bias, do, lse, delta):
-    blocks = static.blocks
-    heads = 1 if bias is None else q.shape[0] // bias.shape[0]
-    extra = [do, lse, delta]
-    dq, = _pallas(_bwd_dq_kernel, blocks, jnp.asarray(blocks.tiles), bias,
-                  heads, q, k, v, extra, 1, 0, **static.tiling)
-    dk, dv = _pallas(
-        _bwd_dkv_kernel, blocks,
-        jnp.asarray(blocks.tiles.transpose(0, 2, 1)), bias, heads, q, k, v,
-        extra, 2, 0, **static.tiling)
-    return dq, dk, dv
+def _call_bwd(static: _Static, qkv, bias, do, o, lse):
+    """``(dqkv [b, n, 3 * heads * dim_head], dv [b, n, heads * dim_head])``:
+    dq and dk where q and k lie in ``qkv``, the columns of dv still to be
+    filled.  The dq call writes its third of the buffer (and delta); the
+    dk/dv call takes the buffer in place and writes dk's third.  A call has
+    one block an output array, so dv comes apart and :func:`_flash_bwd`
+    writes it in."""
+    tiles = static.blocks.tiles
+    buffer, delta = _pallas(
+        _bwd_dq_kernel, static, jnp.asarray(tiles), bias, qkv, [do, o],
+        [lse], [("wide", 3, 0), ("stat",)])
+    return _pallas(
+        _bwd_dkv_kernel, static, jnp.asarray(tiles.transpose(0, 2, 1)), bias,
+        qkv, [do], [lse, delta], [("wide", 3, 1), ("wide", 1, 0)], scratch=2,
+        aliased=buffer)
 
 
 # --- the kernels, kept between processes -------------------------------------
@@ -445,58 +602,40 @@ def _kernels(name: str, static: _Static, *args):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _flash_attention(static: _Static, q, k, v, bias):
-    out, _ = _flash_fwd(static, q, k, v, bias)
-    return out
+def _flash_attention(static: _Static, qkv, bias):
+    return _flash_fwd(static, qkv, bias)[0]
 
 
-def _padded_len(n: int, block_q: int, block_k: int) -> int:
-    """The kernel's actual padded sequence length — shared with the VMEM
-    guard so its estimate can never diverge from what _prepare allocates."""
-    n_pad = _round_up(n, max(block_q, block_k))
-    n_pad = _round_up(n_pad, block_q)
-    return _round_up(n_pad, block_k)
-
-
-def _flash_fwd(static: _Static, q, k, v, bias):
-    b, h, n, dh = q.shape
-    n_pad = _padded_len(n, static.block_q, static.block_k)
-    bias_p = None if bias is None else jnp.pad(
-        bias.astype(jnp.float32), ((0, 0), (0, n_pad - n)))[:, None, :]
-
-    def flat_pad(t):
-        t = t.reshape(b * h, n, dh)
-        return jnp.pad(t, ((0, 0), (0, n_pad - n), (0, 0)))
-
-    # the dense branch's own form: q * scale in the input dtype, then the
-    # dot; the kernels take q scaled, forward and backward
-    qf, kf, vf = flat_pad(q * dh ** -0.5), flat_pad(k), flat_pad(v)
-    o, lse = _kernels("fwd", static, qf, kf, vf, bias_p)
-    out = o[:, :n, :].reshape(b, h, n, dh)
-    return out, (qf, kf, vf, bias_p, o, lse)
+def _flash_fwd(static: _Static, qkv, bias):
+    """``qkv``: ``to_qkv``'s result, ``[b, n, 3, heads, dim_head]`` or flat
+    over its last three axes; ``bias``: None or the additive ``[b, n]`` key
+    bias.  Returns ``o [b, n, heads * dim_head]`` and the residuals the
+    backward needs beside ``qkv``."""
+    b, n = qkv.shape[:2]
+    if bias is not None:    # in the key blocks' layout, a row a block
+        bias = bias.astype(jnp.float32)
+        bias = jnp.stack([bias[:, k0:k0 + static.block_k]
+                          for k0 in static.k_starts], axis=1)
+    o, lse = _kernels("fwd", static, qkv.reshape(b, n, -1), bias)
+    return o, (qkv, bias, o, lse)
 
 
 def _flash_bwd(static: _Static, residuals, g):
     # a custom VJP's backward is traced outside the forward's name scope:
-    # put the backward kernels (and delta) under the scope the forward's
-    # callers give it, or a trace reads the forward alone
+    # put the backward kernels under the scope the forward's callers give
+    # it, or a trace reads the forward alone
     with prof.scope("attn-scores"):
-        qf, kf, vf, bias_p, o, lse = residuals
-        bh, n_pad, dh = qf.shape
-        b, h, n = g.shape[:3]
-        do = jnp.pad(g.reshape(bh, n, dh).astype(qf.dtype),
-                     ((0, 0), (0, n_pad - n), (0, 0)))
-        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                        axis=-1)[:, None, :]  # [bh, 1, n_pad]
-        dq, dk, dv = _kernels("bwd", static, qf, kf, vf, bias_p, do, lse,
-                              delta)
-
-        def unflat(t):
-            return t[:, :n, :].reshape(b, h, n, dh)
-
+        qkv, bias, o, lse = residuals
+        b, n = qkv.shape[:2]
+        dqkv, dv = _kernels("bwd", static, qkv.reshape(b, n, -1), bias,
+                            g.astype(qkv.dtype), o, lse)
+        # in place: one read and one write of dv (the kernels fill q's and
+        # k's columns themselves; ROADMAP S3 has what a third would take)
+        dqkv = jax.lax.dynamic_update_slice(
+            dqkv, dv, (0, 0, dqkv.shape[2] - dv.shape[2]))
         # the key-padding bias is not trainable
-        dbias = None if bias_p is None else jnp.zeros((b, n), jnp.float32)
-        return unflat(dq) * dh ** -0.5, unflat(dk), unflat(dv), dbias
+        dbias = None if bias is None else jnp.zeros((b, n), jnp.float32)
+        return dqkv.reshape(qkv.shape), dbias
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
@@ -513,101 +652,130 @@ def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
             * itemsize)
 
 
-def _vmem_resident_bytes(n_pad: int, dh: int, itemsize: int, block_q: int,
+def _vmem_resident_bytes(n: int, lanes: int, itemsize: int, block_q: int,
                          block_k: int, tiles: int = 1,
                          has_bias: bool = False) -> int:
     """VMEM one program holds in the hungriest of the three kernels (dk/dv:
-    q, k, v, do in and dk, dv out, one whole sequence each, logsumexp and
-    delta), in VMEM's padded layouts: every operand that moves with the grid
-    twice (Pallas double-buffers them), the mask tiles once (their block
-    never changes), and one float32 ``[block_q, block_k]`` tile of
-    intermediates.  Held against the compiler's own answers by
-    ``tests/test_tpu_compile.py``: at n = 4176 it takes every tiling up to
-    2304 x 2304 and refuses the backward of one 4224 x 4224 block, where
-    this reads 98 MiB and Mosaic 113."""
-    seq = _tile_bytes(n_pad, dh, itemsize)
-    stat = _tile_bytes(1, n_pad, 4)
-    moving = 6 * seq + (3 if has_bias else 2) * stat
-    return (2 * moving + tiles * _tile_bytes(block_q, block_k, 1)
-            + _tile_bytes(block_q, block_k, 4))
+    q, k, v, do in and dk, dv out, a lane block of the whole sequence each;
+    its two scratch copies; logsumexp, delta and the bias in their block
+    layouts), in VMEM's padded layouts: every operand that moves with
+    the grid twice (Pallas double-buffers them), the scratch and the mask
+    tiles once (the tiles' block never changes), and three float32
+    ``[block_q, block_k]`` tiles of intermediates (scores, probabilities,
+    their gradient).  Held against the compiler's own answers by
+    ``tests/test_tpu_compile.py``: at n = 4176 both take every tiling up to
+    2304 x 2304 and refuse the backward of 2560 x 2560 (this reads 110 MiB
+    there; with one tile of intermediates it read 57 and passed it)."""
+    seq = _tile_bytes(n, lanes, itemsize)
+    stats = 2 * _tile_bytes(-(-n // block_q), block_q, 4)
+    if has_bias:
+        stats += _tile_bytes(-(-n // block_k), block_k, 4)
+    return (2 * (6 * seq + stats) + 2 * seq
+            + tiles * _tile_bytes(block_q, block_k, 1)
+            + 3 * _tile_bytes(block_q, block_k, 4))
 
 
-def _checked_static(q, pattern: AttnPattern, has_bias: bool, block_q: int,
+def _checked_static(n: int, heads: int, dim_head: int, dtype,
+                    pattern: AttnPattern, has_bias: bool, block_q: int,
                     block_k: int, interpret: bool, all_partial: bool,
                     cache_kernels: bool) -> _Static:
-    """The static description of a call on ``q``-shaped arguments, or a
-    ValueError where the TPU's compiler would refuse it."""
-    n, dh = q.shape[2:]
+    """The static description of a call on ``[b, n, 3 * heads * dim_head]``,
+    or a ValueError where the TPU's compiler would refuse it.  Tiles wider
+    than the sequence are cut to it (one block)."""
+    block_q, block_k = min(block_q, n), min(block_k, n)
+    # layers of one variant share their kernels
+    static = _Static(kernel_pattern(pattern), n, heads, dim_head, block_q,
+                     block_k, interpret, all_partial, cache_kernels)
     if not interpret:
         if block_q % LANES or block_k % LANES:
             # Mosaic requires the last block dim be a multiple of the
-            # 128-lane width (the lse output [b, h, n] blocks the q axis in
-            # its last dim; k blocks stream through the same lanes) —
-            # sub-128 tiles fail deep inside lowering, so reject them at
-            # the API edge.  Seen on the chip: manual session 2026-08-02.
+            # 128-lane width (the statistics block the q axis in their last
+            # dim; k blocks stream through the same lanes) — sub-128 tiles
+            # fail deep inside lowering, so reject them at the API edge.
+            # Seen on the chip: manual session 2026-08-02.
             raise ValueError(
                 f"block_q/block_k must be multiples of the TPU lane width "
-                f"128 (got {block_q}/{block_k})")
-        n_pad = _padded_len(n, block_q, block_k)
+                f"128 and no longer than the sequence (got {block_q}/"
+                f"{block_k} at n={n})")
+        if lane_block(heads, dim_head) is None or n % 16:
+            raise ValueError(
+                f"the compiled kernel takes heads * dim_head in whole "
+                f"{LANES}-lane blocks of whole heads and n in whole 16-row "
+                f"sublane tiles (got {heads} x {dim_head}, n={n})")
         estimate = functools.partial(
-            _vmem_resident_bytes, n_pad, dh, q.dtype.itemsize, block_q,
-            block_k, has_bias=has_bias)
+            _vmem_resident_bytes, n, static.lanes, jnp.dtype(dtype).itemsize,
+            block_q, block_k, has_bias=has_bias)
         tiles, resident = 1, estimate(1)
         if resident <= VMEM_BUDGET_BYTES:   # else: no need to draw the mask
-            tiles = _pattern_blocks(pattern, n, n_pad, block_q, block_k,
-                                    all_partial).tiles.shape[0]
+            tiles = static.blocks.tiles.shape[0]
             resident = estimate(tiles)
         if resident > VMEM_BUDGET_BYTES:
             raise ValueError(
-                f"flash_pattern_attention keeps one (batch, head)'s whole "
+                f"the flash kernel keeps a lane block of one sample's whole "
                 f"sequences and the pattern's mask tiles ({tiles}+) "
-                f"VMEM-resident: n={n} (padded "
-                f"{n_pad}), dh={dh}, tiles {block_q}x{block_k} need "
-                f"~{resident / 1e6:.1f} MB of the "
+                f"VMEM-resident: n={n}, {static.lanes} lanes, tiles "
+                f"{block_q}x{block_k} need ~{resident / 1e6:.1f} MB of the "
                 f"~{VMEM_BUDGET_BYTES / 1e6:.0f} MB budget. Use smaller "
                 "tiles, the dense path or sequence parallelism (ring_axis) "
                 "for sequences this long.")
-    # layers of one variant share their kernels
-    return _Static(kernel_pattern(pattern), n, block_q, block_k, interpret,
-                   all_partial, cache_kernels)
+    return static
 
 
-def flash_pattern_attention(q, k, v, pattern: AttnPattern,
-                            key_pad_bias: Optional[jax.Array] = None, *,
-                            block_q: int = 128, block_k: int = 128,
-                            interpret: bool = False,
-                            all_partial: bool = False,
-                            cache_kernels: bool = False) -> jax.Array:
-    """Block-sparse flash attention for any `AttnPattern`.
+def flash_qkv_attention(qkv, pattern: AttnPattern,
+                        key_pad_bias: Optional[jax.Array] = None, *,
+                        block_q: int = 128, block_k: int = 128,
+                        interpret: bool = False, all_partial: bool = False,
+                        cache_kernels: bool = False) -> jax.Array:
+    """Block-sparse flash attention for any `AttnPattern`, on the
+    projections' own arrays.
 
-    q/k/v: [b, heads, n, dim_head]; `key_pad_bias` is an optional additive
-    f32 [b, n] key bias (0 keep / -1e30 drop) carrying the per-sample key
-    padding mask.  Returns [b, heads, n, dim_head] in q's dtype.
+    ``qkv``: ``to_qkv``'s result ``[b, n, 3, heads, dim_head]``;
+    `key_pad_bias` is an optional additive f32 [b, n] key bias (0 keep /
+    -1e30 drop) carrying the per-sample key padding mask.  Returns ``[b, n,
+    heads * dim_head]`` (``to_out``'s input) in qkv's dtype.
     ``all_partial`` is a test hook (:func:`_pattern_blocks`);
     ``cache_kernels`` keeps the traced kernels between processes
     (:func:`_kernels`: the model's default path asks for it).
 
-    Raises ValueError when the sequence is long enough that one program's
-    whole sequences and mask tiles would overflow the VMEM the kernels may
-    take — callers should fall back to the dense-masked XLA path (or
-    sequence parallelism, parallel/ring.py) instead of letting Mosaic fail
-    opaquely mid-compile.  The guard only applies to real TPU compilation;
-    the interpreter (CPU/GPU correctness runs) has no VMEM limit.
+    Raises ValueError where the compiler would refuse the call: heads that
+    do not fill whole lane blocks, a length off the 16-row sublane tiles, or
+    a sequence long enough that one program's whole sequences and mask tiles
+    would overflow the VMEM the kernels may take — callers should fall back
+    to the dense-masked XLA path (or sequence parallelism, parallel/ring.py)
+    instead of letting Mosaic fail opaquely mid-compile.  The guards only
+    apply to real TPU compilation; the interpreter (CPU/GPU correctness
+    runs) has no such limits.
     """
+    _, n, _, heads, dim_head = qkv.shape
     return _flash_attention(
-        _checked_static(q, pattern, key_pad_bias is not None, block_q,
-                        block_k, interpret, all_partial, cache_kernels),
-        q, k, v, key_pad_bias)
+        _checked_static(n, heads, dim_head, qkv.dtype, pattern,
+                        key_pad_bias is not None, block_q, block_k, interpret,
+                        all_partial, cache_kernels), qkv, key_pad_bias)
 
 
-def flash_attention_halves(q, pattern: AttnPattern, has_bias: bool, *,
+def flash_pattern_attention(q, k, v, pattern: AttnPattern,
+                            key_pad_bias: Optional[jax.Array] = None,
+                            **options) -> jax.Array:
+    """:func:`flash_qkv_attention` for head-major operands, ``[b, heads, n,
+    dim_head]`` in and out: a wrapper for the tests and ``chip_smoke.py``,
+    which hold q, k and v apart.  It stacks them into the kernel's layout
+    and undoes that on ``o``; the model never calls it."""
+    b, heads, n, dim_head = q.shape
+    qkv = jnp.stack([q, k, v]).transpose(1, 3, 0, 2, 4)
+    out = flash_qkv_attention(qkv, pattern, key_pad_bias, **options)
+    return out.reshape(b, n, heads, dim_head).transpose(0, 2, 1, 3)
+
+
+def flash_attention_halves(n: int, heads: int, dim_head: int, dtype,
+                           pattern: AttnPattern, has_bias: bool, *,
                            block_q: int, block_k: int,
                            cache_kernels: bool = False):
-    """:func:`flash_pattern_attention`'s two halves for a caller with a VJP
-    of its own (``ops/attention.py`` switches platforms inside one):
-    ``forward(q, k, v, key_pad_bias) -> (out, residuals)`` and
-    ``backward(residuals, cotangent) -> (dq, dk, dv, dbias)``."""
-    static = _checked_static(q, pattern, has_bias, block_q, block_k, False,
-                             False, cache_kernels)
+    """:func:`flash_qkv_attention`'s two halves for a caller with a VJP of
+    its own (``ops/attention.py`` switches platforms inside one), on
+    ``qkv`` of ``heads`` heads, ``[b, n, 3, heads, dim_head]`` or flat over
+    the last three axes: ``forward(qkv, key_pad_bias) -> (out, residuals)``
+    and ``backward(residuals, cotangent) -> (dqkv, dbias)``."""
+    static = _checked_static(n, heads, dim_head, dtype, pattern, has_bias,
+                             block_q, block_k, False, False, cache_kernels)
     return (functools.partial(_flash_fwd, static),
             functools.partial(_flash_bwd, static))

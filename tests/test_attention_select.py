@@ -27,41 +27,52 @@ from dalle_pytorch_tpu.training import (make_dalle_train_step,  # noqa: E402
 BF16, F32 = jnp.bfloat16, jnp.float32
 CUB, LUCID, FMAP64 = (80, 32), (256, 32), (80, 64)     # (text, fmap)
 
-# (text, fmap), dim_head, dtype, variant, kv_heads, ring_axis -> tiles | None
+# (text, fmap), heads, dim_head, dtype, variant, kv_heads, ring_axis -> tiles
 TABLE = [
-    ((17, 8), 64, BF16, "full", None, None, None),        # n = 81
-    ((1, 16), 64, BF16, "full", None, None, None),        # n = 257
-    ((16, 24), 64, BF16, "full", None, None, (128, 128)),  # n = 592
-    (CUB, 64, BF16, "full", None, None, (384, 384)),      # n = 1104
-    (CUB, 64, BF16, "axial_row", None, None, (128, 128)),
-    (CUB, 64, BF16, "axial_col", None, None, (384, 384)),
-    (CUB, 64, BF16, "conv_like", None, None, (128, 128)),
-    (LUCID, 64, BF16, "full", None, None, (256, 256)),    # n = 1280
-    (LUCID, 128, BF16, "full", None, None, (256, 256)),
-    (FMAP64, 64, BF16, "full", None, None, (384, 384)),   # n = 4176
-    (FMAP64, 64, BF16, "conv_like", None, None, (128, 128)),
-    (LUCID, 128, BF16, "full", 1, None, None),            # grouped keys
-    (CUB, 64, BF16, "full", None, "sp", None),            # the sp plans
-    (CUB, 64, F32, "full", None, None, None),             # f32 activations
-    (CUB, 16, BF16, "full", None, None, None),            # a narrow head
+    ((17, 8), 8, 64, BF16, "full", None, None, None),        # n = 81
+    ((1, 16), 8, 64, BF16, "full", None, None, None),        # n = 257
+    ((16, 24), 2, 64, BF16, "full", None, None, (128, 128)),  # n = 592
+    (CUB, 8, 64, BF16, "full", None, None, (384, 384)),      # n = 1104
+    (CUB, 8, 64, BF16, "axial_row", None, None, (128, 128)),
+    (CUB, 8, 64, BF16, "axial_col", None, None, (384, 384)),
+    (CUB, 8, 64, BF16, "conv_like", None, None, (128, 128)),
+    (LUCID, 16, 64, BF16, "full", None, None, (256, 256)),   # n = 1280
+    (LUCID, 4, 64, BF16, "full", None, None, (256, 256)),    # dp4.tp4's shard
+    (LUCID, 8, 128, BF16, "full", None, None, (256, 256)),
+    (LUCID, 1, 128, BF16, "full", None, None, (256, 256)),   # a head a program
+    (FMAP64, 8, 64, BF16, "full", None, None, (384, 384)),   # n = 4176
+    (FMAP64, 8, 64, BF16, "conv_like", None, None, (128, 128)),
+    (LUCID, 20, 128, BF16, "full", 1, None, None),           # grouped keys
+    (CUB, 8, 64, BF16, "full", None, "sp", None),            # the sp plans
+    (CUB, 8, 64, F32, "full", None, None, None),             # f32 activations
+    (CUB, 8, 16, BF16, "full", None, None, None),            # a narrow head
+    # the operand contract (PR 35): the kernel reads to_qkv's own array in
+    # 128-column blocks of whole heads, at the sequence's own length
+    (CUB, 3, 64, BF16, "full", None, None, None),            # 192 columns
+    (CUB, 1, 64, BF16, "full", None, None, None),            # half a block
+    (CUB, 2, 192, BF16, "full", None, None, None),           # heads astride
+    ((17, 24), 8, 64, BF16, "full", None, None, None),       # n = 593
+    ((24, 24), 8, 64, BF16, "full", None, None, None),       # n = 600 = 16*37.5
 ]
 
 
-@pytest.mark.parametrize("geom,dh,dtype,variant,kv_heads,ring_axis,want",
-                         TABLE)
-def test_selection_table(geom, dh, dtype, variant, kv_heads, ring_axis, want):
+@pytest.mark.parametrize(
+    "geom,heads,dh,dtype,variant,kv_heads,ring_axis,want", TABLE)
+def test_selection_table(geom, heads, dh, dtype, variant, kv_heads,
+                         ring_axis, want):
     text, fmap = geom
     n = text + fmap * fmap
     pattern = AttnPattern(variant=variant, seq_len=n - 1, text_len=text,
                           fmap=fmap)
-    assert flash_tiles(n, dh, dtype, pattern, kv_heads, ring_axis) == want
+    assert flash_tiles(n, heads, dh, dtype, pattern, kv_heads,
+                       ring_axis) == want
 
 
-# --- a tiny cub200: four patterns, bf16, dim_head 64, n = 591 ----------------
+# --- a tiny cub200: four patterns, bf16, dim_head 64, n = 592 ----------------
 
 def tiny_cub(**overrides):
     cfg = DALLEConfig(
-        dim=64, num_text_tokens=64, text_seq_len=15, depth=4, heads=2,
+        dim=64, num_text_tokens=64, text_seq_len=16, depth=4, heads=2,
         dim_head=64, attn_types=("full", "axial_row", "axial_col",
                                  "conv_like"),
         num_image_tokens=32, image_size=96, image_fmap_size=24,
@@ -152,7 +163,7 @@ def test_cub200_trace_reports_eight_flash_layers(tmp_path):
         pattern = AttnPattern(variant=variant, seq_len=cfg.seq_len,
                               text_len=cfg.text_seq_len + 1,
                               fmap=cfg.image_fmap_size)
-        tiles = flash_tiles(1104, 64, jnp.bfloat16, pattern)
+        tiles = flash_tiles(1104, 8, 64, jnp.bfloat16, pattern)
         assert tiles == ((384, 384) if variant in ("full", "axial_col")
                          else (128, 128))
         skipped, partly, wholly = block_counts(pattern, 1104, *tiles)
@@ -162,6 +173,11 @@ def test_cub200_trace_reports_eight_flash_layers(tmp_path):
     assert rec["tiles"] == ["128x128", "384x384"] and rec["n"] == 1104
     assert rec["blocks_computed_share"] == round(computed / blocks, 4)
     assert 0.3 < rec["blocks_computed_share"] < 0.7
+    # the operand contract: two heads of 64 side by side on a program's
+    # lanes, nothing padded in HBM (48 rows a sequence before PR 35)
+    assert (rec["heads_per_program"], rec["hbm_pad_rows"]) == (2, 0)
+    assert "graft_attn_heads_per_program 2" in rendered
+    assert "graft_attn_hbm_pad_rows 0" in rendered
     assert "graft_attn_flash_layers 8" in rendered
     assert "graft_attn_dense_layers 0" in rendered
     assert "graft_attn_blocks_computed_share 0." in rendered
@@ -171,6 +187,7 @@ def test_cub200_trace_reports_eight_flash_layers(tmp_path):
     assert "-- attention --" in text
     assert ("attention core: 8 layers on the flash kernel (tiles 128x128, "
             "384x384") in text
+    assert "2 heads a program, 0 rows of padding in HBM" in text
 
 
 def test_jamba_trace_reports_no_flash_layer(tmp_path):
@@ -183,6 +200,7 @@ def test_jamba_trace_reports_no_flash_layer(tmp_path):
     assert len(records) == 1
     assert (records[0]["flash_layers"], records[0]["dense_layers"]) == (0, 2)
     assert records[0]["blocks_computed_share"] == 0.0
+    assert records[0]["heads_per_program"] == 0     # no flash layer speaks
     assert "graft_attn_flash_layers 0" in rendered
     assert "graft_attn_dense_layers 2" in rendered
 
@@ -204,44 +222,48 @@ def test_kernel_call_is_split_over_the_plans_mesh(monkeypatch):
     pattern = AttnPattern(variant="axial_row", seq_len=24, text_len=8, fmap=4)
     seen = []
 
-    def fake_halves(q, pattern, has_bias, **tiles):
-        def ref(q, k, v, bias):
-            return dense_reference(q, k, v, pattern,
-                                   key_pad_bias=bias).astype(q.dtype)
+    def fake_halves(n, heads, dim_head, dtype, pattern, has_bias, **tiles):
+        def ref(qkv, bias):     # of a shard: five axes, or flat over three
+            q, k, v = qkv.reshape(*qkv.shape[:2], 3, heads,
+                                  dim_head).transpose(2, 0, 3, 1, 4)
+            out = dense_reference(q, k, v, pattern,
+                                  key_pad_bias=bias).astype(qkv.dtype)
+            return out.transpose(0, 2, 1, 3).reshape(*qkv.shape[:2], -1)
 
-        # residuals in the kernel's own layout: q, k, v and o flat over
-        # (batch, head), the bias, a row of statistics
-        def forward(q, k, v, bias):
-            seen.append(q.shape)
-            b, h, n, dh = q.shape
-            flat = [t.reshape(b * h, n, dh) for t in (q, k, v)]
-            out = ref(q, k, v, bias)
-            return out, (*flat, bias, out.reshape(b * h, n, dh),
-                         jnp.zeros((b * h, 1, n), jnp.float32))
+        # residuals in the kernel's own layout: qkv as the projection wrote
+        # it, the bias, o as to_out reads it, a block of statistics a head
+        def forward(qkv, bias):
+            seen.append(qkv.shape)
+            out = ref(qkv, bias)
+            return out, (qkv, bias, out, jnp.zeros(
+                (qkv.shape[0], heads, 1, n), jnp.float32))
 
         def backward(residuals, g):
-            q, k, v = (t.reshape(g.shape) for t in residuals[:3])
-            return (*jax.vjp(lambda q, k, v: ref(q, k, v, residuals[3]),
-                             q, k, v)[1](g), None)
+            return (*jax.vjp(lambda qkv: ref(qkv, residuals[1]),
+                             residuals[0])[1](g), None)
 
         return forward, backward
 
     monkeypatch.setattr(attention_pallas, "flash_attention_halves",
                         fake_halves)
     q, k, v, g = jax.random.normal(jax.random.PRNGKey(0), (4, 4, 2, 24, 16))
+    qkv = jnp.stack([q, k, v]).transpose(1, 3, 0, 2, 4)  # [b, n, 3, h, dh]
     with attention.kernel_mesh(part):
         mesh = attention._kernel_mesh[-1]
     core = attention._Core(attention.kernel_pattern(pattern), jnp.dtype(F32),
-                           (128, 128), mesh)
-    forward, backward = core.halves(q, None)
-    out, residuals = jax.jit(forward)(q, k, v, None)
-    got = jax.jit(backward)(residuals, g)
-    assert set(seen) == {(2, 1, 24, 16)}    # batch over dp, heads over tp
+                           (128, 128), mesh, 2, 16)
+    forward, backward = core.halves(qkv, None)
+    out, residuals = jax.jit(forward)(qkv, None)
+    got = jax.jit(backward)(residuals, g.transpose(0, 2, 1, 3).reshape(
+        4, 24, 32))
+    assert set(seen) == {(2, 24, 3, 1, 16)}  # batch over dp, heads over tp
     ref, vjp = jax.vjp(lambda q, k, v: dense_reference(q, k, v, pattern),
                        q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
-    for a, b in zip(got, vjp(g)):
+    np.testing.assert_allclose(
+        np.asarray(out.reshape(4, 24, 2, 16).transpose(0, 2, 1, 3)),
+        np.asarray(ref), atol=2e-5, rtol=2e-5)
+    assert got.shape == qkv.shape       # dq, dk, dv where q, k, v lie
+    for a, b in zip(got.transpose(2, 0, 3, 1, 4), vjp(g)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=5e-4)
 
@@ -256,8 +278,44 @@ def test_kernel_call_is_split_over_the_plans_mesh(monkeypatch):
     with attention.kernel_mesh(part):
         odd = jax.jit(layer.apply)(params, x)
     assert not seen
+    # (without a mesh the same layer hands the kernel one flat array)
     np.testing.assert_array_equal(np.asarray(odd),
                                   np.asarray(layer.apply(params, x)))
+
+
+@pytest.mark.parametrize("heads,want", [(2, None), (4, (128, 128))])
+def test_tp_split_must_leave_whole_lane_blocks(monkeypatch, heads, want):
+    """The selection sees the heads a shard holds: ``tp2`` leaves one
+    64-wide head of two (half a lane block: the dense branch, GSPMD's to
+    place) and two of four (the kernel, inside the ``shard_map``)."""
+    from dalle_pytorch_tpu.parallel.plan import ParallelPlan
+
+    part = ParallelPlan.parse("dp2.tp2").partitioner(
+        devices=jax.devices()[:4])
+    pattern = AttnPattern(variant="axial_row", seq_len=591, text_len=16,
+                          fmap=24)
+    asked = []
+    real = attention.flash_tiles
+
+    def spy(n, heads, *rest):
+        asked.append((heads, real(n, heads, *rest)))
+        return asked[-1][1]
+
+    monkeypatch.setattr(attention, "flash_tiles", spy)
+    layer = attention.MultiHeadAttention(pattern=pattern, dim=32, heads=heads,
+                                         dim_head=64, dtype=BF16)
+    x = jnp.zeros((2, 592, 32), BF16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+    before = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)   # keep no kernel
+    try:
+        with attention.kernel_mesh(part):
+            traced = str(jax.make_jaxpr(layer.apply)(params, x))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    assert asked == [(heads // 2, want)]
+    assert ("shard_map" in traced) == (want is not None)
+    assert ("pallas_call" in traced) == (want is not None)
 
 
 # --- the kernels, kept between processes ------------------------------------
@@ -274,8 +332,8 @@ def test_kernels_are_kept_beside_the_compile_cache(tmp_path, monkeypatch):
         variant="axial_row", seq_len=591, text_len=16, fmap=24,
         layout_seed=3))
     assert pattern.layout_seed == 0     # layers of one variant: one kernel
-    static = ap._Static(pattern, 592, 128, 128, False, False, True)
-    avals = ((((4, 640, 64), jnp.dtype(jnp.bfloat16)),) * 3) + (None,)
+    static = ap._Static(pattern, 592, 2, 64, 128, 128, False, False, True)
+    avals = (((4, 592, 3 * 2 * 64), jnp.dtype(jnp.bfloat16)), None)
     built = []
     real = ap._pallas
     monkeypatch.setattr(ap, "_pallas",

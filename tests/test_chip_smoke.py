@@ -35,7 +35,8 @@ TOY = dataclasses.replace(
                        HEADS=2, DIM_HEAD=16,
                        ATTN_TYPES=["axial_row", "conv_like"]),
     ckpt_every=2, gen_images=2, serve_requests=3, serve_slots=2,
-    attn_text=5, attn_fmap=4, attn_shape=(2, 2, 8), attn_blocks=(128,),
+    # the narrowest call the compiled kernel takes: n = 128, two heads of 64
+    attn_text=64, attn_fmap=8, attn_shape=(2, 2, 64), attn_blocks=(128,),
     plan_batch=4, fleet_replicas=4, fleet_requests=4, fleet_slots=1)
 
 

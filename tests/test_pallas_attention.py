@@ -123,9 +123,8 @@ def test_block_sparsity_actually_skips():
     guarantee: axial patterns touch far fewer blocks than full)."""
     from dalle_pytorch_tpu.ops.attention_pallas import SKIP, _pattern_blocks
 
-    full = _pattern_blocks(make_pattern("full"), N, 24, BLOCK, BLOCK).table
-    axial = _pattern_blocks(make_pattern("axial_row"), N, 24, BLOCK,
-                            BLOCK).table
+    full = _pattern_blocks(make_pattern("full"), N, BLOCK, BLOCK).table
+    axial = _pattern_blocks(make_pattern("axial_row"), N, BLOCK, BLOCK).table
     assert (axial != SKIP).sum() <= (full != SKIP).sum()
     # causal: upper-triangle blocks (beyond diagonal) are skipped
     assert full[0, 1] == SKIP and full[0, 2] == SKIP
@@ -136,9 +135,30 @@ def test_compiled_kernel_off_tpu_fails_loudly():
     whatever the backend: off-TPU that is an error at lowering, never a
     silent drop to the interpreter (which would report interpreter results
     — and interpreter speed — under the kernel's name)."""
-    q, k, v = rand_qkv(jax.random.PRNGKey(0))
+    q = jnp.zeros((1, 2, 128, 64), jnp.bfloat16)   # a shape the chip takes
+    pattern = AttnPattern(variant="full", seq_len=127, text_len=64, fmap=8)
     with pytest.raises(ValueError, match="[Ii]nterpret"):
-        flash_pattern_attention(q, k, v, make_pattern("full"))
+        flash_pattern_attention(q, q, q, pattern)
+
+
+@pytest.mark.parametrize("shape,blocks,what", [
+    ((1, 3, 128, 64), (128, 128), "lane"),     # 192 columns: no lane blocks
+    ((1, 1, 128, 64), (128, 128), "lane"),     # half a lane block
+    ((1, 2, 136, 64), (128, 128), "16-row"),   # a tail off the sublane tiles
+    ((1, 2, 128, 64), (64, 128), "multiples of the TPU lane width"),
+    ((1, 2, 64, 64), (128, 128), "multiples of the TPU lane width"),
+], ids=["3-heads", "1-head", "n-136", "tile-64", "n-under-a-tile"])
+def test_compiled_kernel_refuses_what_the_chip_would(shape, blocks, what):
+    """The operand contract at the API's edge (no interpreter): whole
+    128-lane blocks of whole heads, lengths on the bf16 sublane tiles, tiles
+    of whole lane widths no longer than the sequence."""
+    q = jnp.zeros(shape, jnp.bfloat16)
+    n = shape[2]
+    pattern = AttnPattern(variant="full", seq_len=n - 1, text_len=n - 64,
+                          fmap=8)
+    with pytest.raises(ValueError, match=what):
+        flash_pattern_attention(q, q, q, pattern, block_q=blocks[0],
+                                block_k=blocks[1])
 
 
 def test_vmem_budget_guard():
@@ -149,10 +169,10 @@ def test_vmem_budget_guard():
         VMEM_BUDGET_BYTES, _vmem_resident_bytes, flash_pattern_attention)
 
     n = 40960  # ~21 MB of f32 K/V at dh=64: over budget
-    assert _vmem_resident_bytes(n, 64, 4, 128, 128) > VMEM_BUDGET_BYTES
+    assert _vmem_resident_bytes(n, 128, 4, 128, 128) > VMEM_BUDGET_BYTES
     pattern = AttnPattern(variant="full", seq_len=n, text_len=16, fmap=0,
                           causal=True)
-    q = jnp.zeros((1, 1, n, 64), jnp.float32)
+    q = jnp.zeros((1, 2, n, 64), jnp.float32)
     # guard fires before any tracing/lowering, so no TPU needed here
     with pytest.raises(ValueError, match="VMEM"):
         flash_pattern_attention(q, q, q, pattern)
@@ -165,23 +185,31 @@ def test_vmem_budget_guard():
         called = {}
         orig = ap._flash_attention
         ap._flash_attention = lambda *a: called.setdefault("yes", True)  # noqa: E731
-        flash_pattern_attention(q, q, q, pattern, interpret=True)
+        ap.flash_qkv_attention(jnp.zeros((1, n, 3, 2, 64), jnp.float32),
+                               pattern, interpret=True)
         assert called.get("yes")
     finally:
         ap._flash_attention = orig
 
     # the CUB geometry stays comfortably inside the budget
-    assert _vmem_resident_bytes(1152, 64, 4, 128, 128) < VMEM_BUDGET_BYTES // 4
+    assert _vmem_resident_bytes(1104, 128, 4, 128, 128) < VMEM_BUDGET_BYTES // 4
 
 
-# --- at the train cells' lengths, bf16 (PR 28) ------------------------------
+# --- at the train cells' lengths, bf16 (PR 28; PR 35: the operand contract) --
 
 CUB = dict(text=80, fmap=32)        # n = 1104, the cub200 cycle
 LUCID = dict(text=256, fmap=32)     # n = 1280, all full
-AT_WIDTH = [("full", CUB), ("axial_row", CUB), ("axial_col", CUB),
-            ("conv_like", CUB), ("full", LUCID)]
+# variant, geometry, heads, dim_head: two heads of 64 share a program's lanes
+# (one program a sample); one head of 128 fills them; four heads of 64 are
+# two lane blocks.  At n = 1104 the tail block shares 48 rows with the block
+# before it: 336 new rows at tile 384, 80 at tile 128 (axial_row, conv_like).
+AT_WIDTH = [("full", CUB, 2, 64), ("axial_row", CUB, 2, 64),
+            ("axial_col", CUB, 2, 64), ("conv_like", CUB, 2, 64),
+            ("full", LUCID, 2, 64), ("full", CUB, 1, 128),
+            ("axial_row", CUB, 4, 64)]
 AT_WIDTH_IDS = ["cub-full", "cub-axial_row", "cub-axial_col",
-                "cub-conv_like", "lucid-full"]
+                "cub-conv_like", "lucid-full", "cub-full-dh128",
+                "cub-axial_row-4heads"]
 
 
 def width_pattern(variant, text, fmap):
@@ -197,8 +225,9 @@ def dense_branch(pattern, dtype):
     return lambda q, k, v: dense_attention(pattern, dtype, q, k, v, None)
 
 
-@pytest.mark.parametrize("variant,geom", AT_WIDTH, ids=AT_WIDTH_IDS)
-def test_bf16_matches_dense_branch_within_its_own_spread(variant, geom):
+@pytest.mark.parametrize("variant,geom,heads,dh", AT_WIDTH, ids=AT_WIDTH_IDS)
+def test_bf16_matches_dense_branch_within_its_own_spread(variant, geom, heads,
+                                                         dh):
     """bf16 inputs at the train cells' lengths, the tiles the selection
     gives: forward and gradients lie as close to the float32 dense answer as
     the dense branch at bf16 does (its spread, measured here, is the
@@ -206,12 +235,12 @@ def test_bf16_matches_dense_branch_within_its_own_spread(variant, geom):
     from dalle_pytorch_tpu.ops.attention import flash_tiles
 
     pattern, n = width_pattern(variant, **geom)
-    tiles = flash_tiles(n, 64, jnp.bfloat16, pattern)
+    tiles = flash_tiles(n, heads, dh, jnp.bfloat16, pattern)
     assert tiles is not None
     ks = jax.random.split(jax.random.PRNGKey(7), 4)
-    q, k, v = (jax.random.normal(kk, (1, 1, n, 64), jnp.float32)
+    q, k, v = (jax.random.normal(kk, (1, heads, n, dh), jnp.float32)
                for kk in ks[:3])
-    tangent = jax.random.normal(ks[3], (1, 1, n, 64), jnp.float32)
+    tangent = jax.random.normal(ks[3], (1, heads, n, dh), jnp.float32)
 
     def outputs(fn, dtype):
         def loss(q, k, v):
@@ -234,42 +263,77 @@ def test_bf16_matches_dense_branch_within_its_own_spread(variant, geom):
         assert np.abs(f - e).max() <= 2.0 * np.abs(d - e).max() + 1e-6, name
 
 
-@pytest.mark.parametrize("variant,geom", AT_WIDTH, ids=AT_WIDTH_IDS)
-def test_three_block_kinds_counted(variant, geom):
+@pytest.mark.parametrize("variant,geom,heads,dh", AT_WIDTH[:5],
+                         ids=AT_WIDTH_IDS[:5])
+def test_three_block_kinds_counted(variant, geom, heads, dh):
     """Skipped, partly and wholly allowed blocks of each pattern at the
     train cells' lengths and the selection's tiles: they add up, a causal
     pattern skips, ``full`` has wholly allowed blocks below the diagonal,
-    and the distinct mask tiles are far fewer than the partly allowed
-    blocks' rows would be."""
-    from dalle_pytorch_tpu.ops.attention import flash_tiles
+    the distinct mask tiles are far fewer than the partly allowed blocks'
+    rows would be, and the blocks, each laid where the kernel reads it (the
+    tail block pulled back to the last full tile), cover every allowed pair
+    of positions exactly once."""
+    from dalle_pytorch_tpu.ops.attention import (dense_pattern_mask,
+                                                 flash_tiles)
     from dalle_pytorch_tpu.ops.attention_pallas import (
-        PARTIAL, SKIP, WHOLE, _padded_len, _pattern_blocks, block_counts)
+        HBM_PAD_ROWS, PARTIAL, SKIP, WHOLE, _pattern_blocks, block_counts,
+        block_starts)
 
     pattern, n = width_pattern(variant, **geom)
-    bq, bk = flash_tiles(n, 64, jnp.bfloat16, pattern)
-    n_pad = _padded_len(n, bq, bk)
-    assert n_pad == -(-n // 128) * 128      # padded to the lanes only
-    blocks = _pattern_blocks(pattern, n, n_pad, bq, bk)
+    bq, bk = flash_tiles(n, heads, dh, jnp.bfloat16, pattern)
+    q_starts, k_starts = block_starts(n, bq), block_starts(n, bk)
+    assert HBM_PAD_ROWS == 0 and q_starts[-1] == n - bq     # nothing padded
+    assert all(s % 16 == 0 for s in q_starts + k_starts)
+    assert len(q_starts) == -(-n // bq) and len(k_starts) == -(-n // bk)
+    blocks = _pattern_blocks(pattern, n, bq, bk)
     skipped, partly, wholly = block_counts(pattern, n, bq, bk)
-    assert skipped + partly + wholly == (n_pad // bq) * (n_pad // bk)
+    assert skipped + partly + wholly == len(q_starts) * len(k_starts)
     assert skipped == (blocks.table == SKIP).sum() > 0
     assert wholly == (blocks.table == WHOLE).sum()
     assert partly == (blocks.table >= PARTIAL).sum() > 0
     assert (wholly > 0) == (variant == "full")
     assert blocks.tiles.shape[0] <= partly
-    # the table says what the mask says
-    from dalle_pytorch_tpu.ops.attention import dense_pattern_mask
-    mask = np.zeros((n_pad, n_pad), bool)
-    mask[:n, :n] = dense_pattern_mask(pattern, n, n)
-    for qb in range(n_pad // bq):
-        for kb in range(n_pad // bk):
-            blk = mask[qb * bq:(qb + 1) * bq, kb * bk:(kb + 1) * bk]
+    # the table says what the mask says, no pair of positions twice
+    counted = np.zeros((n, n), np.int32)
+    for qb, q0 in enumerate(q_starts):
+        for kb, k0 in enumerate(k_starts):
             code = blocks.table[qb, kb]
-            if code >= PARTIAL:
-                np.testing.assert_array_equal(
-                    blocks.tiles[code - PARTIAL].astype(bool), blk)
-            else:
-                assert blk.all() if code == WHOLE else not blk.any()
+            counted[q0:q0 + bq, k0:k0 + bk] += (
+                blocks.tiles[code - PARTIAL] if code >= PARTIAL
+                else int(code == WHOLE))
+    np.testing.assert_array_equal(
+        counted, np.broadcast_to(dense_pattern_mask(pattern, n, n), (n, n)))
+
+
+@pytest.mark.parametrize("variant,text,fmap,tile", [
+    ("full", 32, 12, 128),          # n = 176: the tail shares 80 of 128 rows
+    ("axial_row", 32, 12, 128),
+    ("conv_like", 16, 16, 128),     # n = 272: three blocks, 112 shared
+    ("full", 16, 16, 256),          # n = 272: two blocks, 240 shared
+], ids=["full-176", "axial_row-176", "conv_like-272", "full-272-t256"])
+def test_tail_gradients_count_no_position_twice(variant, text, fmap, tile):
+    """The tail block overlaps the block before it: in float32, o, dq, dk
+    and dv at every position are those of ``jax.vjp`` of the dense branch (a
+    shared query row counted twice would double its part of dk and dv, a
+    shared key row stored from the tail's masked copy would zero dk and
+    dv there)."""
+    from dalle_pytorch_tpu.ops.attention import dense_attention
+
+    pattern, n = width_pattern(variant, text, fmap)
+    assert n % tile and n % 16 == 0
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, g = (jax.random.normal(kk, (1, 2, n, 64), jnp.float32)
+                  for kk in ks)
+    want, vjp = jax.vjp(lambda q, k, v: dense_attention(
+        pattern, jnp.float32, q, k, v, None), q, k, v)
+    got, flash_vjp = jax.vjp(lambda q, k, v: flash_pattern_attention(
+        q, k, v, pattern, block_q=tile, block_k=tile, interpret=True),
+        q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    for name, a, b in zip(("dq", "dk", "dv"), flash_vjp(g), vjp(g)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-4, err_msg=name)
 
 
 @pytest.mark.parametrize("variant", ["full", "axial_col"])
@@ -304,11 +368,13 @@ def no_kernel_files():
 
 
 def _core(variant="axial_row"):
-    """The static part of a layer's switched core at toy width, with the
-    pattern the dense branch takes."""
-    pattern = AttnPattern(variant=variant, seq_len=24, text_len=8, fmap=4)
+    """The static part of a layer's switched core at the narrowest shape
+    the compiled kernel takes (n = 128, two heads of 64), with the pattern
+    the dense branch takes."""
+    pattern = AttnPattern(variant=variant, seq_len=127, text_len=64, fmap=8)
     return pattern, attention._Core(attention.kernel_pattern(pattern),
-                                    jnp.dtype(jnp.float32), (128, 128), None)
+                                    jnp.dtype(jnp.float32), (128, 128), None,
+                                    2, 64)
 
 
 @pytest.mark.parametrize("variant", ["full", "axial_row"])
@@ -318,14 +384,17 @@ def test_layer_key_padding_mask(variant, no_kernel_files):
     keys for the sparse variants): the core's kernel half against its dense
     branch, on the mask as the model has it."""
     pattern, core = _core(variant)
-    q, k, v = jax.random.normal(jax.random.PRNGKey(0), (3, 2, 2, 24, 16))
+    q, k, v = jax.random.normal(jax.random.PRNGKey(0), (3, 2, 2, 128, 64))
+    qkv = jnp.stack([q, k, v]).transpose(1, 3, 0, 2, 4)  # [b, n, 3, h, dh]
     mask = jnp.asarray(np.r_[[[True] * 5 + [False] * 3],
                              [[True] * 8]])          # text keys, [b, 8]
     ref = attention.dense_attention(pattern, jnp.float32, q, k, v, mask)
     with pltpu.force_tpu_interpret_mode():
-        out, _ = core.halves(q, mask)[0](q, k, v, mask)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=2e-5, rtol=2e-5)
+        out, _ = core.halves(qkv, mask)[0](qkv, mask)
+    assert out.shape == (2, 128, 128)        # to_out's input as it stands
+    np.testing.assert_allclose(
+        np.asarray(out.reshape(2, 128, 2, 64).transpose(0, 2, 1, 3)),
+        np.asarray(ref), atol=2e-5, rtol=2e-5)
     unmasked = attention.dense_attention(pattern, jnp.float32, q, k, v, None)
     assert not np.allclose(np.asarray(ref[0]), np.asarray(unmasked[0]))
 
@@ -363,8 +432,8 @@ def test_backward_kernels_carry_the_forwards_scope(monkeypatch,
 
     monkeypatch.setattr(attention, "flash_tiles", lambda *a: (128, 128))
     pattern, _ = _core()
-    layer = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=16)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 32))
+    layer = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=64)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 128, 32))
     params = layer.init(jax.random.PRNGKey(1), x)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda p: jnp.sum(layer.apply(p, x) ** 2)))(params)

@@ -327,7 +327,7 @@ def test_window_mask_is_one_predicate_in_three_forms(n, window):
     assert f"window={window}" in repr(pattern) != repr(plain)
     with pytest.raises(AssertionError):
         AttnPattern("axial_row", seq_len=n, text_len=4, fmap=2, window=4)
-    assert flash_tiles(1024, 128, jnp.bfloat16, dataclasses.replace(
+    assert flash_tiles(1024, 4, 128, jnp.bfloat16, dataclasses.replace(
         pattern, seq_len=1024, window=512)) is None
 
 

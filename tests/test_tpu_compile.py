@@ -32,7 +32,7 @@ from dalle_pytorch_tpu import DALLE  # noqa: E402
 from dalle_pytorch_tpu.lint.spmd import fresh_stats_compile  # noqa: E402
 from dalle_pytorch_tpu.ops.attention import AttnPattern  # noqa: E402
 from dalle_pytorch_tpu.ops.attention_pallas import (  # noqa: E402
-    flash_pattern_attention)
+    flash_qkv_attention)
 from dalle_pytorch_tpu.presets import cub200_config  # noqa: E402
 
 V5E_HBM_BYTES = 16 * 2 ** 30
@@ -81,22 +81,26 @@ def _param_shapes(cfg):
 
 def _compile_attention(one_chip, variant, block_q, block_k, shape, grad,
                        fmap):
+    """The kernel on the projections' own array: ``shape`` is (batch, heads,
+    n, dim_head) of a ``qkv [b, n, 3, heads, dh]``."""
     text = 80
     n = text + fmap * fmap
-    assert shape[2] == n
+    b, heads, length, dh = shape
+    assert length == n
     pattern = AttnPattern(variant=variant, seq_len=n - 1, text_len=text,
                           fmap=fmap)
-    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((b, n, 3, heads, dh), jnp.bfloat16,
+                             sharding=one_chip)
 
-    def fwd(q, k, v):
-        return flash_pattern_attention(q, k, v, pattern, block_q=block_q,
-                                       block_k=block_k, interpret=False)
+    def fwd(qkv):
+        return flash_qkv_attention(qkv, pattern, block_q=block_q,
+                                   block_k=block_k, interpret=False)
 
-    def loss(q, k, v):
-        return jnp.sum(fwd(q, k, v).astype(jnp.float32))
+    def loss(qkv):
+        return jnp.sum(fwd(qkv).astype(jnp.float32))
 
-    fn = jax.grad(loss, (0, 1, 2)) if grad else fwd
-    return jax.jit(fn).lower(x, x, x).compile()
+    fn = jax.grad(loss) if grad else fwd
+    return jax.jit(fn).lower(x).compile()
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
@@ -126,24 +130,26 @@ def test_kernels_compile_at_fmap64_shape(one_chip, blocks, grad):
 
 @pytest.mark.parametrize("blocks,grad,fits", [
     ((256, 512), True, True), ((512, 512), False, True),
-    ((2304, 2304), True, True), ((4224, 4224), True, False)],
+    ((2304, 2304), True, True), ((2560, 2560), True, False)],
     ids=["b256x512-grad", "b512x512-fwd", "b2304x2304-grad",
-         "b4224x4224-grad"])
+         "b2560x2560-grad"])
 def test_compiler_refuses_large_tiles_at_fmap64(one_chip, blocks, grad, fits):
     """Turned round in PR 28: the compiler's answer and the guard's estimate
     (ops/attention_pallas.py::_vmem_resident_bytes), side by side, at n =
     4176.  Until PR 28 the kernel held ``[block_q, n_pad]`` bool mask rows
     that the estimate counted at a byte an element, and the compiler refused
-    the first two pairs while the guard passed them.  The kernel now holds
-    the pattern's distinct mask tiles once and may take 96 MiB: both accept
-    every tiling up to 2304 x 2304, both refuse one 4224 x 4224 block
-    backward (Mosaic wants 113 MiB)."""
+    the first two pairs while the guard passed them.  The kernel holds the
+    pattern's distinct mask tiles once and may take 96 MiB.  On the
+    projections' own arrays (PR 35: a lane block of two heads a program,
+    tiles no longer than the sequence) both accept every tiling up to 2304
+    x 2304 and both refuse the backward at 2560 x 2560 (Mosaic runs out of
+    VMEM on the dk/dv kernel's stack; the estimate reads 110 MiB, counting
+    three float32 tiles of intermediates where it counted one)."""
     from dalle_pytorch_tpu.ops import attention_pallas as ap
 
-    n_pad = ap._padded_len(4176, *blocks)
     pattern = AttnPattern(variant="full", seq_len=4175, text_len=80, fmap=64)
-    tiles = ap._pattern_blocks(pattern, 4176, n_pad, *blocks).tiles.shape[0]
-    estimate = ap._vmem_resident_bytes(n_pad, 64, 2, *blocks, tiles)
+    tiles = ap._pattern_blocks(pattern, 4176, *blocks).tiles.shape[0]
+    estimate = ap._vmem_resident_bytes(4176, 128, 2, *blocks, tiles)
     assert (estimate <= ap.VMEM_BUDGET_BYTES) == fits
     if fits:
         _compile_attention(one_chip, "full", *blocks, (4, 8, 4176, 64), grad,
@@ -187,15 +193,38 @@ def _assert_flash_step(cfg, compiled):
         assert not re.search(rf"f32\[[\d,]*{side},{side}\]", text), side
 
 
+def _glue(hlo_text, n):
+    """Instructions that only move a ``bf16[.., n, ..]`` activation about
+    under one of the attention layer's scopes: ``(scope, opcode)`` of every
+    ``pad``, ``slice``, ``copy`` and ``transpose`` (a fusion that computes
+    something is not one, nor is the one in-place ``dynamic-update-slice`` a
+    layer that writes dv beside dq and dk)."""
+    found = []
+    for line in hlo_text.splitlines():
+        op = re.search(r"= bf16\[([\d,]+)\]\S* (pad|slice|copy|transpose)\(",
+                       line)
+        scope = re.search(r"graftprof:(attn-[\w]+)", line)
+        if op and scope and str(n) in op.group(1).split(","):
+            found.append((scope.group(1), op.group(2)))
+    return found
+
+
 def test_default_cub200_train_step_holds_the_kernel(topo):
     """``cub200-train``'s step as the benchmark builds it (batch 16, the VAE
-    inside): 24 kernels, and the compiler plans under half the 7.57 GB the
-    dense scores took (ledger, PR 27)."""
+    inside): 24 kernels on the projections' own arrays (PR 35): nothing is
+    padded or sliced under ``attn-scores`` (the parent held 48 pads and 56
+    slices of 1104 -> 1152 about its kernels), and no copy or transposition
+    of a ``bf16[.., 1104, ..]`` activation lies between ``to_qkv``'s product
+    and a kernel or between a kernel and ``to_out``'s (the parent: 64
+    copies); the compiler plans 2.34 GB (my AOT compile, PR 35) where the
+    padded, head-major residuals made it 3.11 and the dense scores 7.57
+    (ledger, PR 27)."""
     cfg, compiled = _train_cell_step("cub200-train", topo.devices)
     _assert_flash_step(cfg, compiled)
+    assert _glue(compiled.as_text(), cfg.seq_len) == []
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
-            < 3.8 * 2 ** 30)
+            < 2.4 * 2 ** 30)
 
 
 def test_default_lucid1024_dp_step_splits_the_kernel(topo):
@@ -210,6 +239,7 @@ def test_default_lucid1024_dp_step_splits_the_kernel(topo):
     cfg, compiled = _train_cell_step("lucid1024-train-dp4", topo.devices)
     _assert_flash_step(cfg, compiled)
     text = compiled.as_text()
+    assert _glue(text, cfg.seq_len) == []   # nor a copy about the kernels
     assert " all-gather(" not in text and " all-gather-start(" not in text
 
     class Run:
