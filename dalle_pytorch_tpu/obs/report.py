@@ -11,6 +11,7 @@ must run on the box whose accelerator just wedged.
 """
 from __future__ import annotations
 
+import bisect
 from typing import Dict, List, Optional
 
 from . import compiles
@@ -48,6 +49,57 @@ def _torn_spans(events: List[dict]) -> List[dict]:
              if r.get("ph") == "E"}
     return [r for r in events if r.get("ph") == "B"
             and (r.get("host", 0), r.get("seq")) not in ended]
+
+
+#: the spans GenerationServer.step writes inside one `serve.step`
+#: (serve/scheduler.py); `prefill` nests inside `admit`, the rest are the
+#: step's own children
+SERVE_PHASES = ("retire", "admit", "prefill", "tick")
+_SERVE_STEP_CHILDREN = ("retire", "admit", "tick", "mem_watermark")
+
+
+def _serve_phases(serve: List[dict]) -> Optional[dict]:
+    """Where the recorded scheduler iterations' time went: per phase its
+    count, total and median seconds and share of `serve.step` time, and the
+    steps' self time (a span's duration less what its children cover).
+    Only spans inside a recorded step count, so a sampled stream
+    (`tick_sample` > 1: one step in N written, every retire and admit)
+    still reads true shares.  None when the stream holds no step span."""
+    spans = [r for r in _span_pairs(serve)
+             if r.get("dur_s") is not None and r.get("mono") is not None]
+    steps = [r for r in spans if r.get("name") == "step"]
+    if not steps:
+        return None
+    def lane_of(r) -> tuple:
+        return (r.get("run"), r.get("host", 0), r.get("pid"), r.get("thread"))
+
+    by_lane: Dict[tuple, list] = {}
+    for r in steps:
+        by_lane.setdefault(lane_of(r), []).append(
+            (float(r["mono"]), float(r["mono"]) + float(r["dur_s"])))
+    for lane in by_lane.values():
+        lane.sort()
+
+    def in_a_step(r) -> bool:
+        lane = by_lane.get(lane_of(r), [])
+        k = bisect.bisect_right(lane, (float(r["mono"]), float("inf"))) - 1
+        return k >= 0 and float(r["mono"]) <= lane[k][1]
+
+    step_s = sum(float(r["dur_s"]) for r in steps)
+    inside = [r for r in spans if r.get("name") != "step" and in_a_step(r)]
+    phases = {}
+    for name in SERVE_PHASES:
+        durs = [float(r["dur_s"]) for r in inside if r.get("name") == name]
+        phases[name] = {"count": len(durs), "total_s": sum(durs),
+                        "median_s": _pct(durs, 50),
+                        "share": sum(durs) / step_s if step_s else None}
+    self_s = max(step_s - sum(float(r["dur_s"]) for r in inside
+                              if r.get("name") in _SERVE_STEP_CHILDREN), 0.0)
+    return {"steps": len(steps), "step_s": step_s,
+            "step_median_s": _pct([float(r["dur_s"]) for r in steps], 50),
+            "self_s": self_s,
+            "self_share": self_s / step_s if step_s else None,
+            "phases": phases}
 
 
 def build_report(events: List[dict]) -> dict:
@@ -139,7 +191,10 @@ def build_report(events: List[dict]) -> dict:
     }
 
     # --- serve --------------------------------------------------------------
-    serve = [r for r in events if r.get("kind") == "serve"]
+    # `serve.retire` / `admit` / `tick` name a span (ph B/E: where the time
+    # went, _serve_phases) and an event (what happened) alike
+    serve_all = [r for r in events if r.get("kind") == "serve"]
+    serve = [r for r in serve_all if "ph" not in r]
     retires = [r for r in serve if r.get("name") == "retire"]
     classes = sorted({str(r.get("slo")) for r in retires}) or []
     per_class = {}
@@ -199,6 +254,7 @@ def build_report(events: List[dict]) -> dict:
                        if has_spec and slot_ticks else None),
         "prefix": prefix_report,
         "by_class": per_class,
+        "phases": _serve_phases(serve_all),
     }
 
     # --- roofline: predicted vs measured ------------------------------------
@@ -571,6 +627,18 @@ def render_text(report: dict) -> str:
                 f"(rate {_fmt(pref['hit_rate'])}), entries "
                 f"{pref['entries']}, prefill FLOPs saved "
                 f"{_fmt(pref['prefill_flops_saved'])}")
+        ph = sv.get("phases")
+        if ph:
+            lines.append(
+                f"  steps recorded {ph['steps']}: {_fmt(ph['step_s'])}s, "
+                f"median {_fmt(ph['step_median_s'])}s, self "
+                f"{_fmt(ph['self_s'])}s ({_fmt(ph['self_share'])} of step "
+                f"time)")
+            for name, row in ph["phases"].items():
+                lines.append(
+                    f"  {name}: n={row['count']} total "
+                    f"{_fmt(row['total_s'])}s median "
+                    f"{_fmt(row['median_s'])}s share {_fmt(row['share'])}")
         for slo, row in sv["by_class"].items():
             lines.append(
                 f"  {slo}: n={row['completed']} p50 "

@@ -141,6 +141,9 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+#: the one no-op span every disabled ``span()`` returns; a caller that
+#: skips a span on a condition of its own (a sampled loop) hands out the same
+NULL_SPAN = _NULL_SPAN
 
 
 class _Span:

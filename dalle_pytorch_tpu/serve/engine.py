@@ -194,10 +194,14 @@ class SlotArena:
                 logits, key, k_vocab=k_vocab, filter_thres=filter_thres,
                 temperature=temp, top_p=top_p)
 
-        def prefill(variables, text):
+        # named for a profiler trace: the programs read jit_serve_prefill,
+        # jit_serve_admit, jit_serve_tick, jit_serve_tick_spec there, keys no
+        # other `prefill` or `tick` in the process collides with
+        def serve_prefill(variables, text):
             return prefill_codes(dalle, variables, text)
 
-        def admit(state, slot, first_logits, caches1, key, temp, write_pos):
+        def serve_admit(state, slot, first_logits, caches1, key, temp,
+                        write_pos):
             """Install a batch-1 prefill into (traced) ``slot``: one
             dynamic_update_slice per cache array, plus the request's first
             sampled code — mirrors decode_codes' pre-scan sampling.
@@ -260,7 +264,7 @@ class SlotArena:
                    if cfg.spec_decode else {}),
             )
 
-        def tick(variables, state, active, write_pos, qweights):
+        def serve_tick(variables, state, active, write_pos, qweights):
             """One decode step over every slot (phase-aligned batched
             ``DALLE.decode_step``: per-slot logical ``index`` vector, one
             shared physical write column).  ``active`` [S] bool masks
@@ -309,7 +313,7 @@ class SlotArena:
         K = cfg.spec_k
         L = self.geometry.image_seq_len
 
-        def tick_spec(variables, state, active, qweights):
+        def serve_tick_spec(variables, state, active, qweights):
             """One SPECULATIVE decode tick over every slot: draft ``K-1``
             tokens through the first ``spec_draft_depth`` blocks, score
             all ``K`` span positions with ONE full-depth
@@ -399,10 +403,10 @@ class SlotArena:
                         rot=rot,
                     ), m
 
-        self._prefill = jax.jit(prefill)
-        self._admit = jax.jit(admit, donate_argnums=(0,))
-        self._tick = jax.jit(tick, donate_argnums=(1,))
-        self._tick_spec = (jax.jit(tick_spec, donate_argnums=(1,))
+        self._prefill = jax.jit(serve_prefill)
+        self._admit = jax.jit(serve_admit, donate_argnums=(0,))
+        self._tick = jax.jit(serve_tick, donate_argnums=(1,))
+        self._tick_spec = (jax.jit(serve_tick_spec, donate_argnums=(1,))
                            if cfg.spec_decode else None)
 
     # --- public API (scheduler-facing) ------------------------------------
@@ -448,11 +452,42 @@ class SlotArena:
                                         self._qweights)
         return jax.device_get(m)
 
-    def fetch_codes(self, slot: int):
-        """Host numpy of one slot's decoded codes [image_seq_len] — the
-        retirement read.  Blocks until every dispatched tick touching the
-        slot has landed."""
-        return jax.device_get(self.state["out"][slot])
+    def take_codes(self, slot: int):
+        """One slot's decoded codes [image_seq_len] as a device array of
+        its own, dispatched now: whatever is admitted into the slot
+        afterwards does not touch it, and ``jax.device_get`` of it is the
+        retirement read (it waits for every tick dispatched so far)."""
+        return self.state["out"][slot]
+
+    def programs(self) -> dict:
+        """The arena's compiled programs under the names a profiler trace
+        gives them (``jit_serve_prefill``, ``jit_serve_admit`` and
+        ``jit_serve_tick``, or ``jit_serve_tick_spec`` under
+        ``spec_decode``), lowered from the state's own shapes: what a
+        reader of a device trace needs to map ops to ``graftprof:`` scopes
+        (``as_text``) or to ask the compiler's memory plan.  Runs nothing
+        and leaves :meth:`trace_counts` as it was."""
+        def shape(*dims, dtype=jnp.int32):
+            return jax.ShapeDtypeStruct(dims, dtype)
+
+        prefill = self._prefill.lower(
+            self.variables, shape(1, self.dalle.cfg.text_seq_len))
+        first_logits, caches1 = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype),
+            prefill.out_info)
+        admit = self._admit.lower(
+            self.state, shape(), first_logits, caches1,
+            shape(2, dtype=jnp.uint32), shape(dtype=jnp.float32), shape())
+        active = shape(self.geometry.num_slots, dtype=jnp.bool_)
+        decode = (self._tick.lower(self.variables, self.state, active,
+                                   shape(), self._qweights)
+                  if self._tick_spec is None else
+                  self._tick_spec.lower(self.variables, self.state, active,
+                                        self._qweights))
+        return {f"jit_{fn.__name__}": lowered.compile()
+                for fn, lowered in ((self._prefill, prefill),
+                                    (self._admit, admit),
+                                    (self._tick_spec or self._tick, decode))}
 
     def trace_counts(self) -> dict:
         """Executable-cache population per jitted entry point — the

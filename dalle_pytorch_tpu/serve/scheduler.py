@@ -4,8 +4,8 @@ The host half of continuous batching (the device half is
 ``serve/engine.py``).  One scheduler iteration (:meth:`GenerationServer.
 step`) is:
 
-1. **retire** — slots whose request decoded its last token are fetched to
-   host, their futures resolved, the slot freed;
+1. **release** — slots whose request decoded its last token are freed,
+   their codes taken as device arrays of their own (read in phase 4);
 2. **admit** — queued requests are prefilled (batch 1) and written into
    free slots, latency-class first.  When the latency queue is non-empty
    and no slot is free, the least-progressed *throughput*-class running
@@ -13,7 +13,11 @@ step`) is:
    and it re-queues at the front of the throughput queue (restarting from
    prefill — its key replays, so the restart is deterministic).  Latency
    requests never preempt each other;
-3. **tick** — one jitted decode step advances every occupied slot.
+3. **tick** — one jitted decode step advances every occupied slot;
+4. **retire** — the released requests' codes are read to the host and
+   their futures resolved.  The read is the one place the loop blocks, and
+   it comes after the dispatches so that the device has the admissions and
+   the tick to run meanwhile.
 
 Requests enter through the thread-safe :meth:`GenerationServer.submit`,
 which returns a :class:`ServeHandle` carrying a ``concurrent.futures.
@@ -29,8 +33,22 @@ mid-decode request failure is rehearsable: the failed request's future
 carries the fault, its slot frees the same iteration, and co-batched
 requests are untouched (tests/test_serve.py pins this).
 
+Where an iteration's time goes is written as spans, each one ``B``/``E``
+pair in the telemetry stream and one ``graft:serve.<name>`` annotation on
+the profiler's clock (``obs/telemetry.py::_Span``), nested as the work
+nests: ``serve.step`` > ``serve.admit`` (one an admitted request, around
+``serve.prefill`` and the install) | ``serve.tick`` (the decode dispatch)
+| ``serve.mem_watermark`` (the memory poll) | ``serve.retire`` (one a
+retired request, around the blocking read of its codes).  ``rid`` on
+``submit``, ``admit``, ``prefill`` and ``retire`` is one chain a request.
+``tick_sample`` bounds the per-tick ``step`` and ``tick`` spans as it bounds
+the ``tick`` records; with no stream open every span is the shared null one.
+
 SLO accounting per request: queue wait (submit -> last admit), decode time
-(last admit -> finish), end-to-end latency, preemption count.
+(last admit -> finish), end-to-end latency, preemption count.  The admit
+stamp is taken when the host has DISPATCHED the install, not when the
+device has run it: behind a queue of dispatched ticks the device may be up
+to a retirement's worth of ticks later.
 :meth:`stats` aggregates p50/p99 latency, occupancy, and decoded-token
 throughput — the ``bench_serve`` row schema (PERF.md).
 
@@ -51,6 +69,7 @@ import threading
 import time
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -221,6 +240,8 @@ class GenerationServer:
         self._queues: Dict[str, Deque[ServeHandle]] = {
             LATENCY: collections.deque(), THROUGHPUT: collections.deque()}
         self._running: Dict[int, _Running] = {}       # slot -> running
+        # released this step, codes not yet on the host: (slot, run, codes)
+        self._retiring: List[tuple] = []
         self._free: List[int] = list(range(num_slots))
         self._next_id = 0
         self._stopped = False
@@ -303,16 +324,30 @@ class GenerationServer:
         """One scheduler iteration: retire, admit, and (unless
         ``tick=False`` — the warm-the-batch move tests use) one decode
         tick.  Returns the number of slots that advanced."""
-        self._retire_finished()
-        self._admit_pending()
-        if not tick:
-            return 0
-        advanced = self._tick_once()
-        if advanced == 0:
-            # drained idle: flush the partial sampling window so the
-            # stream's aggregates cover every tick that actually ran
-            self._flush_tick_agg()
-        return advanced
+        # the tick that closes a `tick_sample` window carries the spans,
+        # as it carries the aggregate record (every tick at tick_sample=1)
+        sampled = self._tick_agg["ticks"] + 1 >= self.tick_sample
+        with (self._span("serve", "step", clock=self._clock,
+                         running=len(self._running),
+                         queued=self.backlog()["queued_total"])
+              if sampled else telemetry.NULL_SPAN):
+            self._retire_finished()
+            try:
+                self._admit_pending()
+                advanced = self._tick_once(sampled) if tick else 0
+            finally:
+                # the blocking read of the finished requests' codes comes
+                # LAST: the device then holds the admissions and this
+                # step's tick while the host waits, so a slow wake-up of
+                # the host costs the device nothing (read first, the
+                # device's queue was empty for as long as the read took);
+                # in a finally, so that no released future is left hanging
+                self._resolve_retired()
+            if tick and advanced == 0:
+                # drained idle: flush the partial sampling window so the
+                # stream's aggregates cover every tick that actually ran
+                self._flush_tick_agg()
+            return advanced
 
     def run_until_idle(self, max_ticks: Optional[int] = None) -> None:
         """Drive until every queued/running request finishes (or fails)."""
@@ -361,44 +396,50 @@ class GenerationServer:
     # --- internals ---------------------------------------------------------
 
     def _retire_finished(self) -> None:
+        """Release the slots whose request decoded its last token: the
+        slot is free for this step's admissions at once, its codes are
+        taken as a device array of their own (dispatched now, ahead of any
+        install into the slot) and read by :meth:`_resolve_retired`."""
         total = self.arena.geometry.image_seq_len
         for slot in sorted(self._running):
             run = self._running[slot]
             if run.done >= total:
-                codes = self.arena.fetch_codes(slot)
-                h = run.handle
-                h.finished_at = self._time()
+                self._retiring.append((slot, run, self.arena.take_codes(slot)))
                 del self._running[slot]
                 self._free.append(slot)
                 if self.prefix is not None and run.prefix_key is not None:
                     self.prefix.release(run.prefix_key)
-                self.completed.append(h)
-                target = self.slo_targets.get(h.slo)
-                self._emit(
-                    "serve", "retire", rid=h.request_id, slot=slot,
-                    slo=h.slo, tokens=run.done, latency_s=h.latency,
-                    queue_wait_s=(h.admitted_at - h.submitted_at
-                                  if h.admitted_at is not None else None),
-                    decode_s=(h.finished_at - h.admitted_at
+
+    def _resolve_retired(self) -> None:
+        """Read the released requests' codes to the host and resolve their
+        futures: the one place the loop blocks on the device."""
+        while self._retiring:
+            slot, run, codes = self._retiring.pop(0)
+            h = run.handle
+            with self._span("serve", "retire", rid=h.request_id, slot=slot):
+                codes = jax.device_get(codes)
+            h.finished_at = self._time()
+            self.completed.append(h)
+            target = self.slo_targets.get(h.slo)
+            self._emit(
+                "serve", "retire", rid=h.request_id, slot=slot,
+                slo=h.slo, tokens=run.done, latency_s=h.latency,
+                queue_wait_s=(h.admitted_at - h.submitted_at
                               if h.admitted_at is not None else None),
-                    preemptions=h.preemptions,
-                    slo_ok=(None if target is None or h.latency is None
-                            else bool(h.latency <= target)))
-                reg = obs_metrics.active()
-                if reg is not None and h.latency is not None:
-                    reg.histogram("graft_serve_latency_seconds",
-                                  "end-to-end request latency", slo=h.slo,
-                                  **self._metrics_labels).observe(h.latency)
-                    reg.counter("graft_serve_retired_total",
-                                "completed requests", slo=h.slo,
-                                **self._metrics_labels).inc()
-                    if target is not None:
-                        reg.counter(
-                            "graft_serve_slo_total",
-                            "retirements by SLO verdict", slo=h.slo,
-                            ok=str(bool(h.latency <= target)).lower(),
-                            **self._metrics_labels).inc()
-                h.future.set_result(codes)
+                decode_s=(h.finished_at - h.admitted_at
+                          if h.admitted_at is not None else None),
+                preemptions=h.preemptions,
+                slo_ok=(None if target is None or h.latency is None
+                        else bool(h.latency <= target)))
+            reg = obs_metrics.active()
+            if (reg is not None and h.latency is not None
+                    and target is not None):
+                reg.counter(
+                    "graft_serve_slo_total",
+                    "retirements by SLO verdict", slo=h.slo,
+                    ok=str(bool(h.latency <= target)).lower(),
+                    **self._metrics_labels).inc()
+            h.future.set_result(codes)
 
     def _fail(self, slot: int, exc: BaseException) -> None:
         run = self._running.pop(slot)
@@ -457,24 +498,29 @@ class GenerationServer:
     def _admit(self, handle: ServeHandle) -> None:
         pkey: Optional[Tuple[int, ...]] = None
         payload = None
-        if self.prefix is not None:
-            pkey = tuple(int(t) for t in handle.text[0])
-            payload = self.prefix.acquire(pkey)
-        hit = payload is not None
-        if payload is None:
-            with self._span("serve", "prefill", rid=handle.request_id):
-                payload = self.arena.prefill(jnp.asarray(handle.text))
-            self.prefill_count += 1
+        slot = self._free[-1]
+        with self._span("serve", "admit", rid=handle.request_id, slot=slot):
             if self.prefix is not None:
-                # insert pins for THIS request (and dedupes a racing
-                # identical insert by keeping the resident payload)
-                payload = self.prefix.insert(pkey, payload)
-        first_logits, caches = payload
-        slot = self._free.pop()
-        # self._clock is the NEXT tick's number — it pins the slot's cache
-        # rotation so every later tick writes the shared physical column
-        self.arena.admit(slot, first_logits, caches, handle.key,
-                         handle.temperature, self._clock)
+                pkey = tuple(int(t) for t in handle.text[0])
+                payload = self.prefix.acquire(pkey)
+            hit = payload is not None
+            if payload is None:
+                with self._span("serve", "prefill", rid=handle.request_id):
+                    payload = self.arena.prefill(jnp.asarray(handle.text))
+                self.prefill_count += 1
+                if self.prefix is not None:
+                    # insert pins for THIS request (and dedupes a racing
+                    # identical insert by keeping the resident payload)
+                    payload = self.prefix.insert(pkey, payload)
+            first_logits, caches = payload
+            self._free.pop()
+            # self._clock is the NEXT tick's number — it pins the slot's
+            # cache rotation so every later tick writes the shared physical
+            # column
+            self.arena.admit(slot, first_logits, caches, handle.key,
+                             handle.temperature, self._clock)
+        # stamped at the install's DISPATCH: the device runs it after the
+        # ticks already queued (module docstring)
         handle.admitted_at = self._time()
         self._emit("serve", "admit", rid=handle.request_id, slot=slot,
                    slo=handle.slo,
@@ -513,7 +559,7 @@ class GenerationServer:
                                        prefix_key=pkey)
         self._decoded_tokens += 1  # admit samples the request's first code
 
-    def _tick_once(self) -> int:
+    def _tick_once(self, sampled: bool = False) -> int:
         # the serve_request faultpoint: one hit per occupied slot per tick,
         # in slot order — an injected failure frees ITS slot and leaves
         # co-batched slots advancing this very tick
@@ -534,12 +580,16 @@ class GenerationServer:
         mask = np.zeros((self.num_slots,), bool)
         for slot in advancing:
             mask[slot] = True
+        span = (self._span("serve", "tick", clock=self._clock,
+                           active=len(advancing))
+                if sampled else telemetry.NULL_SPAN)
         if self._spec:
             # speculative tick: each active slot commits its accepted
             # span (1..spec_k tokens) — progress accounting consumes the
             # per-slot lengths, everything else (occupancy, SLO math) is
             # still per-tick/per-request
-            ms = self.arena.tick_spec(mask)
+            with span:
+                ms = self.arena.tick_spec(mask)
             self._clock += 1
             tokens = 0
             for slot in advancing:
@@ -548,7 +598,8 @@ class GenerationServer:
                 tokens += adv
             self._spec_committed += tokens
         else:
-            self.arena.tick(mask, self._clock)
+            with span:
+                self.arena.tick(mask, self._clock)
             self._clock += 1
             for slot in advancing:
                 self._running[slot].done += 1
@@ -590,21 +641,11 @@ class GenerationServer:
                    **({"spec": True} if self._spec else {}))
         reg = obs_metrics.active()
         if reg is not None:
-            if self._spec and agg["active_sum"]:
-                # measured accepted-K over the window: the cost model's
-                # denominator (prof.predicted_spec_speedup), exported so
-                # the A/B stage and monitor can join it live
-                reg.gauge("graft_serve_spec_accepted_k",
-                          "mean committed tokens per active slot-tick",
-                          **self._metrics_labels
-                          ).set(agg["tokens"] / agg["active_sum"])
             reg.gauge("graft_serve_occupancy",
                       "occupied-slot fraction over the last tick window",
                       **self._metrics_labels
                       ).set(agg["active_sum"]
                             / (agg["ticks"] * self.num_slots))
-            reg.counter("graft_serve_ticks_total", "decode ticks run",
-                        **self._metrics_labels).inc(agg["ticks"])
             # re-assert the static byte-stream gauge here too: the
             # registry may have been installed after __init__ ran
             reg.gauge("graft_serve_predicted_bytes_per_token",
@@ -625,7 +666,10 @@ class GenerationServer:
         (the series ``monitor --fleet`` prints beside the predicted byte
         stream)."""
         self._ticks_since_watermark = 0
-        rec = self.mem_tracker.snapshot("serve_steady")
+        # on the loop's thread whether or not a stream is open: the span
+        # is there so that the poll's cost is seen
+        with self._span("serve", "mem_watermark"):
+            rec = self.mem_tracker.snapshot("serve_steady")
         self._emit("mem", "watermark", **rec)
         if rec.get("headroom_bytes") is not None:
             self.last_headroom_bytes = int(rec["headroom_bytes"])

@@ -399,8 +399,9 @@ def test_serve_request_fault_leaves_causal_trail(tmp_path, tiny_serve):
     survivor = (h0 if h1.request_id == victim else h1).request_id
 
     def seq_of(name, rid):
+        # the event of that name, not the span (ph B/E) that shares it
         return next(r["seq"] for r in recs if r.get("name") == name
-                    and r.get("rid") == rid)
+                    and r.get("rid") == rid and "ph" not in r)
 
     fault = next(r for r in recs if r["kind"] == "fault"
                  and r["name"] == "serve_request")
@@ -408,14 +409,20 @@ def test_serve_request_fault_leaves_causal_trail(tmp_path, tiny_serve):
         < fault["seq"] < seq_of("fail", victim)
     assert seq_of("submit", survivor) < seq_of("admit", survivor) \
         < seq_of("retire", survivor)
-    retire = next(r for r in recs if r["name"] == "retire")
+    retire = next(r for r in recs if r["name"] == "retire"
+                  and "ph" not in r)
     assert retire["rid"] == survivor
     assert retire["tokens"] == 16  # image_seq_len at this geometry
     assert retire["slo_ok"] is True and retire["latency_s"] is not None
     fail = next(r for r in recs if r["name"] == "fail")
     assert fail["slot"] == next(r["slot"] for r in recs
                                 if r["name"] == "admit"
-                                and r["rid"] == victim)
+                                and r.get("rid") == victim)
+    # the spans of the same chain: the survivor's retire span closed before
+    # its retire event, around the blocking read of its codes
+    span = next(r for r in recs if r["name"] == "retire"
+                and r.get("ph") == "B")
+    assert span["rid"] == survivor and span["seq"] < retire["seq"]
     # stats() attainment mirrors the per-request slo_ok records
     cls = srv.completed[0].slo
     assert stats["slo_attainment"][cls] == 1.0
@@ -449,7 +456,8 @@ def test_serve_tick_sampling_aggregates_preserve_report(tmp_path,
         telemetry.shutdown()
         recs = telemetry.read_events(tmp_path / f"tel-s{sample}")
         return stats, [r for r in recs if r.get("kind") == "serve"
-                       and r.get("name") == "tick"], build_report(recs)
+                       and r.get("name") == "tick"
+                       and "ph" not in r], build_report(recs)
 
     stats1, ticks1, rep1 = drive(1)
     stats3, ticks3, rep3 = drive(3)
