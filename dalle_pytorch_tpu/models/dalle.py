@@ -726,6 +726,11 @@ class DALLE(nn.Module):
         (ops/transformer.py::Transformer.lane_dense_caches)."""
         return self.transformer.lane_dense_caches(caches)
 
+    def arena_forms(self, dtype):
+        """Per layer, the form the serving arena stores its caches in
+        (ops/transformer.py::Transformer.arena_forms)."""
+        return self.transformer.arena_forms(dtype)
+
     def dense_read_bounds(self):
         """Per layer, the prefixes the decode step's dense cache read
         chooses among (ops/transformer.py::Transformer.dense_read_bounds)."""

@@ -241,7 +241,18 @@ def build_report(events: List[dict]) -> dict:
             "entries": prefix_recs[-1].get("entries"),
             "prefill_flops_saved": prefix_recs[-1].get("flops_saved"),
         }
+    # serve/engine.py::SlotArena emits one `serve.arena_layout` record per
+    # built arena: the form it stores its caches in and the cache-sized
+    # copies left in its compiled tick; the last arena speaks
+    arenas = [r for r in serve if r.get("name") == "arena_layout"]
+    arena_report = ({"arenas": len(arenas),
+                     **{k: arenas[-1].get(k) for k in (
+                         "slots", "folded_layers", "plain_layers",
+                         "ring_layers", "recurrent_layers",
+                         "install_bytes_per_slot", "tick_relayout_bytes")}}
+                    if arenas else None)
     serve_report = {
+        "arena": arena_report,
         "submitted": sum(r.get("name") == "submit" for r in serve),
         "completed": len(retires),
         "failed": sum(r.get("name") == "fail" for r in serve),
@@ -610,6 +621,16 @@ def render_text(report: dict) -> str:
 
     sv = report["serve"]
     lines.append("-- serve --")
+    ar = sv.get("arena")
+    if ar:
+        lines.append(
+            f"arena layout: {ar['slots']} slots; caches of "
+            f"{ar['folded_layers']} layers stored head-folded, "
+            f"{ar['plain_layers']} plain, {ar['ring_layers']} rings, "
+            f"{ar['recurrent_layers']} recurrent; an install writes "
+            f"{ar['install_bytes_per_slot']} bytes, the compiled tick "
+            f"relayouts {ar['tick_relayout_bytes']} (last of "
+            f"{ar['arenas']} arenas)")
     if sv["submitted"] or sv["completed"]:
         lines.append(
             f"requests {sv['submitted']} submitted / {sv['completed']} "
@@ -645,7 +666,7 @@ def render_text(report: dict) -> str:
                 f"{_fmt(row['latency_p50'])}s p99 {_fmt(row['latency_p99'])}s"
                 f" wait {_fmt(row['queue_wait_mean'])}s attainment "
                 f"{_fmt(row['attainment'])}")
-    else:
+    elif not ar:
         lines.append("no serve events")
 
     prof = report.get("prof")

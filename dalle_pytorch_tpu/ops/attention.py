@@ -44,7 +44,7 @@ import numpy as np
 
 from ..obs import metrics, prof, telemetry
 from ..utils.helpers import max_neg_value
-from .quant import (cache_values, cache_write, cache_write_rows,
+from .quant import (CacheForm, cache_values, cache_write, cache_write_rows,
                     circular_slice_in_dim, fold_cache, qdense, scaled_qdot,
                     split_cache)
 from .ssm import fan_in_normal, rms_norm
@@ -1066,7 +1066,11 @@ class MultiHeadAttention(nn.Module):
         ``[b]`` vector (continuous batching: every sequence sits at its own
         depth) while all rows write their k/v at the SAME physical cache
         column ``write_pos`` (a traced scalar — the arena clock mod
-        n_cache).  Each row's cache is stored rotated by
+        n_cache).  The caches then come in the form the arena stores them
+        in (:meth:`arena_form`: head-folded ``[b, heads / fold, n, fold *
+        dh]``, the sliced layers' position-major ``[b, n, heads / fold,
+        fold * dh]``, or plain; :meth:`_stored_form` tells which) and are
+        returned so.  Each row's cache is stored rotated by
         ``r = (write_pos - index) mod n_cache``, so the one shared-column
         ``dynamic_update_slice`` IS each row's logically-next position —
         a per-row write position would lower to an XLA scatter, which
@@ -1093,8 +1097,8 @@ class MultiHeadAttention(nn.Module):
             # a head-folded cache (lane_dense_cache) is told by its shape
             fold = (1 if self.kv_heads is not None else
                     self.heads // cache_values(cache_k).shape[1])
-            cache_k = cache_write(cache_k, k, (0, 0, index, 0), fold)
-            cache_v = cache_write(cache_v, v, (0, 0, index, 0), fold)
+            cache_k = cache_write(cache_k, k, index, CacheForm(fold))
+            cache_v = cache_write(cache_v, v, index, CacheForm(fold))
             k_vals, k_scale = split_cache(cache_k)
             v_vals, v_scale = split_cache(cache_v)
         n_k = k_vals.shape[2]
@@ -1219,7 +1223,7 @@ class MultiHeadAttention(nn.Module):
         index = jnp.asarray(index, jnp.int32)
         with prof.scope("attn-cache"):
             if index.ndim == 0:
-                at = (0, 0, jnp.remainder(index, slots), 0)
+                at = jnp.remainder(index, slots)
                 cache_k = cache_write(cache_k, k, at)
                 cache_v = cache_write(cache_v, v, at)
             else:
@@ -1264,13 +1268,61 @@ class MultiHeadAttention(nn.Module):
         decode scan should carry: head-folded (``quant.fold_cache`` by
         :func:`kv_fold_factor`) where :meth:`decode_step` reads the whole
         cache, as given where it reads slices (they touch a tenth of it).
-        The arena and the span pass never come here."""
+        The serving arena stores its caches by :meth:`arena_form` instead."""
         if self.kv_heads is not None or decode_key_positions(
                 self.pattern, jnp.int32(0)) is not None:
             return cache    # grouped keys' reads have no folded form
         values = cache_values(cache)
         return fold_cache(cache, kv_fold_factor(
             self.heads, self.dim_head, values.dtype))
+
+    def arena_form(self, dtype) -> CacheForm:
+        """The form the serving arena STORES this layer's key and value
+        caches in between its programs (serve/engine.py), for a cache of
+        ``dtype``: the one place that decides it.  ``SlotArena`` allocates
+        and installs by it; the aligned step and the span pass
+        (:meth:`_stored_form`) read and write the array where it lies.
+
+        Why a form of its own: the chip keeps an array whose minor dimension
+        does not fill the 128 lanes in a layout of its choosing, and at
+        ``dim_head`` 64 and 128 slots it chose the SLOTS for the lanes, so
+        every tick copied every cache into the order its reads want and
+        back, and an install rewrote one lane of every tile (PERF.md,
+        Findings PR 37).  So, decided from what the trace can see:
+
+        * a rotated cache the static scan's predicate folds
+          (:func:`kv_fold_factor`) is stored head-folded, the lanes filled
+          and the slot axis major: the one-column write is in place, a
+          slot's rows are one run of memory;
+        * of those, a layer that reads SLICES along the position axis
+          (spans and gathers per row: every pattern but ``full``) is stored
+          position-major besides, ``[slots, n, heads / fold, lanes]``: a
+          position's keys of every head are one tile, and the axes a gather
+          indexes (slot, position) are the major ones, which is the order
+          the compiler otherwise copies the whole array into before it
+          gathers;
+        * what the predicate declines (a 4-byte cache, an odd head count, a
+          ``dim_head`` that fills or does not divide the lanes), grouped
+          keys (their reads have no folded form) and a sliding-window
+          layer's ring (written per row) keep the plain form and the code
+          they ran before."""
+        if self.kv_heads is not None or self.pattern.window:
+            return CacheForm()
+        fold = kv_fold_factor(self.heads, self.dim_head, dtype)
+        sliced = decode_key_positions(self.pattern, jnp.int32(0)) is not None
+        return CacheForm(fold, position_major=fold > 1 and sliced)
+
+    def _stored_form(self, cache) -> CacheForm:
+        """The form of a rotated cache as it was handed over: the arena's
+        (:meth:`arena_form`), or plain ``[b, heads, n, dh]`` from a caller
+        that brings arrays of its own (the static sampler's span pass, the
+        tools that trace one tick); told apart by the minor dimension."""
+        values = cache_values(cache)
+        if values.shape[-1] == self.dim_head:
+            return CacheForm()
+        form = self.arena_form(values.dtype)
+        assert values.shape[-1] == form.fold * self.dim_head, values.shape
+        return form
 
     def _decode_step_aligned(self, x, q, k, v, cache_k, cache_v, index,
                              write_pos, mask, qw=None):
@@ -1291,34 +1343,40 @@ class MultiHeadAttention(nn.Module):
         False control) because key order, values at valid lanes, and
         masks are all equal; only the HBM access pattern differs.
         Non-contiguous windows (axial_col, dilated conv) keep the
-        gather."""
+        gather, and so does every window of a cache the arena stores
+        position-major (:meth:`arena_form`), where a key is a whole tile."""
         assert mask is None, (
             "phase-aligned decode does not take a key padding mask; serve "
             "requests carry fully-valid prompts")
         b = x.shape[0]
-        n_k = split_cache(cache_k)[0].shape[2]
-        scale = self.dim_head ** -0.5
+        form = self._stored_form(cache_k)
+        n_k = cache_values(cache_k).shape[form.position_axis]
         idx = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
         r = jnp.remainder(write_pos - idx, n_k)  # [b] rotation per row
         # the ONE aligned write: every row's next token lands in the same
         # physical column, so this stays a dynamic_update_slice (in-place
         # under donation) instead of a scatter
         with prof.scope("attn-cache"):
-            cache_k = cache_write(cache_k, k, (0, 0, write_pos, 0))
-            cache_v = cache_write(cache_v, v, (0, 0, write_pos, 0))
+            cache_k = cache_write(cache_k, k, write_pos, form)
+            cache_v = cache_write(cache_v, v, write_pos, form)
             k_vals, k_scale = split_cache(cache_k)
             v_vals, v_scale = split_cache(cache_v)
         out = self._aligned_read(q, k_vals, k_scale, v_vals, v_scale,
-                                 idx, r, x.dtype)
+                                 idx, r, x.dtype, form)
         out = out.transpose(0, 2, 1, 3).reshape(b, 1, self.heads * self.dim_head)
         return self._out_proj(out, qw), cache_k, cache_v
 
     def _aligned_read(self, q, k_vals, k_scale, v_vals, v_scale, idx, r,
-                      out_dtype):
+                      out_dtype, form: CacheForm = CacheForm()):
         """The read half of the phase-aligned decode step: one query per
         row (``q`` [b, heads, 1, dh]) at logical position ``idx`` [b]
-        against row caches rotated by ``r`` [b].  Returns the attended
-        values [b, heads, 1, dh].
+        against row caches rotated by ``r`` [b], their values stored in
+        ``form``.  Returns the attended values [b, heads, 1, dh].  The
+        gathers run along the form's position axis, over the array as it
+        lies; the folded branches of the dots (:meth:`_dots`,
+        :meth:`_attn_v`) tell the fold by the shape, and take what a
+        position-major read brings as one group of all the heads
+        (``CacheForm.for_dots``).
 
         Shared verbatim between :meth:`_decode_step_aligned` (the greedy
         serve tick) and :meth:`decode_span` (the speculative draft/verify
@@ -1326,7 +1384,8 @@ class MultiHeadAttention(nn.Module):
         one program means the two paths consume bitwise-identical masked
         softmaxes, which is what lets the spec-decode bit-equality tests
         extend the greedy harness unchanged."""
-        n_k = k_vals.shape[2]
+        ax = form.position_axis
+        n_k = k_vals.shape[ax]
         scale = self.dim_head ** -0.5
         sliced = decode_key_positions(self.pattern, jnp.int32(0))
         if sliced is not None:
@@ -1337,7 +1396,8 @@ class MultiHeadAttention(nn.Module):
                 lambda i: decode_key_positions(self.pattern, i))(idx)
             valid = valid & (positions >= 0) & (positions < n_k)
             T = self.pattern.text_len
-            if sliced[2] and self.aligned_span_decode:
+            if (sliced[2] and self.aligned_span_decode
+                    and not form.position_major):
                 # span reads: per row, the text prefix is the circular
                 # span [r, r+T) and the image window [pos[T]+r, ...+m)
                 # — two block reads instead of T+m key gathers.  Values
@@ -1363,13 +1423,25 @@ class MultiHeadAttention(nn.Module):
                 with prof.scope("attn-cache"):
                     k_sub, v_sub = spans(k_vals), spans(v_vals)
             else:
+                # one key a gather: what a non-contiguous window takes, and
+                # every window of a position-major cache, where a key of
+                # every head is one whole tile and the spans' second pass
+                # (block reads, then a reorder) costs more than it saves
+                # (3.51 against 4.50 ms a tick; PERF.md, Findings PR 37)
                 safe = jnp.clip(positions, 0, n_k - 1)
                 phys = jnp.remainder(safe + r[:, None], n_k)     # [b, m]
+                at = jnp.expand_dims(
+                    phys, (2, 3) if form.position_major else (1, 3))
+                # ``phys`` is in range by the remainder; said so, the chip
+                # gathers a position-major cache without a pass to fill
+                # what is out of range, and without the two copies that
+                # pass drew after it (0.5 ms a pass over a tick's keys)
+                mode = "promise_in_bounds" if form.position_major else None
                 with prof.scope("attn-cache"):
-                    k_sub = jnp.take_along_axis(
-                        k_vals, phys[:, None, :, None], axis=2)  # [b,h,m,dh]
-                    v_sub = jnp.take_along_axis(
-                        v_vals, phys[:, None, :, None], axis=2)
+                    k_sub = form.for_dots(jnp.take_along_axis(
+                        k_vals, at, axis=ax, mode=mode))         # [b,h,m,dh]
+                    v_sub = form.for_dots(jnp.take_along_axis(
+                        v_vals, at, axis=ax, mode=mode))
             with prof.scope("attn-scores"):
                 dots = self._cache_dots(q * scale, k_sub, k_scale)
                 row = (_allowed(self.pattern, idx[:, None], positions, jnp)
@@ -1415,13 +1487,14 @@ class MultiHeadAttention(nn.Module):
         property the serve bit-equality tests already pin)."""
         b, K, _ = x.shape
         q, k, v = self._qkv_decode(x, qw)  # [b, h, K, dh]
-        n_k = split_cache(cache_k)[0].shape[2]
+        form = self._stored_form(cache_k)
+        n_k = cache_values(cache_k).shape[form.position_axis]
         idx = qpos.astype(jnp.int32)
         r = jnp.remainder(jnp.asarray(rot, jnp.int32), n_k)  # [b]
         phys = jnp.remainder(idx + r[:, None], n_k)          # [b, K]
         with prof.scope("attn-cache"):
-            cache_k = cache_write_rows(cache_k, k, phys, valid)
-            cache_v = cache_write_rows(cache_v, v, phys, valid)
+            cache_k = cache_write_rows(cache_k, k, phys, valid, form)
+            cache_v = cache_write_rows(cache_v, v, phys, valid, form)
             k_vals, k_scale = split_cache(cache_k)
             v_vals, v_scale = split_cache(cache_v)
         # fold the span into the batch axis: row (b, j) of the folded
@@ -1437,7 +1510,7 @@ class MultiHeadAttention(nn.Module):
 
         out = self._aligned_read(qf, fold(k_vals), fold(k_scale),
                                  fold(v_vals), fold(v_scale),
-                                 idx_f, r_f, x.dtype)
+                                 idx_f, r_f, x.dtype, form)
         out = out.transpose(0, 2, 1, 3).reshape(
             b, K, self.heads * self.dim_head)
         return self._out_proj(out, qw), cache_k, cache_v

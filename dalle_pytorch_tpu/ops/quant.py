@@ -38,7 +38,7 @@ Scale-layout contract (DESIGN.md §12):
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple, Union
+from typing import NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -119,20 +119,65 @@ def fold_cache(cache: CacheLike, fold: int) -> CacheLike:
     return values if scale is None else (values, scale)
 
 
-def cache_write(cache: CacheLike, new, start, fold: int = 1) -> CacheLike:
-    """``dynamic_update_slice`` of one decode-step row into a cache entry
-    of either layout (the scale plane is write-position-invariant).  With
-    ``fold`` the entry's values are head-folded (:func:`fold_heads`): the
-    row is quantized per head first, then folded the same way."""
+class CacheForm(NamedTuple):
+    """The form a decode cache's values are STORED in: ``fold`` heads side
+    by side in the minor dimension (:func:`fold_heads`), and the position
+    axis before or after the heads.  ``CacheForm()`` is the plain ``[b,
+    heads, n, dh]``; ``CacheForm(2)`` the static scan's lane-dense ``[b,
+    heads / 2, n, 2 dh]``; ``position_major`` gives ``[b, n, heads / fold,
+    fold * dh]``: one position's keys of every head are one run of memory
+    (the serving arena's sliced layers,
+    ops/attention.py::MultiHeadAttention.arena_form).  A per-head scale
+    plane keeps its ``[b, heads, 1, 1]`` under every form."""
+
+    fold: int = 1
+    position_major: bool = False
+
+    @property
+    def position_axis(self) -> int:
+        return 1 if self.position_major else 2
+
+    def shape(self, rows: int, heads: int, slots: int, dim_head: int):
+        groups, lanes = heads // self.fold, self.fold * dim_head
+        return ((rows, slots, groups, lanes) if self.position_major
+                else (rows, groups, slots, lanes))
+
+    def store(self, kv):
+        """``[b, heads, n, dh]`` in this form."""
+        kv = fold_heads(kv, self.fold)
+        return kv.transpose(0, 2, 1, 3) if self.position_major else kv
+
+    def for_dots(self, values):
+        """Stored values (a read of ``m`` positions) as the folded dots
+        take them (ops/attention.py::MultiHeadAttention._dots, ``_attn_v``:
+        ``[b, groups, m, lanes]``, the fold told by the groups).  Position-
+        major values are ONE group of every head side by side, ``[b, 1, m,
+        heads * dh]``: a reshape, nothing moves."""
+        if not self.position_major:
+            return values
+        b, m = values.shape[:2]
+        return values.reshape(b, 1, m, -1)
+
+
+def cache_write(cache: CacheLike, new, column,
+                form: CacheForm = CacheForm()) -> CacheLike:
+    """``dynamic_update_slice`` of one decode-step row ``new`` ``[b, heads,
+    1, dh]`` into a cache entry of either layout at position ``column`` (the
+    scale plane is write-position-invariant).  The entry's values are
+    stored in ``form``: the row is quantized per head first, then brought
+    into the same form."""
     values, scale = split_cache(cache)
+    start = [0, 0, 0, 0]
+    start[form.position_axis] = column
     updated = jax.lax.dynamic_update_slice(
-        values, fold_heads(requantize(new, scale, values.dtype), fold), start)
+        values, form.store(requantize(new, scale, values.dtype)), start)
     if scale is None:
         return updated
     return (updated, scale)
 
 
-def cache_write_rows(cache: CacheLike, new, rows, valid) -> CacheLike:
+def cache_write_rows(cache: CacheLike, new, rows, valid,
+                     form: CacheForm = CacheForm()) -> CacheLike:
     """Write a K-wide span of decode rows into a cache entry of either
     layout at PER-ROW physical columns — the speculative-decode commit
     (``ops/attention.py::MultiHeadAttention.decode_span``).
@@ -145,17 +190,24 @@ def cache_write_rows(cache: CacheLike, new, rows, valid) -> CacheLike:
     into live columns).  Unlike :func:`cache_write` this lowers to a
     scatter (per-row columns can't share one dynamic_update_slice) — the
     speculative path amortizes that cost over the K tokens it commits,
-    and the greedy/serve tick keeps the aligned single-column write."""
+    and the greedy/serve tick keeps the aligned single-column write.
+    ``form``: as in :func:`cache_write`."""
     values, scale = split_cache(cache)
-    q = requantize(new, scale, values.dtype)
+    # position-major [b, K, groups, lanes]: the scatter's own order
+    q = CacheForm(form.fold, True).store(requantize(new, scale, values.dtype))
+    valid = valid[:, :, None, None]
+    b = values.shape[0]
     # invalid lanes re-write their current value: a gather+select keeps
     # the scatter's index set static (distinct within each row), which a
     # masked index would not
-    cur = jnp.take_along_axis(values, rows[:, None, :, None], axis=2)
-    upd = jnp.where(valid[:, None, :, None], q, cur)
-    b = values.shape[0]
-    updated = values.at[jnp.arange(b)[:, None], :, rows, :].set(
-        upd.transpose(0, 2, 1, 3))
+    if form.position_major:
+        cur = jnp.take_along_axis(values, rows[:, :, None, None], axis=1)
+        updated = values.at[jnp.arange(b)[:, None], rows].set(
+            jnp.where(valid, q, cur))
+    else:
+        cur = jnp.take_along_axis(values, rows[:, None, :, None], axis=2)
+        updated = values.at[jnp.arange(b)[:, None], :, rows, :].set(
+            jnp.where(valid, q, cur.transpose(0, 2, 1, 3)))
     if scale is None:
         return updated
     return (updated, scale)

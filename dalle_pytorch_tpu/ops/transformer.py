@@ -800,6 +800,13 @@ class Transformer(nn.Module):
                 for blk, kind, (ck, cv) in zip(self.attn_blocks, self.mixers,
                                                caches)]
 
+    def arena_forms(self, dtype):
+        """Per layer, the form the serving arena stores its caches of
+        ``dtype`` in (MultiHeadAttention.arena_form); None for a layer that
+        carries a recurrent state."""
+        return [None if is_recurrent(kind) else blk.attn.arena_form(dtype)
+                for blk, kind in zip(self.attn_blocks, self.mixers)]
+
     def dense_read_bounds(self):
         """Per layer, the prefixes its decode step's dense cache read chooses
         among (MultiHeadAttention.dense_read_bounds); None for a layer that

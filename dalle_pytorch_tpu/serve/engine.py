@@ -8,15 +8,38 @@ head-of-line blocking the Orca iteration-level-scheduling paper measures).
 This module is the device half of the fix:
 
 * **One arena, N slots, every shape static.**  The KV caches live in
-  per-layer arrays ``[num_slots, heads, seq_len, dim_head]`` allocated
-  once.  A request occupies one slot; its per-slot decode position is a
-  *traced* ``int32``, so slots at different depths of their decode share
-  one compiled program.
+  per-layer arrays allocated once, the slot axis first.  A request
+  occupies one slot; its per-slot decode position is a *traced*
+  ``int32``, so slots at different depths of their decode share one
+  compiled program.
+* **The caches are stored in the form the tick reads.**  Between the
+  programs (``fresh_state``'s output, the donated input and output of
+  ``jit_serve_admit`` and ``jit_serve_tick``) a layer's arrays have the
+  form its own ``MultiHeadAttention.arena_form`` gives
+  (ops/quant.py::CacheForm), decided from heads, ``dim_head``, the cache's
+  dtype, the pattern and the mixer: where the static scan's predicate
+  folds (bf16 or int8, ``dim_head`` 64, an even head count) ``[num_slots,
+  heads / fold, seq_len, fold * dim_head]`` for a ``full`` layer, whose
+  tick reads the whole array, and ``[num_slots, seq_len, heads / fold,
+  fold * dim_head]`` for a layer that reads spans and gathers along the
+  positions; else (a 4-byte cache, ``dim_head`` 128 or 96, grouped keys,
+  a ring) the plain ``[num_slots, kv heads, slots, dim_head]``.  The
+  lanes are filled and the slot axis is major, so the chip keeps the
+  array in the order it was given: the tick holds no copy of a whole
+  cache, and an install writes one slot's rows, one run of memory.
+  (Stored plain at ``dim_head`` 64 and 128 slots the chip put the SLOTS
+  on the lanes: every tick copied all sixteen arrays in and out, 27 of
+  its 33 ms, and an install rewrote a lane of every tile, 36 ms; PERF.md,
+  Findings PR 37.)  Per built arena, where a telemetry stream or a metrics
+  registry is open: a ``serve.arena_layout`` record (:meth:`SlotArena.
+  layout`) and gauges ``graft_serve_arena_folded_layers`` /
+  ``graft_serve_tick_relayout_bytes``.
 * **Admission is a ``dynamic_update_slice``, never a retrace.**  A new
   request is prefilled at batch 1 (one compiled prefill shape), then its
-  caches are written into a free slot by the jitted :meth:`SlotArena.admit`
-  — the slot id is traced, so admitting into slot 0 and slot 17 is the
-  same executable.  Retiring a finished request is pure host bookkeeping
+  caches are brought into the stored form and written into a free slot by
+  the jitted :meth:`SlotArena.admit` — the slot id is traced, so admitting
+  into slot 0 and slot 17 is the same executable.  Retiring a finished
+  request is pure host bookkeeping
   (the slot is marked free; its stale cache bytes are overwritten by the
   next admit and are unreachable meanwhile — decode attention masks keys
   beyond the slot's position).
@@ -62,6 +85,9 @@ module knows nothing about requests, only slots.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
+import re
 from typing import Optional
 
 import jax
@@ -69,9 +95,28 @@ import jax.numpy as jnp
 
 from ..models.dalle import (DALLE, prefill_codes, quantize_decode_weights,
                             sample_image_code)
-from ..obs import prof
-from ..ops.quant import split_cache
+from ..obs import metrics, prof, telemetry
+from ..ops.quant import cache_values, split_cache
 from ..ops.transformer import is_recurrent
+
+
+#: ``%name = bf16[128,4,1104,128]{3,2,1,0:T(8,128)(2,1)} copy(`` in a compiled
+#: program's text: the bits of an element and the dimensions of the result
+_RELAYOUT = re.compile(
+    r"= [a-z]+(\d+)\[([\d,]+)\](?:\{[^}]*\})? (?:copy|transpose)\(")
+
+
+def relayout_bytes(hlo_text: str, elements) -> int:
+    """Bytes of the ``copy`` and ``transpose`` results in a compiled
+    program's text that are as large as one of the arrays of ``elements``
+    elements: what the program spends bringing whole caches into another
+    order than the one they are stored in.  0 is the goal."""
+    total = 0
+    for bits, dims in _RELAYOUT.findall(hlo_text):
+        count = math.prod(map(int, dims.split(",")))
+        if count in elements:
+            total += count * int(bits) // 8
+    return total
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,10 +173,17 @@ class SlotArena:
         # depths cannot share a write column there, so such a layer takes
         # ops/attention.py's per-row ring step and no rotation
         ring = [kind == "window" for kind in cfg.mixers]
+        # the form each layer's caches are STORED in between the programs
+        # (ops/quant.py::CacheForm; None for a recurrent layer), asked of the
+        # layers themselves: the tick reads and writes the arrays where
+        # they lie, an install writes one slot's rows
+        self._forms = forms = dalle.apply(variables, self._cache_dtype,
+                                          method=DALLE.arena_forms)
 
-        def fresh_entry(slots):
-            values = jnp.zeros((S, cfg.kv_heads, slots, cfg.dim_head),
-                               self._cache_dtype)
+        def fresh_entry(slots, form):
+            values = jnp.zeros(
+                form.shape(S, cfg.kv_heads, slots, cfg.dim_head),
+                self._cache_dtype)
             if not cfg.kv_cache_int8:
                 return values
             return (values, jnp.ones((S, cfg.heads, 1, 1), jnp.float32))
@@ -152,10 +204,10 @@ class SlotArena:
             zero = (dalle.apply(variables, S, method=DALLE.decode_init_state)
                     if any(recurrent) else [None] * cfg.depth)
             return dict(
-                caches=[entry if rec else (fresh_entry(slots),
-                                           fresh_entry(slots))
-                        for rec, entry, slots in zip(recurrent, zero,
-                                                     cfg.cache_lens)],
+                caches=[entry if rec else (fresh_entry(slots, form),
+                                           fresh_entry(slots, form))
+                        for rec, entry, slots, form in zip(
+                            recurrent, zero, cfg.cache_lens, forms)],
                 code=jnp.zeros((S,), jnp.int32),
                 index=jnp.zeros((S,), jnp.int32),
                 pos=jnp.zeros((S,), jnp.int32),
@@ -214,15 +266,18 @@ class SlotArena:
             rot = jnp.remainder(write_pos - jnp.int32(n_pre),
                                 jnp.int32(self.geometry.seq_len))
 
-            def install(arena_entry, new_entry):
-                """Roll the prefilled values into the slot's rotation and
-                write them (one DUS); int8 entries also carry the slot's
-                per-head scale plane across — scales are write-position-
-                invariant, so only the values roll."""
+            def install(form, arena_entry, new_entry):
+                """Bring the prefilled values into the stored ``form``, roll
+                them into the slot's rotation and write them: one DUS of the
+                slot's own rows, one run of memory with the slot axis major.
+                Int8 entries also carry the slot's per-head scale plane
+                across — scales are write-position-invariant, so only the
+                values roll."""
                 vals, scale = split_cache(arena_entry)
                 new_vals, new_scale = split_cache(new_entry)
                 vals = jax.lax.dynamic_update_slice(
-                    vals, jnp.roll(new_vals.astype(vals.dtype), rot, axis=2),
+                    vals, jnp.roll(form.store(new_vals.astype(vals.dtype)),
+                                   rot, axis=form.position_axis),
                     (slot, 0, 0, 0))
                 if scale is None:
                     return vals
@@ -237,10 +292,11 @@ class SlotArena:
                     arena_entry, new_entry.astype(arena_entry.dtype),
                     (slot,) + (0,) * (arena_entry.ndim - 1))
 
-            caches = [tuple(map(install_whole if rec or rng else install,
+            caches = [tuple(map(install_whole if rec or rng
+                                else functools.partial(install, form),
                                 old, new))
-                      for rec, rng, old, new in zip(
-                          recurrent, ring, state["caches"], caches1)]
+                      for rec, rng, form, old, new in zip(
+                          recurrent, ring, forms, state["caches"], caches1)]
             ks = jax.random.split(key, self.geometry.image_seq_len)
             code0 = sample_one(first_logits[0], ks[0], temp)
 
@@ -408,6 +464,19 @@ class SlotArena:
         self._tick = jax.jit(serve_tick, donate_argnums=(1,))
         self._tick_spec = (jax.jit(serve_tick_spec, donate_argnums=(1,))
                            if cfg.spec_decode else None)
+        # a static choice, so its counters are per built arena; they cost a
+        # compile of the tick, paid only where someone listens
+        reg = metrics.active()
+        # graftlint: disable=SRV001 (telemetry.get() returns the open stream or None; it waits for nothing)
+        if telemetry.get() is not None or reg is not None:
+            layout = self.layout()
+            telemetry.emit("serve", "arena_layout", **layout)
+            if reg is not None:
+                said = "the last built arena (serve.arena_layout)"
+                reg.gauge("graft_serve_arena_folded_layers", said).set(
+                    layout["folded_layers"])
+                reg.gauge("graft_serve_tick_relayout_bytes", said).set(
+                    layout["tick_relayout_bytes"])
 
     # --- public API (scheduler-facing) ------------------------------------
 
@@ -459,6 +528,17 @@ class SlotArena:
         retirement read (it waits for every tick dispatched so far)."""
         return self.state["out"][slot]
 
+    def _lower_decode(self):
+        """The tick (``tick_spec`` under ``spec_decode``), lowered from the
+        state's own shapes."""
+        active = jax.ShapeDtypeStruct((self.geometry.num_slots,), jnp.bool_)
+        if self._tick_spec is not None:
+            return self._tick_spec.lower(self.variables, self.state, active,
+                                         self._qweights)
+        return self._tick.lower(self.variables, self.state, active,
+                                jax.ShapeDtypeStruct((), jnp.int32),
+                                self._qweights)
+
     def programs(self) -> dict:
         """The arena's compiled programs under the names a profiler trace
         gives them (``jit_serve_prefill``, ``jit_serve_admit`` and
@@ -478,16 +558,42 @@ class SlotArena:
         admit = self._admit.lower(
             self.state, shape(), first_logits, caches1,
             shape(2, dtype=jnp.uint32), shape(dtype=jnp.float32), shape())
-        active = shape(self.geometry.num_slots, dtype=jnp.bool_)
-        decode = (self._tick.lower(self.variables, self.state, active,
-                                   shape(), self._qweights)
-                  if self._tick_spec is None else
-                  self._tick_spec.lower(self.variables, self.state, active,
-                                        self._qweights))
         return {f"jit_{fn.__name__}": lowered.compile()
                 for fn, lowered in ((self._prefill, prefill),
                                     (self._admit, admit),
-                                    (self._tick_spec or self._tick, decode))}
+                                    (self._tick_spec or self._tick,
+                                     self._lower_decode()))}
+
+    def layout(self) -> dict:
+        """The ``serve.arena_layout`` record: how this arena stores its
+        decode state between its programs, and what the stored form costs
+        the tick.  ``folded_layers`` / ``plain_layers``: rotated key/value
+        caches stored head-folded (ops/attention.py::MultiHeadAttention.
+        arena_form) / as ``[slots, kv heads, n, dim_head]``;
+        ``ring_layers`` / ``recurrent_layers``: sliding-window rings /
+        recurrent entries; ``install_bytes_per_slot``: what an admission
+        writes of the caches, one slot's rows; ``tick_relayout_bytes``
+        (:func:`relayout_bytes`): cache-sized ``copy`` / ``transpose``
+        results in the COMPILED tick, compiled here for the backend the
+        arena runs on."""
+        forms = [form for form in self._forms if form is not None]
+        rings = self.dalle.cfg.mixers.count("window")
+        folded = sum(form.fold > 1 for form in forms)
+        sizes = {cache_values(entry).size
+                 for form, pair in zip(self._forms, self.state["caches"])
+                 if form is not None for entry in pair}
+        return {
+            "slots": self.geometry.num_slots,
+            "folded_layers": folded,
+            "plain_layers": len(forms) - folded - rings,
+            "ring_layers": rings,
+            "recurrent_layers": len(self._forms) - len(forms),
+            "install_bytes_per_slot": sum(
+                leaf.nbytes for leaf in jax.tree.leaves(self.state["caches"])
+            ) // self.geometry.num_slots,
+            "tick_relayout_bytes": relayout_bytes(
+                self._lower_decode().compile().as_text(), sizes),
+        }
 
     def trace_counts(self) -> dict:
         """Executable-cache population per jitted entry point — the

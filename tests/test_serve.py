@@ -295,16 +295,43 @@ def test_future_result_from_another_thread(small):
         np.testing.assert_array_equal(waiter.result(30.0), refs[0])
 
 
-def test_arena_geometry_and_cache_dtype(small):
+def _rewidth(cfg, dim_head):
+    """``cfg`` with another head width, and parameters for it."""
+    cfg = dataclasses.replace(cfg, dim_head=dim_head)
+    dalle = DALLE(cfg)
+    params = dalle.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, cfg.text_seq_len), jnp.int32),
+        jnp.zeros((1, cfg.image_seq_len), jnp.int32), return_loss=True)
+    return cfg, dalle, params
+
+
+def _stored_shapes(cfg, slots, folded):
+    """Per layer, the shape the arena stores a cache in: plain, or where the
+    rule folds (ops/attention.py::MultiHeadAttention.arena_form) two heads
+    side by side, the sliced layers position-major."""
+    plain = (slots, cfg.heads, cfg.seq_len, cfg.dim_head)
+    if not folded:
+        return [plain] * cfg.depth
+    head_major = (slots, cfg.heads // 2, cfg.seq_len, 2 * cfg.dim_head)
+    position_major = (slots, cfg.seq_len, cfg.heads // 2, 2 * cfg.dim_head)
+    return [head_major if kind == "full" else position_major
+            for kind in cfg.attn_types]
+
+
+@pytest.mark.parametrize("dim_head", [8, 64], ids=["plain", "folded"])
+def test_arena_geometry_and_cache_dtype(small, dim_head):
     """The arena honors kv_cache_bf16 storage (the serve path inherits the
-    measured byte-cut) and its shapes never depend on occupancy."""
-    cfg, dalle, params, _, _ = small
+    measured byte-cut) and its shapes never depend on occupancy; two heads
+    of 64 are stored side by side, two of 8 as they come."""
+    cfg, dalle, params = (small[:3] if dim_head == small[0].dim_head
+                          else _rewidth(small[0], dim_head))
     arena = SlotArena(dalle, params, num_slots=4)
     g = arena.geometry
     assert (g.num_slots, g.n_pre, g.image_seq_len, g.seq_len) == (
         4, cfg.text_seq_len + 1, cfg.image_seq_len, cfg.seq_len)
-    for k, v in arena.state["caches"]:
-        assert k.shape == (4, cfg.heads, cfg.seq_len, cfg.dim_head)
+    for (k, v), shape in zip(arena.state["caches"],
+                             _stored_shapes(cfg, 4, dim_head == 64)):
+        assert k.shape == v.shape == shape
         assert k.dtype == jnp.bfloat16  # kv_cache_bf16 default ON
         assert v.dtype == jnp.bfloat16
 
@@ -433,17 +460,21 @@ def test_int8_serve_bit_matches_static_sampler(small, weights):
     assert srv.trace_counts() == {"prefill": 1, "admit": 1, "tick": 1}
 
 
-def test_int8_arena_carries_scale_planes(small):
+@pytest.mark.parametrize("dim_head", [8, 64], ids=["plain", "folded"])
+def test_int8_arena_carries_scale_planes(small, dim_head):
     """The int8 arena's cache entries are (int8 values, f32 per-slot
     per-head scale) pairs, scale planes init to ones (a zero scale would
-    NaN the masked lanes' saturating re-quantize)."""
+    NaN the masked lanes' saturating re-quantize); the values in the form
+    the rule gives a one-byte cache, the planes per head under either."""
     cfg8, dalle8, params, _, _ = _int8_setup(small)
+    if dim_head != cfg8.dim_head:
+        cfg8, dalle8, params = _rewidth(cfg8, dim_head)
     arena = SlotArena(dalle8, params, num_slots=3)
-    for k, v in arena.state["caches"]:
+    for (k, v), shape in zip(arena.state["caches"],
+                             _stored_shapes(cfg8, 3, dim_head == 64)):
         for values, scale in (k, v):
             assert values.dtype == jnp.int8
-            assert values.shape == (3, cfg8.heads, cfg8.seq_len,
-                                    cfg8.dim_head)
+            assert values.shape == shape
             assert scale.dtype == jnp.float32
             assert scale.shape == (3, cfg8.heads, 1, 1)
             np.testing.assert_array_equal(np.asarray(scale), 1.0)

@@ -333,6 +333,47 @@ def test_serve_tick_compiles_and_fits(one_chip, serve_arena):
             < V5E_HBM_BYTES)
 
 
+def test_serve_programs_hold_no_copy_of_a_whole_cache(one_chip):
+    """ISSUE 37: at 128 slots of ``dim_head`` 64 the chip kept the arena's
+    plain ``[slots, 8, n, 64]`` caches with the SLOTS on the lanes, and the
+    tick copied each of them in and out (at these shapes the parent's tick
+    held 16 such copies, 302 MB).  Stored by ``MultiHeadAttention.
+    arena_form`` the compiled tick and install hold none: every cache is
+    read and written where it lies.  One cycle of the ``cub200`` patterns at
+    fmap 8 (n = 144): the choice of layout follows slots and ``dim_head``,
+    not the length."""
+    from dalle_pytorch_tpu.serve import SlotArena
+    from dalle_pytorch_tpu.serve.engine import relayout_bytes
+
+    slots = 128
+    cfg = dataclasses.replace(cub200_config(), depth=4, image_fmap_size=8)
+    model, shapes = _param_shapes(cfg)
+    arena = SlotArena(model, {"params": shapes}, num_slots=slots,
+                      filter_thres=0.9)
+    sizes = {a.size for pair in arena.state["caches"] for a in pair}
+    assert sizes == {slots * cfg.heads * cfg.seq_len * cfg.dim_head}
+    variables, state = _on(one_chip, arena.variables), _on(one_chip,
+                                                           arena.state)
+    tick = arena._tick.lower(
+        variables, state,
+        jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+        _scalar(one_chip, jnp.int32), None).compile()
+    assert relayout_bytes(tick.as_text(), sizes) == 0
+    prefill = arena._prefill.lower(variables, jax.ShapeDtypeStruct(
+        (1, cfg.text_seq_len), jnp.int32, sharding=one_chip))
+    first_logits, caches = _on(one_chip, prefill.out_info)
+    admit = arena._admit.lower(
+        state, _scalar(one_chip, jnp.int32), first_logits, caches,
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+        _scalar(one_chip, jnp.float32), _scalar(one_chip, jnp.int32)
+    ).compile()
+    assert relayout_bytes(admit.as_text(), sizes) == 0
+    # an install's temporaries are of one slot's size, not of the arena's
+    one_slot = sum(a.nbytes for pair in arena.state["caches"]
+                   for a in pair) // slots
+    assert admit.memory_analysis().temp_size_in_bytes < 2 * one_slot
+
+
 # --- the decode scan's carried KV caches: no lane padding --------------------
 
 _LAID_OUT = re.compile(r"\b(?:bf16|s8)\[([\d,]+)\]\{([\d,]+):T\(")
