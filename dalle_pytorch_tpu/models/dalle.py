@@ -37,9 +37,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs import metrics, prof, telemetry
-from ..ops.attention import record_kernel_choices
+from ..ops.attention import LANES, record_kernel_choices
 from ..ops.ssm import fan_in_normal, normal_init
-from ..ops.transformer import (RMSNorm, Transformer, TrunkSpec, is_recurrent,
+from ..ops.transformer import (RMSNorm, Transformer, TrunkSpec,
+                                cache_position_axis, is_latent, is_recurrent,
                                 layer_cache_lens, layer_mixers)
 from ..utils.helpers import (TOP_K_PASSES, max_neg_value, top_k_count,
                              top_k_filter, top_p_filter)
@@ -172,18 +173,21 @@ class DALLEConfig:
             # a ring of keys cannot be recomputed backwards (reversible) or
             # rolled back (spec_decode); the trunk's blocks have no int8
             # kernels (weights_int8: expert banks least of all) and its
-            # grouped, rotated or ring caches no int8 layout (kv_cache_int8)
+            # grouped, rotated, ring or latent caches no int8 layout
+            # (kv_cache_int8)
             for field in ("reversible", "spec_decode", "weights_int8",
                           "kv_cache_int8", "sparse_attn"):
                 assert not getattr(self, field), (
                     f"{field} is not supported over a TrunkSpec trunk (its "
                     "layers carry a recurrent state-space or linear-"
-                    "attention state, grouped keys or a ring of them, and "
-                    "routed experts; none has that form)")
+                    "attention state, grouped keys, a ring of them or a "
+                    "latent ('mla') in place of them, and routed experts "
+                    "('moe_reglu', 'moe_swiglu_shared'); none has that form)")
             assert self.ring_axis is None and self.ff_experts <= 1, (
                 "a TrunkSpec trunk runs unsharded in sequence (no ring or "
-                "Ulysses form of a windowed or grouped layer) and routes "
-                "through its own ff = 'moe_reglu', not ff_experts")
+                "Ulysses form of a windowed, grouped or latent layer) and "
+                "routes through its own ff ('moe_reglu', "
+                "'moe_swiglu_shared'), not ff_experts")
             assert self.attn_dropout == 0 and self.ff_dropout == 0, (
                 "a TrunkSpec trunk has no dropout")
             assert self.heads % self.trunk.kv_heads == 0, (
@@ -232,7 +236,8 @@ class DALLEConfig:
         """Each layer's mixer, and so the kind of its decode state:
         "attention" carries ``(k, v)`` over every position, "window" over a
         ring of the window's length, "mamba" ``(window, h)``, "gdn"
-        ``(window, S)`` (ops/transformer.py::is_recurrent)."""
+        ``(window, S)`` (ops/transformer.py::is_recurrent), "mla" one
+        latent and one rotated key a position (``is_latent``)."""
         return layer_mixers(self.trunk, self.depth)
 
     @property
@@ -676,21 +681,25 @@ class DALLE(nn.Module):
         if cfg.trunk is not None:
             # the prompt's positions only: a recurrent layer's state is the
             # one after the last of them, and an attention layer's keys and
-            # values are padded out to the cache's static length; a window
-            # layer's cache is a ring (position p in slot p mod slots), so
-            # a prompt longer than it leaves its last ``slots`` positions,
-            # rolled to their slots
-            def stored(a, slots):
+            # values (a latent layer's latent and rotated key) are padded
+            # out to the cache's static length along their position axis; a
+            # window layer's cache is a ring (position p in slot p mod
+            # slots), so a prompt longer than it leaves its last ``slots``
+            # positions, rolled to their slots
+            def stored(a, slots, axis):
                 a = a.astype(jnp.bfloat16) if cfg.kv_cache_bf16 else a
                 if n_pre <= slots:
-                    return jnp.pad(a, ((0, 0), (0, 0), (0, slots - n_pre),
-                                       (0, 0)))
-                return jnp.roll(a[:, :, n_pre - slots:],
-                                (n_pre - slots) % slots, axis=2)
+                    pad = [(0, 0)] * a.ndim
+                    pad[axis] = (0, slots - n_pre)
+                    return jnp.pad(a, pad)
+                return jnp.roll(
+                    jax.lax.slice_in_dim(a, n_pre - slots, n_pre, axis=axis),
+                    (n_pre - slots) % slots, axis=axis)
 
             with prof.scope("attn-cache"):
                 kvs = [kv if is_recurrent(kind) else
-                       tuple(stored(a, slots) for a in kv)
+                       tuple(stored(a, slots, cache_position_axis(kind))
+                             for a in kv)
                        for kind, slots, kv in zip(cfg.mixers, cfg.cache_lens,
                                                   kvs)]
         elif cfg.kv_cache_int8:
@@ -988,7 +997,10 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
     layers' caches were folded and how many kept plain, a
     ``decode.state_layout`` record and four gauges how many layers carry
     keys and values, how many a state-space state, how many a
-    linear-attention state (with the shape a row of it is carried in), and
+    linear-attention state (with the shape a row of it is carried in), how
+    many a latent pair (``latent_layers``, with the bytes a position holds
+    and the bytes its stored form walks, gauge
+    ``graft_decode_latent_layers``), and
     the bytes of decode state one row holds; over a routed trunk a ``decode.moe_layout`` record
     and three gauges besides: the expert layers, the window layers, and the
     key/value slots one row holds over all layers (a window layer holds its
@@ -1001,7 +1013,9 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
     cfg = dalle.cfg
     with prof.scope("attn-cache"):
         folded = dalle.apply(params, caches, method=DALLE.lane_dense_caches)
-    attn = [i for i, kind in enumerate(cfg.mixers) if not is_recurrent(kind)]
+    latent = [i for i, kind in enumerate(cfg.mixers) if is_latent(kind)]
+    attn = [i for i, kind in enumerate(cfg.mixers)
+            if not (is_recurrent(kind) or is_latent(kind))]
     linear = [i for i, kind in enumerate(cfg.mixers) if kind == "gdn"]
     dense = sum(cache_values(folded[i][0]).shape
                 != cache_values(caches[i][0]).shape for i in attn)
@@ -1010,11 +1024,27 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
         "kv_layout": {"kv_lane_dense_layers": dense,
                       "kv_plain_layers": len(attn) - dense},
         "state_layout": {
-            "ssm_layers": len(caches) - len(attn) - len(linear),
+            "ssm_layers": len(caches) - len(attn) - len(latent) - len(linear),
             "kv_layers": len(attn), "linear_layers": len(linear),
             "state_bytes_per_row": sum(
                 a.size * a.dtype.itemsize
                 for a in jax.tree.leaves(caches)) // rows}}
+    if latent:
+        # a latent layer holds one pair a position and no head axis: what a
+        # position costs as the mathematics counts it, and as the arrays
+        # are handed over (each minor dimension padded to the lanes: 64
+        # rotary values take a whole tile row; inside the scan the v5e's
+        # compiler puts the positions on the lanes and pads nothing,
+        # PERF.md PR 38)
+        pair = caches[latent[0]]
+        records["kv_layout"]["kv_latent_layers"] = len(latent)
+        records["state_layout"].update(
+            latent_layers=len(latent),
+            latent_bytes_per_position=sum(
+                a.shape[-1] * a.dtype.itemsize for a in pair),
+            latent_bytes_walked_per_position=sum(
+                -(-a.shape[-1] // LANES) * LANES * a.dtype.itemsize
+                for a in pair))
     # the shape one row of a linear-attention state is carried in: said in
     # the state_layout record, no gauge
     state_shape = ({"linear_state_shape": list(caches[linear[0]][1].shape[1:])}
@@ -1035,16 +1065,18 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
                       "decode_codes' last trace (kv_reach)").set(reach[name])
     if cfg.trunk is not None and cfg.trunk.routed:
         t = cfg.trunk
-        counts = {"moe_layers": cfg.depth,
+        counts = {"moe_layers": cfg.depth - t.dense_layers,
                   "window_layers": cfg.mixers.count("window"),
                   "kv_slots_per_row": sum(cfg.cache_lens)}
         telemetry.emit(
-            "decode", "moe_layout", rows=rows, layers=cfg.depth,
+            "decode", "moe_layout", rows=rows, layers=counts["moe_layers"],
             experts=t.experts, experts_per_token=t.experts_per_token,
-            expert_bytes_per_layer=3 * t.experts * cfg.dim * t.expert_dim
-            * jnp.dtype(t.param_dtype).itemsize,
+            expert_bytes_per_layer=3 * t.held_experts * cfg.dim
+            * t.expert_dim * jnp.dtype(t.param_dtype).itemsize,
             window_layers=counts["window_layers"],
-            kv_slots_per_row=counts["kv_slots_per_row"])
+            kv_slots_per_row=counts["kv_slots_per_row"],
+            scoring=t.scoring, experts_held=t.held_experts,
+            shared_experts=t.shared_experts)
         if reg is not None:
             for name, value in counts.items():
                 reg.gauge(f"graft_decode_{name}",

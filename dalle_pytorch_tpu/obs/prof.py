@@ -42,7 +42,7 @@ SCOPES = ("embed", "attn-qkv", "attn-scores", "attn-cache", "attn-out",
           "ff", "logits-head", "vae-conv", "optimizer", "decode-step",
           "serve-tick", "spec-draft", "spec-verify", "sample",
           "ssm-proj", "ssm-conv", "ssm-scan", "moe-route", "moe-experts",
-          "gdn-proj", "gdn-conv", "gdn-state")
+          "gdn-proj", "gdn-conv", "gdn-state", "mla-proj", "mla-read")
 
 #: Residual bucket for equations under no scope.
 UNATTRIBUTED = "unattributed"

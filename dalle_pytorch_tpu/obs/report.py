@@ -343,6 +343,11 @@ def build_report(events: List[dict]) -> dict:
     # the shape a row of their matrix state is carried in
     _, linear = last_decode(
         "state_layout", ("linear_layers", "linear_state_shape"))
+    # where some layers cache a latent in place of keys and values, how many,
+    # the bytes a position holds and the bytes its stored form walks
+    _, latent = last_decode(
+        "state_layout", ("latent_layers", "latent_bytes_per_position",
+                         "latent_bytes_walked_per_position"))
     # over a routed trunk a `decode.moe_layout` record besides: the expert
     # layers, their banks' bytes, the window layers and the key slots a row
     # holds over all layers
@@ -352,6 +357,10 @@ def build_report(events: List[dict]) -> dict:
                        "kv_slots_per_row"))
     # and a `decode.kv_reach` record: the layers whose dense cache read the
     # position bounds, and the share of their slots a tick reads
+    # a sigmoid-routed layer says how it scores, how many of its experts this
+    # device holds and how many shared experts stand beside them
+    _, share = last_decode(
+        "moe_layout", ("scoring", "experts_held", "shared_experts"))
     _, reach = last_decode(
         "kv_reach", ("bounded_layers", "unbounded_layers", "buckets",
                      "read_share"))
@@ -361,7 +370,11 @@ def build_report(events: List[dict]) -> dict:
                          **({"reach": reach} if reach else {}),
                          **({"linear": linear}
                             if linear.get("linear_layers") else {}),
-                         **({"moe": routed} if routed else {})}
+                         **({"latent": latent}
+                            if latent.get("latent_layers") else {}),
+                         **({"moe": routed} if routed else {}),
+                         **({"moe_share": share}
+                            if share.get("scoring") == "sigmoid" else {})}
     # models/dalle.py::sample_image_code emits one `sample.top_k` record per
     # traced sampler (a decode_codes program holds two, a serve tick its
     # own): how many logits the top-k filter keeps and how it finds the
@@ -726,6 +739,12 @@ def render_text(report: dict) -> str:
                     f"linear attention: {lin.get('linear_layers')} of the "
                     f"recurrent layers, a float32 state of "
                     f"{lin.get('linear_state_shape')} a row")
+        if "latent" in dec:
+            lat = dec["latent"]
+            lines.append(
+                f"latent cache: {lat.get('latent_layers')} layers, "
+                f"{lat.get('latent_bytes_per_position')} bytes a position "
+                f"({lat.get('latent_bytes_walked_per_position')} as stored)")
         if "moe" in dec:
             m = dec["moe"]
             lines.append(
@@ -735,6 +754,12 @@ def render_text(report: dict) -> str:
                 f"({m.get('expert_bytes_per_layer')} bytes of banks a "
                 f"layer); {m.get('window_layers')} window layers, "
                 f"{m.get('kv_slots_per_row')} key slots a row")
+        if "moe_share" in dec:
+            sh = dec["moe_share"]
+            lines.append(
+                f"expert share: {sh.get('scoring')} scores, "
+                f"{sh.get('experts_held')} held, "
+                f"{sh.get('shared_experts')} shared")
     if sam:
         lines.append(
             f"sampler top-k: keeps {sam.get('k')} of {sam.get('vocab')} "

@@ -156,6 +156,19 @@ def read_bounds(slots: int) -> Tuple[int, ...]:
     return tuple(range(width, slots, width)) + (slots,)
 
 
+def switch_read_prefix(bounds, reads, operands, filled):
+    """One of ``reads`` (one a static prefix of ``bounds``, the last the
+    whole cache) on ``operands``: the first whose prefix holds ``filled``
+    slots (a traced scalar), chosen by a ``lax.switch`` around the read
+    alone; the whole cache where ``filled`` is None or there is one bucket.
+    A compare and a sum find the bucket: ``searchsorted`` lowers to a loop
+    of its own."""
+    if filled is None or len(bounds) == 1:
+        return reads[-1](*operands)
+    bucket = jnp.sum(filled > jnp.asarray(bounds[:-1], jnp.int32))
+    return jax.lax.switch(bucket, reads, *operands)
+
+
 def make_variable_sparse_layout(
     num_blocks: int,
     global_blocks: int,
@@ -576,7 +589,8 @@ def kernel_mesh(partitioner) -> Iterator[None]:
 def record_kernel_choices(model: str) -> Iterator[None]:
     """Collect every attention layer's choice during one trace of a model
     and say what was chosen: one ``attention.kernel`` telemetry record and
-    five gauges (flash layers, dense layers, the share of blocks the flash
+    five gauges (flash layers, dense layers, latent layers where the model has
+    any, the share of blocks the flash
     layers compute, skipped blocks left out, and the kernel's operand
     contract: heads a program shares the lanes between, positions of
     padding a call adds to a sequence in HBM).  A layer traced twice (a
@@ -588,11 +602,16 @@ def record_kernel_choices(model: str) -> Iterator[None]:
         layers = list(_choices.pop().values())
         if layers:
             flash = [c for c in layers if c["tiles"] is not None]
+            # latent-attention layers (ops/latent_attention.py) run their own
+            # published form over a sequence: counted apart, and only where
+            # there are any, so that other models' records stay as they were
+            latent = sum(c.get("latent", False) for c in layers)
             computed = sum(c["computed"] for c in flash)
             blocks = sum(c["blocks"] for c in flash)
             counts = {
                 "flash_layers": len(flash),
-                "dense_layers": len(layers) - len(flash),
+                "dense_layers": len(layers) - len(flash) - latent,
+                **({"latent_layers": latent} if latent else {}),
                 "blocks_computed_share":
                     round(computed / blocks, 4) if blocks else 0.0,
                 "heads_per_program":
@@ -1199,12 +1218,7 @@ class MultiHeadAttention(nn.Module):
             attn_v=self._attn_v, out_dtype=jnp.dtype(out_dtype))
             for bound in bounds]
         operands = (q_scaled, k_vals, k_scale, v_vals, v_scale, row)
-        if filled is None or len(bounds) == 1:
-            return reads[-1](*operands)
-        # the first bound to hold ``filled`` slots (a compare and a sum:
-        # ``searchsorted`` lowers to a loop of its own)
-        bucket = jnp.sum(filled > jnp.asarray(bounds[:-1], jnp.int32))
-        return jax.lax.switch(bucket, reads, *operands)
+        return switch_read_prefix(bounds, reads, operands, filled)
 
     def _decode_step_ring(self, x, q, k, v, cache_k, cache_v, index, mask,
                           qw=None):

@@ -34,11 +34,13 @@ TPU-native design choices:
   fraction x mean probability per expert), returned separately so callers
   weight it.
 
-Two layers share one routing rule (:func:`route`): :class:`MoEFeedForward`
-above, the 2021 block's GEGLU experts, and :class:`ExpertsReGLU`, the
-dropless bias-free ReGLU experts of a ``TrunkSpec`` trunk
+Three layers share one routing rule (:func:`route`): :class:`MoEFeedForward`
+above, the 2021 block's GEGLU experts; :class:`ExpertsReGLU`, the dropless
+bias-free ReGLU experts of a ``TrunkSpec`` trunk
 (ops/transformer.py::TrunkMoEBlock), whose router logits come from the
-caller.
+caller; and :class:`ExpertsSwiGLUShared`, SwiGLU experts under a sigmoid
+router with a selection bias, beside a shared expert, on the experts this
+device holds (ops/transformer.py::TrunkSharedMoEBlock).
 """
 from __future__ import annotations
 
@@ -51,14 +53,38 @@ import jax.numpy as jnp
 from ..obs import prof
 
 
-def route(logits, k: int):
-    """The one routing rule: float32 softmax over ALL experts, the ``k``
-    largest probabilities, renormalised over the chosen.
+SCORINGS = ("softmax", "sigmoid")
 
-    ``logits`` ``[..., e]`` -> ``(probs [..., e], top_idx [..., k], combine
+
+def route(logits, k: int, scoring: str = "softmax", bias=None,
+          scale: float = 1.0):
+    """The one routing rule: float32 scores over ALL experts, the ``k``
+    largest, renormalised over the chosen.
+
+    ``logits`` ``[..., e]`` -> ``(scores [..., e], top_idx [..., k], combine
     [..., e])``, all float32 but the indices: ``combine`` holds each chosen
-    expert's weight at its own column and exact zeros elsewhere (rows sum to
-    1).  ``jax.lax.top_k`` breaks exact ties towards the lower index."""
+    expert's weight at its own column and exact zeros elsewhere.
+    ``jax.lax.top_k`` breaks exact ties towards the lower index.
+
+    ``scoring`` "softmax": the scores are the softmax's probabilities and a
+    row of ``combine`` sums to 1.  "sigmoid" (``noaux_tc``): each score is its
+    own logit's sigmoid; ``bias`` ``[e]`` is added for the CHOICE alone (the
+    ``k`` largest of ``score + bias``) and never enters a weight, which is
+    ``scale * score_e / (sum of the chosen scores + 1e-20)``."""
+    assert scoring in SCORINGS, scoring
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        e = scores.shape[-1]
+        _, top_idx = jax.lax.top_k(
+            scores if bias is None else scores + bias.astype(jnp.float32), k)
+        onehot = jax.nn.one_hot(top_idx, e, dtype=scores.dtype)
+        chosen = onehot.sum(axis=-2)                         # [..., e] 0 / 1
+        combine = scores * chosen
+        combine = scale * combine / (
+            combine.sum(axis=-1, keepdims=True) + 1e-20)
+        return scores, top_idx, combine
+    assert bias is None and scale == 1.0, (
+        "a selection bias and a scale belong to sigmoid scoring")
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     e = probs.shape[-1]
     top_p, top_idx = jax.lax.top_k(probs, k)
@@ -67,6 +93,25 @@ def route(logits, k: int):
     combine = combine / jnp.clip(
         combine.sum(axis=-1, keepdims=True), 1e-9)
     return probs, top_idx, combine
+
+
+def _bank_products(x, combine, w_gate, w_up, w_down, act, dtype):
+    """Every bank's expert on every token ``x`` ``[t, d]``, the ``combine``
+    weights' exact zeros cancelling the unchosen before one contraction over
+    experts x width into the model width: float32 ``[t, d]``.  ``combine``
+    ``[t, e]`` has one column a bank; ``act`` is the gate's nonlinearity."""
+    with prof.scope("moe-experts"):
+        # graftlint: disable=DOT001 (uniform: x and the banks are both cast to dtype)
+        gate = jnp.einsum("td,edf->tef", x, w_gate)
+        # graftlint: disable=DOT001 (uniform: x and the banks are both cast to dtype)
+        up = jnp.einsum("td,edf->tef", x, w_up)
+        hidden = act(gate) * up
+    with prof.scope("moe-route"):
+        hidden = (hidden.astype(jnp.float32)
+                  * combine[:, :, None]).astype(dtype)
+    with prof.scope("moe-experts"):
+        return jnp.einsum("tef,efd->td", hidden, w_down,
+                          preferred_element_type=jnp.float32)
 
 
 class ExpertsReGLU(nn.Module):
@@ -132,18 +177,104 @@ class ExpertsReGLU(nn.Module):
         w_gate, w_up, w_down = (self.w_gate.astype(self.dtype),
                                 self.w_up.astype(self.dtype),
                                 self.w_down.astype(self.dtype))
-        with prof.scope("moe-experts"):
-            # graftlint: disable=DOT001 (uniform: x and the banks are both cast to self.dtype)
-            gate = jnp.einsum("td,edf->tef", x, w_gate)
-            # graftlint: disable=DOT001 (uniform: x and the banks are both cast to self.dtype)
-            up = jnp.einsum("td,edf->tef", x, w_up)
-            act = jax.nn.relu(gate) * up
+        y = _bank_products(x, combine, w_gate, w_up, w_down, jax.nn.relu,
+                           self.dtype)
+        return y.reshape(b, n, d).astype(m.dtype)
+
+
+class ExpertsSwiGLUShared(nn.Module):
+    """Dropless mixture of SwiGLU experts under a sigmoid router, beside
+    shared experts that every token takes, on the experts THIS device holds
+    (GLM-4.7-Flash's ``noaux_tc`` layer; ops/transformer.py::
+    TrunkSharedMoEBlock).  With ``m`` the normed input of the sublayer::
+
+        sc     = sigmoid(m W_r)                     # float32, all ``experts``
+        chosen = the k largest of sc + b            # b: selection bias only
+        w_e    = scale * sc_e / (sum_chosen sc + 1e-20)
+        y      = sum_{held e} w_e W_down_e(silu(W_gate_e m) * (W_up_e m))
+                 + W_down_s(silu(W_gate_s m) * (W_up_s m))
+
+    ``experts`` stays the router's width; ``held`` banks exist here, experts
+    ``first .. first + held - 1`` (the share of a deployment that splits the
+    experts over devices, as expert parallelism does): the layer routes over
+    all ``experts``, multiplies its own banks, and what the others would have
+    added is left out.  On one device there is no exchange; ``held =
+    experts`` is the whole layer.  The ``shared`` shared experts are one
+    SwiGLU of width ``shared x expert_dim``.  Scopes: ``moe-route`` (router
+    product, sigmoid, top-k, weights) and ``moe-experts`` (the banks'
+    products and the shared expert's)."""
+
+    dim: int
+    experts: int
+    k: int
+    expert_dim: int
+    held: int
+    first: int = 0
+    shared: int = 1
+    scale: float = 1.0
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        from .ssm import fan_in_normal
+
+        e, d, f = self.held, self.dim, self.expert_dim
+        assert 0 <= self.first and self.first + e <= self.experts, (
+            self.first, e, self.experts)
+        bank = dict(dtype=self.param_dtype)
+        self.w_router = self.param("w_router", fan_in_normal(d),
+                                   (d, self.experts), **bank)
+        # enters the choice and never a weight; drawn, not zero, so that a
+        # seeded model's choices depend on it
+        self.router_bias = self.param(
+            "router_bias",
+            lambda key, shape: jax.random.uniform(key, shape, jnp.float32,
+                                                  -0.1, 0.1),
+            (self.experts,))
+        self.w_gate = self.param("w_gate", fan_in_normal(d), (e, d, f),
+                                 **bank)
+        self.w_up = self.param("w_up", fan_in_normal(d), (e, d, f), **bank)
+        self.w_down = self.param("w_down", fan_in_normal(f), (e, f, d),
+                                 **bank)
+        fs = self.shared * f
+        self.shared_gate = self.param("shared_gate", fan_in_normal(d),
+                                      (d, fs), **bank)
+        self.shared_up = self.param("shared_up", fan_in_normal(d), (d, fs),
+                                    **bank)
+        self.shared_down = self.param("shared_down", fan_in_normal(fs),
+                                      (fs, d), **bank)
+
+    def __call__(self, m):
+        """``m`` ``[b, n, dim]`` (the normed hidden state) -> ``[b, n, dim]``
+        in ``m``'s dtype."""
+        b, n, d = m.shape
+        tokens = b * n
+        x = m.reshape(tokens, d).astype(self.dtype)
         with prof.scope("moe-route"):
-            act = (act.astype(jnp.float32)
-                   * combine[:, :, None]).astype(self.dtype)
+            logits = jnp.einsum("td,de->te", x,
+                                self.w_router.astype(self.dtype),
+                                preferred_element_type=jnp.float32)
+            _, top_idx, combine = route(logits, self.k, "sigmoid",
+                                        self.router_bias, self.scale)
+            # what this layer's routing chose and how it weighted its
+            # choices, for tests and the benchmark's comparison (a no-op
+            # unless "intermediates" is mutable)
+            self.sow("intermediates", "top_idx", top_idx.reshape(b, n, -1))
+            self.sow("intermediates", "top_weight", jnp.take_along_axis(
+                combine, top_idx, axis=-1).reshape(b, n, -1))
+            combine = combine[:, self.first:self.first + self.held]
+        y = _bank_products(x, combine, self.w_gate.astype(self.dtype),
+                           self.w_up.astype(self.dtype),
+                           self.w_down.astype(self.dtype), jax.nn.silu,
+                           self.dtype)
         with prof.scope("moe-experts"):
-            y = jnp.einsum("tef,efd->td", act, w_down,
-                           preferred_element_type=jnp.float32)
+            # graftlint: disable=DOT001 (uniform: x and the kernel are both cast to self.dtype)
+            gate = jnp.dot(x, self.shared_gate.astype(self.dtype))
+            # graftlint: disable=DOT001 (uniform: x and the kernel are both cast to self.dtype)
+            up = jnp.dot(x, self.shared_up.astype(self.dtype))
+            y = y + jnp.dot(jax.nn.silu(gate) * up,
+                            self.shared_down.astype(self.dtype),
+                            preferred_element_type=jnp.float32)
         return y.reshape(b, n, d).astype(m.dtype)
 
 

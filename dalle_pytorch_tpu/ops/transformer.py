@@ -27,15 +27,20 @@ import jax.numpy as jnp
 from ..obs import prof, telemetry
 from ..utils.helpers import cast_tuple, default
 from .attention import AttnPattern, MultiHeadAttention
+from .latent_attention import LatentAttention
 from .linear_attention import GatedDeltaMixer
 from .reversible import reversible_sequence, reversible_sequence_naive
 from .ssm import MambaMixer, fan_in_normal, rms_norm
 
-MIXERS = ("attention", "gdn", "mamba", "window")
+MIXERS = ("attention", "gdn", "mamba", "mla", "window")
 #: the mixers whose decode state is a recurrent state (two leaves with the
 #: rows on axis 0 and no position axis), not keys and values
 RECURRENT_MIXERS = ("gdn", "mamba")
-FFS = ("swiglu", "moe_reglu")
+#: the mixers that rotate their queries and keys by position
+ROTARY_MIXERS = ("mla", "window")
+FFS = ("swiglu", "moe_reglu", "moe_swiglu_shared")
+#: the feed-forwards that route tokens to experts
+ROUTED_FFS = ("moe_reglu", "moe_swiglu_shared")
 NORM_AT = ("input", "output")
 
 
@@ -56,18 +61,32 @@ class TrunkSpec:
     ``window`` keys, ``"mamba"`` Mamba-1 with normed ``dt``/``B``/``C``,
     ``"gdn"`` gated-delta-rule linear attention (ops/linear_attention.py):
     ``DALLEConfig.heads`` heads of ``lin_key_dim`` x ``lin_value_dim`` state
-    behind ``lin_conv``-tap convolutions.  ``qk_norm``: attention layers
-    RMS-norm their projected queries and keys over the projection's whole
-    width, before the split into heads.
+    behind ``lin_conv``-tap convolutions; ``"mla"`` multi-head latent
+    attention (ops/latent_attention.py): global, rotated (``rope_theta``)
+    over ``rope_dim`` of a head's ``nope_dim + rope_dim`` query/key
+    dimensions, queries through a normed ``q_rank`` bottleneck, keys and
+    values of ``value_dim`` from one normed ``kv_rank`` latent a position,
+    which with one shared rotary key is all its decode cache holds.
+    ``qk_norm``: attention layers RMS-norm their projected queries and keys
+    over the projection's whole width, before the split into heads.
     Feed-forward: ``"swiglu"`` a dense gated SiLU of width ``ff_dim``;
     ``"moe_reglu"`` ``experts`` routed ReGLU experts of width ``expert_dim``,
     ``experts_per_token`` a token, dropless (ops/moe.py::ExpertsReGLU), whose
     router reads the layer's INPUT (before the norm and the mixer, so the
-    two halves of a layer are no longer independent).  ``tied_table``: one
-    table for the embedding and the head, or (False) a table and a separate
-    ``head`` (``models/dalle.py``).  A rotary trunk takes no position
-    embedding from DALL-E's client.  Built from a plain dict (a checkpoint's
-    hparams, a benchmark configuration)."""
+    two halves of a layer are no longer independent);
+    ``"moe_swiglu_shared"`` routed SwiGLU experts of width ``expert_dim``
+    whose sigmoid router reads the sublayer's NORMED input (a selection bias
+    in the choice alone, weights renormalised and scaled by ``route_scale``)
+    beside ``shared_experts`` experts that every token takes
+    (ops/moe.py::ExpertsSwiGLUShared); ``experts`` stays the router's width
+    and ``experts_held`` (0: all) banks exist here, experts ``experts_first``
+    onwards: the share of a deployment that splits each layer's experts over
+    devices.  The first ``dense_layers`` layers take the dense ``"swiglu"``
+    of ``ff_dim`` whatever ``ff`` says (:meth:`ff_kind`).  ``tied_table``:
+    one table for the embedding and the head, or (False) a table and a
+    separate ``head`` (``models/dalle.py``).  A rotary trunk takes no
+    position embedding from DALL-E's client.  Built from a plain dict (a
+    checkpoint's hparams, a benchmark configuration)."""
 
     mixers: Tuple[str, ...]
     ff_dim: int = 0
@@ -91,6 +110,16 @@ class TrunkSpec:
     lin_key_dim: int = 0
     lin_value_dim: int = 0
     lin_conv: int = 4
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    value_dim: int = 0
+    dense_layers: int = 0
+    experts_held: int = 0
+    experts_first: int = 0
+    shared_experts: int = 0
+    route_scale: float = 1.0
 
     def __post_init__(self):
         object.__setattr__(self, "mixers", tuple(self.mixers))
@@ -107,31 +136,75 @@ class TrunkSpec:
             self.lin_key_dim > 0 and self.lin_value_dim > 0), (
             f"'gdn' layers need lin_key_dim and lin_value_dim, which need "
             f"them: {self.mixers}, {self.lin_key_dim}, {self.lin_value_dim}")
+        latent = (self.q_rank, self.kv_rank, self.nope_dim, self.rope_dim,
+                  self.value_dim)
+        assert (all(d > 0 for d in latent) if "mla" in self.mixers
+                else not any(latent)), (
+            f"'mla' layers need q_rank, kv_rank, nope_dim, rope_dim and "
+            f"value_dim, which need them: {self.mixers}, {latent}")
         assert self.norm_at in NORM_AT, self.norm_at
         assert self.norm_at == "input" or (
-            "mamba" not in self.mixers and self.ff == "swiglu"), (
+            not {"mamba", "mla"} & set(self.mixers)
+            and self.ff == "swiglu"), (
             "norm_at = 'output' closes attention, 'gdn' and swiglu "
             f"sublayers only: {self.mixers}, ff {self.ff!r}")
-        if self.ff == "swiglu":
+        shared = self.ff == "moe_swiglu_shared"
+        assert shared or not (self.dense_layers or self.experts_held
+                              or self.experts_first or self.shared_experts
+                              or self.route_scale != 1.0), (
+            "dense_layers, experts_held, experts_first, shared_experts and "
+            f"route_scale belong to ff = 'moe_swiglu_shared', not {self.ff!r}")
+        if not self.routed or self.dense_layers:
             assert self.ff_dim > 0, "a swiglu feed-forward needs ff_dim"
-        else:
+        if self.routed:
             assert (0 < self.experts_per_token <= self.experts
                     and self.expert_dim > 0), (
-                f"moe_reglu needs experts >= experts_per_token > 0 and an "
+                f"{self.ff} needs experts >= experts_per_token > 0 and an "
                 f"expert_dim: {self.experts}, {self.experts_per_token}, "
                 f"{self.expert_dim}")
+            assert (self.experts_first >= 0 and self.experts_first
+                    + self.held_experts <= self.experts), (
+                f"experts held {self.experts_first}.."
+                f"{self.experts_first + self.held_experts} outside the "
+                f"router's {self.experts}")
 
     def mixer(self, layer: int) -> str:
         return self.mixers[layer % len(self.mixers)]
 
+    def ff_kind(self, layer: int) -> str:
+        """Layer ``layer``'s feed-forward: the leading ``dense_layers`` are
+        dense SwiGLUs, the rest ``ff``."""
+        return "swiglu" if layer < self.dense_layers else self.ff
+
     @property
     def rotary(self) -> bool:
         """Some layer rotates its queries and keys."""
-        return "window" in self.mixers
+        return any(is_rotary(kind) for kind in self.mixers)
 
     @property
     def routed(self) -> bool:
-        return self.ff == "moe_reglu"
+        """Some layer routes its tokens to experts."""
+        return is_routed(self.ff)
+
+    @property
+    def held_experts(self) -> int:
+        """Expert banks a routed layer holds here (all, unless told)."""
+        return self.experts_held or self.experts
+
+    @property
+    def scoring(self) -> str:
+        """How a routed layer scores its experts (ops/moe.py::route)."""
+        return "sigmoid" if self.ff == "moe_swiglu_shared" else "softmax"
+
+
+def is_rotary(kind: str) -> bool:
+    """A layer of this mixer kind rotates its queries and keys itself."""
+    return kind in ROTARY_MIXERS
+
+
+def is_routed(ff: str) -> bool:
+    """A feed-forward of this kind routes its tokens to experts."""
+    return ff in ROUTED_FFS
 
 
 def layer_mixers(trunk: Optional[TrunkSpec], depth: int) -> Tuple[str, ...]:
@@ -139,7 +212,8 @@ def layer_mixers(trunk: Optional[TrunkSpec], depth: int) -> Tuple[str, ...]:
     ``(k, v)`` over every position for "attention", ``(k, v)`` over a ring
     of the window's length for "window", ``(window, h)`` for "mamba",
     ``(window, S)`` for "gdn" (:func:`is_recurrent` tells the last two from
-    the first two)."""
+    the others), ``(c, k_rope)`` over every position for "mla"
+    (:func:`is_latent`: no head axis, the positions on axis 1)."""
     if trunk is None:
         return ("attention",) * depth
     return tuple(trunk.mixer(i) for i in range(depth))
@@ -150,6 +224,18 @@ def is_recurrent(kind: str) -> bool:
     (``(window, state)``: rows on axis 0, no position axis, replaced whole
     at every step), not a cache of keys and values."""
     return kind in RECURRENT_MIXERS
+
+
+def is_latent(kind: str) -> bool:
+    """A layer of this mixer kind caches one normed latent and one rotated
+    key a position (``(c [rows, slots, kv_rank], k_rope [rows, slots,
+    rope_dim])``: ops/latent_attention.py), not keys and values a head."""
+    return kind == "mla"
+
+
+def cache_position_axis(kind: str) -> int:
+    """The position axis of a non-recurrent layer's cache arrays."""
+    return 1 if is_latent(kind) else 2
 
 
 def layer_cache_lens(trunk: Optional[TrunkSpec], depth: int,
@@ -413,6 +499,43 @@ class TrunkLinearBlock(nn.Module):
         return self.gdn.init_state(batch)
 
 
+class TrunkLatentBlock(nn.Module):
+    """PreNorm(latent attention) of a :class:`TrunkSpec` trunk
+    (ops/latent_attention.py).  Its decode state ``(c, k_rope)`` rides where
+    an attention layer's ``(k, v)`` does and takes the same calls as
+    :class:`TrunkAttnBlock`; the arrays have no head axis (:func:`is_latent`)."""
+
+    pattern: AttnPattern
+    dim: int
+    heads: int
+    spec: TrunkSpec
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        spec = self.spec
+        self.norm = RMSNorm(spec.norm_eps, name="norm")
+        self.mla = LatentAttention(
+            pattern=self.pattern, dim=self.dim, heads=self.heads,
+            q_rank=spec.q_rank, kv_rank=spec.kv_rank, nope_dim=spec.nope_dim,
+            rope_dim=spec.rope_dim, value_dim=spec.value_dim,
+            rope_theta=spec.rope_theta, eps=spec.norm_eps, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="mla")
+
+    def _normed(self, x):
+        with prof.scope("mla-proj"):
+            return self.norm(x).astype(x.dtype)
+
+    def __call__(self, x, mask=None, deterministic: bool = True,
+                 return_kv: bool = False):
+        return self.mla(self._normed(x), mask=mask, return_kv=return_kv)
+
+    def decode_step(self, x, cache_c, cache_kr, index, mask=None,
+                    write_pos=None, qw=None):
+        return self.mla.decode_step(self._normed(x), cache_c, cache_kr,
+                                    index, mask=mask, write_pos=write_pos)
+
+
 class SwiGLUBlock(nn.Module):
     """PreNorm(``W_down(silu(W_gate h) * (W_up h))``) of a
     :class:`TrunkSpec` trunk: RMSNorm, no bias, no LayerScale; without
@@ -473,6 +596,36 @@ class TrunkMoEBlock(nn.Module):
         return self.moe(normed, router_logits)
 
 
+class TrunkSharedMoEBlock(nn.Module):
+    """PreNorm(sigmoid-routed SwiGLU experts + shared experts) of a
+    :class:`TrunkSpec` trunk (ops/moe.py::ExpertsSwiGLUShared), on the
+    experts the spec says are held here.  The router reads the sublayer's
+    normed input, so the block takes the hidden state alone, as
+    :class:`SwiGLUBlock` does."""
+
+    dim: int
+    spec: TrunkSpec
+    dtype: Any = jnp.float32
+    param_dtype: Any = jnp.float32
+
+    def setup(self):
+        from .moe import ExpertsSwiGLUShared
+
+        spec = self.spec
+        self.norm = RMSNorm(spec.norm_eps, name="norm")
+        self.moe = ExpertsSwiGLUShared(
+            dim=self.dim, experts=spec.experts, k=spec.experts_per_token,
+            expert_dim=spec.expert_dim, held=spec.held_experts,
+            first=spec.experts_first, shared=spec.shared_experts,
+            scale=spec.route_scale, dtype=self.dtype,
+            param_dtype=self.param_dtype, name="moe")
+
+    def __call__(self, x, deterministic: bool = True):
+        with prof.scope("moe-route"):
+            normed = self.norm(x).astype(x.dtype)
+        return self.moe(normed)
+
+
 class MoEFFBlock(nn.Module):
     """LayerScale(PreNorm(MoE feed-forward)) — the FFBlock with its GEGLU
     swapped for a top-k routed expert mixture (ops/moe.py).  The switch
@@ -517,10 +670,11 @@ class MoEFFBlock(nn.Module):
 class Transformer(nn.Module):
     """Depth x (attn, ff) residual stack with cycled attention variants
     (ref transformer.py:71-123); with a ``trunk`` (:class:`TrunkSpec`),
-    depth x (mixer, feed-forward) with each layer's mixer global or windowed
-    attention, Mamba or gated-delta-rule linear attention and its
-    feed-forward a SwiGLU or routed experts, the norm on each sublayer's
-    input or on its output."""
+    depth x (mixer, feed-forward) with each layer's mixer global, windowed
+    or latent attention, Mamba or gated-delta-rule linear attention and its
+    feed-forward a SwiGLU or routed experts (of either kind, after the
+    spec's leading dense layers), the norm on each sublayer's input or on
+    its output."""
 
     dim: int
     depth: int
@@ -592,6 +746,10 @@ class Transformer(nn.Module):
                     attn_blocks.append(TrunkLinearBlock(
                         heads=self.heads, spec=spec, prenorm=prenorm,
                         name=f"layers_{ind}_gdn", **kw))
+                elif is_latent(kind):
+                    attn_blocks.append(TrunkLatentBlock(
+                        pattern=pattern, heads=self.heads, spec=spec,
+                        name=f"layers_{ind}_attn", **kw))
                 else:
                     windowed = kind == "window"
                     attn_blocks.append(TrunkAttnBlock(
@@ -603,8 +761,12 @@ class Transformer(nn.Module):
                         rope_theta=spec.rope_theta if windowed else None,
                         prenorm=prenorm, qk_norm=spec.qk_norm,
                         name=f"layers_{ind}_attn", **kw))
-                if spec.routed:
+                ff_kind = spec.ff_kind(ind)
+                if ff_kind == "moe_reglu":
                     ff_blocks.append(TrunkMoEBlock(
+                        spec=spec, name=f"layers_{ind}_ff", **kw))
+                elif ff_kind == "moe_swiglu_shared":
+                    ff_blocks.append(TrunkSharedMoEBlock(
                         spec=spec, name=f"layers_{ind}_ff", **kw))
                 else:
                     ff_blocks.append(SwiGLUBlock(
@@ -693,7 +855,10 @@ class Transformer(nn.Module):
             telemetry.emit("moe", "route", tokens=x.shape[0] * x.shape[1],
                            experts=self.trunk.experts,
                            k=self.trunk.experts_per_token,
-                           layers=self.depth)
+                           layers=self.depth - self.trunk.dense_layers,
+                           scoring=self.trunk.scoring,
+                           experts_held=self.trunk.held_experts,
+                           shared_experts=self.trunk.shared_experts)
         use_remat = (self.use_remat and not self.is_initializing()
                      and not return_kv)
         remat_block = nn.remat(
@@ -776,8 +941,9 @@ class Transformer(nn.Module):
         """Zeroed decode state, one pair per layer: ``(k, v)`` ``[b, kv
         heads, slots, dh]`` for an attention layer, ``slots`` its own
         (:attr:`cache_lens`: ``seq_len``, or a ring of the window's length),
-        the block's own ``(window, state)`` for a recurrent one (ops/ssm.py,
-        ops/linear_attention.py)."""
+        ``(c [b, slots, kv_rank], k_rope [b, slots, rope_dim])`` for a latent
+        one, the block's own ``(window, state)`` for a recurrent one
+        (ops/ssm.py, ops/linear_attention.py)."""
         dtype = dtype or self.dtype
         kv_heads = self.heads if self.trunk is None else self.trunk.kv_heads
 
@@ -786,7 +952,9 @@ class Transformer(nn.Module):
             return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
         return [
-            blk.init_state(batch) if is_recurrent(kind) else pair(slots)
+            blk.init_state(batch) if is_recurrent(kind) else
+            blk.mla.init_cache(batch, slots, dtype) if is_latent(kind) else
+            pair(slots)
             for blk, kind, slots in zip(self.attn_blocks, self.mixers,
                                         self.cache_lens)
         ]
@@ -794,8 +962,9 @@ class Transformer(nn.Module):
     def lane_dense_caches(self, caches):
         """Per-layer caches as ``decode_codes``' scan should carry them
         (MultiHeadAttention.lane_dense_cache): :meth:`decode_step` takes
-        either layout, told by the shape."""
-        return [(ck, cv) if is_recurrent(kind) else
+        either layout, told by the shape.  A latent pair has no head to fold
+        and passes as it is, like a recurrent state."""
+        return [(ck, cv) if is_recurrent(kind) or is_latent(kind) else
                 (blk.attn.lane_dense_cache(ck), blk.attn.lane_dense_cache(cv))
                 for blk, kind, (ck, cv) in zip(self.attn_blocks, self.mixers,
                                                caches)]
@@ -804,14 +973,16 @@ class Transformer(nn.Module):
         """Per layer, the form the serving arena stores its caches of
         ``dtype`` in (MultiHeadAttention.arena_form); None for a layer that
         carries a recurrent state."""
-        return [None if is_recurrent(kind) else blk.attn.arena_form(dtype)
+        return [None if is_recurrent(kind) else
+                (blk.mla if is_latent(kind) else blk.attn).arena_form(dtype)
                 for blk, kind in zip(self.attn_blocks, self.mixers)]
 
     def dense_read_bounds(self):
         """Per layer, the prefixes its decode step's dense cache read chooses
         among (MultiHeadAttention.dense_read_bounds); None for a layer that
         reads slices or carries a recurrent state."""
-        return [None if is_recurrent(kind) else blk.attn.dense_read_bounds()
+        return [None if is_recurrent(kind) else
+                (blk.mla if is_latent(kind) else blk.attn).dense_read_bounds()
                 for blk, kind in zip(self.attn_blocks, self.mixers)]
 
     def decode_step(self, x, caches, index, mask=None, write_pos=None,

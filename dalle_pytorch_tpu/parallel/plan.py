@@ -55,6 +55,14 @@ TRUNK_RULES: Tuple[Tuple[str, P], ...] = (
     # [dim, 2, kv_heads, dh] stay whole on every tp shard
     (r".*attn/to_q/kernel$", P("fsdp", "tp", None)),
     (r".*attn/to_kv/kernel$", P("fsdp", None, None, None)),
+    # latent attention: the two down-projections [dim, rank] feed norms over
+    # their whole width, so they are replicated in (fsdp on the model
+    # width alone); heads are independent from the up-projections on, so
+    # w_qb [q_rank, heads, d], w_kvb [kv_rank, heads, d] column-parallel and
+    # w_o [heads, d_v, dim] row-parallel over tp
+    (r".*attn/mla/(w_qa|w_kva)$", P("fsdp", None)),
+    (r".*attn/mla/(w_qb|w_kvb)$", P(None, "tp", None)),
+    (r".*attn/mla/w_o$", P("tp", None, "fsdp")),
     # Mamba mixer: the d_in channels are independent through the
     # convolution and the scan, so they split over tp — in_proj [dim, 2,
     # d_in] and dt_proj [R, d_in] column-parallel, x_proj [d_in, R + 2N] and
@@ -83,6 +91,9 @@ TRUNK_RULES: Tuple[Tuple[str, P], ...] = (
     # them like everything else
     (r".*ff/moe/(w_gate|w_up|w_down)$", P(None, None, None)),
     (r".*ff/moe/w_router$", P(None, None)),
+    # the shared expert beside them is a SwiGLU: as ff/(gate|up|down)
+    (r".*ff/moe/shared_(gate|up)$", P("fsdp", "tp")),
+    (r".*ff/moe/shared_down$", P("tp", "fsdp")),
     # the table [vocab, dim], tied or with its separate head of the same
     # shape: as the token embeddings below
     (r".*table/embedding$", P("fsdp", "tp")),

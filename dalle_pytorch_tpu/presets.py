@@ -31,6 +31,16 @@ smallthinker-21ba3b  (2.37B, dim-2560)  DALL-E over the first period (4
                               of 52 layers) of SmallThinker-21BA3B's
                               trunk, all 64 experts of each, bf16; one
                               chip generates
+glm-flash-tiny  (~0.1M, dim-32)  latent attention, a leading dense
+                              layer, sigmoid-routed SwiGLU experts with a
+                              shared expert, 2 of 8 held, at toy width
+                              (tests)
+glm-4.7-flash  (1.15B, dim-2048)  DALL-E over one chip's share of
+                              GLM-4.7-Flash's trunk (5 of 47 layers, 8 of
+                              64 experts a layer held: eight chips share
+                              each layer), bf16; one chip generates 128
+                              candidates of n = 4,352 over a 3.2 GB
+                              latent cache
 ==========  ======  ========  =======================================
 
 ``cub-512`` and ``cub-1024`` are ALSO :data:`~dalle_pytorch_tpu.parallel.
@@ -66,6 +76,8 @@ PARAM_BANDS = {
     "smallthinker-21ba3b": (2.3e9, 2.45e9),
     "olmo-hybrid-tiny": (0.01e6, 1e6),
     "olmo-hybrid-7b": (2.4e9, 2.47e9),
+    "glm-flash-tiny": (0.01e6, 1e6),
+    "glm-4.7-flash": (1.1e9, 1.2e9),
 }
 
 
@@ -300,6 +312,67 @@ def olmo_hybrid_7b_config(**overrides):
     return DALLEConfig(**base)
 
 
+#: GLM-4.7-Flash's trunk (huggingface.co/zai-org/GLM-4.7-Flash, config.json,
+#: ``glm4_moe_lite``): 47 layers of multi-head latent attention (20 heads;
+#: queries through a normed 768 bottleneck; one normed 512 latent and one
+#: shared 64-wide rotary key a position; 192 + 64 query/key and 256 value
+#: dimensions a head; theta 1e6); layer 0 a dense SwiGLU of 10,240, the rest
+#: 64 sigmoid-routed SwiGLU experts of 1,536, 4 a token, weights renormalised
+#: and scaled by 1.8, beside one shared expert; an untied head.  HERE: one
+#: chip's share of a deployment in which eight chips share each layer, the
+#: 64 experts split 8 a chip (``experts_held``, experts 0-7).
+GLM_4_7_FLASH_TRUNK = dict(
+    mixers=("mla",), ff_dim=10240, norm="rms", norm_eps=1e-5,
+    ff="moe_swiglu_shared", rope_theta=1000000.0, q_rank=768, kv_rank=512,
+    nope_dim=192, rope_dim=64, value_dim=256, dense_layers=1, experts=64,
+    experts_per_token=4, expert_dim=1536, experts_held=8, experts_first=0,
+    shared_experts=1, route_scale=1.8, tied_table=False,
+    param_dtype="bfloat16")
+
+
+def glm_flash_tiny_config(**overrides):
+    """The same trunk at toy width with every mechanism (tests): one dense
+    and two routed layers, 8 experts of which 2 are held, 2 a token, the
+    ranks and the parts of a head all of different sizes."""
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=32, depth=3, heads=4, dim_head=8, num_text_tokens=50,
+                text_seq_len=8, num_image_tokens=32, image_size=64,
+                image_fmap_size=4,
+                trunk=dict(GLM_4_7_FLASH_TRUNK, ff_dim=80, q_rank=24,
+                           kv_rank=20, nope_dim=12, rope_dim=4, value_dim=10,
+                           experts=8, experts_per_token=2, expert_dim=24,
+                           experts_held=2, experts_first=2,
+                           param_dtype="float32"))
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
+def glm_4_7_flash_config(**overrides):
+    """DALL-E's client over one chip's share of GLM-4.7-Flash's trunk, every
+    width as published: the leading dense layer and four of the 46 routed
+    layers that follow (5 of 47), each routed layer's router over all 64
+    experts and the banks of experts 0-7 (8 of 64: eight chips share each
+    layer), the shared expert, the whole 154,880-row table and head: 1.146B
+    parameters, 2.29 GB in bfloat16.  The rows are 146,432 text ids + 256
+    per-position pad ids + 8,192 image codes of a 512 px, 64 x 64 code grid
+    (n = 4,352).  ``dim_head`` is the 256 query/key dimensions of a head
+    (192 unrotated + 64 rotated).  ``benchmark/configs/glm-4.7-flash.json``
+    is the same model as the benchmark runs it; the next-token-prediction
+    layer is not held."""
+    import jax.numpy as jnp
+
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=2048, depth=5, heads=20, dim_head=256,
+                num_text_tokens=146432, text_seq_len=256,
+                num_image_tokens=8192, image_size=512, image_fmap_size=64,
+                attn_types=("full",), trunk=GLM_4_7_FLASH_TRUNK,
+                dtype=jnp.bfloat16)
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
 #: Every named config geometry (CLI ``--preset`` surface).
 CONFIG_PRESETS = {
     "tiny": tiny_config,
@@ -312,6 +385,8 @@ CONFIG_PRESETS = {
     "smallthinker-21ba3b": smallthinker_21ba3b_config,
     "olmo-hybrid-tiny": olmo_hybrid_tiny_config,
     "olmo-hybrid-7b": olmo_hybrid_7b_config,
+    "glm-flash-tiny": glm_flash_tiny_config,
+    "glm-4.7-flash": glm_4_7_flash_config,
 }
 
 #: The scale rungs that are ALSO plan-registry entries: registry name ->

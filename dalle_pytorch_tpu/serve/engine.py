@@ -68,6 +68,12 @@ This module is the device half of the fix:
   p in slot ``p mod ring``: tied to the row's own positions, not to the
   arena clock, so it is written per row and read whole
   (``MultiHeadAttention._decode_step_ring``); ``admit`` installs it unrolled.
+* **Latent slots.**  A latent-attention layer (``"mla"``,
+  ops/latent_attention.py) keeps ``(c, k_rope)`` per slot, ``[num_slots,
+  seq_len, kv_rank]`` and ``[num_slots, seq_len, rope_dim]``: no head axis
+  to fold, the positions on axis 1 (``LatentAttention.arena_form``: one more
+  answer of the one rule).  It is rotated, installed and written at the
+  shared column like a key/value cache.
 * **Recurrent entries beside the caches.**  A state-space layer
   (``DALLEConfig.trunk``) keeps ``(window, h)`` per slot, ``[num_slots,
   ...]`` with no position axis: nothing to rotate and nothing a mask could
@@ -97,7 +103,7 @@ from ..models.dalle import (DALLE, prefill_codes, quantize_decode_weights,
                             sample_image_code)
 from ..obs import metrics, prof, telemetry
 from ..ops.quant import cache_values, split_cache
-from ..ops.transformer import is_recurrent
+from ..ops.transformer import is_latent, is_recurrent
 
 
 #: ``%name = bf16[128,4,1104,128]{3,2,1,0:T(8,128)(2,1)} copy(`` in a compiled
@@ -168,6 +174,10 @@ class SlotArena:
                              else cfg.dtype)
         S = num_slots
         recurrent = [is_recurrent(kind) for kind in cfg.mixers]
+        # a latent layer's slot holds ``(c, k_rope)`` with no head axis, the
+        # positions on axis 1: allocated by the model itself like a
+        # recurrent state, rotated and written like a cache
+        latent = [is_latent(kind) for kind in cfg.mixers]
         # a window layer's slot holds a ring of its own length, position p
         # in slot p mod ring whatever the arena's clock: rows at different
         # depths cannot share a write column there, so such a layer takes
@@ -202,12 +212,12 @@ class SlotArena:
             # an attention layer's cache
             # from the geometry above
             zero = (dalle.apply(variables, S, method=DALLE.decode_init_state)
-                    if any(recurrent) else [None] * cfg.depth)
+                    if any(recurrent) or any(latent) else [None] * cfg.depth)
             return dict(
-                caches=[entry if rec else (fresh_entry(slots, form),
-                                           fresh_entry(slots, form))
-                        for rec, entry, slots, form in zip(
-                            recurrent, zero, cfg.cache_lens, forms)],
+                caches=[entry if rec or lat else (fresh_entry(slots, form),
+                                                  fresh_entry(slots, form))
+                        for rec, lat, entry, slots, form in zip(
+                            recurrent, latent, zero, cfg.cache_lens, forms)],
                 code=jnp.zeros((S,), jnp.int32),
                 index=jnp.zeros((S,), jnp.int32),
                 pos=jnp.zeros((S,), jnp.int32),
@@ -266,19 +276,22 @@ class SlotArena:
             rot = jnp.remainder(write_pos - jnp.int32(n_pre),
                                 jnp.int32(self.geometry.seq_len))
 
-            def install(form, arena_entry, new_entry):
+            def install(form, lat, arena_entry, new_entry):
                 """Bring the prefilled values into the stored ``form``, roll
                 them into the slot's rotation and write them: one DUS of the
                 slot's own rows, one run of memory with the slot axis major.
                 Int8 entries also carry the slot's per-head scale plane
                 across — scales are write-position-invariant, so only the
-                values roll."""
+                values roll.  A latent array (``lat``: no head axis) is
+                prefilled in the form it is stored in."""
                 vals, scale = split_cache(arena_entry)
                 new_vals, new_scale = split_cache(new_entry)
+                new_vals = new_vals.astype(vals.dtype)
+                if not lat:
+                    new_vals = form.store(new_vals)
                 vals = jax.lax.dynamic_update_slice(
-                    vals, jnp.roll(form.store(new_vals.astype(vals.dtype)),
-                                   rot, axis=form.position_axis),
-                    (slot, 0, 0, 0))
+                    vals, jnp.roll(new_vals, rot, axis=form.position_axis),
+                    (slot,) + (0,) * (vals.ndim - 1))
                 if scale is None:
                     return vals
                 return (vals, jax.lax.dynamic_update_slice(
@@ -293,10 +306,11 @@ class SlotArena:
                     (slot,) + (0,) * (arena_entry.ndim - 1))
 
             caches = [tuple(map(install_whole if rec or rng
-                                else functools.partial(install, form),
+                                else functools.partial(install, form, lat),
                                 old, new))
-                      for rec, rng, form, old, new in zip(
-                          recurrent, ring, forms, state["caches"], caches1)]
+                      for rec, rng, lat, form, old, new in zip(
+                          recurrent, ring, latent, forms, state["caches"],
+                          caches1)]
             ks = jax.random.split(key, self.geometry.image_seq_len)
             code0 = sample_one(first_logits[0], ks[0], temp)
 
@@ -570,14 +584,16 @@ class SlotArena:
         the tick.  ``folded_layers`` / ``plain_layers``: rotated key/value
         caches stored head-folded (ops/attention.py::MultiHeadAttention.
         arena_form) / as ``[slots, kv heads, n, dim_head]``;
-        ``ring_layers`` / ``recurrent_layers``: sliding-window rings /
-        recurrent entries; ``install_bytes_per_slot``: what an admission
+        ``ring_layers`` / ``latent_layers`` / ``recurrent_layers``:
+        sliding-window rings / latent pairs (one latent and one rotated key
+        a position, no head axis) / recurrent entries; ``install_bytes_per_slot``: what an admission
         writes of the caches, one slot's rows; ``tick_relayout_bytes``
         (:func:`relayout_bytes`): cache-sized ``copy`` / ``transpose``
         results in the COMPILED tick, compiled here for the backend the
         arena runs on."""
         forms = [form for form in self._forms if form is not None]
         rings = self.dalle.cfg.mixers.count("window")
+        latent = sum(map(is_latent, self.dalle.cfg.mixers))
         folded = sum(form.fold > 1 for form in forms)
         sizes = {cache_values(entry).size
                  for form, pair in zip(self._forms, self.state["caches"])
@@ -585,8 +601,10 @@ class SlotArena:
         return {
             "slots": self.geometry.num_slots,
             "folded_layers": folded,
-            "plain_layers": len(forms) - folded - rings,
+            "plain_layers": len(forms) - folded - rings - latent,
             "ring_layers": rings,
+            # only where there are any: other models' records stay as they were
+            **({"latent_layers": latent} if latent else {}),
             "recurrent_layers": len(self._forms) - len(forms),
             "install_bytes_per_slot": sum(
                 leaf.nbytes for leaf in jax.tree.leaves(self.state["caches"])

@@ -1028,3 +1028,44 @@ def test_train_vae_sharded_checkpoints_and_resume(tiny_dataset, tmp_path,
     train_vae.main(["--image_folder", str(tiny_dataset), "--image_size", "16",
                     "--resume_path", str(final), "--sharded_checkpoints"])
     assert int(load_checkpoint(final)["epoch"]) == 2
+
+
+def test_train_then_generate_over_a_latent_attention_trunk(
+        trained_vae, tiny_dataset, tiny_tokenizer_json, tmp_path_factory):
+    """`train_dalle.py --trunk glm-flash-tiny` trains two steps of DALL-E
+    over latent attention, a leading dense layer and sigmoid-routed experts
+    with a shared one (2 of 8 held); the spec rides in the checkpoint's
+    hparams, so `generate.py` rebuilds the model from the checkpoint alone
+    and samples through the latent cache."""
+    wd = tmp_path_factory.mktemp("latent_cli")
+    _run_train_dalle(wd, dict(BATCH_SIZE=6, TEXT_SEQ_LEN=8),
+                     ["--trunk", "glm-flash-tiny"], trained_vae,
+                     tiny_dataset, tiny_tokenizer_json)
+    from dalle_pytorch_tpu.utils.checkpoint import load_checkpoint
+
+    ckpt = load_checkpoint(wd / "dalle-final.pt")
+    trunk = ckpt["hparams"]["trunk"]
+    assert (trunk["mixers"], trunk["ff"], trunk["dense_layers"],
+            trunk["experts"], trunk["experts_held"], trunk["experts_first"],
+            trunk["kv_rank"]) == (["mla"], "moe_swiglu_shared", 1, 8, 2, 2,
+                                  20)
+    weights = ckpt["weights"]["transformer"]
+    assert "gate" in weights["layers_0_ff"]               # the dense layer
+    assert weights["layers_1_ff"]["moe"]["w_gate"].shape[0] == 2
+    assert weights["layers_1_attn"]["mla"]["w_kvb"].shape == (20, 4, 22)
+    assert np.isfinite(_first_loss(wd))
+    cwd = os.getcwd()
+    os.chdir(wd)
+    try:
+        import generate
+
+        generate.main(["--dalle_path", str(wd / "dalle-final.pt"),
+                       "--text", "red bird", "--num_images", "2",
+                       "--batch_size", "2",
+                       "--bpe_path", str(tiny_tokenizer_json),
+                       "--outputs_dir", str(wd / "outputs")])
+    finally:
+        os.chdir(cwd)
+    images = list((wd / "outputs").rglob("*.jpg")) + list(
+        (wd / "outputs").rglob("*.png"))
+    assert len(images) == 2

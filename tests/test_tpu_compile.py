@@ -502,6 +502,39 @@ def test_generate_b8_compiles(one_chip):
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)).compile()
 
 
+def test_the_absorbed_latent_tick_compiles_and_decompresses_nothing(one_chip):
+    """One latent-attention layer at GLM-4.7-Flash's published widths, 8 rows
+    against a 4,352-slot cache (ops/latent_attention.py): the tick compiles
+    for the v5e, holds one branch a bucket of ``read_bounds`` and no array as
+    long as the cache and as wide as the heads' decompressed keys and values
+    (20 x 448): it reads the latent in the absorbed form."""
+    from dalle_pytorch_tpu.ops.attention import read_bounds
+    from dalle_pytorch_tpu.ops.latent_attention import LatentAttention
+
+    rows, slots, dim = 8, 4352, 2048
+    layer = LatentAttention(
+        pattern=AttnPattern(variant="full", seq_len=slots, text_len=257,
+                            fmap=64, causal=True),
+        dim=dim, heads=20, q_rank=768, kv_rank=512, nope_dim=192,
+        rope_dim=64, value_dim=256, rope_theta=1e6, eps=1e-5,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((rows, 1, dim), jnp.bfloat16, sharding=one_chip)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4, dim), jnp.bfloat16))
+    caches = jax.eval_shape(lambda: layer.init_cache(rows, slots,
+                                                     jnp.bfloat16))
+    compiled = jax.jit(lambda p, x, c, kr, i: layer.apply(
+        p, x, c, kr, i, method=LatentAttention.decode_step)).lower(
+            _on(one_chip, params), x, *_on(one_chip, caches),
+            _scalar(one_chip, jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(read_bounds(slots)) == 7 and text.count(" conditional(") == 1
+    for decompressed in ("4352,20,448", "20,4352,448", "4352,8960",
+                         "4352,20,192", "4352,20,256"):
+        assert decompressed not in text, decompressed
+    assert f"bf16[{rows},{slots},512]" in text
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("spec", chip_smoke.FULL.plan_specs)
 def test_sharded_step_compiles_for_four_chips(topo, spec):
