@@ -730,20 +730,24 @@ class DALLE(nn.Module):
         return self.transformer.decode_init_cache(
             batch, jnp.bfloat16 if cfg.kv_cache_bf16 else cfg.dtype)
 
-    def lane_dense_caches(self, caches):
+    def lane_dense_caches(self, caches, masked: bool = False):
         """The prefill's caches as ``decode_codes``' scan carries them
         (ops/transformer.py::Transformer.lane_dense_caches)."""
-        return self.transformer.lane_dense_caches(caches)
+        return self.transformer.lane_dense_caches(caches, masked)
 
     def arena_forms(self, dtype):
         """Per layer, the form the serving arena stores its caches in
         (ops/transformer.py::Transformer.arena_forms)."""
         return self.transformer.arena_forms(dtype)
 
-    def dense_read_bounds(self):
-        """Per layer, the prefixes the decode step's dense cache read
-        chooses among (ops/transformer.py::Transformer.dense_read_bounds)."""
-        return self.transformer.dense_read_bounds()
+    def dense_read_bounds(self, masked: bool = False):
+        """Per layer, the prefixes the decode step's dense cache read ends
+        at (ops/transformer.py::Transformer.dense_read_bounds), the caches
+        in the prefill's storage dtype; ``masked``: of a call with a
+        key-padding mask."""
+        cfg = self.cfg
+        return self.transformer.dense_read_bounds(
+            jnp.bfloat16 if cfg.kv_cache_bf16 else cfg.dtype, masked)
 
     def decode_step(self, code, caches, index, mask=None, write_pos=None,
                     qweights=None):
@@ -956,17 +960,20 @@ def tile_prefill(first_logits, caches, reps: int):
     return broadcast_prefill(first_logits, caches, reps)
 
 
-def _kv_reach(dalle: DALLE, params, caches, n_pre: int) -> dict:
+def _kv_reach(dalle: DALLE, params, caches, n_pre: int,
+              masked: bool = False) -> dict:
     """What a ``decode_codes`` call's bounded cache reads come to
-    (ops/attention.py::MultiHeadAttention._masked_read), from static
-    shapes: the attention layers whose dense read chooses among several
-    prefixes and those that read as before (slices, or a cache of one
-    bucket), the prefixes over all layers, and ``read_share``: over the
-    dense-read layers, weighted by their caches' bytes, the mean over the
-    call's ticks (positions ``n_pre`` to the last) of slots read over slots
-    held; 1.0 where no layer reads densely."""
+    (ops/attention.py::MultiHeadAttention._masked_read; a latent layer's
+    one-pass kernel or two-pass read, ops/latent_attention.py), from static
+    shapes: the layers whose dense read ends at one of several prefixes (a
+    ``lax.switch``'s buckets, or the ends of the kernel's blocks) and those
+    that read as before (slices, or a cache of one bucket), the prefixes
+    over all layers, and ``read_share``: over the dense-read layers,
+    weighted by their caches' bytes, the mean over the call's ticks
+    (positions ``n_pre`` to the last) of slots read over slots held; 1.0
+    where no layer reads densely."""
     cfg = dalle.cfg
-    bounds = dalle.apply(params, method=DALLE.dense_read_bounds)
+    bounds = dalle.apply(params, masked, method=DALLE.dense_read_bounds)
     ticks = np.arange(n_pre, cfg.seq_len)
     read = held = 0.0
     for layer, cache in zip(bounds, caches):
@@ -987,12 +994,15 @@ def _kv_reach(dalle: DALLE, params, caches, n_pre: int) -> dict:
             "read_share": read / held if held else 1.0}
 
 
-def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
+def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int,
+                       masked: bool = False):
     """``caches`` with the dense-read layers' entries head-folded wherever
     XLA:TPU would pad the plain layout to the lanes
     (ops/attention.py::kv_fold_factor): one relayout a call, so that every
     tick of the scan reads each cache byte once.  Recurrent entries pass as
-    they are.  A static choice, so its counters are per trace: a
+    they are; a latent pair rides as one array where its read takes one
+    pass (ops/latent_attention.py::fold_latent; not where the call is
+    ``masked``).  A static choice, so its counters are per trace: a
     ``decode.kv_layout`` record and two gauges say how many attention
     layers' caches were folded and how many kept plain, a
     ``decode.state_layout`` record and four gauges how many layers carry
@@ -1012,7 +1022,8 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
 
     cfg = dalle.cfg
     with prof.scope("attn-cache"):
-        folded = dalle.apply(params, caches, method=DALLE.lane_dense_caches)
+        folded = dalle.apply(params, caches, masked,
+                             method=DALLE.lane_dense_caches)
     latent = [i for i, kind in enumerate(cfg.mixers) if is_latent(kind)]
     attn = [i for i, kind in enumerate(cfg.mixers)
             if not (is_recurrent(kind) or is_latent(kind))]
@@ -1031,20 +1042,23 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
                 for a in jax.tree.leaves(caches)) // rows}}
     if latent:
         # a latent layer holds one pair a position and no head axis: what a
-        # position costs as the mathematics counts it, and as the arrays
-        # are handed over (each minor dimension padded to the lanes: 64
-        # rotary values take a whole tile row; inside the scan the v5e's
-        # compiler puts the positions on the lanes and pads nothing,
-        # PERF.md PR 38)
-        pair = caches[latent[0]]
+        # position costs as the mathematics counts it, and as the scan
+        # carries the arrays row-major (each minor dimension padded to the
+        # lanes: 64 rotary values alone take a whole tile row; two
+        # positions folded into one row of the layer's one array fill
+        # theirs, LatentAttention.lane_dense_cache; the unfolded pair the
+        # v5e's compiler lays out itself inside the scan, the positions on
+        # the lanes, PERF.md PR 38)
+        slots = caches[latent[0]][0].shape[1]
         records["kv_layout"]["kv_latent_layers"] = len(latent)
         records["state_layout"].update(
             latent_layers=len(latent),
             latent_bytes_per_position=sum(
-                a.shape[-1] * a.dtype.itemsize for a in pair),
+                a.shape[-1] * a.dtype.itemsize for a in caches[latent[0]]),
             latent_bytes_walked_per_position=sum(
                 -(-a.shape[-1] // LANES) * LANES * a.dtype.itemsize
-                for a in pair))
+                * a.shape[1] // slots
+                for a in jax.tree.leaves(folded[latent[0]])))
     # the shape one row of a linear-attention state is carried in: said in
     # the state_layout record, no gauge
     state_shape = ({"linear_state_shape": list(caches[linear[0]][1].shape[1:])}
@@ -1057,7 +1071,7 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int):
             for name, value in counts.items():
                 reg.gauge(f"graft_decode_{name}",
                           f"decode_codes' last trace ({record})").set(value)
-    reach = _kv_reach(dalle, params, caches, n_pre)
+    reach = _kv_reach(dalle, params, caches, n_pre, masked)
     telemetry.emit("decode", "kv_reach", rows=rows, **reach)
     if reg is not None:
         for name in ("bounded_layers", "read_share"):
@@ -1129,7 +1143,8 @@ def decode_codes(dalle: DALLE, params, first_logits, caches, rng, *,
                     if cfg.weights_int8 else None)
         rng, key0 = jax.random.split(rng)
         first_code = sample(first_logits, key0)
-        caches = _lane_dense_caches(dalle, params, caches, n_pre)
+        caches = _lane_dense_caches(dalle, params, caches, n_pre,
+                                    masked=mask is not None)
 
         num_steps = cfg.seq_len - n_pre  # remaining image positions
         keys = (jax.random.split(rng, num_steps) if num_steps > 0

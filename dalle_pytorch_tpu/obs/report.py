@@ -400,6 +400,11 @@ def build_report(events: List[dict]) -> dict:
             k: kernel[-1].get(k) for k in
             ("model", "n", "tiles", "flash_layers", "dense_layers",
              "blocks_computed_share", "heads_per_program", "hbm_pad_rows")}}
+        # latent layers say how a tick of theirs reads the cache; the line
+        # stands under `-- decode --`, beside the latent cache's
+        if kernel[-1].get("latent_read") and "latent" in (decode_report or {}):
+            decode_report["latent"].update(
+                {k: kernel[-1].get(k) for k in ("latent_read", "block")})
 
     # --- memory: predicted vs measured --------------------------------------
     # MemTracker emits `mem.watermark` at phase boundaries (obs/mem.py)
@@ -744,7 +749,11 @@ def render_text(report: dict) -> str:
             lines.append(
                 f"latent cache: {lat.get('latent_layers')} layers, "
                 f"{lat.get('latent_bytes_per_position')} bytes a position "
-                f"({lat.get('latent_bytes_walked_per_position')} as stored)")
+                f"({lat.get('latent_bytes_walked_per_position')} as stored)"
+                + ({"one_pass": f"; read in one pass, {lat.get('block')} "
+                                f"positions a block",
+                    "two_pass": "; read in two passes"}.get(
+                        lat.get("latent_read"), "")))
         if "moe" in dec:
             m = dec["moe"]
             lines.append(

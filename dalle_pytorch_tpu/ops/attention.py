@@ -605,13 +605,13 @@ def record_kernel_choices(model: str) -> Iterator[None]:
             # latent-attention layers (ops/latent_attention.py) run their own
             # published form over a sequence: counted apart, and only where
             # there are any, so that other models' records stay as they were
-            latent = sum(c.get("latent", False) for c in layers)
+            latent = [c for c in layers if c.get("latent", False)]
             computed = sum(c["computed"] for c in flash)
             blocks = sum(c["blocks"] for c in flash)
             counts = {
                 "flash_layers": len(flash),
-                "dense_layers": len(layers) - len(flash) - latent,
-                **({"latent_layers": latent} if latent else {}),
+                "dense_layers": len(layers) - len(flash) - len(latent),
+                **({"latent_layers": len(latent)} if latent else {}),
                 "blocks_computed_share":
                     round(computed / blocks, 4) if blocks else 0.0,
                 "heads_per_program":
@@ -622,7 +622,12 @@ def record_kernel_choices(model: str) -> Iterator[None]:
                 "attention", "kernel", model=model,
                 n=max(c["n"] for c in layers),
                 tiles=sorted({"x".join(map(str, c["tiles"])) for c in flash}),
-                **counts)
+                **counts,
+                # how a tick of the latent layers reads their cache: one
+                # pass in a kernel, by blocks of so many positions, or two
+                # in plain XLA (ops/latent_attention.py::one_pass_read)
+                **({k: latent[0][k] for k in ("latent_read", "block")}
+                   if latent else {}))
             reg = metrics.active()
             if reg is not None:
                 for name, value in counts.items():

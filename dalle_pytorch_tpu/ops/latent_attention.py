@@ -36,30 +36,124 @@ rope_dim) x 2`` FLOPs, as batched products of ``heads`` query rows.
 The decode state of a layer is the pair ``(c [rows, slots, kv_rank], k_rope
 [rows, slots, rope_dim])``: normed, rotated, no head axis, the position axis
 second.  It rides where an attention layer's ``(k, v)`` does
-(ops/transformer.py::TrunkLatentBlock); the static sampler's read is bounded
-by the position (:func:`~dalle_pytorch_tpu.ops.attention.read_bounds`, the
-rule of every dense-read cache), the serving arena's runs phase-aligned over
-rotated slots (``write_pos``).  Scopes: ``mla-proj`` (the five products, the
-two norms, the rotation, ``q_lat`` and ``o_lat W_uv``), ``mla-read`` (scores
-over the latent, softmax, the weighted sum of ``c``); the cache write stays
-under ``attn-cache``.
+(ops/transformer.py::TrunkLatentBlock); the serving arena's read runs
+phase-aligned over rotated slots (``write_pos``), the static sampler's is
+bounded by the position, in one of two ways:
+
+* **two passes** (the pair as published): three einsums and a softmax in
+  plain XLA over a static prefix chosen per tick by a ``lax.switch``
+  (:func:`~dalle_pytorch_tpu.ops.attention.read_bounds`, the rule of every
+  dense-read cache).  The compiler reads the latent once for the scores and
+  once for the weighted sum, with ``[rows, heads, reach]`` float32 scores in
+  HBM between.
+* **one pass** (the pair FOLDED into one array, :func:`fold_latent`: ``[rows,
+  slots / 2, 2 kv_rank + 2 rope_dim]``, a block of 256 positions in 128 rows,
+  its two halves side by side as ``[c | c | k_rope | k_rope]``): where the
+  program is lowered for a TPU, one Pallas kernel that walks the folded
+  latent a block at a time up to the block that holds ``index``, keeps each
+  block in VMEM for both products and runs an online softmax
+  (ops/latent_attention_pallas.py); anywhere else the two-pass read of the
+  unfolded pair (``jax.lax.platform_dependent``, as the train path's flash
+  kernel is chosen).  ``decode_codes``' scan carries the fold
+  (:meth:`LatentAttention.lane_dense_cache`) where :func:`one_pass_read` says
+  the kernel takes the shapes and the call has no key-padding mask.  Why a
+  fold: a Pallas operand is row-major, where 64 rotary values a position pad
+  to a 128-lane row (a ninth more bytes walked, a quarter more at rest); a
+  separate ``[rows, rope_dim, slots]`` array is dense, but the v5e's compiler
+  stages any array of its size (71 MB a layer) through its alternate memory
+  and back around every use, every tick, as it did around the two-pass read,
+  and its tick write is one lane of 64 x 128 rows (0.1 ms a layer as
+  transfers of 256 bytes); folded, a row of 1,152 lanes is nine whole tiles,
+  the layer's cache is one array too large to stage and one stream for the
+  kernel, and the tick's write is one row.
+
+Scopes: ``mla-proj`` (the five products, the two norms, the rotation,
+``q_lat`` and ``o_lat W_uv``), ``mla-read`` (scores over the latent, softmax,
+the weighted sum of ``c``); the cache write stays under ``attn-cache``.
 """
 from __future__ import annotations
 
 import functools
+import hashlib
+import os
+from pathlib import Path
 from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
+from jax.interpreters import mlir
 
 from ..obs import prof
 from ..utils.helpers import max_neg_value
-from .attention import (AttnPattern, _choices, _scope_key_pad, apply_rope,
-                        dense_attention, pattern_mask_row, read_bounds,
-                        switch_read_prefix)
+from .attention import (LANES, AttnPattern, _choices, _scope_key_pad,
+                        apply_rope, dense_attention, pattern_mask_row,
+                        read_bounds, switch_read_prefix)
 from .quant import CacheForm
 from .ssm import fan_in_normal, rms_norm
+
+#: positions a block of the one-pass read holds.  The position bounds the
+#: walk at the block, so the slack over what the position reaches is half a
+#: block a row: at 4,352 slots and a mean reach of 3,201, 4.0% at 256 and 7.5%
+#: at 512 (the two-pass read's buckets of 640: 9.1%)
+READ_BLOCK = 256
+
+#: bytes of latent a program of the one-pass read moves at most: rows are
+#: added to a program until its block is this large (16 rows of 256 positions
+#: at GLM-4.7-Flash's widths: 4.7 MB, twice that in VMEM).  A step of the
+#: kernel's grid costs about a microsecond of the scalar core's bookkeeping
+#: that the transfers do not hide (PERF.md PR 39: 0.766 ms a layer at 8 rows
+#: a program, 0.731 at 16, 0.721 at 32); the blocks stay at 256 positions
+#: because larger ones lose at the bound what they win at the start
+READ_PROGRAM_BYTES = 8 * 2 ** 20
+
+
+def one_pass_read(slots: int, kv_rank: int, rope_dim: int, dtype) -> bool:
+    """Whether the static sampler's read of a latent cache takes one pass
+    over a FOLDED cache (:func:`fold_latent`), from static shapes alone: a
+    bfloat16 cache, the latent in whole 128-lane tiles, two positions' rotary
+    keys filling one (``rope_dim`` 64, as every published latent attention
+    has it), slots two whole blocks or more.  A float32 toy, a narrow twin, a
+    short or a ragged cache keep the two-pass read."""
+    return (jnp.dtype(dtype) == jnp.bfloat16 and kv_rank % LANES == 0
+            and 2 * rope_dim == LANES and slots >= 2 * READ_BLOCK
+            and slots % READ_BLOCK == 0)
+
+
+def rows_per_program(rows: int, width: int, dtype) -> int:
+    """Cache rows a program of the one-pass read takes: the largest power of
+    two that divides ``rows`` and keeps the program's block (:data:`READ_BLOCK`
+    positions of ``width`` values a row) within :data:`READ_PROGRAM_BYTES`."""
+    per = 1
+    while (rows % (2 * per) == 0 and 2 * per * READ_BLOCK * width
+           * jnp.dtype(dtype).itemsize <= READ_PROGRAM_BYTES):
+        per *= 2
+    return per
+
+
+def fold_latent(cache_c, cache_kr):
+    """``(c [b, slots, kv_rank], k_rope [b, slots, rope_dim])`` as one array
+    ``[b, slots / 2, 2 kv_rank + 2 rope_dim]``, a block of :data:`READ_BLOCK`
+    positions in 128 rows, its two halves side by side: row ``i`` of block
+    ``j`` holds positions ``j 256 + i`` and ``j 256 + 128 + i`` as ``[c | c |
+    k_rope | k_rope]`` (module docstring)."""
+    b, slots, _ = cache_c.shape
+    halves = [a.reshape(b, slots // READ_BLOCK, 2, READ_BLOCK // 2, -1)
+              for a in (cache_c, cache_kr)]
+    return jnp.concatenate([a[:, :, h] for a in halves for h in (0, 1)],
+                           axis=-1).reshape(b, slots // 2, -1)
+
+
+def unfold_latent(folded, kv_rank: int):
+    """:func:`fold_latent` undone: ``(c, k_rope)``."""
+    b, rows, _ = folded.shape
+
+    def unfold(a):
+        a = a.reshape(b, 2 * rows // READ_BLOCK, READ_BLOCK // 2, 2, -1)
+        return a.transpose(0, 1, 3, 2, 4).reshape(b, 2 * rows, -1)
+
+    return unfold(folded[..., :2 * kv_rank]), unfold(folded[..., 2 * kv_rank:])
 
 
 class LatentAttention(nn.Module):
@@ -140,8 +234,14 @@ class LatentAttention(nn.Module):
         kv_rank], k_rope [b, n, rope_dim])``."""
         b, n, _ = x.shape
         if _choices and not self.is_initializing():
-            _choices[-1][self.pattern] = dict(n=n, tiles=None, computed=0,
-                                              blocks=0, latent=True)
+            # how a tick of this layer would read a cache held in the
+            # activations' dtype (the decode records speak for the cache a
+            # call is handed: ``decode.kv_reach``)
+            one_pass = self.one_pass_read(self.pattern.cache_len, self.dtype)
+            _choices[-1][self.pattern] = dict(
+                n=n, tiles=None, computed=0, blocks=0, latent=True,
+                latent_read="one_pass" if one_pass else "two_pass",
+                block=READ_BLOCK if one_pass else 0)
         with prof.scope("mla-proj"):
             q_nope, q_rope, c, k_rope = self._project(x, jnp.arange(n))
             # graftlint: disable=DOT001 (uniform: c and the kernel are both self.dtype)
@@ -165,10 +265,30 @@ class LatentAttention(nn.Module):
         return (jnp.zeros((batch, slots, self.kv_rank), dtype),
                 jnp.zeros((batch, slots, self.rope_dim), dtype))
 
-    def dense_read_bounds(self) -> Tuple[int, ...]:
-        """The prefixes the static sampler's read of this layer's latent
-        chooses among (ops/attention.py::read_bounds of its slots)."""
-        return read_bounds(self.pattern.cache_len)
+    def one_pass_read(self, slots: int, dtype) -> bool:
+        """:func:`one_pass_read` of this layer's widths."""
+        return one_pass_read(slots, self.kv_rank, self.rope_dim, dtype)
+
+    def dense_read_bounds(self, dtype,
+                          masked: bool = False) -> Tuple[int, ...]:
+        """The prefixes the static sampler's read of this layer's latent, a
+        cache of ``dtype``, ends at: the ends of the one-pass read's blocks
+        where :func:`one_pass_read` holds and the call is not ``masked``,
+        else the buckets the two-pass read chooses among
+        (ops/attention.py::read_bounds of its slots)."""
+        slots = self.pattern.cache_len
+        if masked or not self.one_pass_read(slots, dtype):
+            return read_bounds(slots)
+        return tuple(range(READ_BLOCK, slots + 1, READ_BLOCK))
+
+    def lane_dense_cache(self, cache_c, cache_kr, masked: bool = False):
+        """The pair as ``decode_codes``' scan should carry it: folded into
+        one array, ``(fold_latent(c, k_rope), None)``, where the read takes
+        one pass (one relayout a call), else as given.  ``masked``: the call
+        has a key-padding mask, which the kernel does not take."""
+        if masked or not self.one_pass_read(cache_c.shape[1], cache_c.dtype):
+            return cache_c, cache_kr
+        return _fold(cache_c, cache_kr), None
 
     def arena_form(self, dtype) -> CacheForm:
         """The form the serving arena stores this layer's pair in: as the
@@ -188,14 +308,19 @@ class LatentAttention(nn.Module):
 
         ``index`` a traced scalar (the static sampler): the new latent is
         written at slot ``index`` and the read runs over a static prefix of
-        the slots that holds ``index + 1`` of them (:meth:`_bounded_read`).
+        the slots that holds ``index + 1`` of them (:func:`_bounded_read`).
+        With ``cache_kr`` None, ``cache_c`` is the folded pair
+        (:meth:`lane_dense_cache`): the read takes one pass
+        (:func:`_one_pass_read`), no ``mask`` and no ``write_pos`` are
+        taken, and the fold is returned with None beside it.
         With ``write_pos`` (the serving arena's phase-aligned mode, see
         ops/attention.py::MultiHeadAttention.decode_step) ``index`` may be
         per row ``[b]``: every row writes physical column ``write_pos``, its
         slots rotated by ``(write_pos - index) mod slots``, and the mask
         goes by each column's logical position."""
         b = x.shape[0]
-        slots = cache_c.shape[1]
+        folded = cache_kr is None
+        slots = cache_c.shape[1] * (2 if folded else 1)
         index = jnp.asarray(index, jnp.int32)
         with prof.scope("mla-proj"):
             q_nope, q_rope, c, k_rope = self._project(x, index[..., None])
@@ -203,7 +328,16 @@ class LatentAttention(nn.Module):
             # graftlint: disable=DOT001 (uniform: q_nope and the kernel are both self.dtype)
             q_lat = jnp.einsum("bhe,che->bhc", q_nope[:, :, 0], w_uk)
             q_lat = (q_lat * self.scale).astype(cache_c.dtype)
-            q_rope = (q_rope[:, :, 0] * self.scale).astype(cache_kr.dtype)
+            q_rope = (q_rope[:, :, 0] * self.scale).astype(cache_c.dtype)
+        if folded:
+            assert mask is None and write_pos is None, (
+                "the folded latent is the static sampler's, without a mask")
+            with prof.scope("attn-cache"):
+                cache_c = _write_folded(cache_c, c, k_rope, index)
+            with prof.scope("mla-read"):
+                o_lat = _one_pass_read(self.pattern, q_lat, q_rope, cache_c,
+                                       index)
+            return self._absorbed_out(o_lat, x.dtype), cache_c, None
         with prof.scope("attn-cache"):
             at = index if write_pos is None else write_pos
             cache_c = jax.lax.dynamic_update_slice(
@@ -215,8 +349,8 @@ class LatentAttention(nn.Module):
                 row = pattern_mask_row(self.pattern, index, slots)[None, :]
                 if mask is not None:
                     row = row & _scope_key_pad(self.pattern, mask, slots)
-                o_lat = self._bounded_read(q_lat, q_rope, cache_c, cache_kr,
-                                           row, index + 1)
+                o_lat = _bounded_read(q_lat, q_rope, cache_c, cache_kr, row,
+                                      index + 1)
             else:
                 assert mask is None, (
                     "phase-aligned decode takes no key padding mask")
@@ -227,25 +361,161 @@ class LatentAttention(nn.Module):
                     slots)
                 o_lat = _read_latent(q_lat, q_rope, cache_c, cache_kr,
                                      logical <= idx[:, None], bound=slots)
+        return self._absorbed_out(o_lat, x.dtype), cache_c, cache_kr
+
+    def _absorbed_out(self, o_lat, dtype):
+        """``o_lat [b, h, kv_rank]`` through ``W_uv`` and ``W_o``: ``[b, 1,
+        dim]``."""
         with prof.scope("mla-proj"):
             w_uv = self.w_kvb[..., self.nope_dim:].astype(self.dtype)
             # graftlint: disable=DOT001 (uniform: o_lat and the kernel are both cast to self.dtype)
             o = jnp.einsum("bhc,chv->bhv", o_lat.astype(self.dtype), w_uv)
-            out = self._out(o[:, :, None]).astype(x.dtype)
-        return out, cache_c, cache_kr
+            return self._out(o[:, :, None]).astype(dtype)
 
-    def _bounded_read(self, q_lat, q_rope, cache_c, cache_kr, row, filled):
-        """The static sampler's read of the latent: the slots written so far
-        are the prefix ``[0, filled)`` and ``row`` is False past it, so the
-        read runs over a static prefix chosen per tick among
-        :func:`read_bounds` (ops/attention.py::switch_read_prefix, as
-        ``MultiHeadAttention._masked_read`` does for keys and values): the
-        slots left out were masked to ``exp(...) = 0``."""
-        bounds = read_bounds(cache_c.shape[1])
-        reads = [functools.partial(_read_latent, bound=bound)
-                 for bound in bounds]
-        return switch_read_prefix(
-            bounds, reads, (q_lat, q_rope, cache_c, cache_kr, row), filled)
+
+# --- the kernels, kept between processes -----------------------------------------
+#
+# As ops/attention_pallas.py keeps the train path's kernels: importing Pallas,
+# tracing a kernel's unrolled body and lowering it to Mosaic's MLIR is Python,
+# seconds a process (PERF.md PR 39: `setup_s` 23 -> 29 s in
+# `glm-4.7-flash-generate`), where the XLA executable around the kernels loads
+# from the compile cache.  So each call is kept beside that cache as a
+# ``jax.export`` artefact, keyed by what it is built from; a later process
+# reads the bytes and binds one ``call_exported``, without importing Pallas.
+
+@functools.lru_cache(maxsize=None)
+def _exported(cache_dir: str, name: str, statics, avals):
+    """``latent_attention_pallas.<name>(*avals, **statics)`` as a
+    ``jax.export.Exported`` for the TPU: read from ``cache_dir``, or traced,
+    lowered and written there."""
+    from jax import export
+
+    import jaxlib
+
+    source = Path(__file__).with_name("latent_attention_pallas.py")
+    key = hashlib.sha256(repr((
+        name, statics, avals, READ_BLOCK, jax.__version__, jaxlib.__version__,
+        hashlib.sha256(source.read_bytes()).hexdigest())).encode()).hexdigest()
+    path = Path(cache_dir) / f"latent-{name}-{key[:40]}.jaxexport"
+    try:
+        return export.deserialize(bytearray(path.read_bytes()))
+    except OSError:
+        pass
+    from . import latent_attention_pallas
+
+    exported = export.export(
+        jax.jit(functools.partial(getattr(latent_attention_pallas, name),
+                                  **dict(statics))),
+        platforms=("tpu",))(*[jax.ShapeDtypeStruct(*a) for a in avals])
+    tmp = path.with_suffix(f".{os.getpid()}.tmp")
+    try:    # whole or not at all: another process may be writing the same
+        path.parent.mkdir(parents=True, exist_ok=True)
+        tmp.write_bytes(exported.serialize())
+        tmp.replace(path)
+    except OSError:
+        pass    # a cache that cannot be written is a cache that misses
+    return exported
+
+
+#: A kept kernel as one opaque operation of the program around it, its
+#: artefact called where the program is lowered.  Called where the program is
+#: traced, ``call_exported`` marks every result of that program as committed
+#: to its device (jax 0.9: ``pxla.jaxpr_transfer_mem_kinds`` counts the
+#: artefact's results as memory-space transfers), and a jitted consumer that
+#: was warmed on an uncommitted array, as the benchmark's VAE decode is,
+#: traces again on the codes: a compile inside the timed window.
+_kept_kernel_p = jex_core.Primitive("latent_kept_kernel")
+_kept_kernel_p.def_abstract_eval(
+    lambda *args, exported: exported.out_avals[0])
+mlir.register_lowering(_kept_kernel_p, mlir.lower_fun(
+    lambda *args, exported: exported.call(*args), multiple_results=False))
+
+
+def _kernel(name: str, *args, **statics):
+    """``latent_attention_pallas.<name>`` (one result), through the kept
+    artefact where the program keeps a compile cache (the compiled kernel for
+    the TPU; a test that interprets the kernel turns the cache off)."""
+    cache_dir = jax.config.jax_compilation_cache_dir
+    if cache_dir:
+        return _kept_kernel_p.bind(*args, exported=_exported(
+            cache_dir, name, tuple(sorted(statics.items())),
+            tuple((a.shape, a.dtype) for a in args)))
+    from . import latent_attention_pallas
+
+    return getattr(latent_attention_pallas, name)(*args, **statics)
+
+
+@jax.jit
+def _fold(cache_c, cache_kr):
+    """:func:`fold_latent`; where the program is lowered for a TPU, as a
+    kernel that copies block by block (ops/latent_attention_pallas.py): the
+    v5e's compiler writes the parts out before it concatenates them, 0.6 GB
+    of temporaries at 128 rows x 4,352 slots."""
+    def kernel(cache_c, cache_kr):
+        # a program of the copy holds a block of the pair and of the fold
+        return _kernel("fold_latent_blocks", cache_c, cache_kr,
+                       rows_per_program=rows_per_program(
+                           cache_c.shape[0], 3 * cache_c.shape[-1],
+                           cache_c.dtype))
+
+    return jax.lax.platform_dependent(cache_c, cache_kr, tpu=kernel,
+                                      default=fold_latent)
+
+
+def _write_folded(folded, c, k_rope, index):
+    """The tick's write into the folded latent (:func:`fold_latent`), ``c [b,
+    1, kv_rank]`` and ``k_rope [b, 1, rope_dim]`` of position ``index``: into
+    its half's lanes of row ``(index // 256) 128 + index mod 128``.  The row
+    is taken out, takes the new values under a static lane pattern selected
+    by the half, and goes back: both moves are dynamic in the row alone, as
+    the unfolded write is."""
+    b, _, width = folded.shape
+    rank, rope = c.shape[-1], k_rope.shape[-1]
+    half = READ_BLOCK // 2
+    at = (0, index // READ_BLOCK * half + index % half, 0)
+    new = jnp.concatenate([c, c, k_rope, k_rope], -1).astype(folded.dtype)
+    lane = jnp.arange(width)
+    second = jnp.where(lane < 2 * rank, lane // rank,
+                       (lane - 2 * rank) // rope)
+    row = jax.lax.dynamic_slice(folded, at, (b, 1, width))
+    return jax.lax.dynamic_update_slice(
+        folded, jnp.where(second == index % READ_BLOCK // half, new, row), at)
+
+
+@functools.partial(jax.jit, static_argnames=("pattern",))
+def _one_pass_read(pattern: AttnPattern, q_lat, q_rope, folded, index):
+    """The static sampler's maskless read of the folded latent: the one-pass
+    kernel where the program is lowered for a TPU, the two-pass read of the
+    unfolded pair anywhere else (``jax.lax.platform_dependent``: no
+    ``jax.default_backend()``, and a compile from a CPU host for a described
+    chip gets the kernel).  Jitted on its static, so that the layers of one
+    shape share one traced switch."""
+    def kernel(q_lat, q_rope, folded, index):
+        return _kernel("latent_read", q_lat, q_rope, folded, index,
+                       rows_per_program=rows_per_program(
+                           folded.shape[0], folded.shape[-1] // 2,
+                           folded.dtype))
+
+    def plain(q_lat, q_rope, folded, index):
+        cache_c, cache_kr = unfold_latent(folded, q_lat.shape[-1])
+        row = pattern_mask_row(pattern, index, cache_c.shape[1])[None, :]
+        return _bounded_read(q_lat, q_rope, cache_c, cache_kr, row, index + 1)
+
+    return jax.lax.platform_dependent(q_lat, q_rope, folded, index,
+                                      tpu=kernel, default=plain)
+
+
+def _bounded_read(q_lat, q_rope, cache_c, cache_kr, row, filled):
+    """The static sampler's two-pass read of the latent: the slots written so
+    far are the prefix ``[0, filled)`` and ``row`` is False past it, so the
+    read runs over a static prefix chosen per tick among :func:`read_bounds`
+    (ops/attention.py::switch_read_prefix, as
+    ``MultiHeadAttention._masked_read`` does for keys and values): the slots
+    left out were masked to ``exp(...) = 0``."""
+    bounds = read_bounds(cache_c.shape[1])
+    reads = [functools.partial(_read_latent, bound=bound) for bound in bounds]
+    return switch_read_prefix(
+        bounds, reads, (q_lat, q_rope, cache_c, cache_kr, row), filled)
 
 
 @functools.partial(jax.jit, static_argnames=("bound",))

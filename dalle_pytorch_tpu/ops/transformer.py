@@ -959,13 +959,17 @@ class Transformer(nn.Module):
                                         self.cache_lens)
         ]
 
-    def lane_dense_caches(self, caches):
+    def lane_dense_caches(self, caches, masked: bool = False):
         """Per-layer caches as ``decode_codes``' scan should carry them
         (MultiHeadAttention.lane_dense_cache): :meth:`decode_step` takes
-        either layout, told by the shape.  A latent pair has no head to fold
-        and passes as it is, like a recurrent state."""
-        return [(ck, cv) if is_recurrent(kind) or is_latent(kind) else
-                (blk.attn.lane_dense_cache(ck), blk.attn.lane_dense_cache(cv))
+        either layout, told by the shape.  A recurrent state passes as it
+        is; a latent pair has no head to fold, and its layer says whether
+        the two ride as one array (LatentAttention.lane_dense_cache: not
+        where the call is ``masked``, has a key-padding mask)."""
+        return [(ck, cv) if is_recurrent(kind) else
+                blk.mla.lane_dense_cache(ck, cv, masked) if is_latent(kind)
+                else (blk.attn.lane_dense_cache(ck),
+                      blk.attn.lane_dense_cache(cv))
                 for blk, kind, (ck, cv) in zip(self.attn_blocks, self.mixers,
                                                caches)]
 
@@ -977,12 +981,16 @@ class Transformer(nn.Module):
                 (blk.mla if is_latent(kind) else blk.attn).arena_form(dtype)
                 for blk, kind in zip(self.attn_blocks, self.mixers)]
 
-    def dense_read_bounds(self):
-        """Per layer, the prefixes its decode step's dense cache read chooses
-        among (MultiHeadAttention.dense_read_bounds); None for a layer that
-        reads slices or carries a recurrent state."""
+    def dense_read_bounds(self, dtype, masked: bool = False):
+        """Per layer, the prefixes its decode step's dense read of a cache of
+        ``dtype`` ends at (MultiHeadAttention.dense_read_bounds, the buckets
+        a ``lax.switch`` chooses among; LatentAttention.dense_read_bounds,
+        those or, without a key-padding mask, the ends of its one-pass
+        read's blocks); None for a layer that reads slices or carries a
+        recurrent state."""
         return [None if is_recurrent(kind) else
-                (blk.mla if is_latent(kind) else blk.attn).dense_read_bounds()
+                blk.mla.dense_read_bounds(dtype, masked) if is_latent(kind)
+                else blk.attn.dense_read_bounds()
                 for blk, kind in zip(self.attn_blocks, self.mixers)]
 
     def decode_step(self, x, caches, index, mask=None, write_pos=None,

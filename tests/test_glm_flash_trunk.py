@@ -708,11 +708,60 @@ def test_traces_report_the_latent_cache_and_the_routing(model, tmp_path):
     kernel = last("attention", "kernel")
     assert (kernel["latent_layers"], kernel["dense_layers"],
             kernel["flash_layers"]) == (3, 0, 0)
+    # a latent 20 wide is no whole lane tile: the plain read, in two passes
+    assert (kernel["latent_read"], kernel["block"]) == ("two_pass", 0)
     for line in ("graft_decode_latent_layers 3", "graft_decode_kv_layers 0",
                  "graft_decode_moe_layers 2", "graft_attn_latent_layers 3"):
         assert line in rendered, line
     report = render_text(build_report(events))
-    assert ("latent cache: 3 layers, 48 bytes a position (512 as stored)"
-            in report)
+    assert ("latent cache: 3 layers, 48 bytes a position (512 as stored); "
+            "read in two passes" in report)
     assert "routed experts: 2 layers of 8, 2 a token" in report
     assert "sigmoid scores, 2 held, 1 shared" in report
+
+
+def test_traces_report_the_one_pass_read_where_the_shapes_take_it(tmp_path):
+    """A twin whose latent fills a lane tile (128 + 64 rotary values), over
+    192 + 24 x 24 = 768 bfloat16 slots: the records describe the kernel's
+    walk (ops/latent_attention.py::one_pass_read): blocks of 256 positions,
+    their ends as the prefixes of ``decode.kv_reach``, the fold's bytes."""
+    trunk = dict(TRUNK, kv_rank=128, rope_dim=64)
+    geometry = dict(GEOMETRY, depth=2, text_seq_len=192, image_size=192,
+                    image_fmap_size=24)
+    cfg = DALLEConfig(trunk=trunk, dtype=jnp.bfloat16, **geometry)
+    dalle = DALLE(cfg)
+    text = jnp.ones((2, cfg.text_seq_len), jnp.int32)
+    codes = jnp.zeros((2, cfg.image_seq_len), jnp.int32)
+    variables = jax.eval_shape(dalle.init, jax.random.PRNGKey(0), text, codes)
+    tel = telemetry.init(str(tmp_path / "tel"))
+    try:
+        jax.jit(lambda v, t, k: generate_codes(
+            dalle, v, t, k, filter_thres=0.9)).lower(
+                variables, text, jax.random.PRNGKey(0))
+        jax.eval_shape(lambda v: dalle.apply(v, text, codes), variables)
+    finally:
+        telemetry.shutdown()
+    events = telemetry.read_events(tel.path)
+
+    def last(kind, name):
+        return [e for e in events
+                if e["kind"] == kind and e["name"] == name][-1]
+
+    kernel = last("attention", "kernel")
+    assert (kernel["latent_layers"], kernel["latent_read"],
+            kernel["block"]) == (2, "one_pass", 256)
+    reach = last("decode", "kv_reach")
+    assert (reach["bounded_layers"], reach["unbounded_layers"],
+            reach["buckets"]) == (2, 0, 6)
+    # ticks at positions 193..767 read the blocks that hold them
+    ticks = np.arange(cfg.text_seq_len + 1, cfg.seq_len)
+    assert reach["read_share"] == pytest.approx(
+        (256 * (ticks // 256 + 1)).mean() / 768)
+    state = last("decode", "state_layout")
+    assert state["latent_bytes_per_position"] == (128 + 64) * 2
+    assert state["latent_bytes_walked_per_position"] == (128 + 64) * 2
+    report = render_text(build_report(events))
+    assert ("latent cache: 2 layers, 384 bytes a position (384 as stored); "
+            "read in one pass, 256 positions a block" in report)
+    assert "kv cache reach: 2 layers' dense reads bounded by the position " \
+        "(6 prefixes)" in report

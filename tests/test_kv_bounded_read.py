@@ -318,7 +318,7 @@ def test_read_share_is_the_mean_prefix_over_the_ticks(n_pre, slots, want):
         cfg = type("Cfg", (), {"seq_len": slots, "mixers": ("attention",)})
 
         @staticmethod
-        def apply(params, method):
+        def apply(params, masked, method):
             return [bounds]
 
     cache = (jnp.zeros((1, 1, slots, 2), jnp.bfloat16),) * 2

@@ -535,6 +535,56 @@ def test_the_absorbed_latent_tick_compiles_and_decompresses_nothing(one_chip):
     assert f"bf16[{rows},{slots},512]" in text
 
 
+def test_the_one_pass_latent_read_compiles_at_the_cells_shape(one_chip):
+    """ops/latent_attention_pallas.py at ``glm-4.7-flash-generate``'s shape
+    (128 rows x 4,352 slots of 512 + 64 values, 20 heads, PERF.md PR 39): the
+    fold and the read lower for the v5e from this CPU host, 16 rows a
+    program; and one layer's tick against the folded cache holds the kernel,
+    no switch, and no copy of the cache."""
+    from dalle_pytorch_tpu.ops.latent_attention import (LatentAttention,
+                                                        rows_per_program)
+    from dalle_pytorch_tpu.ops.latent_attention_pallas import (
+        fold_latent_blocks, latent_read)
+
+    rows, slots, heads, rank, rope, dim = 128, 4352, 20, 512, 64, 2048
+
+    def on(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    per = rows_per_program(rows, rank + rope, jnp.bfloat16)
+    assert per == 16
+    folded = on(rows, slots // 2, 2 * (rank + rope))
+    compiled = jax.jit(lambda c, kr: fold_latent_blocks(
+        c, kr, rows_per_program=8)).lower(
+            on(rows, slots, rank), on(rows, slots, rope)).compile()
+    # one pass: nothing as large as a layer's cache beside the result
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 28
+    text = jax.jit(lambda q, qr, lat, i: latent_read(
+        q, qr, lat, i, rows_per_program=per)).lower(
+            on(rows, heads, rank), on(rows, heads, rope), folded,
+            _scalar(one_chip, jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+    layer = LatentAttention(
+        pattern=AttnPattern(variant="full", seq_len=slots, text_len=257,
+                            fmap=64, causal=True),
+        dim=dim, heads=heads, q_rank=768, kv_rank=rank, nope_dim=192,
+        rope_dim=rope, value_dim=256, rope_theta=1e6, eps=1e-5,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4, dim), jnp.bfloat16))
+    compiled = jax.jit(lambda p, x, lat, i: layer.apply(
+        p, x, lat, None, i, method=LatentAttention.decode_step),
+        donate_argnums=2).lower(
+            _on(one_chip, params), on(rows, 1, dim), folded,
+            _scalar(one_chip, jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert " conditional(" not in text
+    cache = rows * (slots // 2) * 2 * (rank + rope) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < cache // 4
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("spec", chip_smoke.FULL.plan_specs)
 def test_sharded_step_compiles_for_four_chips(topo, spec):
