@@ -190,8 +190,9 @@ class DALLEConfig:
                 "'moe_swiglu_shared'), not ff_experts")
             assert self.attn_dropout == 0 and self.ff_dropout == 0, (
                 "a TrunkSpec trunk has no dropout")
-            assert self.heads % self.trunk.kv_heads == 0, (
-                self.heads, self.trunk.kv_heads)
+            for heads in (self.heads, self.trunk.window_heads or self.heads):
+                assert heads % self.trunk.kv_heads == 0, (
+                    heads, self.trunk.kv_heads)
         assert not (self.weights_int8 and self.ff_experts > 1), (
             "weights_int8 quantizes the dense GEGLU kernels; MoE expert "
             "kernels are not supported on the quantized decode path")

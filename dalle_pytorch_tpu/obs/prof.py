@@ -42,7 +42,8 @@ SCOPES = ("embed", "attn-qkv", "attn-scores", "attn-cache", "attn-out",
           "ff", "logits-head", "vae-conv", "optimizer", "decode-step",
           "serve-tick", "spec-draft", "spec-verify", "sample",
           "ssm-proj", "ssm-conv", "ssm-scan", "moe-route", "moe-experts",
-          "gdn-proj", "gdn-conv", "gdn-state", "mla-proj", "mla-read")
+          "gdn-proj", "gdn-conv", "gdn-state", "mla-proj", "mla-read",
+          "attn-gate")
 
 #: Residual bucket for equations under no scope.
 UNATTRIBUTED = "unattributed"

@@ -357,7 +357,7 @@ def build_report(events: List[dict]) -> dict:
                        "kv_slots_per_row"))
     # and a `decode.kv_reach` record: the layers whose dense cache read the
     # position bounds, and the share of their slots a tick reads
-    # a sigmoid-routed layer says how it scores, how many of its experts this
+    # a shared-expert layer says how it scores, how many of its experts this
     # device holds and how many shared experts stand beside them
     _, share = last_decode(
         "moe_layout", ("scoring", "experts_held", "shared_experts"))
@@ -374,7 +374,7 @@ def build_report(events: List[dict]) -> dict:
                             if latent.get("latent_layers") else {}),
                          **({"moe": routed} if routed else {}),
                          **({"moe_share": share}
-                            if share.get("scoring") == "sigmoid" else {})}
+                            if share.get("shared_experts") else {})}
     # models/dalle.py::sample_image_code emits one `sample.top_k` record per
     # traced sampler (a decode_codes program holds two, a serve tick its
     # own): how many logits the top-k filter keeps and how it finds the

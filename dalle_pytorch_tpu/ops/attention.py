@@ -110,22 +110,89 @@ def _block_diag_q(q, fold: int):
         b, h // fold, fold * dh, fold)
 
 
-def apply_rope(x, positions, theta: float):
-    """Rotary position embedding over the whole last axis of ``x`` ``[b, h,
-    n, d]`` at integer ``positions`` ``[n]`` or ``[b, n]``: dimension ``i <
-    d / 2`` pairs with ``i + d / 2`` (rotate-half) and turns by ``p *
-    theta^(-2i / d)``.  Angles, sines and the rotation in float32; the
-    result in ``x``'s dtype."""
-    half = x.shape[-1] // 2
-    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    angle = jnp.asarray(positions, jnp.float32)[..., None] * freq
+def apply_rope(x, positions, theta: float, rotary_dim: Optional[int] = None,
+               freq=None, scale: float = 1.0):
+    """Rotary position embedding of ``x`` ``[b, h, n, d]`` at integer
+    ``positions`` ``[n]`` or ``[b, n]`` over its first ``rotary_dim``
+    dimensions (None: all ``d``; the rest pass as they are, HF's
+    partial-rotary convention): dimension ``i < r / 2`` pairs with ``i + r /
+    2`` (rotate-half) and turns by ``p * freq_i``, ``freq_i = theta^(-2i /
+    r)`` unless a table ``freq`` ``[r / 2]`` is given (:func:`yarn_table`),
+    the cosines and sines times ``scale`` (YaRN's attention factor).  Angles,
+    sines and the rotation in float32; the result in ``x``'s dtype."""
+    rot = x.shape[-1] if rotary_dim is None else rotary_dim
+    half = rot // 2
+    if freq is None:
+        freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    angle = jnp.asarray(positions, jnp.float32)[..., None] * jnp.asarray(
+        freq, jnp.float32)
     if angle.ndim == 3:                 # per-row positions: [b, 1, n, d / 2]
         angle = angle[:, None]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    lo, hi = x[..., :half].astype(jnp.float32), x[..., half:].astype(
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
+    lo, hi = x[..., :half].astype(jnp.float32), x[..., half:rot].astype(
         jnp.float32)
-    return jnp.concatenate([lo * cos - hi * sin, hi * cos + lo * sin],
-                           axis=-1).astype(x.dtype)
+    turned = [lo * cos - hi * sin, hi * cos + lo * sin]
+    if rot < x.shape[-1]:
+        turned.append(x[..., rot:].astype(jnp.float32))
+    return jnp.concatenate(turned, axis=-1).astype(x.dtype)
+
+
+#: YaRN's turns over the original length above which a frequency is kept
+#: and below which it is interpolated (HF's defaults, and Laguna's values)
+YARN_BETA_FAST, YARN_BETA_SLOW = 32.0, 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """YaRN's long-context rotation (arXiv:2309.00071, as HF's ``rope_type:
+    yarn`` computes it): frequencies that turn fewer than
+    :data:`YARN_BETA_SLOW` times over ``original_len`` positions are divided
+    by ``factor``, those that turn more than :data:`YARN_BETA_FAST` times are
+    kept, a linear ramp between (its ends rounded outwards), and the cosines
+    and sines are multiplied by :attr:`scale`."""
+
+    factor: float
+    original_len: int
+
+    def __post_init__(self):
+        assert self.factor > 1 and self.original_len > 0, self
+
+    @property
+    def scale(self) -> float:
+        """The attention factor, ``0.1 ln(factor) + 1`` (1.4852 at 128)."""
+        return 0.1 * float(np.log(self.factor)) + 1.0
+
+
+def yarn_ramp(theta: float, rotary_dim: int, yarn: YaRN) -> Tuple[int, int]:
+    """``(low, high)``: the rotary pairs below ``low`` keep their frequency,
+    those from ``high`` on are interpolated; HF's ``find_correction_range``
+    with ``truncate`` (theta 5e5, 64 dimensions, 8,192 positions, betas 32
+    and 1: 9.04 -> 9, 17.49 -> 18)."""
+    def dim(turns):
+        return (rotary_dim * np.log(yarn.original_len / (turns * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = max(int(np.floor(dim(YARN_BETA_FAST))), 0)
+    high = min(int(np.ceil(dim(YARN_BETA_SLOW))), rotary_dim - 1)
+    return low, high
+
+
+@functools.lru_cache(maxsize=16)
+def yarn_table(theta: float, rotary_dim: int, yarn: YaRN) -> np.ndarray:
+    """The float32 frequencies ``[rotary_dim / 2]`` of a YaRN rotation:
+    ``lerp(base / factor, base, 1 - ramp)`` with ``base_i = theta^(-2i /
+    rotary_dim)`` and ``ramp`` 0 below :func:`yarn_ramp`'s ``low``, 1 from
+    its ``high``.  Read-only."""
+    low, high = yarn_ramp(theta, rotary_dim, yarn)
+    base = theta ** (-np.arange(0, rotary_dim, 2, dtype=np.float64)
+                     / rotary_dim)
+    ramp = np.clip((np.arange(rotary_dim // 2) - low)
+                   / max(high - low, 1e-3), 0, 1)
+    table = (base / yarn.factor * ramp + base * (1 - ramp)).astype(np.float32)
+    table.setflags(write=False)
+    return table
 
 
 def ring_positions(index, slots: int):
@@ -823,7 +890,16 @@ class MultiHeadAttention(nn.Module):
     # rotate queries and keys by their position (:func:`apply_rope`, the
     # index in the sequence the layer sees); None: no position encoding.
     # Keys enter the cache rotated, so a ring's slot order does not matter.
+    # ``rope_dim``: the leading dimensions of a head that turn (None: all);
+    # ``rope_yarn``: YaRN's frequencies and attention factor (:class:`YaRN`)
     rope_theta: Optional[float] = None
+    rope_dim: Optional[int] = None
+    rope_yarn: Optional[YaRN] = None
+    # multiply each head's attended values by a sigmoid of the layer's input
+    # (``to_gate`` [dim, heads], one scalar a head and position: the
+    # head-wise gate of Gated Attention, arXiv:2505.06708), before
+    # ``to_out``; scope ``attn-gate``
+    head_gate: bool = False
     # RMS-norm the projected queries and keys, each over its projection's
     # whole width (all heads together, before the split into heads and
     # before any rotation), with a float32 gain of that width: ``q_norm``,
@@ -862,7 +938,14 @@ class MultiHeadAttention(nn.Module):
                 param_dtype=self.param_dtype,
                 kernel_init=fan_in_normal(self.heads * self.dim_head),
                 name="to_out")
+            if self.head_gate:
+                self.to_gate = nn.Dense(
+                    self.heads, use_bias=False, dtype=self.dtype,
+                    param_dtype=self.param_dtype,
+                    kernel_init=fan_in_normal(self.dim), name="to_gate")
             return
+        assert not self.head_gate, (
+            "the head gate is built with kv_heads (the trunk's blocks)")
         # fused QKV as a [dim, 3, heads, dh] DenseGeneral: the (3,) axis is
         # never sharded, so splitting q/k/v is a free unsharded-axis index,
         # and tensor parallelism shards the heads axis cleanly (a flat
@@ -899,14 +982,35 @@ class MultiHeadAttention(nn.Module):
                 if self.rope_theta is not None:
                     if positions is None:
                         positions = jnp.arange(x.shape[1])
-                    q = apply_rope(q, positions, self.rope_theta)
-                    k = apply_rope(k, positions, self.rope_theta)
+                    q, k = self._rotate(q, positions), self._rotate(
+                        k, positions)
                 return q, k, v
             assert self.rope_theta is None, (
                 "rotary layers are built with kv_heads (the trunk's blocks)")
             qkv = self.to_qkv(x)  # [b, n, 3, heads, dh]
             qkv = qkv.transpose(2, 0, 3, 1, 4)  # [3, b, heads, n, dh]
             return qkv[0], qkv[1], qkv[2]
+
+    def _rotate(self, a, positions):
+        """:func:`apply_rope` by this layer's rotation."""
+        if self.rope_yarn is None:
+            return apply_rope(a, positions, self.rope_theta, self.rope_dim)
+        rot = self.rope_dim or self.dim_head
+        return apply_rope(a, positions, self.rope_theta, rot,
+                          yarn_table(self.rope_theta, rot, self.rope_yarn),
+                          self.rope_yarn.scale)
+
+    def _gated(self, out, x):
+        """``out`` ``[b, n, heads * dh]`` with each head's part times
+        ``sigmoid(x W_gate)`` of its own column (``head_gate``; else as it
+        is); ``x`` ``[b, n, dim]`` the layer's input."""
+        if not self.head_gate:
+            return out
+        with prof.scope("attn-gate"):
+            b, n, _ = out.shape
+            gate = jax.nn.sigmoid(self.to_gate(x).astype(jnp.float32))
+            return (out.reshape(b, n, self.heads, self.dim_head)
+                    * gate[..., None]).astype(out.dtype).reshape(b, n, -1)
 
     def _kernel_qkv(self, x, core: _Core):
         """The fused projection as the flash kernel reads it.  Where no
@@ -936,7 +1040,7 @@ class MultiHeadAttention(nn.Module):
             qkv = self._kernel_qkv(x, core)
             with prof.scope("attn-scores"):
                 out = _switched_core(core, qkv, mask)
-            return self._project_out(out, x.dtype, deterministic)
+            return self._project_out(out, x, deterministic)
 
         q, k, v = self._qkv(x)
         if ring:
@@ -965,20 +1069,21 @@ class MultiHeadAttention(nn.Module):
                 out = dense_attention(self.pattern, x.dtype, q, k, v, mask,
                                       grouped=self.kv_heads is not None)
 
-        out = self._project_out(out, x.dtype, deterministic)
+        out = self._project_out(out, x, deterministic)
         if return_kv:
             return out, (k, v)
         return out
 
-    def _project_out(self, out, dtype, deterministic: bool):
-        """``to_out`` and its dropout on the core's result: ``[b, heads, n,
-        dh]`` of the dense branches, ``[b, n, heads * dh]`` of the kernel."""
+    def _project_out(self, out, x, deterministic: bool):
+        """The head gate, ``to_out`` and its dropout on the core's result:
+        ``[b, heads, n, dh]`` of the dense branches, ``[b, n, heads * dh]``
+        of the kernel; ``x`` the layer's input."""
         with prof.scope("attn-out"):
-            out = out.astype(dtype)
+            out = out.astype(x.dtype)
             if out.ndim == 4:
                 b, _, n, _ = out.shape
                 out = out.transpose(0, 2, 1, 3).reshape(b, n, self.heads * self.dim_head)
-            out = self.to_out(out)
+            out = self.to_out(self._gated(out, x))
             return self.drop(out, deterministic=deterministic)
 
     def _kernel_core(self, b: int, n: int, dtype, act_dtype,
@@ -1036,7 +1141,11 @@ class MultiHeadAttention(nn.Module):
             qkv = qkv.transpose(2, 0, 3, 1, 4)  # [3, b, heads, n, dh]
             return qkv[0], qkv[1], qkv[2]
 
-    def _out_proj(self, out, qw):
+    def _out_proj(self, out, qw, x):
+        """``to_out`` (under ``weights_int8`` its int8 kernel) on ``out``
+        ``[b, n, heads * dh]``, after the head gate of the layer's input
+        ``x``."""
+        out = self._gated(out, x)
         with prof.scope("attn-out"):
             if qw is None:
                 return self.to_out(out)
@@ -1185,7 +1294,7 @@ class MultiHeadAttention(nn.Module):
                 out = self._cache_values(attn, v_sub, v_scale, x.dtype)
                 out = out.transpose(0, 2, 1, 3).reshape(
                     b, 1, self.heads * self.dim_head)
-            return self._out_proj(out, qw), cache_k, cache_v
+            return self._out_proj(out, qw, x), cache_k, cache_v
         with prof.scope("attn-scores"):
             layout = self.pattern.block_layout()
             row = pattern_mask_row(
@@ -1199,7 +1308,7 @@ class MultiHeadAttention(nn.Module):
                 filled=index + 1 if self.pattern.causal else None)
             out = out.transpose(0, 2, 1, 3).reshape(
                 b, 1, self.heads * self.dim_head)
-        return self._out_proj(out, qw), cache_k, cache_v
+        return self._out_proj(out, qw, x), cache_k, cache_v
 
     def _masked_read(self, q_scaled, k_vals, k_scale, v_vals, v_scale, row,
                      out_dtype, filled=None):
@@ -1272,7 +1381,7 @@ class MultiHeadAttention(nn.Module):
                 filled=None if index.ndim else jnp.minimum(index + 1, slots))
             out = out.transpose(0, 2, 1, 3).reshape(
                 b, 1, self.heads * self.dim_head)
-        return self._out_proj(out, qw), cache_k, cache_v
+        return self._out_proj(out, qw, x), cache_k, cache_v
 
     def dense_read_bounds(self) -> Optional[Tuple[int, ...]]:
         """The prefixes the static sampler's :meth:`decode_step` chooses
@@ -1383,7 +1492,7 @@ class MultiHeadAttention(nn.Module):
         out = self._aligned_read(q, k_vals, k_scale, v_vals, v_scale,
                                  idx, r, x.dtype, form)
         out = out.transpose(0, 2, 1, 3).reshape(b, 1, self.heads * self.dim_head)
-        return self._out_proj(out, qw), cache_k, cache_v
+        return self._out_proj(out, qw, x), cache_k, cache_v
 
     def _aligned_read(self, q, k_vals, k_scale, v_vals, v_scale, idx, r,
                       out_dtype, form: CacheForm = CacheForm()):
@@ -1532,7 +1641,7 @@ class MultiHeadAttention(nn.Module):
                                  idx_f, r_f, x.dtype, form)
         out = out.transpose(0, 2, 1, 3).reshape(
             b, K, self.heads * self.dim_head)
-        return self._out_proj(out, qw), cache_k, cache_v
+        return self._out_proj(out, qw, x), cache_k, cache_v
 
     def _cache_values(self, attn, v, v_scale, out_dtype):
         """``attn`` (f32) over a cache read's values: :meth:`_attn_v`, or

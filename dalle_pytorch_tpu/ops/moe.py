@@ -38,9 +38,10 @@ Three layers share one routing rule (:func:`route`): :class:`MoEFeedForward`
 above, the 2021 block's GEGLU experts; :class:`ExpertsReGLU`, the dropless
 bias-free ReGLU experts of a ``TrunkSpec`` trunk
 (ops/transformer.py::TrunkMoEBlock), whose router logits come from the
-caller; and :class:`ExpertsSwiGLUShared`, SwiGLU experts under a sigmoid
-router with a selection bias, beside a shared expert, on the experts this
-device holds (ops/transformer.py::TrunkSharedMoEBlock).
+caller; and :class:`ExpertsSwiGLUShared`, SwiGLU experts under a softmax
+or a sigmoid router (the latter with a selection bias), their weights
+scaled, beside a shared expert, on the experts this device holds
+(ops/transformer.py::TrunkSharedMoEBlock).
 """
 from __future__ import annotations
 
@@ -67,10 +68,10 @@ def route(logits, k: int, scoring: str = "softmax", bias=None,
     ``jax.lax.top_k`` breaks exact ties towards the lower index.
 
     ``scoring`` "softmax": the scores are the softmax's probabilities and a
-    row of ``combine`` sums to 1.  "sigmoid" (``noaux_tc``): each score is its
-    own logit's sigmoid; ``bias`` ``[e]`` is added for the CHOICE alone (the
-    ``k`` largest of ``score + bias``) and never enters a weight, which is
-    ``scale * score_e / (sum of the chosen scores + 1e-20)``."""
+    row of ``combine`` sums to ``scale``.  "sigmoid" (``noaux_tc``): each
+    score is its own logit's sigmoid; ``bias`` ``[e]`` is added for the CHOICE
+    alone (the ``k`` largest of ``score + bias``) and never enters a weight,
+    which is ``scale * score_e / (sum of the chosen scores + 1e-20)``."""
     assert scoring in SCORINGS, scoring
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits.astype(jnp.float32))
@@ -83,8 +84,7 @@ def route(logits, k: int, scoring: str = "softmax", bias=None,
         combine = scale * combine / (
             combine.sum(axis=-1, keepdims=True) + 1e-20)
         return scores, top_idx, combine
-    assert bias is None and scale == 1.0, (
-        "a selection bias and a scale belong to sigmoid scoring")
+    assert bias is None, "a selection bias belongs to sigmoid scoring"
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     e = probs.shape[-1]
     top_p, top_idx = jax.lax.top_k(probs, k)
@@ -92,6 +92,8 @@ def route(logits, k: int, scoring: str = "softmax", bias=None,
     combine = (top_p[..., None] * onehot).sum(axis=-2)      # [..., e]
     combine = combine / jnp.clip(
         combine.sum(axis=-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        combine = scale * combine
     return probs, top_idx, combine
 
 
@@ -183,16 +185,20 @@ class ExpertsReGLU(nn.Module):
 
 
 class ExpertsSwiGLUShared(nn.Module):
-    """Dropless mixture of SwiGLU experts under a sigmoid router, beside
-    shared experts that every token takes, on the experts THIS device holds
-    (GLM-4.7-Flash's ``noaux_tc`` layer; ops/transformer.py::
-    TrunkSharedMoEBlock).  With ``m`` the normed input of the sublayer::
+    """Dropless mixture of SwiGLU experts beside shared experts that every
+    token takes, on the experts THIS device holds (ops/transformer.py::
+    TrunkSharedMoEBlock).  With ``m`` the normed input of the sublayer,
+    ``scoring`` "sigmoid" (GLM-4.7-Flash's ``noaux_tc`` layer)::
 
         sc     = sigmoid(m W_r)                     # float32, all ``experts``
         chosen = the k largest of sc + b            # b: selection bias only
         w_e    = scale * sc_e / (sum_chosen sc + 1e-20)
         y      = sum_{held e} w_e W_down_e(silu(W_gate_e m) * (W_up_e m))
                  + W_down_s(silu(W_gate_s m) * (W_up_s m))
+
+    and "softmax" (Laguna's): ``p = softmax(m W_r)``, the k largest of ``p``,
+    ``w_e = scale * p_e / sum_chosen p``; no selection bias, and no
+    ``router_bias`` leaf.
 
     ``experts`` stays the router's width; ``held`` banks exist here, experts
     ``first .. first + held - 1`` (the share of a deployment that splits the
@@ -201,7 +207,7 @@ class ExpertsSwiGLUShared(nn.Module):
     added is left out.  On one device there is no exchange; ``held =
     experts`` is the whole layer.  The ``shared`` shared experts are one
     SwiGLU of width ``shared x expert_dim``.  Scopes: ``moe-route`` (router
-    product, sigmoid, top-k, weights) and ``moe-experts`` (the banks'
+    product, scores, top-k, weights) and ``moe-experts`` (the banks'
     products and the shared expert's)."""
 
     dim: int
@@ -211,6 +217,7 @@ class ExpertsSwiGLUShared(nn.Module):
     held: int
     first: int = 0
     shared: int = 1
+    scoring: str = "sigmoid"
     scale: float = 1.0
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
@@ -226,11 +233,12 @@ class ExpertsSwiGLUShared(nn.Module):
                                    (d, self.experts), **bank)
         # enters the choice and never a weight; drawn, not zero, so that a
         # seeded model's choices depend on it
-        self.router_bias = self.param(
-            "router_bias",
-            lambda key, shape: jax.random.uniform(key, shape, jnp.float32,
-                                                  -0.1, 0.1),
-            (self.experts,))
+        if self.scoring == "sigmoid":
+            self.router_bias = self.param(
+                "router_bias",
+                lambda key, shape: jax.random.uniform(key, shape, jnp.float32,
+                                                      -0.1, 0.1),
+                (self.experts,))
         self.w_gate = self.param("w_gate", fan_in_normal(d), (e, d, f),
                                  **bank)
         self.w_up = self.param("w_up", fan_in_normal(d), (e, d, f), **bank)
@@ -254,8 +262,10 @@ class ExpertsSwiGLUShared(nn.Module):
             logits = jnp.einsum("td,de->te", x,
                                 self.w_router.astype(self.dtype),
                                 preferred_element_type=jnp.float32)
-            _, top_idx, combine = route(logits, self.k, "sigmoid",
-                                        self.router_bias, self.scale)
+            _, top_idx, combine = route(
+                logits, self.k, self.scoring,
+                self.router_bias if self.scoring == "sigmoid" else None,
+                self.scale)
             # what this layer's routing chose and how it weighted its
             # choices, for tests and the benchmark's comparison (a no-op
             # unless "intermediates" is mutable)

@@ -26,21 +26,23 @@ import jax.numpy as jnp
 
 from ..obs import prof, telemetry
 from ..utils.helpers import cast_tuple, default
-from .attention import AttnPattern, MultiHeadAttention
+from .attention import YaRN, AttnPattern, MultiHeadAttention
 from .latent_attention import LatentAttention
 from .linear_attention import GatedDeltaMixer
 from .reversible import reversible_sequence, reversible_sequence_naive
 from .ssm import MambaMixer, fan_in_normal, rms_norm
 
-MIXERS = ("attention", "gdn", "mamba", "mla", "window")
+MIXERS = ("attention", "gdn", "mamba", "mla", "rotated", "window")
 #: the mixers whose decode state is a recurrent state (two leaves with the
 #: rows on axis 0 and no position axis), not keys and values
 RECURRENT_MIXERS = ("gdn", "mamba")
 #: the mixers that rotate their queries and keys by position
-ROTARY_MIXERS = ("mla", "window")
+ROTARY_MIXERS = ("mla", "rotated", "window")
 FFS = ("swiglu", "moe_reglu", "moe_swiglu_shared")
 #: the feed-forwards that route tokens to experts
 ROUTED_FFS = ("moe_reglu", "moe_swiglu_shared")
+#: how a routed layer scores its experts (ops/moe.py::route)
+SCORINGS = ("softmax", "sigmoid")
 NORM_AT = ("input", "output")
 
 
@@ -57,8 +59,14 @@ class TrunkSpec:
     Every field is a model field (it changes the parameter tree or the
     mathematics).  Mixers: ``"attention"`` is global grouped-query attention
     without position encoding, ``"window"`` the same attention rotated
-    (``rope_theta``, ops/attention.py::apply_rope) and bounded to the last
-    ``window`` keys, ``"mamba"`` Mamba-1 with normed ``dt``/``B``/``C``,
+    (``rope_theta`` over every dimension of a head, ops/attention.py::
+    apply_rope) and bounded to the last ``window`` keys, with
+    ``window_heads`` query heads (0: ``DALLEConfig.heads``, which every
+    other attention layer has), ``"rotated"`` global attention rotated by a
+    rotation of its own: ``global_rope_theta`` over the leading
+    ``global_rope_fraction`` of a head's dimensions, with YaRN's
+    frequencies and attention factor where ``yarn_factor`` is set (over
+    ``yarn_original_len`` positions: :meth:`yarn`), ``"mamba"`` Mamba-1 with normed ``dt``/``B``/``C``,
     ``"gdn"`` gated-delta-rule linear attention (ops/linear_attention.py):
     ``DALLEConfig.heads`` heads of ``lin_key_dim`` x ``lin_value_dim`` state
     behind ``lin_conv``-tap convolutions; ``"mla"`` multi-head latent
@@ -69,19 +77,27 @@ class TrunkSpec:
     which with one shared rotary key is all its decode cache holds.
     ``qk_norm``: attention layers RMS-norm their projected queries and keys
     over the projection's whole width, before the split into heads.
+    ``head_gate``: every attention layer multiplies each head's attended
+    values by a sigmoid of the sublayer's normed input, one scalar a head
+    (ops/attention.py::MultiHeadAttention.head_gate).
     Feed-forward: ``"swiglu"`` a dense gated SiLU of width ``ff_dim``;
     ``"moe_reglu"`` ``experts`` routed ReGLU experts of width ``expert_dim``,
     ``experts_per_token`` a token, dropless (ops/moe.py::ExpertsReGLU), whose
     router reads the layer's INPUT (before the norm and the mixer, so the
     two halves of a layer are no longer independent);
     ``"moe_swiglu_shared"`` routed SwiGLU experts of width ``expert_dim``
-    whose sigmoid router reads the sublayer's NORMED input (a selection bias
-    in the choice alone, weights renormalised and scaled by ``route_scale``)
-    beside ``shared_experts`` experts that every token takes
+    whose router reads the sublayer's NORMED input, its weights renormalised
+    over the chosen and scaled by ``route_scale``, beside ``shared_experts``
+    experts that every token takes
     (ops/moe.py::ExpertsSwiGLUShared); ``experts`` stays the router's width
     and ``experts_held`` (0: all) banks exist here, experts ``experts_first``
     onwards: the share of a deployment that splits each layer's experts over
-    devices.  The first ``dense_layers`` layers take the dense ``"swiglu"``
+    devices.  ``scoring`` says how a routed layer scores its experts
+    (ops/moe.py::route): ``"softmax"`` (``"moe_reglu"`` knows no other), or
+    ``"sigmoid"`` with a selection bias in the choice alone; unstated, as in
+    a configuration written before the field, it is what the family of the
+    ``ff`` had then (``"sigmoid"`` for ``"moe_swiglu_shared"``).  The first
+    ``dense_layers`` layers take the dense ``"swiglu"``
     of ``ff_dim`` whatever ``ff`` says (:meth:`ff_kind`).  ``tied_table``:
     one table for the embedding and the head, or (False) a table and a
     separate ``head`` (``models/dalle.py``).  A rotary trunk takes no
@@ -120,9 +136,19 @@ class TrunkSpec:
     experts_first: int = 0
     shared_experts: int = 0
     route_scale: float = 1.0
+    scoring: str = ""
+    window_heads: int = 0
+    global_rope_theta: float = 0.0
+    global_rope_fraction: float = 1.0
+    yarn_factor: float = 0.0
+    yarn_original_len: int = 0
+    head_gate: bool = False
 
     def __post_init__(self):
         object.__setattr__(self, "mixers", tuple(self.mixers))
+        if not self.scoring:
+            object.__setattr__(self, "scoring", (
+                "sigmoid" if self.ff == "moe_swiglu_shared" else "softmax"))
         assert self.mixers and set(self.mixers) <= set(MIXERS), (
             f"trunk mixers {self.mixers} outside {MIXERS}")
         assert self.norm == "rms" and self.ff in FFS, (
@@ -142,6 +168,15 @@ class TrunkSpec:
                 else not any(latent)), (
             f"'mla' layers need q_rank, kv_rank, nope_dim, rope_dim and "
             f"value_dim, which need them: {self.mixers}, {latent}")
+        assert self.window_heads >= 0 and (
+            not self.window_heads or "window" in self.mixers), (
+            f"window_heads belong to 'window' layers: {self.mixers}")
+        assert ("rotated" in self.mixers) == (self.global_rope_theta > 0), (
+            f"'rotated' layers need global_rope_theta and it needs them: "
+            f"{self.mixers}, {self.global_rope_theta}")
+        assert 0 < self.global_rope_fraction <= 1, self.global_rope_fraction
+        assert not self.yarn_factor or "rotated" in self.mixers, (
+            "YaRN scales a 'rotated' layer's rotation")
         assert self.norm_at in NORM_AT, self.norm_at
         assert self.norm_at == "input" or (
             not {"mamba", "mla"} & set(self.mixers)
@@ -151,9 +186,12 @@ class TrunkSpec:
         shared = self.ff == "moe_swiglu_shared"
         assert shared or not (self.dense_layers or self.experts_held
                               or self.experts_first or self.shared_experts
-                              or self.route_scale != 1.0), (
-            "dense_layers, experts_held, experts_first, shared_experts and "
-            f"route_scale belong to ff = 'moe_swiglu_shared', not {self.ff!r}")
+                              or self.route_scale != 1.0
+                              or self.scoring == "sigmoid"), (
+            "dense_layers, experts_held, experts_first, shared_experts, "
+            "route_scale and sigmoid scoring belong to ff = "
+            f"'moe_swiglu_shared', not {self.ff!r}")
+        assert self.scoring in SCORINGS, self.scoring
         if not self.routed or self.dense_layers:
             assert self.ff_dim > 0, "a swiglu feed-forward needs ff_dim"
         if self.routed:
@@ -192,9 +230,11 @@ class TrunkSpec:
         return self.experts_held or self.experts
 
     @property
-    def scoring(self) -> str:
-        """How a routed layer scores its experts (ops/moe.py::route)."""
-        return "sigmoid" if self.ff == "moe_swiglu_shared" else "softmax"
+    def yarn(self) -> Optional[YaRN]:
+        """A "rotated" layer's YaRN scaling, or None (a plain rotation)."""
+        if not self.yarn_factor:
+            return None
+        return YaRN(self.yarn_factor, self.yarn_original_len)
 
 
 def is_rotary(kind: str) -> bool:
@@ -391,9 +431,15 @@ class TrunkAttnBlock(nn.Module):
     dim_head: int
     kv_heads: int
     eps: float = 1e-6
-    rope_theta: Optional[float] = None   # a "window" layer's; its window
-    prenorm: bool = True                 # is the pattern's.  prenorm: the
-    qk_norm: bool = False                # block norms its own input
+    # a rotary layer's rotation (MultiHeadAttention's fields; a "window"
+    # layer's window is the pattern's); prenorm: the block norms its own
+    # input; qk_norm and head_gate: TrunkSpec's
+    rope_theta: Optional[float] = None
+    rope_dim: Optional[int] = None
+    rope_yarn: Optional[YaRN] = None
+    prenorm: bool = True
+    qk_norm: bool = False
+    head_gate: bool = False
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
@@ -403,8 +449,9 @@ class TrunkAttnBlock(nn.Module):
         self.attn = MultiHeadAttention(
             pattern=self.pattern, dim=self.dim, heads=self.heads,
             dim_head=self.dim_head, kv_heads=self.kv_heads, use_bias=False,
-            rope_theta=self.rope_theta, qk_norm=self.qk_norm,
-            norm_eps=self.eps, dtype=self.dtype,
+            rope_theta=self.rope_theta, rope_dim=self.rope_dim,
+            rope_yarn=self.rope_yarn, qk_norm=self.qk_norm,
+            head_gate=self.head_gate, norm_eps=self.eps, dtype=self.dtype,
             param_dtype=self.param_dtype, name="attn")
 
     def _normed(self, x):
@@ -597,9 +644,9 @@ class TrunkMoEBlock(nn.Module):
 
 
 class TrunkSharedMoEBlock(nn.Module):
-    """PreNorm(sigmoid-routed SwiGLU experts + shared experts) of a
-    :class:`TrunkSpec` trunk (ops/moe.py::ExpertsSwiGLUShared), on the
-    experts the spec says are held here.  The router reads the sublayer's
+    """PreNorm(routed SwiGLU experts + shared experts) of a
+    :class:`TrunkSpec` trunk (ops/moe.py::ExpertsSwiGLUShared), scored as
+    the spec states, on the experts the spec says are held here.  The router reads the sublayer's
     normed input, so the block takes the hidden state alone, as
     :class:`SwiGLUBlock` does."""
 
@@ -617,7 +664,7 @@ class TrunkSharedMoEBlock(nn.Module):
             dim=self.dim, experts=spec.experts, k=spec.experts_per_token,
             expert_dim=spec.expert_dim, held=spec.held_experts,
             first=spec.experts_first, shared=spec.shared_experts,
-            scale=spec.route_scale, dtype=self.dtype,
+            scoring=spec.scoring, scale=spec.route_scale, dtype=self.dtype,
             param_dtype=self.param_dtype, name="moe")
 
     def __call__(self, x, deterministic: bool = True):
@@ -752,14 +799,25 @@ class Transformer(nn.Module):
                         name=f"layers_{ind}_attn", **kw))
                 else:
                     windowed = kind == "window"
+                    rotated = kind == "rotated"
                     attn_blocks.append(TrunkAttnBlock(
                         pattern=dataclasses.replace(
                             pattern, window=spec.window) if windowed
-                        else pattern, heads=self.heads,
+                        else pattern,
+                        heads=spec.window_heads or self.heads if windowed
+                        else self.heads,
                         dim_head=self.dim_head, kv_heads=spec.kv_heads,
                         eps=spec.norm_eps,
-                        rope_theta=spec.rope_theta if windowed else None,
+                        rope_theta=(spec.rope_theta if windowed else
+                                    spec.global_rope_theta if rotated
+                                    else None),
+                        rope_dim=int(self.dim_head
+                                     * spec.global_rope_fraction)
+                        if rotated and spec.global_rope_fraction < 1
+                        else None,
+                        rope_yarn=spec.yarn if rotated else None,
                         prenorm=prenorm, qk_norm=spec.qk_norm,
+                        head_gate=spec.head_gate,
                         name=f"layers_{ind}_attn", **kw))
                 ff_kind = spec.ff_kind(ind)
                 if ff_kind == "moe_reglu":

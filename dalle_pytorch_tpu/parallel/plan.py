@@ -55,6 +55,8 @@ TRUNK_RULES: Tuple[Tuple[str, P], ...] = (
     # [dim, 2, kv_heads, dh] stay whole on every tp shard
     (r".*attn/to_q/kernel$", P("fsdp", "tp", None)),
     (r".*attn/to_kv/kernel$", P("fsdp", None, None, None)),
+    # the head gate [dim, heads]: one column a query head, over tp with them
+    (r".*attn/to_gate/kernel$", P("fsdp", "tp")),
     # latent attention: the two down-projections [dim, rank] feed norms over
     # their whole width, so they are replicated in (fsdp on the model
     # width alone); heads are independent from the up-projections on, so

@@ -3,7 +3,7 @@
 One place naming the (config geometry, ParallelPlan) pairs a run can ask
 for by name, so ``train_dalle.py``'s hard-coded CUB block is one preset
 of many and the analysis suite can gate rungs that do not fit a single
-chip.  Four rungs of the 2021 block and one other trunk today:
+chip.  Four rungs of the 2021 block and six other trunks today:
 
 ==========  ======  ========  =======================================
 preset      params  geometry  role
@@ -41,6 +41,15 @@ glm-4.7-flash  (1.15B, dim-2048)  DALL-E over one chip's share of
                               each layer), bf16; one chip generates 128
                               candidates of n = 4,352 over a 3.2 GB
                               latent cache
+laguna-tiny  (~0.1M, dim-32)  rotated global and sliding-window layers
+                              of different head counts, YaRN, a head
+                              gate, softmax-routed SwiGLU experts with a
+                              shared expert, 2 of 8 held (tests)
+laguna-s-2.1  (1.65B, dim-3072)  DALL-E over one chip's share of
+                              Laguna-S-2.1's trunk (5 of 48 layers, 16 of
+                              256 experts a layer held: sixteen chips
+                              share each layer), bf16; one chip
+                              generates 96 candidates of n = 4,352
 ==========  ======  ========  =======================================
 
 ``cub-512`` and ``cub-1024`` are ALSO :data:`~dalle_pytorch_tpu.parallel.
@@ -78,6 +87,8 @@ PARAM_BANDS = {
     "olmo-hybrid-7b": (2.4e9, 2.47e9),
     "glm-flash-tiny": (0.01e6, 1e6),
     "glm-4.7-flash": (1.1e9, 1.2e9),
+    "laguna-tiny": (0.01e6, 1e6),
+    "laguna-s-2.1": (1.6e9, 1.7e9),
 }
 
 
@@ -323,7 +334,8 @@ def olmo_hybrid_7b_config(**overrides):
 #: 64 experts split 8 a chip (``experts_held``, experts 0-7).
 GLM_4_7_FLASH_TRUNK = dict(
     mixers=("mla",), ff_dim=10240, norm="rms", norm_eps=1e-5,
-    ff="moe_swiglu_shared", rope_theta=1000000.0, q_rank=768, kv_rank=512,
+    ff="moe_swiglu_shared", scoring="sigmoid", rope_theta=1000000.0,
+    q_rank=768, kv_rank=512,
     nope_dim=192, rope_dim=64, value_dim=256, dense_layers=1, experts=64,
     experts_per_token=4, expert_dim=1536, experts_held=8, experts_first=0,
     shared_experts=1, route_scale=1.8, tied_table=False,
@@ -373,6 +385,74 @@ def glm_4_7_flash_config(**overrides):
     return DALLEConfig(**base)
 
 
+#: Laguna-S-2.1's trunk (huggingface.co/poolside/Laguna-S-2.1, config.json,
+#: ``laguna``): layer i of 48 is global iff i mod 4 == 0, 48 query heads
+#: rotated by YaRN (theta 5e5, the leading 64 of 128 dimensions, factor 128
+#: over 8,192 positions, attention factor 1.4852), else bounded to 512 keys,
+#: 72 query heads rotated over all 128 dimensions (theta 1e4); 8 key heads
+#: of 128 in both; a sigmoid gate a head on every attention layer; layer 0
+#: a dense SwiGLU of 12,288, the rest 256 softmax-routed SwiGLU experts of
+#: 1,024, 10 a token, weights renormalised and scaled by 2.5, beside one
+#: shared expert; an untied head.  HERE: one chip's share of a deployment
+#: in which sixteen chips share each layer, the 256 experts split 16 a chip
+#: (``experts_held``, experts 0-15).
+LAGUNA_S_2_1_TRUNK = dict(
+    mixers=("rotated", "window", "window", "window"), ff_dim=12288,
+    kv_heads=8, norm="rms", norm_eps=1e-6, ff="moe_swiglu_shared",
+    scoring="softmax", window=512, rope_theta=10000.0, window_heads=72,
+    global_rope_theta=500000.0, global_rope_fraction=0.5, yarn_factor=128.0,
+    yarn_original_len=8192, head_gate=True,
+    dense_layers=1, experts=256, experts_per_token=10, expert_dim=1024,
+    experts_held=16, experts_first=0, shared_experts=1, route_scale=2.5,
+    tied_table=False, param_dtype="bfloat16")
+
+
+def laguna_tiny_config(**overrides):
+    """The same trunk at toy width with every mechanism (tests): a dense
+    global layer, three window layers and a routed global one; global and
+    window layers of different head counts (4 and 6 over 2 keys of 16), the
+    leading 8 dimensions of a global head rotated by YaRN (its ramp over
+    pairs 1-3), a window of 4 that the 24 positions wrap five times, 8
+    experts of which 2 are held, 3 a token."""
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=32, depth=5, heads=4, dim_head=16, num_text_tokens=50,
+                text_seq_len=8, num_image_tokens=32, image_size=64,
+                image_fmap_size=4,
+                trunk=dict(LAGUNA_S_2_1_TRUNK, ff_dim=80, kv_heads=2,
+                           window=4, window_heads=6, experts=8,
+                           experts_per_token=3, expert_dim=24,
+                           experts_held=2, experts_first=2,
+                           param_dtype="float32"))
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
+def laguna_s_2_1_config(**overrides):
+    """DALL-E's client over one chip's share of Laguna-S-2.1's trunk, every
+    width as published: the leading dense layer and the four routed layers
+    that follow (5 of 48: global, window, window, window, global), each
+    routed layer's router over all 256 experts and the banks of experts
+    0-15 (16 of 256: sixteen chips share each layer), the shared expert, the
+    whole 100,352-row table and head: 1.653B parameters, 3.31 GB in
+    bfloat16.  The rows are 91,904 text ids + 256 per-position pad ids +
+    8,192 image codes of a 512 px, 64 x 64 code grid (n = 4,352, so that
+    the 512-key window wraps its ring 4.5 times a request).
+    ``benchmark/configs/laguna-s-2.1.json`` is the same model as the
+    benchmark runs it; the other 43 layers would lie on further chips."""
+    import jax.numpy as jnp
+
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=3072, depth=5, heads=48, dim_head=128,
+                num_text_tokens=91904, text_seq_len=256,
+                num_image_tokens=8192, image_size=512, image_fmap_size=64,
+                attn_types=("full",), trunk=LAGUNA_S_2_1_TRUNK,
+                dtype=jnp.bfloat16)
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
 #: Every named config geometry (CLI ``--preset`` surface).
 CONFIG_PRESETS = {
     "tiny": tiny_config,
@@ -387,6 +467,8 @@ CONFIG_PRESETS = {
     "olmo-hybrid-7b": olmo_hybrid_7b_config,
     "glm-flash-tiny": glm_flash_tiny_config,
     "glm-4.7-flash": glm_4_7_flash_config,
+    "laguna-tiny": laguna_tiny_config,
+    "laguna-s-2.1": laguna_s_2_1_config,
 }
 
 #: The scale rungs that are ALSO plan-registry entries: registry name ->

@@ -585,6 +585,49 @@ def test_the_one_pass_latent_read_compiles_at_the_cells_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < cache // 4
 
 
+def test_the_window_and_global_scan_compiles_at_published_widths(one_chip):
+    """``laguna-s-2.1-generate``'s decode program at the cell's 96 rows and
+    published widths, depth cut to 2 (a YaRN-rotated global layer of 48
+    heads and a 512-key window layer of 72, over 8 key heads of 128, both
+    gated): it compiles for the v5e, carries the global cache whole and the
+    window's ring at 512 slots, holds one bounded read a layer (a switch of
+    ``read_bounds`` of the slots each holds), and plans temporaries of about
+    the scan's own copy of the caches, no more."""
+    from benchmark import harness
+    from dalle_pytorch_tpu.models.dalle import (decode_codes, prefill_codes,
+                                                tile_prefill)
+
+    cell = harness.load_cell("laguna-s-2.1-generate")
+    rows, n_prime = int(cell.traffic["fanout"]), int(
+        cell.traffic["prime_codes"])
+    cfg = dataclasses.replace(harness.build_configs(cell.config)[0], depth=2)
+    assert cfg.mixers == ("rotated", "window") and rows == 96
+    model, shapes = _param_shapes(cfg)
+    variables = {"params": shapes}
+    text = jnp.zeros((1, cfg.text_seq_len), jnp.int32)
+    prime = jnp.zeros((1, n_prime), jnp.int32)
+    first, caches = jax.eval_shape(
+        lambda v, t, p: tile_prefill(*prefill_codes(model, v, t,
+                                                    prime_codes=p), rows),
+        variables, text, prime)
+    assert [c[0].shape for c in caches] == [(rows, 8, 4352, 128),
+                                           (rows, 8, 512, 128)]
+    compiled = jax.jit(lambda v, f, c, k, p: decode_codes(
+        model, v, f, c, k, n_prime=n_prime,
+        prime_codes=jnp.repeat(p, rows, axis=0), filter_thres=0.9)).lower(
+            _on(one_chip, variables), _on(one_chip, first),
+            _on(one_chip, caches),
+            jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
+            jax.ShapeDtypeStruct(prime.shape, jnp.int32,
+                                 sharding=one_chip)).compile()
+    assert compiled.as_text().count(" conditional(") == 2
+    cache_bytes = sum(2 * math.prod(c[0].shape) * 2 for c in caches)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1.3 * cache_bytes
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < V5E_HBM_BYTES)
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("spec", chip_smoke.FULL.plan_specs)
 def test_sharded_step_compiles_for_four_chips(topo, spec):
