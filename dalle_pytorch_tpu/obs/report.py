@@ -392,6 +392,7 @@ def build_report(events: List[dict]) -> dict:
     # record per trace of a model: layers on the flash kernel, layers on the
     # dense-masked branch, the share of blocks the flash layers compute, and
     # the kernel's operand contract (heads a program, padding it adds in HBM)
+    # and the pallas_calls a flash layer's backward makes
     kernel = [r for r in events if r.get("kind") == "attention"
               and r.get("name") == "kernel"]
     attention_report: Optional[dict] = None
@@ -399,7 +400,8 @@ def build_report(events: List[dict]) -> dict:
         attention_report = {"traces": len(kernel), **{
             k: kernel[-1].get(k) for k in
             ("model", "n", "tiles", "flash_layers", "dense_layers",
-             "blocks_computed_share", "heads_per_program", "hbm_pad_rows")}}
+             "blocks_computed_share", "heads_per_program", "hbm_pad_rows",
+             "backward_calls")}}
         # latent layers say how a tick of theirs reads the cache; the line
         # stands under `-- decode --`, beside the latent cache's
         if kernel[-1].get("latent_read") and "latent" in (decode_report or {}):
@@ -784,7 +786,8 @@ def render_text(report: dict) -> str:
             f"kernel (tiles {', '.join(att.get('tiles') or []) or '-'}; "
             f"{100 * (att.get('blocks_computed_share') or 0):.1f}% of their "
             f"blocks computed; {att.get('heads_per_program')} heads a "
-            f"program, {att.get('hbm_pad_rows')} rows of padding in HBM), "
+            f"program, {att.get('hbm_pad_rows')} rows of padding in HBM, "
+            f"{att.get('backward_calls')} backward call(s) a layer), "
             f"{att.get('dense_layers')} dense "
             f"(n {att.get('n')}; last of {att.get('traces')} "
             f"{att.get('model')} traces; the kernel is lowered for a TPU "

@@ -656,11 +656,12 @@ def kernel_mesh(partitioner) -> Iterator[None]:
 def record_kernel_choices(model: str) -> Iterator[None]:
     """Collect every attention layer's choice during one trace of a model
     and say what was chosen: one ``attention.kernel`` telemetry record and
-    five gauges (flash layers, dense layers, latent layers where the model has
+    its gauges (flash layers, dense layers, latent layers where the model has
     any, the share of blocks the flash
-    layers compute, skipped blocks left out, and the kernel's operand
+    layers compute, skipped blocks left out, the kernel's operand
     contract: heads a program shares the lanes between, positions of
-    padding a call adds to a sequence in HBM).  A layer traced twice (a
+    padding a call adds to a sequence in HBM, and the ``pallas_call``s a
+    flash layer's backward makes).  A layer traced twice (a
     reversible stack's custom VJP) counts once: its pattern is its key."""
     _choices.append({})
     try:
@@ -684,7 +685,9 @@ def record_kernel_choices(model: str) -> Iterator[None]:
                 "heads_per_program":
                     max((c["heads_per_program"] for c in flash), default=0),
                 "hbm_pad_rows":
-                    max((c["hbm_pad_rows"] for c in flash), default=0)}
+                    max((c["hbm_pad_rows"] for c in flash), default=0),
+                "backward_calls":
+                    max((c["backward_calls"] for c in flash), default=0)}
             telemetry.emit(
                 "attention", "kernel", model=model,
                 n=max(c["n"] for c in layers),
@@ -1113,7 +1116,8 @@ class MultiHeadAttention(nn.Module):
             choice = dict(n=n, tiles=core and core.tiles, computed=0,
                           blocks=0)
             if core is not None:
-                from .attention_pallas import HBM_PAD_ROWS, block_counts
+                from .attention_pallas import (BACKWARD_CALLS, HBM_PAD_ROWS,
+                                               block_counts)
 
                 skipped, partly, wholly = block_counts(core.pattern, n,
                                                        *core.tiles)
@@ -1122,7 +1126,8 @@ class MultiHeadAttention(nn.Module):
                     blocks=skipped + partly + wholly,
                     heads_per_program=lane_block(
                         core.shard_heads, self.dim_head) // self.dim_head,
-                    hbm_pad_rows=HBM_PAD_ROWS)
+                    hbm_pad_rows=HBM_PAD_ROWS,
+                    backward_calls=BACKWARD_CALLS)
             _choices[-1][self.pattern] = choice
         return core
 

@@ -15,11 +15,12 @@ Design (TPU-first):
 * **the projections' own arrays** (PR 35): the kernels read q, k and v
   straight out of ``to_qkv``'s result ``[b, n, 3 * heads * dim_head]`` (the
   same array under three block specs), write ``o`` as ``[b, n, heads *
-  dim_head]``, which is ``to_out``'s input as it stands, and write dq and
-  dk into one ``[b, n, 3 * heads * dim_head]`` buffer where q and k lie in
-  ``qkv`` (the dk/dv call takes the dq call's buffer through
-  ``input_output_aliases``); dv, the dk/dv call's second output, is written
-  into the buffer's last third by one in-place update.  A program's block
+  dim_head]``, which is ``to_out``'s input as it stands, and write dq, dk
+  and dv into one ``[b, n, 3 * heads * dim_head]`` array where q, k and v
+  lie in ``qkv``: the backward call leaves that array in HBM and copies its
+  program's three lane blocks into it itself (PR 41; until then a dq call,
+  a dk/dv call that took its buffer through ``input_output_aliases``, and
+  an in-place update for dv).  A program's block
   is the whole sequence, at its own length, by one lane block
   (:func:`~.attention.lane_block`: 128 lanes, two heads of 64 side by side,
   or one head of 128); the grid is ``(batch, lane blocks)``, and a program
@@ -46,14 +47,18 @@ Design (TPU-first):
   the probabilities to the activation dtype before ``attn.v``), with f32
   inputs nothing is rounded.  ``q * dim_head ** -0.5`` is taken in the input
   dtype, before the product, as the dense path does.  Running max, sum,
-  ``exp``, logsumexp, delta (computed in the dq kernel from ``do`` and
+  ``exp``, logsumexp, delta (computed in the backward from ``do`` and
   ``o``) and the accumulators are float32 whatever the inputs.
 * **keys/values stay VMEM-resident** per program: at n≈1104 a lane block's
   q, k, v fit comfortably, so the inner loop does no HBM traffic at all.
-* full custom VJP: flash backward (dq, then dk/dv on transposed tiles so
-  that every product is in the MXU's native form) with the same block
-  table, using the saved logsumexp rows; both backward kernels are traced
-  under the forward's ``graftprof:attn-scores`` scope.
+* full custom VJP: ONE flash backward kernel (PR 41) with the same block
+  table, using the saved logsumexp rows: on transposed tiles, a key block
+  at a time, each computed block's scores, probabilities and their
+  gradient made once and used for dk, dv and dq (five products where the
+  two kernels before it made seven and two passes of the scores); dq
+  accumulates in a float32 VMEM array of the whole sequence, which fits
+  because a program holds one sample's whole sequence.  It is traced under
+  the forward's ``graftprof:attn-scores`` scope.
 """
 from __future__ import annotations
 
@@ -81,8 +86,13 @@ SKIP, WHOLE, PARTIAL = 0, 1, 2
 #: until PR 35; the ``attention.kernel`` record carries it)
 HBM_PAD_ROWS = 0
 
+#: ``pallas_call``s one flash layer's backward makes (2 until PR 41: dq, then
+#: dk and dv; the ``attention.kernel`` record carries it)
+BACKWARD_CALLS = 1
+
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def _round_up(x: int, m: int) -> int:
@@ -172,7 +182,7 @@ def block_counts(pattern: AttnPattern, n: int, block_q: int,
 # pipeline fetches the next step's blocks during the current step, and a
 # program that is one step hides the next program's q, k, v behind all of
 # its work, not behind its last head's (PERF.md, Findings PR 35: as a grid
-# axis the dk/dv kernel waited 4.7 us a program for them).
+# axis the then dk/dv kernel waited 4.7 us a program for them).
 #
 # Two heads share the 128 lanes of a block.  A step has its head's operand
 # by a select over the lanes (:func:`_only`), never by a slice: a product
@@ -250,32 +260,27 @@ def _fwd_block(q, k_blk, v_blk, bias, tile, carry, *, guard: bool):
     return m_new, l * alpha + l_new, acc * alpha + pv
 
 
-def _dq_block(q, do, lse, delta, k_blk, v_blk, bias, tile, dq):
-    s = _scores(q, k_blk, bias, tile)
-    p = jnp.exp(s - lse)                      # [bq, bk]
-    dp = jax.lax.dot_general(do, v_blk, _NT,
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    return dq + jax.lax.dot_general(ds.astype(k_blk.dtype), k_blk, _NN,
-                                    preferred_element_type=jnp.float32)
-
-
-def _dkv_block(k_blk, v_blk, q, do, lse, delta, bias, tile, dk, dv):
-    """On *transposed* tiles ``[bk, bq]``: the scores as ``k @ q.T``, so
-    that logsumexp and delta (``[1, bq]``, stored along the lanes)
-    broadcast down the sublanes and all five products are ``a @ b`` or ``a
-    @ b.T`` — no transposed operand, no lane-to-sublane move.  ``bias`` is
-    a column, ``tile`` a transposed mask tile."""
+def _bwd_block(k_blk, v_blk, q, do, lse, delta, bias, tile, dk, dv):
+    """One computed block of the backward, on *transposed* tiles ``[bk,
+    bq]``: the scores as ``k @ q.T``, so that logsumexp and delta (``[1,
+    bq]``, stored along the lanes) broadcast down the sublanes; the scores,
+    probabilities and their gradient made once for all three gradients.
+    Returns dk and dv with the block's part added, and the block's part of
+    dq, ``ds_t.T @ k`` (unscaled): of the five products it is the one with a
+    transposed operand.  ``bias`` is a column, ``tile`` a transposed mask
+    tile."""
     s_t = _scores(k_blk, q, bias, tile)
     p_t = jnp.exp(s_t - lse)                                 # [bk, bq]
     dv = dv + jax.lax.dot_general(p_t.astype(do.dtype), do, _NN,
                                   preferred_element_type=jnp.float32)
     dp_t = jax.lax.dot_general(v_blk, do, _NT,
                                preferred_element_type=jnp.float32)
-    ds_t = p_t * (dp_t - delta)
-    dk = dk + jax.lax.dot_general(ds_t.astype(q.dtype), q, _NN,
+    ds_t = (p_t * (dp_t - delta)).astype(q.dtype)
+    dk = dk + jax.lax.dot_general(ds_t, q, _NN,
                                   preferred_element_type=jnp.float32)
-    return dk, dv
+    dq = jax.lax.dot_general(ds_t, k_blk, _TN,
+                             preferred_element_type=jnp.float32)
+    return dk, dv, dq
 
 
 def _computed(blocks: PatternBlocks, qb=None, kb=None):
@@ -322,54 +327,39 @@ def _fwd_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest, static: "_Static",
     _each_head(static, one_head)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest, static: "_Static",
-                   has_bias: bool):
-    """dq a query block at a time, and delta (the row sums of ``do * o``
-    over the head's lanes) on the way, for the dk/dv kernel."""
-    bias_ref = rest[0] if has_bias else None
-    do_ref, o_ref, lse_ref, dq_ref, delta_ref = rest[-5:]
-    blocks, bq, bk = static.blocks, static.block_q, static.block_k
-
-    def one_head(head):
-        own = _own_lanes(static, head)
-        for qb, q0 in enumerate(static.q_starts):
-            rows = slice(q0, q0 + bq)
-            q = _only(q_ref[0, rows, :], own) * static.scale
-            do = _only(do_ref[0, rows, :], own)
-            delta = jnp.sum(do.astype(jnp.float32)
-                            * o_ref[0, rows, :].astype(jnp.float32),
-                            axis=1, keepdims=True)              # [bq, 1]
-            lse = lse_ref[0, head, qb, :][:, None]
-            dq = jnp.zeros((bq, static.lanes), jnp.float32)
-            for kb, number in _computed(blocks, qb=qb):
-                cols = slice(static.k_starts[kb], static.k_starts[kb] + bk)
-                dq = _dq_block(
-                    q, do, lse, delta, k_ref[0, cols, :], v_ref[0, cols, :],
-                    bias_ref[0, kb:kb + 1, :] if has_bias else None,
-                    None if number is None else tiles_ref[number], dq)
-            _store(dq_ref, (0,), qb * bq, (dq * static.scale)[qb * bq - q0:],
-                   own)
-            delta_ref[0, head, qb, :] = delta[:, 0]
-
-    _each_head(static, one_head)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest,
-                    static: "_Static", has_bias: bool):
-    """dk and dv, a key block at a time (:func:`_dkv_block`); ``tiles_ref``
-    holds the mask tiles transposed.  The scaled, selected q and the
-    selected do are made once a head: the inner loop reads them as often as
-    it has blocks."""
+def _bwd_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest, static: "_Static",
+                has_bias: bool):
+    """dq, dk and dv in one pass over the computed blocks, a key block at a
+    time (:func:`_bwd_block`); ``tiles_ref`` holds the mask tiles
+    transposed.  A head first makes its scaled, selected q, its selected do
+    and delta (the row sums of ``do * o`` over its lanes, in the statistics'
+    lane layout) once, and zeroes a float32 dq accumulator of the whole
+    sequence, to which each block adds its part in its query block's rows.
+    The gradients are gathered in a VMEM copy of the program's three lane
+    blocks (``grads``, two of them: the programs of a sample's lane blocks
+    take turns), copied into ``dqkv`` (left in HBM) at the program's end and
+    waited for at the next program's end, so that the copies run under the
+    next program's blocks and no branch lies among the blocks."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     bias_ref = rest[0] if has_bias else None
-    do_ref, lse_ref, delta_ref, _, dk_ref, dv_ref, q_own, do_own = rest[-8:]
+    (do_ref, o_ref, lse_ref, dqkv_ref, q_own, do_own, delta, dq, grads,
+     sems) = rest[-10:]
     blocks, bq, bk = static.blocks, static.block_q, static.block_k
+    sample, block = pl.program_id(0), pl.program_id(1)
+    slot = block % 2
 
     def one_head(head):
         own = _own_lanes(static, head)
         q_own[...] = _only(q_ref[0], own) * static.scale
         do_own[...] = _only(do_ref[0], own)
+        for qb, q0 in enumerate(static.q_starts):
+            rows = slice(q0, q0 + bq)
+            delta[qb, :] = jnp.sum(do_own[rows, :].astype(jnp.float32)
+                                   * o_ref[0, rows, :].astype(jnp.float32),
+                                   axis=1)
+        dq[...] = jnp.zeros(dq.shape, jnp.float32)
         for kb, k0 in enumerate(static.k_starts):
             k_blk, v_blk = k_ref[0, k0:k0 + bk, :], v_ref[0, k0:k0 + bk, :]
             # the bias over this key block, as a column
@@ -378,16 +368,40 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, tiles_ref, *rest,
             dv = jnp.zeros((bk, static.lanes), jnp.float32)
             for qb, number in _computed(blocks, kb=kb):
                 rows = slice(static.q_starts[qb], static.q_starts[qb] + bq)
-                dk, dv = _dkv_block(
+                dk, dv, dq_blk = _bwd_block(
                     k_blk, v_blk, q_own[rows, :], do_own[rows, :],
                     lse_ref[0, pl.ds(head, 1), qb, :],
-                    delta_ref[0, pl.ds(head, 1), qb, :], bias,
+                    delta[qb:qb + 1, :], bias,
                     None if number is None else tiles_ref[number], dk, dv)
+                dq[rows, :] = dq[rows, :] + dq_blk
             new = kb * bk - k0
-            _store(dk_ref, (0,), kb * bk, dk[new:], own)
-            _store(dv_ref, (0,), kb * bk, dv[new:], own)
+            _store(grads, (slot, 1), kb * bk, dk[new:], own)
+            _store(grads, (slot, 2), kb * bk, dv[new:], own)
+        _store(grads, (slot, 0), 0, dq[...] * static.scale, own)
 
     _each_head(static, one_head)
+
+    def copies(turn, lane_block):
+        """The copies of ``grads[turn]`` into the sample's lane block
+        ``lane_block`` of each third."""
+        return [pltpu.make_async_copy(
+            grads.at[turn, third], dqkv_ref.at[sample, :, pl.ds(
+                pl.multiple_of((third * static.lane_blocks + lane_block)
+                               * static.lanes, static.lanes), static.lanes)],
+            sems.at[turn, third]) for third in range(3)]
+
+    @pl.when(block > 0)
+    def _():
+        for copy in copies(1 - slot, block - 1):
+            copy.wait()
+
+    for copy in copies(slot, block):
+        copy.start()
+
+    @pl.when(block == static.lane_blocks - 1)
+    def _():
+        for copy in copies(slot, block):
+            copy.wait()
 
 
 # ---------------------------------------------------------------------------
@@ -400,19 +414,22 @@ VMEM_LIMIT_BYTES = 96 * 1024 * 1024
 
 
 def _pallas(kernel, static: "_Static", tiles, bias, qkv, wide, stats, outs,
-            *, scratch=0, aliased=None):
-    """One of the three kernels over the grid ``(batch, lane blocks)``: q, k
+            scratch=(), lanes_in_order=False):
+    """One of the two kernels over the grid ``(batch, lane blocks)``: q, k
     and v as column blocks of ``qkv`` (``[b, n, 3 * heads * dim_head]``,
     passed three times), each ``wide`` operand (``[b, n, heads *
     dim_head]``: do, o) the same column block of its array, each of
     ``stats`` the ``[blocks, tile]`` float32 statistics of the block's
     heads, the mask tiles one block for the whole call, the key bias one
-    sample's.  ``outs``: ``("wide", thirds, third)`` is an array of
-    ``thirds * heads * dim_head`` columns of which the program writes its
-    lane block of third ``third``; ``("stat",)`` statistics.  ``aliased``:
-    an array left in HBM that the first output overwrites in place (the
-    blocks no program writes keep what it held).  Every program writes its
-    own blocks exactly once, so both grid axes are parallel.
+    sample's.  ``outs``: ``("wide", thirds)`` is an array of ``thirds *
+    heads * dim_head`` columns, of which the program writes its lane block
+    of the first third through the pipeline, or, with ``thirds`` over 1, its
+    lane block of every third itself (the array stays in HBM); ``("stat",)``
+    statistics.  ``scratch``: the kernel's scratch shapes.  Every program
+    writes its own blocks exactly once, so both grid axes are parallel,
+    unless the programs of a sample must run in the order of its lane
+    blocks (``lanes_in_order``: one waits for the copies of the one
+    before it).
 
     Pallas is imported here, not with the module: a process that finds its
     kernels in the cache (:func:`_kernels`) never pays that second."""
@@ -439,18 +456,14 @@ def _pallas(kernel, static: "_Static", tiles, bias, qkv, wide, stats, outs,
         args.append(bias)
     in_specs += [column(0)] * len(wide) + [stat] * len(stats)
     args += [*wide, *stats]
-    aliases = {}
-    if aliased is not None:
-        aliases[len(args)] = 0
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-        args.append(aliased)
     out_specs, out_shape = [], []
-    for kind, *columns in outs:
+    for kind, *thirds in outs:
         if kind == "wide":
-            thirds, third = columns
-            out_specs.append(column(third))
+            out_specs.append(column(0) if thirds == [1] else
+                             pl.BlockSpec(memory_space=pl.ANY))
             out_shape.append(jax.ShapeDtypeStruct(
-                (b, n, thirds * static.heads * static.dim_head), qkv.dtype))
+                (b, n, thirds[0] * static.heads * static.dim_head),
+                qkv.dtype))
         else:
             out_specs.append(stat)
             out_shape.append(jax.ShapeDtypeStruct(
@@ -458,10 +471,10 @@ def _pallas(kernel, static: "_Static", tiles, bias, qkv, wide, stats, outs,
     return pl.pallas_call(
         functools.partial(kernel, static=static, has_bias=bias is not None),
         grid=(b, groups), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, input_output_aliases=aliases,
-        scratch_shapes=[pltpu.VMEM((n, lanes), qkv.dtype)] * scratch,
+        out_shape=out_shape, scratch_shapes=list(scratch),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=(
+                "parallel", "arbitrary" if lanes_in_order else "parallel"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=static.interpret)(*args)
 
@@ -519,25 +532,28 @@ class _Static(NamedTuple):
 def _call_fwd(static: _Static, qkv, bias):
     """``(o [b, n, heads * dim_head], logsumexp [b, heads, blocks, tile])``."""
     return _pallas(_fwd_kernel, static, jnp.asarray(static.blocks.tiles),
-                   bias, qkv, [], [], [("wide", 1, 0), ("stat",)])
+                   bias, qkv, [], [], [("wide", 1), ("stat",)])
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _call_bwd(static: _Static, qkv, bias, do, o, lse):
-    """``(dqkv [b, n, 3 * heads * dim_head], dv [b, n, heads * dim_head])``:
-    dq and dk where q and k lie in ``qkv``, the columns of dv still to be
-    filled.  The dq call writes its third of the buffer (and delta); the
-    dk/dv call takes the buffer in place and writes dk's third.  A call has
-    one block an output array, so dv comes apart and :func:`_flash_bwd`
-    writes it in."""
-    tiles = static.blocks.tiles
-    buffer, delta = _pallas(
-        _bwd_dq_kernel, static, jnp.asarray(tiles), bias, qkv, [do, o],
-        [lse], [("wide", 3, 0), ("stat",)])
-    return _pallas(
-        _bwd_dkv_kernel, static, jnp.asarray(tiles.transpose(0, 2, 1)), bias,
-        qkv, [do], [lse, delta], [("wide", 3, 1), ("wide", 1, 0)], scratch=2,
-        aliased=buffer)
+    """``dqkv [b, n, 3 * heads * dim_head]``: dq, dk and dv where q, k and v
+    lie in ``qkv``, all three written by the one call (:func:`_bwd_kernel`)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, lanes, dtype = static.n, static.lanes, qkv.dtype
+    scratch = [pltpu.VMEM((n, lanes), dtype),                  # q, scaled
+               pltpu.VMEM((n, lanes), dtype),                  # do
+               pltpu.VMEM((len(static.q_starts), static.block_q),
+                          jnp.float32),                        # delta
+               pltpu.VMEM((n, lanes), jnp.float32),            # dq
+               pltpu.VMEM((2, 3, n, lanes), dtype),            # dq, dk, dv
+               pltpu.SemaphoreType.DMA((2, 3))]
+    (dqkv,) = _pallas(
+        _bwd_kernel, static,
+        jnp.asarray(static.blocks.tiles.transpose(0, 2, 1)), bias, qkv,
+        [do, o], [lse], [("wide", 3)], scratch, lanes_in_order=True)
+    return dqkv
 
 
 # --- the kernels, kept between processes -------------------------------------
@@ -622,17 +638,13 @@ def _flash_fwd(static: _Static, qkv, bias):
 
 def _flash_bwd(static: _Static, residuals, g):
     # a custom VJP's backward is traced outside the forward's name scope:
-    # put the backward kernels under the scope the forward's callers give
+    # put the backward kernel under the scope the forward's callers give
     # it, or a trace reads the forward alone
     with prof.scope("attn-scores"):
         qkv, bias, o, lse = residuals
         b, n = qkv.shape[:2]
-        dqkv, dv = _kernels("bwd", static, qkv.reshape(b, n, -1), bias,
-                            g.astype(qkv.dtype), o, lse)
-        # in place: one read and one write of dv (the kernels fill q's and
-        # k's columns themselves; ROADMAP S3 has what a third would take)
-        dqkv = jax.lax.dynamic_update_slice(
-            dqkv, dv, (0, 0, dqkv.shape[2] - dv.shape[2]))
+        dqkv = _kernels("bwd", static, qkv.reshape(b, n, -1), bias,
+                        g.astype(qkv.dtype), o, lse)
         # the key-padding bias is not trainable
         dbias = None if bias is None else jnp.zeros((b, n), jnp.float32)
         return dqkv.reshape(qkv.shape), dbias
@@ -655,22 +667,27 @@ def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
 def _vmem_resident_bytes(n: int, lanes: int, itemsize: int, block_q: int,
                          block_k: int, tiles: int = 1,
                          has_bias: bool = False) -> int:
-    """VMEM one program holds in the hungriest of the three kernels (dk/dv:
-    q, k, v, do in and dk, dv out, a lane block of the whole sequence each;
-    its two scratch copies; logsumexp, delta and the bias in their block
-    layouts), in VMEM's padded layouts: every operand that moves with
-    the grid twice (Pallas double-buffers them), the scratch and the mask
-    tiles once (the tiles' block never changes), and three float32
-    ``[block_q, block_k]`` tiles of intermediates (scores, probabilities,
-    their gradient).  Held against the compiler's own answers by
-    ``tests/test_tpu_compile.py``: at n = 4176 both take every tiling up to
-    2304 x 2304 and refuse the backward of 2560 x 2560 (this reads 110 MiB
-    there; with one tile of intermediates it read 57 and passed it)."""
+    """VMEM one program holds in the hungrier of the two kernels (the
+    backward: q, k, v, do and o in, a lane block of the whole sequence each;
+    logsumexp and the bias in their block layouts; its scratch: the scaled
+    q, the selected do, two copies of the three gradients' lane blocks, the
+    float32 dq accumulator and delta), in VMEM's padded layouts: every
+    operand that moves with the grid twice (Pallas double-buffers them), the
+    scratch and the mask tiles once (the tiles' block never changes), and
+    three float32 ``[block_q, block_k]`` tiles of intermediates (scores,
+    probabilities, their gradient).  Held against the compiler's own answers
+    by ``tests/test_tpu_compile.py``: at n = 4176 both take every tiling up
+    to 2176 x 2176 (this reads 88.5 MiB) and refuse the backward of 2432 x
+    2432 (105.4; the compiler asks 114.1 MiB there).  Between them, at 2304,
+    the compiler still takes what this refuses (96.7)."""
     seq = _tile_bytes(n, lanes, itemsize)
-    stats = 2 * _tile_bytes(-(-n // block_q), block_q, 4)
+    nq = -(-n // block_q)
+    stats = 2 * _tile_bytes(nq, block_q, 4)
     if has_bias:
         stats += _tile_bytes(-(-n // block_k), block_k, 4)
-    return (2 * (6 * seq + stats) + 2 * seq
+    scratch = (8 * seq + _tile_bytes(n, lanes, 4)
+               + _tile_bytes(nq, block_q, 4))
+    return (2 * (5 * seq + stats) + scratch
             + tiles * _tile_bytes(block_q, block_k, 1)
             + 3 * _tile_bytes(block_q, block_k, 4))
 

@@ -178,6 +178,9 @@ def test_cub200_trace_reports_eight_flash_layers(tmp_path):
     assert (rec["heads_per_program"], rec["hbm_pad_rows"]) == (2, 0)
     assert "graft_attn_heads_per_program 2" in rendered
     assert "graft_attn_hbm_pad_rows 0" in rendered
+    # one pallas_call for a layer's backward (two until PR 41)
+    assert rec["backward_calls"] == 1
+    assert "graft_attn_backward_calls 1" in rendered
     assert "graft_attn_flash_layers 8" in rendered
     assert "graft_attn_dense_layers 0" in rendered
     assert "graft_attn_blocks_computed_share 0." in rendered
@@ -187,7 +190,8 @@ def test_cub200_trace_reports_eight_flash_layers(tmp_path):
     assert "-- attention --" in text
     assert ("attention core: 8 layers on the flash kernel (tiles 128x128, "
             "384x384") in text
-    assert "2 heads a program, 0 rows of padding in HBM" in text
+    assert ("2 heads a program, 0 rows of padding in HBM, 1 backward "
+            "call(s) a layer") in text
 
 
 def test_jamba_trace_reports_no_flash_layer(tmp_path):
@@ -201,6 +205,7 @@ def test_jamba_trace_reports_no_flash_layer(tmp_path):
     assert (records[0]["flash_layers"], records[0]["dense_layers"]) == (0, 2)
     assert records[0]["blocks_computed_share"] == 0.0
     assert records[0]["heads_per_program"] == 0     # no flash layer speaks
+    assert records[0]["backward_calls"] == 0
     assert "graft_attn_flash_layers 0" in rendered
     assert "graft_attn_dense_layers 2" in rendered
 

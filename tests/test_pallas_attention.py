@@ -421,13 +421,29 @@ def test_kernel_under_checkpoint():
                                    rtol=2e-4, atol=2e-4)
 
 
+def _equations(jaxpr, outer=""):
+    """``(name stack, equation)`` of every equation, nested jaxprs included:
+    an equation inside a nested jit names its scopes from that jit on, the
+    jit's own equation carries the rest."""
+    for eqn in jaxpr.eqns:
+        here = f"{outer}/{eqn.source_info.name_stack}"
+        yield here, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(sub, here)
+
+
+@pytest.mark.parametrize("what", ["scope", "no_update", "record"])
 def test_backward_kernels_carry_the_forwards_scope(monkeypatch,
-                                                    no_kernel_files):
-    """All three ``pallas_call``s of a differentiated layer sit under
+                                                   no_kernel_files, what):
+    """A differentiated layer holds two ``pallas_call``s, the forward and one
+    backward (three until PR 41: dq, then dk/dv), both under
     ``graftprof:attn-scores`` (a custom VJP's backward does not inherit the
-    forward's name scope by itself).  Traced, not lowered: the layer's
-    default path with a shape that asks for the kernel, whose TPU branch the
-    jaxpr holds beside the dense one."""
+    forward's name scope by itself); the backward writes dq, dk and dv
+    itself, so no ``dynamic_update_slice`` of the ``[b, n, 3 * heads *
+    dim_head]`` gradient follows it (until PR 41 dv went in by one); and the
+    ``attention.kernel`` record says so, ``backward_calls`` 1.  Traced, not
+    lowered: the layer's default path with a shape that asks for the kernel,
+    whose TPU branch the jaxpr holds beside the dense one."""
     from dalle_pytorch_tpu.ops.attention import MultiHeadAttention
 
     monkeypatch.setattr(attention, "flash_tiles", lambda *a: (128, 128))
@@ -435,19 +451,28 @@ def test_backward_kernels_carry_the_forwards_scope(monkeypatch,
     layer = MultiHeadAttention(pattern=pattern, dim=32, heads=2, dim_head=64)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 128, 32))
     params = layer.init(jax.random.PRNGKey(1), x)
-    jaxpr = jax.make_jaxpr(jax.grad(
-        lambda p: jnp.sum(layer.apply(p, x) ** 2)))(params)
+    records = []
+    monkeypatch.setattr(attention.telemetry, "emit",
+                        lambda kind, name, **kw: records.append(kw))
+    with attention.record_kernel_choices("layer"):
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda p: jnp.sum(layer.apply(p, x) ** 2)))(params)
+    equations = list(_equations(jaxpr.jaxpr))
 
-    def stacks(jaxpr, outer=""):
-        # an equation inside a nested jit names its scopes from that jit on:
-        # the jit's own equation carries the rest
-        for eqn in jaxpr.eqns:
-            here = f"{outer}/{eqn.source_info.name_stack}"
-            if eqn.primitive.name == "pallas_call":
-                yield here
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                yield from stacks(sub, here)
-
-    found = list(stacks(jaxpr.jaxpr))
-    assert len(found) == 3
-    assert all("graftprof:attn-scores" in s for s in found), found
+    if what == "scope":
+        found = [stack for stack, eqn in equations
+                 if eqn.primitive.name == "pallas_call"]
+        assert len(found) == 2
+        assert all("graftprof:attn-scores" in s for s in found), found
+    elif what == "no_update":
+        gradient = (2, 128, 3 * 2 * 64)
+        assert any(eqn.primitive.name == "pallas_call"
+                   and eqn.outvars[0].aval.shape == gradient
+                   for _, eqn in equations)
+        assert not [stack for stack, eqn in equations
+                    if eqn.primitive.name == "dynamic_update_slice"
+                    and eqn.invars[0].aval.shape == gradient]
+    else:
+        assert len(records) == 1
+        assert records[0]["flash_layers"] == 1
+        assert records[0]["backward_calls"] == 1

@@ -108,11 +108,12 @@ def _compile_attention(one_chip, variant, block_q, block_k, shape, grad,
                          ids=["b128x128", "b256x512"])
 @pytest.mark.parametrize("variant", chip_smoke.VARIANTS)
 def test_kernels_compile_at_cub_shape(one_chip, variant, blocks, grad):
-    """_call_fwd alone (1 kernel) / fwd + dq + dk/dv (3 kernels) at the CUB
-    train shape (b16, h8, n1104, dh64) bf16."""
+    """_call_fwd alone (1 kernel) / the forward and the one backward kernel
+    (2; 3 until PR 41, dq apart from dk/dv) at the CUB train shape (b16, h8,
+    n1104, dh64) bf16."""
     compiled = _compile_attention(one_chip, variant, *blocks,
                                   (16, 8, 1104, 64), grad, fmap=32)
-    assert compiled.as_text().count("tpu_custom_call") == (3 if grad else 1)
+    assert compiled.as_text().count("tpu_custom_call") == (2 if grad else 1)
 
 
 @pytest.mark.parametrize("blocks,grad", [
@@ -125,14 +126,15 @@ def test_kernels_compile_at_fmap64_shape(one_chip, blocks, grad):
     more."""
     compiled = _compile_attention(one_chip, "full", *blocks,
                                   (4, 8, 4176, 64), grad, fmap=64)
-    assert compiled.as_text().count("tpu_custom_call") == (3 if grad else 1)
+    assert compiled.as_text().count("tpu_custom_call") == (2 if grad else 1)
 
 
 @pytest.mark.parametrize("blocks,grad,fits", [
     ((256, 512), True, True), ((512, 512), False, True),
-    ((2304, 2304), True, True), ((2560, 2560), True, False)],
-    ids=["b256x512-grad", "b512x512-fwd", "b2304x2304-grad",
-         "b2560x2560-grad"])
+    ((2176, 2176), True, True), ((2432, 2432), True, False),
+    ((2560, 2560), True, False)],
+    ids=["b256x512-grad", "b512x512-fwd", "b2176x2176-grad",
+         "b2432x2432-grad", "b2560x2560-grad"])
 def test_compiler_refuses_large_tiles_at_fmap64(one_chip, blocks, grad, fits):
     """Turned round in PR 28: the compiler's answer and the guard's estimate
     (ops/attention_pallas.py::_vmem_resident_bytes), side by side, at n =
@@ -140,11 +142,14 @@ def test_compiler_refuses_large_tiles_at_fmap64(one_chip, blocks, grad, fits):
     that the estimate counted at a byte an element, and the compiler refused
     the first two pairs while the guard passed them.  The kernel holds the
     pattern's distinct mask tiles once and may take 96 MiB.  On the
-    projections' own arrays (PR 35: a lane block of two heads a program,
-    tiles no longer than the sequence) both accept every tiling up to 2304
-    x 2304 and both refuse the backward at 2560 x 2560 (Mosaic runs out of
-    VMEM on the dk/dv kernel's stack; the estimate reads 110 MiB, counting
-    three float32 tiles of intermediates where it counted one)."""
+    projections' own arrays (PR 35) both took every tiling up to 2304 x 2304
+    and refused the backward at 2560 x 2560.  Re-derived for the one
+    backward kernel (PR 41: a float32 dq accumulator of the whole sequence
+    and two copies of the three gradients' lane blocks held besides): both
+    take 2176 x 2176 (the estimate reads 88.5 MiB) and both refuse 2432 x
+    2432 (105.4; the compiler asks 114.1) and 2560 x 2560 (114.5).  At 2304
+    the compiler still takes what the guard refuses (96.7): the guard errs
+    on the side that costs a smaller tile, never a failed compile."""
     from dalle_pytorch_tpu.ops import attention_pallas as ap
 
     pattern = AttnPattern(variant="full", seq_len=4175, text_len=80, fmap=64)
@@ -182,11 +187,12 @@ def _train_cell_step(name, devices):
 
 
 def _assert_flash_step(cfg, compiled):
-    """Three kernels a layer, each under ``graftprof:attn-scores`` (or the
-    trace would read the forward alone), and no ``f32[.., n, n]`` left."""
+    """Two kernels a layer (three until PR 41), each under
+    ``graftprof:attn-scores`` (or the trace would read the forward alone),
+    and no ``f32[.., n, n]`` left."""
     text = compiled.as_text()
     calls = _kernel_calls(text)
-    assert len(calls) == 3 * cfg.depth
+    assert len(calls) == 2 * cfg.depth
     assert all("graftprof:attn-scores" in line for line in calls)
     n = cfg.seq_len
     for side in {n, -(-n // 128) * 128}:
@@ -209,19 +215,33 @@ def _glue(hlo_text, n):
     return found
 
 
+def _gradient_moves(hlo_text, batch, cfg):
+    """Instructions, fused or not, that update or copy a whole ``bf16[batch,
+    n, 3 * heads * dim_head]`` array: what the backward kernel's gradient
+    would cost beside the call (until PR 41 dv went into the dk/dv call's
+    buffer by one ``dynamic-update-slice``)."""
+    shape = f"bf16[{batch},{cfg.seq_len},{3 * cfg.heads * cfg.dim_head}]"
+    return [line.split(" = ")[0].strip() for line in hlo_text.splitlines()
+            if f"= {shape}" in line and re.search(
+                r" (dynamic-update-slice|copy|copy-start)\(", line)]
+
+
 def test_default_cub200_train_step_holds_the_kernel(topo):
     """``cub200-train``'s step as the benchmark builds it (batch 16, the VAE
-    inside): 24 kernels on the projections' own arrays (PR 35): nothing is
+    inside): 16 kernels on the projections' own arrays (24 until PR 41,
+    which made the backward one call; PR 35): nothing is
     padded or sliced under ``attn-scores`` (the parent held 48 pads and 56
     slices of 1104 -> 1152 about its kernels), and no copy or transposition
     of a ``bf16[.., 1104, ..]`` activation lies between ``to_qkv``'s product
     and a kernel or between a kernel and ``to_out``'s (the parent: 64
     copies); the compiler plans 2.34 GB (my AOT compile, PR 35) where the
     padded, head-major residuals made it 3.11 and the dense scores 7.57
-    (ledger, PR 27)."""
+    (ledger, PR 27).  Nor does the gradient of ``qkv`` move beside the
+    backward call: no update or copy of a ``bf16[16, 1104, 1536]``."""
     cfg, compiled = _train_cell_step("cub200-train", topo.devices)
     _assert_flash_step(cfg, compiled)
     assert _glue(compiled.as_text(), cfg.seq_len) == []
+    assert _gradient_moves(compiled.as_text(), 16, cfg) == []
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             < 2.4 * 2 ** 30)
@@ -233,13 +253,15 @@ def test_default_lucid1024_dp_step_splits_the_kernel(topo):
     Mosaic kernel cannot be partitioned by GSPMD), so the step compiles, holds
     no all-gather, and its collectives are the gradient all-reduces alone:
     559.6 MB of result bytes, ``collective_bytes_per_step`` of the ledger's
-    PR 27."""
+    PR 27.  No update or copy of a chip's ``bf16[4, 1280, 3072]`` gradient
+    of ``qkv`` lies beside the backward call."""
     from benchmark.layer_metrics import collective_bytes_per_step as reader
 
     cfg, compiled = _train_cell_step("lucid1024-train-dp4", topo.devices)
     _assert_flash_step(cfg, compiled)
     text = compiled.as_text()
     assert _glue(text, cfg.seq_len) == []   # nor a copy about the kernels
+    assert _gradient_moves(text, 4, cfg) == []    # 16 images over 4 chips
     assert " all-gather(" not in text and " all-gather-start(" not in text
 
     class Run:
@@ -252,7 +274,8 @@ def test_default_lucid1024_dp_step_splits_the_kernel(topo):
 
 def test_rematerialised_layer_compiles(one_chip):
     """``use_remat``: the custom VJP under ``jax.checkpoint`` compiles for
-    the chip (forward, its recomputation, dq, dk/dv: 4 kernels a layer)."""
+    the chip (forward, its recomputation, the backward: 3 kernels a layer;
+    4 until PR 41)."""
     cfg = dataclasses.replace(cub200_config(), depth=1, use_remat=True)
     model, shapes = _param_shapes(cfg)
     batch = jax.ShapeDtypeStruct((16, cfg.text_seq_len), jnp.int32,
@@ -262,7 +285,7 @@ def test_rematerialised_layer_compiles(one_chip):
     compiled = jax.jit(jax.grad(
         lambda p, t, c: model.apply({"params": p}, t, c, return_loss=True))
     ).lower(_on(one_chip, shapes), batch, codes).compile()
-    assert len(_kernel_calls(compiled.as_text())) == 4
+    assert len(_kernel_calls(compiled.as_text())) == 3
 
 
 @pytest.mark.parametrize("cell", ["lucid1024-generate", "cub200-generate",
