@@ -1019,6 +1019,7 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int,
     with gauges ``graft_decode_kv_bounded_layers`` / ``_kv_read_share``: how
     many layers' dense reads the position bounds, and the share of their
     slots a tick of this call (``n_pre`` positions prefilled) reads."""
+    from ..ops.linear_attention import one_pass_step
     from ..ops.quant import cache_values
 
     cfg = dalle.cfg
@@ -1060,6 +1061,13 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int,
                 -(-a.shape[-1] // LANES) * LANES * a.dtype.itemsize
                 * a.shape[1] // slots
                 for a in jax.tree.leaves(folded[latent[0]])))
+    if linear:
+        # how many linear layers' states the tick updates in one pass
+        # (ops/linear_attention.py::one_pass_step)
+        records["state_layout"]["linear_one_pass_layers"] = sum(
+            one_pass_step(cfg.heads, caches[i][1].shape[2],
+                          cfg.trunk.lin_value_dim, caches[i][1].dtype)
+            for i in linear)
     # the shape one row of a linear-attention state is carried in: said in
     # the state_layout record, no gauge
     state_shape = ({"linear_state_shape": list(caches[linear[0]][1].shape[1:])}

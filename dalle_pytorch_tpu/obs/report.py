@@ -339,10 +339,12 @@ def build_report(events: List[dict]) -> dict:
         "kv_layout", ("rows", "kv_lane_dense_layers", "kv_plain_layers"))
     _, state = last_decode(
         "state_layout", ("kv_layers", "ssm_layers", "state_bytes_per_row"))
-    # where some of the recurrent layers are linear attention, how many and
-    # the shape a row of their matrix state is carried in
+    # where some of the recurrent layers are linear attention, how many, the
+    # shape a row of their matrix state is carried in and how many of those
+    # states the tick updates in one pass
     _, linear = last_decode(
-        "state_layout", ("linear_layers", "linear_state_shape"))
+        "state_layout", ("linear_layers", "linear_state_shape",
+                         "linear_one_pass_layers"))
     # where some layers cache a latent in place of keys and values, how many,
     # the bytes a position holds and the bytes its stored form walks
     _, latent = last_decode(
@@ -745,7 +747,9 @@ def render_text(report: dict) -> str:
                 lines.append(
                     f"linear attention: {lin.get('linear_layers')} of the "
                     f"recurrent layers, a float32 state of "
-                    f"{lin.get('linear_state_shape')} a row")
+                    f"{lin.get('linear_state_shape')} a row"
+                    + ("; state updated in one pass"
+                       if lin.get("linear_one_pass_layers") else ""))
         if "latent" in dec:
             lat = dec["latent"]
             lines.append(

@@ -74,19 +74,15 @@ the weighted sum of ``c``); the cache write stays under ``attn-cache``.
 from __future__ import annotations
 
 import functools
-import hashlib
-import os
-from pathlib import Path
 from typing import Any, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-from jax.extend import core as jex_core
-from jax.interpreters import mlir
 
 from ..obs import prof
 from ..utils.helpers import max_neg_value
+from . import kept
 from .attention import (LANES, AttnPattern, _choices, _scope_key_pad,
                         apply_rope, dense_attention, pattern_mask_row,
                         read_bounds, switch_read_prefix)
@@ -373,76 +369,13 @@ class LatentAttention(nn.Module):
             return self._out(o[:, :, None]).astype(dtype)
 
 
-# --- the kernels, kept between processes -----------------------------------------
-#
-# As ops/attention_pallas.py keeps the train path's kernels: importing Pallas,
-# tracing a kernel's unrolled body and lowering it to Mosaic's MLIR is Python,
-# seconds a process (PERF.md PR 39: `setup_s` 23 -> 29 s in
-# `glm-4.7-flash-generate`), where the XLA executable around the kernels loads
-# from the compile cache.  So each call is kept beside that cache as a
-# ``jax.export`` artefact, keyed by what it is built from; a later process
-# reads the bytes and binds one ``call_exported``, without importing Pallas.
-
-@functools.lru_cache(maxsize=None)
-def _exported(cache_dir: str, name: str, statics, avals):
-    """``latent_attention_pallas.<name>(*avals, **statics)`` as a
-    ``jax.export.Exported`` for the TPU: read from ``cache_dir``, or traced,
-    lowered and written there."""
-    from jax import export
-
-    import jaxlib
-
-    source = Path(__file__).with_name("latent_attention_pallas.py")
-    key = hashlib.sha256(repr((
-        name, statics, avals, READ_BLOCK, jax.__version__, jaxlib.__version__,
-        hashlib.sha256(source.read_bytes()).hexdigest())).encode()).hexdigest()
-    path = Path(cache_dir) / f"latent-{name}-{key[:40]}.jaxexport"
-    try:
-        return export.deserialize(bytearray(path.read_bytes()))
-    except OSError:
-        pass
-    from . import latent_attention_pallas
-
-    exported = export.export(
-        jax.jit(functools.partial(getattr(latent_attention_pallas, name),
-                                  **dict(statics))),
-        platforms=("tpu",))(*[jax.ShapeDtypeStruct(*a) for a in avals])
-    tmp = path.with_suffix(f".{os.getpid()}.tmp")
-    try:    # whole or not at all: another process may be writing the same
-        path.parent.mkdir(parents=True, exist_ok=True)
-        tmp.write_bytes(exported.serialize())
-        tmp.replace(path)
-    except OSError:
-        pass    # a cache that cannot be written is a cache that misses
-    return exported
-
-
-#: A kept kernel as one opaque operation of the program around it, its
-#: artefact called where the program is lowered.  Called where the program is
-#: traced, ``call_exported`` marks every result of that program as committed
-#: to its device (jax 0.9: ``pxla.jaxpr_transfer_mem_kinds`` counts the
-#: artefact's results as memory-space transfers), and a jitted consumer that
-#: was warmed on an uncommitted array, as the benchmark's VAE decode is,
-#: traces again on the codes: a compile inside the timed window.
-_kept_kernel_p = jex_core.Primitive("latent_kept_kernel")
-_kept_kernel_p.def_abstract_eval(
-    lambda *args, exported: exported.out_avals[0])
-mlir.register_lowering(_kept_kernel_p, mlir.lower_fun(
-    lambda *args, exported: exported.call(*args), multiple_results=False))
-
+# --- the kernels, kept between processes (ops/kept.py) -------------------------
 
 def _kernel(name: str, *args, **statics):
-    """``latent_attention_pallas.<name>`` (one result), through the kept
-    artefact where the program keeps a compile cache (the compiled kernel for
-    the TPU; a test that interprets the kernel turns the cache off)."""
-    cache_dir = jax.config.jax_compilation_cache_dir
-    if cache_dir:
-        return _kept_kernel_p.bind(*args, exported=_exported(
-            cache_dir, name, tuple(sorted(statics.items())),
-            tuple((a.shape, a.dtype) for a in args)))
-    from . import latent_attention_pallas
-
-    return getattr(latent_attention_pallas, name)(*args, **statics)
+    """``latent_attention_pallas.<name>`` (one result), kept beside the
+    compile cache (ops/kept.py::kernel)."""
+    return kept.kernel("latent_attention_pallas", name, *args,
+                       salt=(READ_BLOCK,), **statics)
 
 
 @jax.jit

@@ -14,11 +14,10 @@ a value ``v_t``, a log-decay ``g_t <= 0`` and a write strength ``beta_t`` in
 in the two forms a decoder needs: one position against a carried state
 (``gated_delta_step``; the decode tick) and over a sequence, ``chunk``
 positions at a time (``gated_delta_rule``; differentiable, used by the
-forward pass, the prefill and training).  Plain ``jnp``/``lax``: no kernel
-here.  The state, the decays and every sum are float32 whatever the
-activation dtype, and the sequence form's matrix products run at
-``Precision.HIGHEST`` (on a TPU a float32 product otherwise rounds its
-operands to bfloat16, the state among them).
+forward pass, the prefill and training).  The state, the decays and every
+sum are float32 whatever the activation dtype, and the sequence form's
+matrix products run at ``Precision.HIGHEST`` (on a TPU a float32 product
+otherwise rounds its operands to bfloat16, the state among them).
 
 Layout: a carried state is ``[b, heads / f, d_k, f * d_v]``: ``f`` heads'
 ``d_v`` columns side by side on the minor axis (:func:`state_fold`: 2 at
@@ -28,11 +27,19 @@ d_v]``, puts 192 on the lanes and pads it to 256: a third more bytes on the
 one tensor the tick is built around (0.83 ms a layer a tick at 64 rows
 against 0.64; every head side by side, ``[b, d_k, heads * d_v]``, pads
 nothing either, but the compiler then writes the keys and queries out once
-per lane, 2.86 ms: PERF.md, Findings PR 34).  The step is elementwise on
-that layout, a head's ``k`` and ``q`` spread over its own lanes by a select,
-and reads the state twice: once for both read-outs (``S^T k`` and ``S^T q``;
-the written state's read-out is ``S_t^T q = S^T q + u (k . q)``), once to
-write.
+per lane, 2.86 ms: PERF.md, Findings).
+
+Which form of the step runs where: where :func:`one_pass_step` admits the
+state (float32, ``d_k`` whole sublane tiles, ``f * d_v`` whole lane tiles)
+and the program is lowered for a TPU, one Pallas kernel
+(ops/linear_attention_pallas.py, kept beside the compile cache,
+ops/kept.py) reads each block of the state once and writes it once, in
+place; everywhere else (the CPU, a narrow or bfloat16 state) the plain
+``jnp`` step, elementwise on the carried layout, a head's ``k`` and ``q``
+spread over its own lanes by a select, which XLA runs as two passes: one for
+both read-outs (``S^T k`` and ``S^T q``; the written state's read-out is
+``S_t^T q = S^T q + u (k . q)``), one to write.  The sequence form is plain
+``jnp``/``lax`` everywhere.
 
 ``GatedDeltaMixer`` is the layer (Gated DeltaNet: Yang, Kautz, Hatamizadeh
 2024) as the Olmo-Hybrid family's ``linear_*`` keys size it.  Its scopes,
@@ -51,6 +58,7 @@ import jax
 import jax.numpy as jnp
 
 from ..obs import prof
+from . import kept
 from .ssm import (F32, causal_conv, causal_conv_step, dt_bias_init,
                   fan_in_normal, rms_norm)
 
@@ -58,6 +66,9 @@ EXACT = jax.lax.Precision.HIGHEST
 
 #: minor dimension of the TPU's tiled layouts (a vector register's lanes)
 LANES = 128
+
+#: sublanes of a float32 tile
+SUBLANES = 8
 
 #: positions a chunk of the sequence form solves at once: the triangular
 #: system is ``[chunk, chunk]`` a head and the work inside a chunk is matrix
@@ -97,11 +108,38 @@ def unfold_state(S, heads: int):
         0, 1, 3, 2, 4).reshape(b, heads, dk, lanes // f)
 
 
+def one_pass_step(heads: int, key_dim: int, value_dim: int, dtype) -> bool:
+    """Whether the decode tick's update of a carried state takes one pass
+    over it (the Pallas kernel, where the program is lowered for a TPU),
+    from static shapes and dtypes alone: a float32 state, ``d_k`` in whole
+    sublane tiles and the carried minor axis, ``f * d_v``
+    (:func:`state_fold`), in whole lane tiles.  A bfloat16 state, a narrow
+    twin or a head count that folds nothing keep the ``jnp`` step."""
+    return (jnp.dtype(dtype) == jnp.float32 and key_dim % SUBLANES == 0
+            and state_fold(heads, value_dim) * value_dim % LANES == 0)
+
+
 def gated_delta_step(S, q, k, v, g, beta):
     """One position of the rule.  ``S`` ``[b, heads / f, d_k, f * d_v]``
     float32 (:func:`fold_state`), ``q`` and ``k`` ``[b, heads, d_k]``, ``v``
     ``[b, heads, d_v]``, ``g`` and ``beta`` ``[b, heads]``.  Returns ``(o
-    [b, heads, d_v] float32, S')``."""
+    [b, heads, d_v] float32, S')``: in one pass over ``S`` where
+    :func:`one_pass_step` holds and the program is lowered for a TPU
+    (``jax.lax.platform_dependent``), else by :func:`_plain_step`."""
+    if not one_pass_step(k.shape[1], k.shape[2], v.shape[-1], S.dtype):
+        return _plain_step(S, q, k, v, g, beta)
+
+    def kernel(*args):
+        return kept.kernel("linear_attention_pallas", "delta_step", *args,
+                           salt=(SUBLANES,))
+
+    return jax.lax.platform_dependent(S, q, k, v, g, beta, tpu=kernel,
+                                      default=_plain_step)
+
+
+def _plain_step(S, q, k, v, g, beta):
+    """:func:`gated_delta_step` in plain ``jnp``, elementwise on the carried
+    layout (module docstring)."""
     b, h, _ = k.shape
     dv = v.shape[-1]
     groups = S.shape[1]

@@ -24,7 +24,7 @@ sys.path.insert(0, str(REPO))
 
 from dalle_pytorch_tpu import DALLE, DALLEConfig  # noqa: E402
 from dalle_pytorch_tpu.models.dalle import decode_codes  # noqa: E402
-from dalle_pytorch_tpu.ops import latent_attention  # noqa: E402
+from dalle_pytorch_tpu.ops import kept, latent_attention  # noqa: E402
 from dalle_pytorch_tpu.ops.attention import AttnPattern, read_bounds  # noqa: E402
 from dalle_pytorch_tpu.ops.latent_attention import (  # noqa: E402
     READ_BLOCK, LatentAttention, _read_latent, _write_folded, fold_latent,
@@ -350,12 +350,12 @@ def test_kept_kernels_leave_the_programs_results_uncommitted(tmp_path):
         consumer = jax.jit(lambda x: x + 1)
         consumer(jnp.zeros((ROWS,), jnp.int32))
         text = str(jax.make_jaxpr(program)(c, kr, q_lat, q_rope, 300))
-        assert text.count("latent_kept_kernel") == 2
+        assert text.count("kept_kernel") == 2
         assert "call_exported" not in text
-        kept = sorted(p.name.split("-")[1] for p in tmp_path.iterdir()
-                      if p.suffix == ".jaxexport")
-        assert kept == ["fold_latent_blocks", "latent_read"]
-        latent_attention._exported.cache_clear()      # a later process
+        names = sorted(p.name.split("-")[1] for p in tmp_path.iterdir()
+                       if p.suffix == ".jaxexport")
+        assert names == ["fold_latent_blocks", "latent_read"]
+        kept.exported.cache_clear()                   # a later process
         got = jax.jit(program)(c, kr, q_lat, q_rope, jnp.asarray(300))
         consumer(got)
         assert consumer._cache_size() == 1

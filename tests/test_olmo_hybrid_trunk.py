@@ -504,8 +504,9 @@ def test_decode_trace_reports_its_state_layout(model, tmp_path):
     assert len(layout) == 1
     assert (layout[0]["ssm_layers"], layout[0]["linear_layers"],
             layout[0]["kv_layers"], layout[0]["linear_state_shape"],
-            layout[0]["state_bytes_per_row"], layout[0]["rows"]) == (
-                0, 3, 1, [4, 8, 16], row_bytes, 4)
+            layout[0]["state_bytes_per_row"], layout[0]["rows"],
+            layout[0]["linear_one_pass_layers"]) == (
+                0, 3, 1, [4, 8, 16], row_bytes, 4, 0)
     for line in ("graft_decode_linear_layers 3", "graft_decode_ssm_layers 0",
                  "graft_decode_kv_layers 1",
                  f"graft_decode_state_bytes_per_row {row_bytes}"):
@@ -515,4 +516,44 @@ def test_decode_trace_reports_its_state_layout(model, tmp_path):
     assert (f"decode state: 1 layers of keys and values, 3 recurrent; "
             f"{row_bytes} bytes a row") in text_report
     assert ("linear attention: 3 of the recurrent layers, a float32 state "
-            "of [4, 8, 16] a row") in text_report
+            "of [4, 8, 16] a row\n") in text_report
+
+
+@pytest.mark.parametrize("trunk,want", [
+    # two heads of 64 fill a lane tile: the kernel's state, in all three
+    (dict(TRUNK, lin_value_dim=64), 3),
+    (None, None),                       # no linear layer, no count
+], ids=["foldable", "no-linear-layer"])
+def test_the_state_layout_counts_the_states_updated_in_one_pass(tmp_path,
+                                                                trunk, want):
+    """The ``decode.state_layout`` record of a trace of ``decode_codes``
+    says how many linear layers' states the tick updates in one pass
+    (ops/linear_attention.py::one_pass_step), with its gauge and the
+    report's line; the tiny twin's 16-lane state reads 0
+    (test_decode_trace_reports_its_state_layout)."""
+    cfg, dalle, variables, text, _ = _model(trunk=trunk)
+    reg = metrics.init()
+    tel = telemetry.init(tmp_path, run_id="one-pass")
+    try:
+        first, caches = tile_prefill(*prefill_codes(dalle, variables,
+                                                    text[:1]), 2)
+        jax.jit(lambda v, f, c, k: decode_codes(dalle, v, f, c, k)).lower(
+            variables, first, caches, jax.random.PRNGKey(0))
+        rendered = reg.render()
+    finally:
+        telemetry.shutdown()
+        metrics.shutdown()
+    events = telemetry.read_events(tel.path)
+    layout = [e for e in events
+              if e["kind"] == "decode" and e["name"] == "state_layout"]
+    assert len(layout) == 1
+    assert layout[0].get("linear_one_pass_layers") == want
+    report = render_text(build_report(events))
+    if want:
+        assert f"graft_decode_linear_one_pass_layers {want}" in rendered
+        assert ("linear attention: 3 of the recurrent layers, a float32 "
+                "state of [2, 8, 128] a row; state updated in one pass"
+                ) in report
+    else:
+        assert "graft_decode_linear_one_pass_layers" not in rendered
+        assert "linear attention" not in report

@@ -608,6 +608,47 @@ def test_the_one_pass_latent_read_compiles_at_the_cells_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < cache // 4
 
 
+def test_the_one_pass_delta_step_compiles_at_the_cells_shape(one_chip):
+    """ops/linear_attention_pallas.py at ``olmo-hybrid-7b-generate``'s shape
+    (64 rows, 30 heads of 96 x 192, the state ``[64, 15, 96, 384]`` float32,
+    PERF.md): the kernel lowers for the v5e from this CPU host and
+    takes the state's buffer for the updated state; one linear layer's tick
+    holds the kernel and no copy of the state, nor temporaries of its
+    size."""
+    from dalle_pytorch_tpu.ops.linear_attention import GatedDeltaMixer
+    from dalle_pytorch_tpu.ops.linear_attention_pallas import delta_step
+
+    rows, heads, dk, dv, dim = 64, 30, 96, 192, 3840
+    state = (rows, heads // 2, dk, 2 * dv)
+    state_bytes = math.prod(state) * 4
+
+    def on(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(delta_step, donate_argnums=0).lower(
+        on(*state), on(rows, heads, dk), on(rows, heads, dk),
+        on(rows, heads, dv), on(rows, heads), on(rows, heads)).compile()
+    assert len(_kernel_calls(compiled.as_text())) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes == state_bytes
+
+    layer = GatedDeltaMixer(dim=dim, heads=heads, key_dim=dk, value_dim=dv,
+                            dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4, dim), jnp.bfloat16))
+    compiled = jax.jit(lambda p, x, w, S: layer.apply(
+        p, x, w, S, method=GatedDeltaMixer.decode_step),
+        donate_argnums=(2, 3)).lower(
+            _on(one_chip, params), on(rows, 1, dim, dtype=jnp.bfloat16),
+            on(rows, 3, heads * (2 * dk + dv), dtype=jnp.bfloat16),
+            on(*state)).compile()
+    text = compiled.as_text()
+    assert len(_kernel_calls(text)) == 1
+    shape = f"f32[{','.join(map(str, state))}]"
+    assert not [line for line in text.splitlines() if f"= {shape}" in line
+                and re.search(r" (copy|copy-start|fusion)\(", line)]
+    assert compiled.memory_analysis().temp_size_in_bytes < state_bytes // 4
+
+
 def test_the_window_and_global_scan_compiles_at_published_widths(one_chip):
     """``laguna-s-2.1-generate``'s decode program at the cell's 96 rows and
     published widths, depth cut to 2 (a YaRN-rotated global layer of 48
