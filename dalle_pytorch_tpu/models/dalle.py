@@ -119,25 +119,6 @@ class DALLEConfig:
     # bit-identical (ops/attention.py::_decode_step_aligned); False is
     # the A/B control.
     aligned_span_decode: bool = True
-    # Self-speculative decoding (graftspec): a shallow-exit draft pass —
-    # the first ``spec_draft_depth`` blocks + the shared logits head —
-    # drafts ``spec_k - 1`` candidate tokens per decode step, then ONE
-    # full-depth K-wide verify span scores all of them in a single
-    # weight-stream pass.  The accepted prefix commits with the exact
-    # keys/logits the greedy path would have used, so output is bitwise
-    # equal to greedy whatever the acceptance rate; rejection just wastes
-    # the drafted work.  Decode is HBM-bandwidth-bound (PERF.md round 5:
-    # 14.9% MFU), so expected speedup = accepted-K per weight read over
-    # the draft overhead (obs/prof.py::predicted_spec_speedup).  OFF by
-    # default until the queued ``gen_spec_ab`` wall-clock A/B lands,
-    # mirroring the int8 precedent.
-    spec_decode: bool = False
-    spec_draft_depth: int = 2   # draft exits after this many blocks
-    spec_k: int = 4             # span width: 1 committed + up to K-1 drafted
-    # test hook: score the verify span but reject every draft (m=1/step) —
-    # pins the fallback path's bit-equality without relying on drafts
-    # happening to miss
-    spec_force_reject: bool = False
     # Per-layer block spec of another trunk under DALL-E's client (prompt
     # layout, phase mask, sampler, loss): ops/transformer.py::TrunkSpec, or
     # the plain dict it is built from (a checkpoint's hparams, a benchmark
@@ -156,8 +137,7 @@ class DALLEConfig:
     _PLAN_FIELDS = ("ring_axis", "sp_impl", "sp_size",
                     "ff_expert_dispatch", "ff_expert_capacity_factor",
                     "kv_cache_bf16", "kv_cache_int8", "weights_int8",
-                    "aligned_span_decode", "spec_decode", "spec_draft_depth",
-                    "spec_k", "spec_force_reject")
+                    "aligned_span_decode")
     # execution switches that no longer exist; checkpoints written while
     # they did carry them in their hparams (from_dict drops them)
     _RETIRED_FIELDS = ("use_pallas", "pallas_block_q", "pallas_block_k",
@@ -170,13 +150,12 @@ class DALLEConfig:
         if self.trunk is not None:
             # what a trunk's layers have no form for yet: a recurrent state
             # (a state-space vector or a linear-attention matrix a head) or
-            # a ring of keys cannot be recomputed backwards (reversible) or
-            # rolled back (spec_decode); the trunk's blocks have no int8
-            # kernels (weights_int8: expert banks least of all) and its
-            # grouped, rotated, ring or latent caches no int8 layout
-            # (kv_cache_int8)
-            for field in ("reversible", "spec_decode", "weights_int8",
-                          "kv_cache_int8", "sparse_attn"):
+            # a ring of keys cannot be recomputed backwards (reversible); the
+            # trunk's blocks have no int8 kernels (weights_int8: expert banks
+            # least of all) and its grouped, rotated, ring or latent caches
+            # no int8 layout (kv_cache_int8)
+            for field in ("reversible", "weights_int8", "kv_cache_int8",
+                          "sparse_attn"):
                 assert not getattr(self, field), (
                     f"{field} is not supported over a TrunkSpec trunk (its "
                     "layers carry a recurrent state-space or linear-"
@@ -196,19 +175,6 @@ class DALLEConfig:
         assert not (self.weights_int8 and self.ff_experts > 1), (
             "weights_int8 quantizes the dense GEGLU kernels; MoE expert "
             "kernels are not supported on the quantized decode path")
-        if self.spec_decode:
-            assert not self.reversible, (
-                "spec_decode requires the residual executor (the reversible "
-                "two-stream recurrence is sequential across positions)")
-            assert 0 < self.spec_draft_depth <= self.depth, (
-                f"spec_draft_depth {self.spec_draft_depth} outside "
-                f"(0, depth={self.depth}]")
-            assert self.spec_k >= 2, (
-                f"spec_k {self.spec_k} < 2 drafts nothing; disable "
-                "spec_decode instead")
-            assert self.spec_k <= self.image_seq_len, (
-                f"spec_k {self.spec_k} exceeds image_seq_len "
-                f"{self.image_seq_len}")
 
     @property
     def image_seq_len(self) -> int:
@@ -796,37 +762,6 @@ class DALLE(nn.Module):
                                 else qweights["head"])
             return logits[:, 0], caches
 
-    def decode_span(self, codes, caches, qpos, rot, valid, depth_limit=None,
-                    qweights=None):
-        """K-token speculative span: ``codes`` [b, K] image-vocab tokens at
-        logical input positions ``qpos`` [b, K] (consecutive per row),
-        per-row cache rotation ``rot`` [b] (zeros for the static sampler),
-        cache-write validity ``valid`` [b, K].  Returns ([b, K,
-        num_image_tokens] image-phase logits — position j's logits predict
-        the token AFTER ``qpos[:, j]`` — and the updated caches).
-
-        ``depth_limit`` (static int) is the self-speculative draft's
-        shallow exit: only the first that many blocks run, then the SAME
-        final-norm + image head scores the truncated hidden state.  The
-        verify pass (depth_limit=None) is the full model and its logits
-        are bitwise what ``decode_step`` would produce query-by-query —
-        the property the spec-decode commit relies on."""
-        cfg = self.cfg
-        with prof.scope("decode-step"):
-            with prof.scope("embed"):
-                emb = self._lookup_ids(codes, True)       # [b, K, dim]
-                img_index = qpos - (cfg.text_seq_len + 1)
-                pos_grid = self.image_pos_emb(cfg.image_seq_len)
-                rows = jnp.clip(img_index, 0, cfg.image_seq_len - 1)
-                x = (emb + jnp.take(pos_grid, rows, axis=0)).astype(cfg.dtype)
-            out, caches = self.transformer.decode_span(
-                x, caches, qpos, rot, valid, depth_limit=depth_limit,
-                qweights=None if qweights is None else qweights["layers"])
-            logits = self._head(out, image_only=True,
-                                qhead=None if qweights is None
-                                else qweights["head"])
-            return logits, caches
-
 
 def quantize_decode_weights(params, cfg: DALLEConfig):
     """One-shot int8 quantization of every decode-path weight matrix —
@@ -1127,16 +1062,6 @@ def decode_codes(dalle: DALLE, params, first_logits, caches, rng, *,
                                  filter_thres=filter_thres,
                                  temperature=temperature, top_p=top_p)
 
-    if cfg.spec_decode:
-        assert n_prime == 0 and prime_codes is None, (
-            "spec_decode does not support primed image codes; prime on the "
-            "greedy sampler or disable spec_decode")
-        assert mask is None, (
-            "spec_decode's span path takes no key padding mask (serve "
-            "precedent: requests carry fully-valid prompts)")
-        return _decode_codes_spec(dalle, params, first_logits, caches, rng,
-                                  sample=sample)
-
     def step(carry, key):
         code, caches, index = carry
         logits, caches = dalle.apply(
@@ -1166,101 +1091,6 @@ def decode_codes(dalle: DALLE, params, first_logits, caches, rng, *,
         if prime_codes is not None and n_prime > 0:
             parts.insert(0, prime_codes)
         return jnp.concatenate(parts, axis=1)
-
-
-def _decode_codes_spec(dalle: DALLE, params, first_logits, caches, rng, *,
-                       sample) -> jax.Array:
-    """The ``spec_decode`` branch of :func:`decode_codes`: a
-    ``lax.while_loop`` that drafts ``spec_k - 1`` tokens through the
-    shallow-exit stack, scores all ``spec_k`` span positions in one
-    full-depth verify pass, and commits the accepted prefix — rows
-    advance by their own accepted length per iteration, so the loop is
-    while-not-done rather than a fixed-length scan.
-
-    Exactness: commit j is sampled from the FULL-model verify logits with
-    the same key stream position the greedy scan would have used, and a
-    draft is only accepted when it equals that commit — so the committed
-    sequence is bitwise the greedy sequence whatever the drafts guessed.
-    (At batch > 1, diverged rows draw through a per-row vmapped sampler
-    instead of the greedy scan's one-key-per-step batched draw — at
-    batch 1, and under argmax sampling at any batch, the two are
-    identical.)  Rejected span positions leave junk k/v in the caches at
-    positions >= the new index: causally masked until the next
-    iteration's span overwrites them (it always covers them — the span
-    starts at the new index and is as wide as the old one)."""
-    cfg = dalle.cfg
-    n_pre = cfg.text_seq_len + 1
-    L = cfg.image_seq_len
-    K = cfg.spec_k
-    b = first_logits.shape[0]
-    num_steps = cfg.seq_len - n_pre  # L - 1 keys, one per later position
-    sample_rows = jax.vmap(sample)   # per-row key (rows diverge in pos)
-
-    with prof.scope("decode-step"):
-        qweights = (quantize_decode_weights(params, cfg)
-                    if cfg.weights_int8 else None)
-        rng, key0 = jax.random.split(rng)
-        first_code = sample(first_logits, key0)
-        keys_all = (jax.random.split(rng, num_steps) if num_steps > 0
-                    else jnp.zeros((1, 2), jnp.uint32))
-        rot0 = jnp.zeros((b,), jnp.int32)  # static sampler: unrotated caches
-
-        def body(carry):
-            caches, code, pos, out = carry
-            active = pos < L
-            remaining = L - pos
-            index = n_pre + pos - 1  # input position of the last committed
-            # keys for out positions pos..pos+K-1 (position p draws
-            # keys_all[p-1], matching the greedy scan's stream)
-            kspan = jax.vmap(lambda p: jnp.take(
-                keys_all, jnp.clip(p - 1 + jnp.arange(K), 0,
-                                   keys_all.shape[0] - 1), axis=0))(pos)
-            drafts = []
-            d = code
-            with prof.scope("spec-draft"):
-                for j in range(1, K):
-                    qp = (index + (j - 1))[:, None]
-                    dvalid = (active & (j - 1 < remaining))[:, None]
-                    dlogits, caches = dalle.apply(
-                        params, d[:, None], caches, qp, rot0, dvalid,
-                        cfg.spec_draft_depth, qweights,
-                        method=DALLE.decode_span)
-                    d = sample_rows(dlogits[:, 0], kspan[:, j - 1])
-                    drafts.append(d)
-            t = jnp.stack([code] + drafts, axis=1)        # [b, K]
-            qpos = index[:, None] + jnp.arange(K)[None, :]
-            vvalid = active[:, None] & (jnp.arange(K)[None, :]
-                                        < remaining[:, None])
-            with prof.scope("spec-verify"):
-                vlogits, caches = dalle.apply(
-                    params, t, caches, qpos, rot0, vvalid, None, qweights,
-                    method=DALLE.decode_span)
-            cand = jax.vmap(sample_rows, in_axes=1, out_axes=1)(
-                vlogits, kspan)                           # [b, K]
-            if cfg.spec_force_reject:
-                matches = jnp.zeros((b,), jnp.int32)
-            else:
-                eq = (t[:, 1:] == cand[:, :-1]).astype(jnp.int32)
-                matches = jnp.sum(jnp.cumprod(eq, axis=1), axis=1)
-            m = jnp.where(active,
-                          jnp.minimum(matches + 1, jnp.maximum(remaining, 1)),
-                          0)
-            last = jnp.take_along_axis(
-                cand, jnp.clip(m - 1, 0, K - 1)[:, None], axis=1)[:, 0]
-
-            def write_row(row, p, c, mm):
-                jj = jnp.arange(K)
-                idxs = jnp.where(jj < mm, p + jj, L)  # L = dropped lane
-                return row.at[idxs].set(c, mode="drop")
-
-            out = jax.vmap(write_row)(out, pos, cand, m)
-            return (caches, jnp.where(active, last, code), pos + m, out)
-
-        out0 = jnp.zeros((b, L), jnp.int32).at[:, 0].set(first_code)
-        _, _, _, out = jax.lax.while_loop(
-            lambda c: jnp.any(c[2] < L), body,
-            (caches, first_code, jnp.ones((b,), jnp.int32), out0))
-        return out
 
 
 def generate_codes(dalle: DALLE, params, text, rng, *, prime_codes=None,

@@ -40,7 +40,7 @@ SCOPE_PREFIX = "graftprof:"
 #: rows enumerate.
 SCOPES = ("embed", "attn-qkv", "attn-scores", "attn-cache", "attn-out",
           "ff", "logits-head", "vae-conv", "optimizer", "decode-step",
-          "serve-tick", "spec-draft", "spec-verify", "sample",
+          "serve-tick", "sample",
           "ssm-proj", "ssm-conv", "ssm-scan", "moe-route", "moe-experts",
           "gdn-proj", "gdn-conv", "gdn-state", "mla-proj", "mla-read",
           "attn-gate")
@@ -560,39 +560,6 @@ def predicted_serve_bytes_per_token(cfg, num_slots: int) -> int:
 
     return int(dalle_decode_cache_bytes(cfg, num_slots)
                // max(num_slots, 1))
-
-
-def predicted_spec_speedup(cfg, accepted_k: Optional[float] = None) -> dict:
-    """Cost-model speedup of self-speculative decode (graftspec).
-
-    Decode is HBM-bandwidth-bound (PERF.md round 5), so step time ≈ the
-    weight+cache byte stream; self-speculation amortizes ONE full-depth
-    stream over ``accepted_k`` committed tokens at the price of ``K - 1``
-    draft streams through the first ``spec_draft_depth`` blocks:
-
-        bytes/token  =  full_stream * (1 + (K-1) * draft_frac) / accepted_k
-        speedup      =  accepted_k / (1 + (K-1) * draft_frac)
-
-    with ``draft_frac = spec_draft_depth / depth`` (the head re-runs per
-    draft but is byte-small next to the stack).  ``accepted_k`` defaults
-    to the neutral prior of half the span, ``(K + 1) / 2``; the A/B
-    stage (``gen_spec_ab``) replaces the prior with a measured rate.
-    Returns the dict the graftprof serve/decode spec rows embed."""
-    k = cfg.spec_k
-    draft_frac = cfg.spec_draft_depth / cfg.depth
-    if accepted_k is None:
-        accepted_k = (k + 1) / 2.0
-    overhead = 1.0 + (k - 1) * draft_frac
-    return {
-        "spec_k": k,
-        "spec_draft_depth": cfg.spec_draft_depth,
-        "draft_frac": round(draft_frac, 4),
-        "assumed_accepted_k": round(float(accepted_k), 4),
-        "stream_overhead": round(overhead, 4),
-        "predicted_speedup": round(float(accepted_k) / overhead, 4),
-        # acceptance rate below which the drafts cost more than they buy
-        "breakeven_accepted_k": round(overhead, 4),
-    }
 
 
 # --- managed on-chip capture (the OBS003 contract) ------------------------

@@ -222,12 +222,6 @@ def build_report(events: List[dict]) -> dict:
         int(r["active_sum"]) if r.get("active_sum") is not None
         else int(r.get("active", 0)) * int(r.get("ticks", 1))
         for r in ticks)
-    # spec decode: tick records carry `tokens` (committed this window,
-    # variable under speculation) next to `active_sum` (slot-ticks) —
-    # their ratio is the measured accepted-K the cost model predicts
-    tick_tokens = sum(int(r["tokens"]) for r in ticks
-                      if r.get("tokens") is not None)
-    has_spec = any(r.get("spec") for r in ticks)
     # prefix cache: one `prefix` record per admission (hit flag +
     # RUNNING totals) — counts sum, totals read off the LAST record
     prefix_recs = [r for r in serve if r.get("name") == "prefix"]
@@ -261,8 +255,6 @@ def build_report(events: List[dict]) -> dict:
         "tick_records": len(ticks),
         "occupied_slot_ticks": slot_ticks,
         "decoded_tokens": sum(int(r.get("tokens", 0)) for r in retires),
-        "accepted_k": (tick_tokens / slot_ticks
-                       if has_spec and slot_ticks else None),
         "prefix": prefix_report,
         "by_class": per_class,
         "phases": _serve_phases(serve_all),
@@ -659,10 +651,6 @@ def render_text(report: dict) -> str:
             f"completed / {sv['failed']} failed, preemptions "
             f"{sv['preemptions']}, ticks {sv['ticks']}, tokens "
             f"{sv['decoded_tokens']}")
-        if sv.get("accepted_k") is not None:
-            lines.append(
-                f"  spec decode: accepted-K {_fmt(sv['accepted_k'])} "
-                f"per active slot-tick")
         pref = sv.get("prefix")
         if pref:
             lines.append(

@@ -1360,10 +1360,9 @@ class MultiHeadAttention(nn.Module):
                 cache_k = cache_write(cache_k, k, at)
                 cache_v = cache_write(cache_v, v, at)
             else:
-                at = jnp.remainder(index, slots)[:, None]          # [b, 1]
-                keep = jnp.ones((b, 1), bool)
-                cache_k = cache_write_rows(cache_k, k, at, keep)
-                cache_v = cache_write_rows(cache_v, v, at, keep)
+                at = jnp.remainder(index, slots)                   # [b]
+                cache_k = cache_write_rows(cache_k, k, at)
+                cache_v = cache_write_rows(cache_v, v, at)
             k_vals, k_scale = split_cache(cache_k)
             v_vals, v_scale = split_cache(cache_v)
         with prof.scope("attn-scores"):
@@ -1413,8 +1412,8 @@ class MultiHeadAttention(nn.Module):
         """The form the serving arena STORES this layer's key and value
         caches in between its programs (serve/engine.py), for a cache of
         ``dtype``: the one place that decides it.  ``SlotArena`` allocates
-        and installs by it; the aligned step and the span pass
-        (:meth:`_stored_form`) read and write the array where it lies.
+        and installs by it; the aligned step (:meth:`_stored_form`) reads
+        and writes the array where it lies.
 
         Why a form of its own: the chip keeps an array whose minor dimension
         does not fill the 128 lanes in a layout of its choosing, and at
@@ -1448,8 +1447,8 @@ class MultiHeadAttention(nn.Module):
     def _stored_form(self, cache) -> CacheForm:
         """The form of a rotated cache as it was handed over: the arena's
         (:meth:`arena_form`), or plain ``[b, heads, n, dh]`` from a caller
-        that brings arrays of its own (the static sampler's span pass, the
-        tools that trace one tick); told apart by the minor dimension."""
+        that brings arrays of its own (the tools that trace one tick); told
+        apart by the minor dimension."""
         values = cache_values(cache)
         if values.shape[-1] == self.dim_head:
             return CacheForm()
@@ -1500,7 +1499,7 @@ class MultiHeadAttention(nn.Module):
         return self._out_proj(out, qw, x), cache_k, cache_v
 
     def _aligned_read(self, q, k_vals, k_scale, v_vals, v_scale, idx, r,
-                      out_dtype, form: CacheForm = CacheForm()):
+                      out_dtype, form: CacheForm):
         """The read half of the phase-aligned decode step: one query per
         row (``q`` [b, heads, 1, dh]) at logical position ``idx`` [b]
         against row caches rotated by ``r`` [b], their values stored in
@@ -1509,14 +1508,7 @@ class MultiHeadAttention(nn.Module):
         lies; the folded branches of the dots (:meth:`_dots`,
         :meth:`_attn_v`) tell the fold by the shape, and take what a
         position-major read brings as one group of all the heads
-        (``CacheForm.for_dots``).
-
-        Shared verbatim between :meth:`_decode_step_aligned` (the greedy
-        serve tick) and :meth:`decode_span` (the speculative draft/verify
-        passes, which fold their K span queries into the batch axis) —
-        one program means the two paths consume bitwise-identical masked
-        softmaxes, which is what lets the spec-decode bit-equality tests
-        extend the greedy harness unchanged."""
+        (``CacheForm.for_dots``)."""
         ax = form.position_axis
         n_k = k_vals.shape[ax]
         scale = self.dim_head ** -0.5
@@ -1595,58 +1587,6 @@ class MultiHeadAttention(nn.Module):
                              max_neg_value(dots.dtype))
             attn = jax.nn.softmax(dots, axis=-1)  # f32
             return self._cache_values(attn, v_vals, v_scale, out_dtype)
-
-    def decode_span(self, x, cache_k, cache_v, qpos, rot, valid, qw=None):
-        """K-token span pass with KV cache — the speculative-decode
-        primitive (draft steps run it at K=1 through a depth-limited
-        stack; the verify scores all K positions in one weight-stream
-        pass).
-
-        x: [b, K, dim] embeddings of the span tokens; ``qpos`` [b, K]
-        int32 logical absolute positions (consecutive per row); ``rot``
-        [b] each row's cache rotation ((write_col - index) mod n — zeros
-        for the static sampler, the admit-time rotation in the serve
-        arena); ``valid`` [b, K] bool gates the cache writes (a position
-        past the row's remaining sequence would wrap-write into a live
-        column).  Returns (out [b, K, dim-equivalent], new_k, new_v).
-
-        All K k/v rows are written BEFORE any read, so query j sees its
-        own and every earlier span position's fresh keys; later span
-        positions are causally masked.  The reads fold the K queries into
-        the batch axis and run :meth:`_aligned_read` — the exact program
-        the greedy serve tick reads with — so a span query at position p
-        produces bitwise the same output as a greedy step at p over the
-        same cache (batch-shape invariance of the per-row program, the
-        property the serve bit-equality tests already pin)."""
-        b, K, _ = x.shape
-        q, k, v = self._qkv_decode(x, qw)  # [b, h, K, dh]
-        form = self._stored_form(cache_k)
-        n_k = cache_values(cache_k).shape[form.position_axis]
-        idx = qpos.astype(jnp.int32)
-        r = jnp.remainder(jnp.asarray(rot, jnp.int32), n_k)  # [b]
-        phys = jnp.remainder(idx + r[:, None], n_k)          # [b, K]
-        with prof.scope("attn-cache"):
-            cache_k = cache_write_rows(cache_k, k, phys, valid, form)
-            cache_v = cache_write_rows(cache_v, v, phys, valid, form)
-            k_vals, k_scale = split_cache(cache_k)
-            v_vals, v_scale = split_cache(cache_v)
-        # fold the span into the batch axis: row (b, j) of the folded
-        # batch is one greedy-shaped query at logical position qpos[b, j]
-        # against (a broadcast view of) row b's cache
-        B = b * K
-        qf = q.transpose(0, 2, 1, 3).reshape(B, self.heads, 1, self.dim_head)
-        idx_f = idx.reshape(B)
-        r_f = jnp.repeat(r, K)
-
-        def fold(a):
-            return None if a is None else jnp.repeat(a, K, axis=0)
-
-        out = self._aligned_read(qf, fold(k_vals), fold(k_scale),
-                                 fold(v_vals), fold(v_scale),
-                                 idx_f, r_f, x.dtype, form)
-        out = out.transpose(0, 2, 1, 3).reshape(
-            b, K, self.heads * self.dim_head)
-        return self._out_proj(out, qw, x), cache_k, cache_v
 
     def _cache_values(self, attn, v, v_scale, out_dtype):
         """``attn`` (f32) over a cache read's values: :meth:`_attn_v`, or

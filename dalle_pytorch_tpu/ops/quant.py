@@ -176,41 +176,16 @@ def cache_write(cache: CacheLike, new, column,
     return (updated, scale)
 
 
-def cache_write_rows(cache: CacheLike, new, rows, valid,
-                     form: CacheForm = CacheForm()) -> CacheLike:
-    """Write a K-wide span of decode rows into a cache entry of either
-    layout at PER-ROW physical columns — the speculative-decode commit
-    (``ops/attention.py::MultiHeadAttention.decode_span``).
-
-    ``new`` is ``[b, heads, K, dh]``; ``rows`` ``[b, K]`` int32 gives each
-    batch row's K physical cache columns (consecutive logical positions
-    through the row's rotation, so the K indices within a row are always
-    distinct); ``valid`` ``[b, K]`` bool keeps the resident value where
-    False (positions past the row's remaining sequence must not wrap-write
-    into live columns).  Unlike :func:`cache_write` this lowers to a
-    scatter (per-row columns can't share one dynamic_update_slice) — the
-    speculative path amortizes that cost over the K tokens it commits,
-    and the greedy/serve tick keeps the aligned single-column write.
-    ``form``: as in :func:`cache_write`."""
-    values, scale = split_cache(cache)
-    # position-major [b, K, groups, lanes]: the scatter's own order
-    q = CacheForm(form.fold, True).store(requantize(new, scale, values.dtype))
-    valid = valid[:, :, None, None]
-    b = values.shape[0]
-    # invalid lanes re-write their current value: a gather+select keeps
-    # the scatter's index set static (distinct within each row), which a
-    # masked index would not
-    if form.position_major:
-        cur = jnp.take_along_axis(values, rows[:, :, None, None], axis=1)
-        updated = values.at[jnp.arange(b)[:, None], rows].set(
-            jnp.where(valid, q, cur))
-    else:
-        cur = jnp.take_along_axis(values, rows[:, None, :, None], axis=2)
-        updated = values.at[jnp.arange(b)[:, None], :, rows, :].set(
-            jnp.where(valid, q, cur.transpose(0, 2, 1, 3)))
-    if scale is None:
-        return updated
-    return (updated, scale)
+def cache_write_rows(cache: jax.Array, new, columns) -> jax.Array:
+    """Write one decode-step row ``new`` ``[b, heads, 1, dh]`` into a plain
+    ``[b, heads, n, dh]`` cache at a column of each row's own, ``columns``
+    ``[b]`` int32 — the ring's per-row write
+    (``ops/attention.py::MultiHeadAttention._decode_step_ring``, whose rows
+    sit at different depths; a ring is never int8, a trunk refuses
+    ``kv_cache_int8``).  Unlike :func:`cache_write` this lowers to a
+    scatter: per-row columns cannot share one dynamic_update_slice."""
+    return cache.at[jnp.arange(cache.shape[0]), :, columns, :].set(
+        new[:, :, 0].astype(cache.dtype))
 
 
 def scaled_qdot(einsum_spec: str, a, qb, scale=None, *,

@@ -351,16 +351,6 @@ class AttnBlock(nn.Module):
         with prof.scope("attn-out"):
             return h * self.scale.astype(h.dtype), ck, cv
 
-    def decode_span(self, x, cache_k, cache_v, qpos, rot, valid, qw=None):
-        """K-token speculative span (see MultiHeadAttention.decode_span);
-        same norm -> attn -> layerscale shape as :meth:`decode_step`."""
-        with prof.scope("attn-qkv"):
-            normed = self.norm(x).astype(x.dtype)
-        h, ck, cv = self.attn.decode_span(
-            normed, cache_k, cache_v, qpos, rot, valid, qw=qw)
-        with prof.scope("attn-out"):
-            return h * self.scale.astype(h.dtype), ck, cv
-
 
 class FFBlock(nn.Module):
     """LayerScale(PreNorm(GEGLU feed-forward)) (ref transformer.py:53-69)."""
@@ -1090,40 +1080,4 @@ class Transformer(nn.Module):
             x = self._residual(x, ind, self._ff(ind, x, routed, qw=qw),
                                ff=True)
             new_caches.append((ck, cv))
-        return x, new_caches
-
-    def decode_span(self, x, caches, qpos, rot, valid, depth_limit=None,
-                    qweights=None):
-        """K-token speculative span pass: x [b, K, dim] at logical
-        positions ``qpos`` [b, K], per-row cache rotation ``rot`` [b],
-        write-validity ``valid`` [b, K].  Returns (out, new_caches).
-
-        ``depth_limit`` (static) runs only the FIRST that many blocks —
-        the self-speculative shallow-exit draft; the untouched deeper
-        layers' caches pass through unchanged, and the verify pass
-        (depth_limit=None) later overwrites every span position at every
-        layer, so a draft's partial writes never outlive their tick.
-
-        Residual executor only: the reversible two-stream recurrence
-        feeds each attention the x2 stream, whose value at a span
-        position depends on the previous position's FF output — a K-wide
-        pass can't form it without sequentializing, which is exactly what
-        the span exists to avoid."""
-        assert not self.reversible, (
-            "speculative span decode supports the residual executor only; "
-            "the reversible two-stream recurrence is inherently sequential "
-            "across positions")
-        depth = self.depth if depth_limit is None else depth_limit
-        assert 0 < depth <= self.depth, (
-            f"depth_limit {depth_limit} outside (0, {self.depth}]")
-        qws = qweights if qweights is not None else [None] * self.depth
-        new_caches = list(caches)
-        for ind in range(depth):
-            attn, qw = self.attn_blocks[ind], qws[ind]
-            ck, cv = new_caches[ind]
-            routed = self._router_logits(ind, x)
-            h, ck, cv = attn.decode_span(x, ck, cv, qpos, rot, valid, qw=qw)
-            x = x + h
-            x = x + self._ff(ind, x, routed, qw=qw)
-            new_caches[ind] = (ck, cv)
         return x, new_caches

@@ -124,10 +124,6 @@ PARTITION_RULES: Tuple[Tuple[str, P], ...] = TRUNK_RULES + (
     # per-phase head kernels (PhaseLogits): each phase tp-shards its OWN
     # vocab dim, so the phase boundary is a param boundary — the sliced
     # head works under tp with no interior-slice resharding
-    # graftspec's shallow-exit draft head is SELF-speculative: it re-uses
-    # these exact head params after spec_draft_depth blocks (no draft-only
-    # kernels exist), so spec_decode adds no partition rules — the plan
-    # fields ride DALLEConfig._PLAN_FIELDS for fingerprinting only
     (r".*to_logits_dense/(text_kernel|image_kernel)$", P("fsdp", "tp")),
     (r".*to_logits_dense/(text_bias|image_bias)$", P("tp")),
     # conv kernels (VAE): shard output channels over fsdp only

@@ -25,13 +25,11 @@ counter with damping — keeps oscillating load from thrashing the ring.
 Between healthy and shed sits the **brownout ladder**: ordered,
 reversible :class:`DegradeLevel` rungs applied fleet-wide when the fleet
 is saturated at ``max_replicas`` (or headroom-limited) and overload
-persists — disable spec decode, tighten throughput-class admission,
-shed throughput entirely, finally shed latency — and restored rung by
-rung, in reverse, once the fleet is calm.  Spec decode is bit-exact
-versus greedy (graftspec), so rung 1 trades only throughput; rungs 2-4
-act through :meth:`FleetRouter.set_shed_factors`, so demoted classes
-fail FAST with a typed :class:`~.router.ShedError` instead of timing
-out.
+persists — tighten throughput-class admission, shed throughput
+entirely, finally shed latency — and restored rung by rung, in reverse,
+once the fleet is calm.  Every rung acts through
+:meth:`FleetRouter.set_shed_factors`, so demoted classes fail FAST with a
+typed :class:`~.router.ShedError` instead of timing out.
 
 The autoscaler survives its own faults: a spawn that never reaches the
 ready-file handshake raises a typed :class:`~.remote.SpawnFailed` (the
@@ -67,11 +65,10 @@ class DegradeLevel(enum.IntEnum):
     (level N implies every rung <= N) and strictly reversible — restore
     walks back one rung at a time with its own hysteresis."""
 
-    HEALTHY = 0           # full service: spec decode on, normal admission
-    NO_SPEC = 1           # disable self-speculative decode fleet-wide
-    TIGHT_THROUGHPUT = 2  # throughput admission bound 4.0x -> 1.0x slots
-    SHED_THROUGHPUT = 3   # shed ALL throughput-class admissions
-    SHED_LATENCY = 4      # shed latency too: the rung before falling over
+    HEALTHY = 0           # full service: normal admission
+    TIGHT_THROUGHPUT = 1  # throughput admission bound 4.0x -> 1.0x slots
+    SHED_THROUGHPUT = 2   # shed ALL throughput-class admissions
+    SHED_LATENCY = 3      # shed latency too: the rung before falling over
 
 
 @dataclasses.dataclass(frozen=True)
@@ -123,7 +120,7 @@ class ScalePolicy:
     max_flaps: int = 2                 # reversals tolerated before damping
     degrade_after: int = 2             # overloaded evals before a new rung
     restore_after: int = 3             # calm evals before stepping back
-    tight_throughput_factor: float = 1.0  # rung-2 throughput shed factor
+    tight_throughput_factor: float = 1.0  # rung-1 throughput shed factor
     spawn_budget: int = 3              # consecutive SpawnFailed tolerated
     spawn_backoff_s: float = 0.5       # base backoff after a SpawnFailed
 
@@ -246,13 +243,6 @@ class AutoScaler:
         elif (factors.get(THROUGHPUT, 0.0)
               <= self.policy.tight_throughput_factor):
             level = DegradeLevel.TIGHT_THROUGHPUT
-        else:
-            # rung 1 leaves the router untouched; read it off the
-            # replicas themselves (spec capable but toggled off)
-            for sig in self._replica_signals():
-                if sig.get("spec_capable") and not sig.get("spec"):
-                    level = DegradeLevel.NO_SPEC
-                    break
         a = self.router.audit()
         with self._lock:
             self._level = level
@@ -549,10 +539,6 @@ class AutoScaler:
                 return
             with self._lock:
                 self._spawn_fails = 0
-                degraded_spec = self._level >= DegradeLevel.NO_SPEC
-            if degraded_spec:
-                # a replica born into a brownout must join degraded
-                self._set_replica_spec(replica, False)
             self.router.join(replica)
             self.spawned.append(replica)
             telemetry.emit("autoscale", "spawned", replica=name)
@@ -574,8 +560,8 @@ class AutoScaler:
 
     def apply_level(self, level: DegradeLevel) -> None:
         """Project one ladder rung onto the fleet.  Idempotent: the full
-        factor/spec state is recomputed from the rung, so re-applying
-        (or applying after a resync) converges."""
+        factor state is recomputed from the rung, so re-applying (or
+        applying after a resync) converges."""
         level = DegradeLevel(level)
         factors: Dict[str, float] = {}
         if level >= DegradeLevel.TIGHT_THROUGHPUT:
@@ -585,23 +571,7 @@ class AutoScaler:
         if level >= DegradeLevel.SHED_LATENCY:
             factors[LATENCY] = 0.0
         self.router.set_shed_factors(factors or None)
-        spec_on = level < DegradeLevel.NO_SPEC
-        for r in self.router.replicas():
-            if r.state in (SERVING, JOINING):
-                self._set_replica_spec(r, spec_on)
         with self._lock:
             self._level = level
         telemetry.emit("autoscale", "level_applied", level=int(level),
-                       level_name=level.name, spec=spec_on,
-                       factors=factors or None)
-
-    def _set_replica_spec(self, replica, enabled: bool) -> None:
-        set_spec = getattr(replica.server, "set_spec", None)
-        if set_spec is None:
-            return
-        try:
-            set_spec(bool(enabled))
-        # graftlint: disable=EXC001 (a brownout toggle on a dying replica must not kill the ladder walk; the failure is reported in-band and the next apply_level converges)
-        except Exception as e:
-            telemetry.emit("autoscale", "spec_toggle_failed",
-                           replica=replica.name, error=repr(e))
+                       level_name=level.name, factors=factors or None)

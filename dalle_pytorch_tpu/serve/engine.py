@@ -231,15 +231,6 @@ class SlotArena:
                 # would poison that (masked) lane's sampler with inf/nan
                 temp=jnp.ones((S,), jnp.float32),
                 out=jnp.zeros((S, cfg.image_seq_len), jnp.int32),
-                # spec_decode: per-slot cache rotation, FROZEN at admit.
-                # The greedy tick derives the shared write column from the
-                # arena clock (all slots advance together); a speculative
-                # tick advances each slot by a per-slot accepted length m,
-                # so the clock identity breaks — each slot keeps the
-                # rotation its prefill was installed with and decode_span
-                # scatters at per-row physical columns instead.
-                **({"rot": jnp.zeros((S,), jnp.int32)}
-                   if cfg.spec_decode else {}),
             )
 
         self.state = jax.jit(
@@ -257,8 +248,8 @@ class SlotArena:
                 temperature=temp, top_p=top_p)
 
         # named for a profiler trace: the programs read jit_serve_prefill,
-        # jit_serve_admit, jit_serve_tick, jit_serve_tick_spec there, keys no
-        # other `prefill` or `tick` in the process collides with
+        # jit_serve_admit and jit_serve_tick there, keys no other `prefill`
+        # or `tick` in the process collides with
         def serve_prefill(variables, text):
             return prefill_codes(dalle, variables, text)
 
@@ -330,8 +321,6 @@ class SlotArena:
                 temp=set1(state["temp"], temp),
                 out=jax.lax.dynamic_update_slice(
                     state["out"], out_row[None], (slot, 0)),
-                **({"rot": set1(state["rot"], rot)}
-                   if cfg.spec_decode else {}),
             )
 
         def serve_tick(variables, state, active, write_pos, qweights):
@@ -377,107 +366,11 @@ class SlotArena:
                     keys=state["keys"],
                     temp=state["temp"],
                     out=jnp.where(active[:, None], written, state["out"]),
-                    **({"rot": state["rot"]} if cfg.spec_decode else {}),
                 )
-
-        K = cfg.spec_k
-        L = self.geometry.image_seq_len
-
-        def serve_tick_spec(variables, state, active, qweights):
-            """One SPECULATIVE decode tick over every slot: draft ``K-1``
-            tokens through the first ``spec_draft_depth`` blocks, score
-            all ``K`` span positions with ONE full-depth
-            ``DALLE.decode_span`` pass, commit the accepted prefix plus
-            the verifier's correction.  Returns ``(state, m)`` where
-            ``m`` [S] int32 is each slot's committed-token count this
-            tick (1 <= m <= K for active slots, 0 for masked lanes) —
-            the scheduler's variable-rate progress accounting input.
-
-            Bit-equality with the greedy tick holds by construction:
-            lane ``j``'s verify read is the greedy tick's exact
-            attention program (``_aligned_read`` over the folded batch),
-            lane keys are the same pre-split per-position stream the
-            greedy tick gathers, and a draft for out position ``p`` is
-            sampled with position ``p``'s key — so an accepted draft IS
-            the token greedy would have sampled.  Rejected lanes leave
-            junk k/v beyond ``index + m``; those rows are causally
-            unreadable this tick and the next span (``m >= 1``) rewrites
-            them before any read."""
-            with prof.scope("serve-tick"):
-                pos = state["pos"]          # [S] decoded-token count
-                index = state["index"]      # [S] input position of `code`
-                rot = state["rot"]
-                remaining = jnp.int32(L) - pos
-                # per-slot keys for out positions pos..pos+K-1 (clipped —
-                # lanes past `remaining` are masked, their key is unused)
-                kspan = jax.vmap(
-                    lambda ks, p: jnp.take(
-                        ks, jnp.clip(p + jnp.arange(K), 0, L - 1),
-                        axis=0))(state["keys"], pos)          # [S, K, 2]
-                caches = state["caches"]
-                lanes = jnp.arange(K)[None, :]                # [1, K]
-                d = state["code"]
-                drafts = []
-                with prof.scope("spec-draft"):
-                    for j in range(1, K):
-                        qp = (index + (j - 1))[:, None]
-                        dvalid = (active & (j - 1 < remaining))[:, None]
-                        dlogits, caches = dalle.apply(
-                            variables, d[:, None], caches, qp, rot,
-                            dvalid, cfg.spec_draft_depth, qweights,
-                            method=DALLE.decode_span)
-                        # draft for out position pos+j-1: SAME key the
-                        # verifier's lane j-1 commit uses, so a correct
-                        # shallow guess is accepted bit-for-bit
-                        d = jax.vmap(sample_one)(
-                            dlogits[:, 0], kspan[:, j - 1], state["temp"])
-                        drafts.append(d)
-                with prof.scope("spec-verify"):
-                    t = jnp.stack([state["code"]] + drafts, axis=1)
-                    qpos = index[:, None] + lanes              # [S, K]
-                    vvalid = active[:, None] & (lanes < remaining[:, None])
-                    vlogits, caches = dalle.apply(
-                        variables, t, caches, qpos, rot, vvalid, None,
-                        qweights, method=DALLE.decode_span)
-                    cand = jax.vmap(jax.vmap(
-                        sample_one, in_axes=(0, 0, None)))(
-                            vlogits, kspan, state["temp"])     # [S, K]
-                    if cfg.spec_force_reject:
-                        matches = jnp.zeros_like(pos)
-                    else:
-                        matches = jnp.sum(jnp.cumprod(
-                            (t[:, 1:] == cand[:, :-1]).astype(jnp.int32),
-                            axis=1), axis=1)
-                    m = jnp.where(
-                        active,
-                        jnp.minimum(matches + 1, jnp.maximum(remaining, 1)),
-                        0)
-                    last = jnp.take_along_axis(
-                        cand, jnp.clip(m - 1, 0, K - 1)[:, None],
-                        axis=1)[:, 0]
-
-                    def write_row(row, p, cand_row, mm):
-                        idxs = jnp.where(jnp.arange(K) < mm,
-                                         p + jnp.arange(K), L)
-                        return row.at[idxs].set(cand_row, mode="drop")
-
-                    return dict(
-                        caches=caches,
-                        code=jnp.where(active, last, state["code"]),
-                        index=index + m,
-                        pos=pos + m,
-                        keys=state["keys"],
-                        temp=state["temp"],
-                        out=jax.vmap(write_row)(
-                            state["out"], pos, cand, m),
-                        rot=rot,
-                    ), m
 
         self._prefill = jax.jit(serve_prefill)
         self._admit = jax.jit(serve_admit, donate_argnums=(0,))
         self._tick = jax.jit(serve_tick, donate_argnums=(1,))
-        self._tick_spec = (jax.jit(serve_tick_spec, donate_argnums=(1,))
-                           if cfg.spec_decode else None)
         # a static choice, so its counters are per built arena; they cost a
         # compile of the tick, paid only where someone listens
         reg = metrics.active()
@@ -522,19 +415,6 @@ class SlotArena:
                                 jnp.int32(clock % self.geometry.seq_len),
                                 self._qweights)
 
-    def tick_spec(self, active_mask):
-        """Advance every active slot by its ACCEPTED speculative span
-        (1..spec_k tokens) in one jitted call; returns the per-slot
-        committed-token counts [num_slots] as host numpy.  No clock —
-        each slot writes at its admit-frozen rotation.  Mutates
-        ``self.state`` (donated)."""
-        assert self._tick_spec is not None, (
-            "tick_spec requires DALLEConfig.spec_decode=True")
-        self.state, m = self._tick_spec(self.variables, self.state,
-                                        jnp.asarray(active_mask),
-                                        self._qweights)
-        return jax.device_get(m)
-
     def take_codes(self, slot: int):
         """One slot's decoded codes [image_seq_len] as a device array of
         its own, dispatched now: whatever is admitted into the slot
@@ -543,12 +423,8 @@ class SlotArena:
         return self.state["out"][slot]
 
     def _lower_decode(self):
-        """The tick (``tick_spec`` under ``spec_decode``), lowered from the
-        state's own shapes."""
+        """The tick, lowered from the state's own shapes."""
         active = jax.ShapeDtypeStruct((self.geometry.num_slots,), jnp.bool_)
-        if self._tick_spec is not None:
-            return self._tick_spec.lower(self.variables, self.state, active,
-                                         self._qweights)
         return self._tick.lower(self.variables, self.state, active,
                                 jax.ShapeDtypeStruct((), jnp.int32),
                                 self._qweights)
@@ -556,8 +432,7 @@ class SlotArena:
     def programs(self) -> dict:
         """The arena's compiled programs under the names a profiler trace
         gives them (``jit_serve_prefill``, ``jit_serve_admit`` and
-        ``jit_serve_tick``, or ``jit_serve_tick_spec`` under
-        ``spec_decode``), lowered from the state's own shapes: what a
+        ``jit_serve_tick``), lowered from the state's own shapes: what a
         reader of a device trace needs to map ops to ``graftprof:`` scopes
         (``as_text``) or to ask the compiler's memory plan.  Runs nothing
         and leaves :meth:`trace_counts` as it was."""
@@ -575,8 +450,7 @@ class SlotArena:
         return {f"jit_{fn.__name__}": lowered.compile()
                 for fn, lowered in ((self._prefill, prefill),
                                     (self._admit, admit),
-                                    (self._tick_spec or self._tick,
-                                     self._lower_decode()))}
+                                    (self._tick, self._lower_decode()))}
 
     def layout(self) -> dict:
         """The ``serve.arena_layout`` record: how this arena stores its
@@ -618,8 +492,6 @@ class SlotArena:
         no-recompile sentinel the S3 serve gate and tests assert on.  A
         healthy server holds every count at 1 forever, whatever the
         admit/retire pattern."""
-        decode = (("tick_spec", self._tick_spec)
-                  if self._tick_spec is not None else ("tick", self._tick))
         return {name: int(fn._cache_size())
                 for name, fn in (("prefill", self._prefill),
-                                 ("admit", self._admit), decode)}
+                                 ("admit", self._admit), ("tick", self._tick))}

@@ -71,8 +71,7 @@ _EMPTY_BACKLOG = {"queued": {slo: 0 for slo in SLO_CLASSES},
 # the autoscaler treats an all-default row as "no information yet"
 _EMPTY_SIGNALS = {"queued": {slo: 0 for slo in SLO_CLASSES}, "running": 0,
                   "num_slots": 0, "headroom_bytes": None,
-                  "predicted_bytes_per_token": 0, "ledger_fingerprint": "",
-                  "spec": False, "spec_capable": False}
+                  "predicted_bytes_per_token": 0, "ledger_fingerprint": ""}
 
 
 class SpawnFailed(RuntimeError):
@@ -137,7 +136,6 @@ class ReplicaServer:
             "drain": self._h_drain,
             "stop": self._h_stop,
             "ping": self._h_ping,
-            "configure": self._h_configure,
         }, host=host, port=port)
         self.port = self._wire.port
 
@@ -228,16 +226,6 @@ class ReplicaServer:
         return {"ok": True, "pid": os.getpid(),
                 "replica": self.replica.name}
 
-    def _h_configure(self, params: dict) -> dict:
-        """Runtime knobs the autoscaler turns fleet-wide (brownout rung
-        1: spec decode off/on).  Returns the state actually in force —
-        a spec-incapable plan answers ``spec: False`` to an enable."""
-        out: dict = {"ok": True}
-        if "spec" in params:
-            out["spec"] = bool(
-                self.replica.server.set_spec(bool(params["spec"])))
-        return out
-
 
 # --- client half ------------------------------------------------------------
 
@@ -271,9 +259,6 @@ class _RemoteServerFacade:
 
     def scale_signals(self) -> dict:
         return self._r._cached_signals()
-
-    def set_spec(self, enabled: bool) -> bool:
-        return self._r._configure_spec(enabled)
 
     @property
     def busy(self) -> bool:
@@ -497,23 +482,6 @@ class RemoteReplica:
             s = dict(self._remote["signals"])
         s["queued"] = dict(s.get("queued") or {})
         return s
-
-    def _configure_spec(self, enabled: bool) -> bool:
-        """Brownout rung 1 over the wire.  A transport failure leaves
-        the remote state unchanged and reports the cached value — the
-        autoscaler re-applies the ladder on every transition, so a
-        missed toggle converges on the next apply."""
-        try:
-            resp = self._probe.call("configure",
-                                    {"spec": bool(enabled)},
-                                    deadline_s=self.call_timeout_s)
-        except wire.WireError as e:
-            telemetry.emit("remote", "configure_rpc_failed",
-                           replica=self.name, error=repr(e))
-            return bool(self._cached_signals().get("spec"))
-        with self._lock:
-            self._remote["signals"]["spec"] = bool(resp.get("spec"))
-        return bool(resp.get("spec"))
 
     def _busy(self) -> bool:
         with self._lock:

@@ -152,17 +152,6 @@ class GenerationServer:
         self.arena = SlotArena(dalle, variables, num_slots,
                                filter_thres=filter_thres, top_p=top_p,
                                device=device)
-        # spec_decode (a model-plan flag, default OFF): the scheduler's
-        # only change is variable tokens-per-tick — tick_spec returns each
-        # slot's accepted span length m and `done`/token accounting add m
-        # instead of 1.  SLO/latency math is untouched (it is per-request
-        # wall-clock, not per-tick).
-        # _spec_capable pins what the model plan compiled; _spec is the
-        # RUNTIME toggle (the graftscale brownout ladder's rung 1 —
-        # set_spec()), never exceeding capability
-        self._spec_capable = bool(dalle.cfg.spec_decode)
-        self._spec = self._spec_capable
-        self._spec_committed = 0
         # prefix_cache (a server knob, default OFF): admissions sharing a
         # prompt install copies of ONE batch-1 prefill via the refcounted
         # radix tree — including identical prompts already sitting in the
@@ -214,7 +203,7 @@ class GenerationServer:
         # consumers (obs/report.py) reconstruct totals exactly; partial
         # windows flush when the server drains idle, so nothing is lost.
         self.tick_sample = max(1, int(tick_sample))
-        self._tick_agg = {"ticks": 0, "tokens": 0, "active_sum": 0,
+        self._tick_agg = {"ticks": 0, "active_sum": 0,
                           "active_min": None, "active_max": 0,
                           "clock_first": None}
         # serve-steady memory watermarks: one obs/mem poll per
@@ -583,37 +572,20 @@ class GenerationServer:
         span = (self._span("serve", "tick", clock=self._clock,
                            active=len(advancing))
                 if sampled else telemetry.NULL_SPAN)
-        if self._spec:
-            # speculative tick: each active slot commits its accepted
-            # span (1..spec_k tokens) — progress accounting consumes the
-            # per-slot lengths, everything else (occupancy, SLO math) is
-            # still per-tick/per-request
-            with span:
-                ms = self.arena.tick_spec(mask)
-            self._clock += 1
-            tokens = 0
-            for slot in advancing:
-                adv = int(ms[slot])
-                self._running[slot].done += adv
-                tokens += adv
-            self._spec_committed += tokens
-        else:
-            with span:
-                self.arena.tick(mask, self._clock)
-            self._clock += 1
-            for slot in advancing:
-                self._running[slot].done += 1
-            tokens = len(advancing)
+        with span:
+            self.arena.tick(mask, self._clock)
+        self._clock += 1
+        for slot in advancing:
+            self._running[slot].done += 1
         n = len(advancing)
         self._ticks += 1
         self._occupied_slot_ticks += n
-        self._decoded_tokens += tokens
+        self._decoded_tokens += n
         # one record per `tick_sample` decode ticks (never per slot per
         # tick): occupancy and clock phase land on the timeline without
         # multiplying the stream by num_slots x tick rate
         agg = self._tick_agg
         agg["ticks"] += 1
-        agg["tokens"] += tokens
         agg["active_sum"] += n
         agg["active_min"] = (n if agg["active_min"] is None
                              else min(agg["active_min"], n))
@@ -634,11 +606,9 @@ class GenerationServer:
         self._emit("serve", "tick", clock=self._clock - 1,
                    active=agg["active_sum"] / agg["ticks"],
                    ticks=agg["ticks"], active_sum=agg["active_sum"],
-                   tokens=agg["tokens"],
                    active_min=agg["active_min"],
                    active_max=agg["active_max"],
-                   clock_first=agg["clock_first"],
-                   **({"spec": True} if self._spec else {}))
+                   clock_first=agg["clock_first"])
         reg = obs_metrics.active()
         if reg is not None:
             reg.gauge("graft_serve_occupancy",
@@ -656,7 +626,7 @@ class GenerationServer:
         if (self.mem_watermark_ticks
                 and self._ticks_since_watermark >= self.mem_watermark_ticks):
             self._emit_mem_watermark()
-        self._tick_agg = {"ticks": 0, "tokens": 0, "active_sum": 0,
+        self._tick_agg = {"ticks": 0, "active_sum": 0,
                           "active_min": None, "active_max": 0,
                           "clock_first": None}
 
@@ -777,33 +747,11 @@ class GenerationServer:
         return dict(queued=queued, queued_total=sum(queued.values()),
                     running=len(self._running))
 
-    @property
-    def spec_enabled(self) -> bool:
-        return self._spec
-
-    def set_spec(self, enabled: bool) -> bool:
-        """Toggle self-speculative decode at the tick boundary — the
-        brownout ladder's mildest rung (graftscale).  Effective only
-        when the model plan compiled the spec entry points
-        (``cfg.spec_decode``); returns the state actually in force.
-        Safe mid-stream: spec commits are bit-identical to greedy
-        (graftspec's acceptance rule), so flipping between ticks cannot
-        change any decoded codes — only tokens-per-tick.  The flag is a
-        plain bool store (the driver already reads it unlocked per
-        tick); no lock is needed or taken."""
-        want = bool(enabled) and self._spec_capable
-        changed = want != self._spec
-        self._spec = want
-        if changed:
-            self._emit("serve", "spec_toggle", enabled=want)
-        return want
-
     def scale_signals(self) -> dict:
         """One autoscaler observation of THIS server: queue depth per
         class + running slots (the demand side), the last serve-steady
         headroom watermark + the ledger's per-slot byte stream and row
-        fingerprint (the capacity side), and the spec-decode state (the
-        brownout ladder's rung-1 readback).  Cheap enough to ride the
+        fingerprint (the capacity side).  Cheap enough to ride the
         graftwire heartbeat."""
         b = self.backlog()
         return dict(
@@ -811,8 +759,7 @@ class GenerationServer:
             num_slots=self.num_slots,
             headroom_bytes=self.last_headroom_bytes,
             predicted_bytes_per_token=self.predicted_bytes_per_token,
-            ledger_fingerprint=self.ledger_fingerprint,
-            spec=self._spec, spec_capable=self._spec_capable)
+            ledger_fingerprint=self.ledger_fingerprint)
 
     def trace_counts(self) -> dict:
         return self.arena.trace_counts()
@@ -859,10 +806,6 @@ class GenerationServer:
             slo_attainment={slo: attainment(slo) for slo in SLO_CLASSES},
             trace_counts=self.trace_counts(),
             prefill_count=self.prefill_count,
-            **({"spec_accepted_k": (
-                self._spec_committed / self._occupied_slot_ticks
-                if self._occupied_slot_ticks else None)}
-               if self._spec else {}),
             **({"prefix": self.prefix.stats()}
                if self.prefix is not None else {}),
         )
@@ -880,5 +823,4 @@ class GenerationServer:
         self._ticks = 0
         self._occupied_slot_ticks = 0
         self._decoded_tokens = 0
-        self._spec_committed = 0
         self.prefill_count = 0

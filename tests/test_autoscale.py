@@ -33,16 +33,13 @@ from dalle_pytorch_tpu.serve.router import _SHED_FACTORS
 
 class StubServer:
     def __init__(self, queued=None, running=0, num_slots=2,
-                 headroom_bytes=None, pbpt=0, fingerprint="",
-                 spec=True, spec_capable=True):
+                 headroom_bytes=None, pbpt=0, fingerprint=""):
         self.queued = dict(queued or {LATENCY: 0, THROUGHPUT: 0})
         self.running = running
         self.num_slots = num_slots
         self.headroom_bytes = headroom_bytes
         self.pbpt = pbpt
         self.fingerprint = fingerprint
-        self.spec = spec and spec_capable
-        self.spec_capable = spec_capable
 
     def backlog(self):
         return dict(queued=dict(self.queued),
@@ -54,12 +51,7 @@ class StubServer:
                     num_slots=self.num_slots,
                     headroom_bytes=self.headroom_bytes,
                     predicted_bytes_per_token=self.pbpt,
-                    ledger_fingerprint=self.fingerprint,
-                    spec=self.spec, spec_capable=self.spec_capable)
-
-    def set_spec(self, enabled):
-        self.spec = bool(enabled) and self.spec_capable
-        return self.spec
+                    ledger_fingerprint=self.fingerprint)
 
 
 class StubReplica:
@@ -246,7 +238,7 @@ def test_headroom_exhausted_escalates_to_brownout():
     assert d.action == "hold" and d.target == 1   # affordable == current
     d = s.decide(starved, now=1.0)
     assert d.action == "degrade"
-    assert d.level == DegradeLevel.NO_SPEC
+    assert d.level == DegradeLevel.TIGHT_THROUGHPUT
     assert "headroom-limited" in d.reason
 
 
@@ -264,12 +256,13 @@ def test_unknown_headroom_skips_the_clamp():
 def test_ladder_descends_rung_by_rung_when_saturated():
     s = mk(degrade_after=1, max_replicas=4)
     over = sig(lat=30, serving=4)
-    walked = [s.decide(over, now=float(t)).level for t in range(4)]
-    assert walked == [DegradeLevel.NO_SPEC, DegradeLevel.TIGHT_THROUGHPUT,
+    walked = [s.decide(over, now=float(t)).level for t in range(3)]
+    assert walked == [DegradeLevel.TIGHT_THROUGHPUT,
                       DegradeLevel.SHED_THROUGHPUT, DegradeLevel.SHED_LATENCY]
+    assert list(DegradeLevel) == [DegradeLevel.HEALTHY] + walked
     # bottom rung: no further degradation, the decision falls through to
     # (saturated) scaling
-    d = s.decide(over, now=4.0)
+    d = s.decide(over, now=3.0)
     assert d.action == "hold" and d.level == DegradeLevel.SHED_LATENCY
     assert d.saturated
 
@@ -278,22 +271,21 @@ def test_ladder_restores_in_reverse_and_outranks_scale_down():
     s = mk(degrade_after=1, restore_after=1, max_replicas=4,
            down_after=1, down_cooldown_s=0.0)
     over = sig(lat=30, serving=4)
-    for t in range(4):
+    for t in range(3):
         s.decide(over, now=float(t))
     assert s.level == DegradeLevel.SHED_LATENCY
     calm = sig(serving=4)
     walked = []
-    for t in range(4, 8):
+    for t in range(3, 6):
         d = s.decide(calm, now=float(t))
         walked.append((d.action, d.level))
     assert walked == [
         ("restore", DegradeLevel.SHED_THROUGHPUT),
         ("restore", DegradeLevel.TIGHT_THROUGHPUT),
-        ("restore", DegradeLevel.NO_SPEC),
         ("restore", DegradeLevel.HEALTHY),
     ]
     # only once fully healthy does capacity start retiring
-    d = s.decide(calm, now=8.0)
+    d = s.decide(calm, now=6.0)
     assert d.action == "scale_down"
 
 
@@ -302,7 +294,7 @@ def test_restore_hysteresis_needs_consecutive_calm_evals():
            up_cooldown_s=0.0)
     sat = sig(lat=30, serving=2)     # at max and overloaded: saturated
     s.decide(sat, now=0.0)
-    assert s.level == DegradeLevel.NO_SPEC
+    assert s.level == DegradeLevel.TIGHT_THROUGHPUT
     calm = sig(serving=2)
     assert s.decide(calm, now=1.0).action == "hold"   # calm 1/3
     assert s.decide(calm, now=2.0).action == "hold"   # calm 2/3
@@ -345,16 +337,11 @@ def test_decision_record_cites_signals_and_ledger():
 # actuation onto a stub fleet
 
 
-def test_apply_level_projects_factors_and_spec():
+def test_apply_level_projects_factors_onto_the_router():
     reps = [StubReplica("a"), StubReplica("b", state=JOINING),
             StubReplica("c", state=DRAINING)]
     router = StubRouter(reps)
     s = AutoScaler(router, policy=ScalePolicy(tight_throughput_factor=1.0))
-
-    s.apply_level(DegradeLevel.NO_SPEC)
-    assert router.shed_factors() == _SHED_FACTORS   # rung 1: router untouched
-    assert not reps[0].server.spec and not reps[1].server.spec
-    assert reps[2].server.spec                      # DRAINING left alone
 
     s.apply_level(DegradeLevel.TIGHT_THROUGHPUT)
     assert router.shed_factors()[THROUGHPUT] == 1.0
@@ -367,26 +354,26 @@ def test_apply_level_projects_factors_and_spec():
     assert router.shed_factors()[LATENCY] == 0.0
     assert router.shed_factors()[THROUGHPUT] == 0.0
 
-    # full restore: defaults back, spec back on — and idempotent
+    # full restore: defaults back — and idempotent
     s.apply_level(DegradeLevel.HEALTHY)
     s.apply_level(DegradeLevel.HEALTHY)
     assert router.shed_factors() == _SHED_FACTORS
-    assert reps[0].server.spec and reps[1].server.spec
+    assert router.factor_calls == [
+        {THROUGHPUT: 1.0}, {THROUGHPUT: 0.0},
+        {THROUGHPUT: 0.0, LATENCY: 0.0}, None, None]
     assert s.level == DegradeLevel.HEALTHY
 
 
-@pytest.mark.parametrize("factors,spec_on,expect", [
-    (None, True, DegradeLevel.HEALTHY),
-    (None, False, DegradeLevel.NO_SPEC),
-    ({THROUGHPUT: 1.0}, True, DegradeLevel.TIGHT_THROUGHPUT),
-    ({THROUGHPUT: 0.0}, True, DegradeLevel.SHED_THROUGHPUT),
-    ({THROUGHPUT: 0.0, LATENCY: 0.0}, True, DegradeLevel.SHED_LATENCY),
+@pytest.mark.parametrize("factors,expect", [
+    (None, DegradeLevel.HEALTHY),
+    ({THROUGHPUT: 1.0}, DegradeLevel.TIGHT_THROUGHPUT),
+    ({THROUGHPUT: 0.0}, DegradeLevel.SHED_THROUGHPUT),
+    ({THROUGHPUT: 0.0, LATENCY: 0.0}, DegradeLevel.SHED_LATENCY),
 ])
-def test_resync_infers_level_from_live_state(factors, spec_on, expect):
+def test_resync_infers_level_from_live_state(factors, expect):
     """The restart contract: a fresh autoscaler over an already-degraded
     fleet resumes the ladder from the router's own observable state."""
-    rep = StubReplica("a", server=StubServer(spec=spec_on))
-    router = StubRouter([rep], factors=factors)
+    router = StubRouter([StubReplica("a")], factors=factors)
     s = AutoScaler(router, policy=ScalePolicy())
     s.resync()
     assert s.level == expect
@@ -488,12 +475,16 @@ def test_scale_up_success_resets_failure_streak_and_joins():
 
 
 def test_scale_up_born_into_brownout_joins_degraded():
+    """A replica spawned during a brownout joins behind the router's shed
+    factors, which the spawn leaves as the rung set them."""
     router = StubRouter([StubReplica("a")])
     s = AutoScaler(router, lambda name: StubReplica(name, state=JOINING),
                    policy=ScalePolicy())
-    s.apply_level(DegradeLevel.NO_SPEC)
+    s.apply_level(DegradeLevel.SHED_THROUGHPUT)
     s._scale_up(1)
-    assert not router.joined[0].server.spec
+    assert [r.name for r in router.joined] == ["as1"]
+    assert router.shed_factors()[THROUGHPUT] == 0.0
+    assert s.level == DegradeLevel.SHED_THROUGHPUT
 
 
 def test_scale_down_picks_lowest_backlog_and_keeps_floor():

@@ -621,8 +621,7 @@ def test_checkpoint_with_every_retired_switch_loads_and_generates(tmp_path):
 EXECUTION_FIELDS = {
     "use_remat", "ff_expert_dispatch", "ff_expert_capacity_factor",
     "ring_axis", "sp_impl", "sp_size", "kv_cache_bf16", "kv_cache_int8",
-    "weights_int8", "aligned_span_decode", "spec_decode",
-    "spec_draft_depth", "spec_k", "spec_force_reject"}
+    "weights_int8", "aligned_span_decode"}
 
 MODEL_FIELDS = {
     "dim", "num_text_tokens", "text_seq_len", "depth", "heads", "dim_head",
@@ -631,12 +630,12 @@ MODEL_FIELDS = {
     "ff_experts", "ff_expert_top_k", "ff_aux_weight", "trunk"}
 
 
-def test_execution_fields_are_exactly_the_fourteen():
+def test_execution_fields_are_exactly_the_ten():
     import dataclasses
 
     names = {f.name for f in dataclasses.fields(DALLEConfig)}
     assert names - MODEL_FIELDS - {"dtype"} == EXECUTION_FIELDS
-    assert len(EXECUTION_FIELDS) == 14
+    assert len(EXECUTION_FIELDS) == 10
     # every plan field is an execution field; use_remat is the one that a
     # checkpoint still records
     assert set(DALLEConfig._PLAN_FIELDS) == EXECUTION_FIELDS - {"use_remat"}

@@ -416,13 +416,12 @@ def test_trunk_spec_refuses_what_it_cannot_build(bad):
 
 
 @pytest.mark.parametrize("field,value", [
-    ("reversible", True), ("spec_decode", True), ("weights_int8", True),
+    ("reversible", True), ("weights_int8", True),
     ("kv_cache_int8", True), ("attn_dropout", 0.1), ("ring_axis", "sp")])
 def test_paths_without_a_form_for_a_latent_cache_refuse(field, value):
     with pytest.raises(AssertionError) as refusal:
         DALLEConfig(trunk=dict(TRUNK), **GEOMETRY, **{field: value})
-    if field in ("reversible", "spec_decode", "weights_int8",
-                 "kv_cache_int8"):
+    if field in ("reversible", "weights_int8", "kv_cache_int8"):
         assert "'mla'" in str(refusal.value)
         assert "moe_swiglu_shared" in str(refusal.value)
 

@@ -330,7 +330,7 @@ def test_without_a_trunk_the_parameter_tree_keeps_its_names():
 
 
 @pytest.mark.parametrize("field,value", [
-    ("reversible", True), ("spec_decode", True), ("weights_int8", True),
+    ("reversible", True), ("weights_int8", True),
     ("kv_cache_int8", True), ("sparse_attn", True), ("ring_axis", "sp"),
     ("ff_experts", 4), ("attn_dropout", 0.1), ("ff_dropout", 0.1)])
 def test_paths_without_a_meaning_for_recurrent_layers_refuse(field, value):

@@ -473,8 +473,7 @@ def test_serve_direct_instruments(tmp_path):
     assert 0.0 < reg.gauge("graft_serve_occupancy").value <= 1.0
     rendered = reg.render()
     for gone in ("graft_serve_retired_total", "graft_serve_ticks_total",
-                 "graft_serve_latency_seconds",
-                 "graft_serve_spec_accepted_k"):
+                 "graft_serve_latency_seconds"):
         assert gone not in rendered
 
 

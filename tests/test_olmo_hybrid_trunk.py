@@ -183,7 +183,7 @@ def test_trunk_arrives_as_a_dict_and_the_config_stays_hashable():
 
 
 @pytest.mark.parametrize("field,value", [
-    ("reversible", True), ("spec_decode", True), ("weights_int8", True),
+    ("reversible", True), ("weights_int8", True),
     ("kv_cache_int8", True)])
 def test_paths_without_a_form_for_a_matrix_state_refuse(field, value):
     with pytest.raises(AssertionError, match="linear-attention state"):

@@ -45,6 +45,23 @@ def test_scope_rejects_bad_names():
         pass  # valid slugs build a usable context manager
 
 
+def test_every_documented_scope_is_entered_by_the_package():
+    """``SCOPES`` is the contract the ledger rows enumerate: each name in it
+    is entered by a ``scope("...")`` call somewhere in the package, so a
+    deleted path takes its scope names with it."""
+    import ast
+
+    entered = set()
+    for path in (REPO / "dalle_pytorch_tpu").rglob("*.py"):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if (isinstance(node, ast.Call) and node.args
+                    and getattr(node.func, "attr",
+                                getattr(node.func, "id", None)) == "scope"
+                    and isinstance(node.args[0], ast.Constant)):
+                entered.add(node.args[0].value)
+    assert set(prof.SCOPES) <= entered, set(prof.SCOPES) - entered
+
+
 def test_attribute_matmul_exact_and_scoped():
     m, k, n = 8, 16, 4
 

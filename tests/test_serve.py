@@ -116,22 +116,19 @@ def test_device_pins_params_and_arena(small):
     assert srv.trace_counts() == {"prefill": 1, "admit": 1, "tick": 1}
 
 
-def test_scale_signals_surface_and_spec_toggle(small):
+def test_scale_signals_surface_demand_and_capacity(small):
     """graftscale's per-server observation: one cheap dict with the
-    demand side (queues, running), the capacity side (headroom + the
-    ledger's per-slot byte stream and row fingerprint), and the spec
-    rung readback — and set_spec is capability-clamped."""
+    demand side (queues, running) and the capacity side (headroom + the
+    ledger's per-slot byte stream and row fingerprint), and nothing
+    else."""
     srv = make_server(small, num_slots=2)
     s = srv.scale_signals()
+    assert set(s) == {"queued", "running", "num_slots", "headroom_bytes",
+                      "predicted_bytes_per_token", "ledger_fingerprint"}
     assert s["num_slots"] == 2
     assert s["queued"] == {LATENCY: 0, THROUGHPUT: 0} and s["running"] == 0
     assert s["predicted_bytes_per_token"] > 0
     assert len(s["ledger_fingerprint"]) == 12   # prof.row_fingerprint
-    # this cfg compiles no spec entry points: the brownout toggle is
-    # capability-clamped to off in BOTH directions
-    assert not s["spec_capable"] and not s["spec"]
-    assert srv.set_spec(True) is False
-    assert srv.set_spec(False) is False
     # demand side tracks the queues
     for t in small[3][:3]:
         srv.submit(t)
@@ -503,69 +500,3 @@ def test_aligned_span_reads_bit_match_gather(small, int8):
         assert srv.trace_counts() == {"prefill": 1, "admit": 1, "tick": 1}
     for a, b in zip(outs[True], outs[False]):
         np.testing.assert_array_equal(a, b)
-
-
-# --- graftspec: self-speculative decode (ISSUE 16) -------------------------
-
-
-def _spec_cfg(cfg, reject):
-    """Spec-decode plan over ``cfg``: the accept-all arm drafts with the
-    FULL depth (the draft pass IS the verify model, so every candidate
-    matches and whole K-spans commit); the reject arm forces matches=0 so
-    every tick falls back to the one-token greedy path."""
-    return dataclasses.replace(
-        cfg, spec_decode=True, spec_k=4,
-        spec_draft_depth=(2 if reject else cfg.depth),
-        spec_force_reject=reject)
-
-
-@pytest.mark.parametrize("reject", [False, True],
-                         ids=["accept-all", "force-reject"])
-def test_spec_decode_static_sampler_bit_matches_greedy(small, reject):
-    """The static spec sampler (models/dalle.py::_decode_codes_spec) is
-    BIT-IDENTICAL to the greedy scan at both acceptance extremes — the
-    rejection path is literally the greedy program, and acceptance only
-    commits candidates the full model scored identically."""
-    cfg, _, params, texts, refs = small
-    dalle_s = DALLE(_spec_cfg(cfg, reject))
-    fl, caches = jax.jit(lambda p, t: prefill_codes(dalle_s, p, t))(
-        params, jnp.asarray(texts[0])[None])
-    out = np.asarray(decode_codes(dalle_s, params, fl, caches,
-                                  jax.random.PRNGKey(7),
-                                  filter_thres=1.0))[0]
-    np.testing.assert_array_equal(out, refs[0])
-
-
-@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("reject", [False, True],
-                         ids=["accept-all", "force-reject"])
-def test_spec_decode_serve_bit_matches_greedy(small, int8, reject):
-    """ISSUE 16 acceptance gate: spec-decode serving through the slot
-    arena (K-wide verify, variable tokens-per-tick commits, per-slot
-    accepted-length masks) is BIT-IDENTICAL to the greedy static sampler
-    at BOTH acceptance extremes, for the bf16 AND the int8 arena, across
-    mid-flight admissions — and the whole interleaving compiles each
-    entry point exactly once (`tick_spec` replaces `tick`)."""
-    if int8:
-        base_cfg, _, params, texts, refs = _int8_setup(
-            small, weights_int8=True)
-    else:
-        base_cfg, _, params, texts, refs = small
-    srv = GenerationServer(DALLE(_spec_cfg(base_cfg, reject)), params,
-                           num_slots=2, filter_thres=1.0)
-    h0 = srv.submit(texts[0])
-    for _ in range(5):
-        srv.step()
-    h1 = srv.submit(texts[1])          # joins mid-flight
-    for _ in range(3):
-        srv.step()
-    h2 = srv.submit(texts[2])          # queued: both slots busy
-    srv.run_until_idle(max_ticks=300)
-    for h, r in ((h0, refs[0]), (h1, refs[1]), (h2, refs[2])):
-        np.testing.assert_array_equal(h.result(0), r)
-    assert srv.trace_counts() == {"prefill": 1, "admit": 1, "tick_spec": 1}
-    ak = srv.stats()["spec_accepted_k"]
-    if reject:
-        assert ak == 1.0  # forced rejection: one greedy token per tick
-    else:
-        assert ak > 1.5  # full-depth drafts: whole K-spans commit

@@ -1,16 +1,14 @@
 """The form the serving arena STORES its caches in (ISSUE 37).
 
 ``MultiHeadAttention.arena_form`` decides it per layer from what the trace
-can see; ``SlotArena`` allocates and installs by it; the aligned step and the
-span pass read and write the arrays where they lie.  The load-bearing
-properties:
+can see; ``SlotArena`` allocates and installs by it; the aligned step reads
+and writes the arrays where they lie.  The load-bearing properties:
 
 * **Exactness in the stored form**: a bf16 model with ``dim_head`` 64 and an
   even head count (the fold engages; ``full`` layers head-folded, the sliced
   ones position-major besides) serves, per request, the codes ``decode_codes``
   gives, over staggered admissions, retirements and several wraps of the
-  clock; so do the speculative and the int8 arenas in the form the rule gives
-  them.
+  clock; so does the int8 arena in the form the rule gives it.
 * **No retrace** across slots and wraps.
 * **What the programs ask for** (the lowered text: the CPU compiler's layouts
   are not the chip's): no transposition of a whole cache in the tick, each
@@ -211,18 +209,11 @@ def test_span_reads_match_the_gather_in_the_stored_form(folded):
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("spec", [None, "accept-all", "force-reject"])
-def test_int8_and_speculative_arenas_match_in_the_stored_form(
-        folded, int8, spec):
+def test_bf16_and_int8_arenas_match_in_the_stored_form(folded, int8):
     cfg, _, params, texts, refs = folded
     if int8:
         cfg = dataclasses.replace(cfg, kv_cache_int8=True, weights_int8=True)
         refs = greedy_refs(DALLE(cfg), params, texts[:3])
-    if spec:
-        reject = spec == "force-reject"
-        cfg = dataclasses.replace(
-            cfg, spec_decode=True, spec_k=4, spec_force_reject=reject,
-            spec_draft_depth=2 if reject else cfg.depth)
     srv = GenerationServer(DALLE(cfg), params, num_slots=2, filter_thres=1.0)
     assert srv.arena._forms[0] == HEAD_MAJOR
     assert srv.arena._forms[1:] == [POSITION_MAJOR] * 3
@@ -236,8 +227,7 @@ def test_int8_and_speculative_arenas_match_in_the_stored_form(
     srv.run_until_idle(max_ticks=300)
     for handle, ref in ((h0, refs[0]), (h1, refs[1]), (h2, refs[2])):
         np.testing.assert_array_equal(handle.result(0), ref)
-    assert srv.trace_counts() == {
-        "prefill": 1, "admit": 1, "tick_spec" if spec else "tick": 1}
+    assert srv.trace_counts() == {"prefill": 1, "admit": 1, "tick": 1}
     if int8:
         for k, v in srv.arena.state["caches"]:
             assert k[0].dtype == jnp.int8 and k[0].shape[-1] == 128

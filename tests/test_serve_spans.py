@@ -5,8 +5,6 @@ closed, bounded by ``tick_sample``; the arena's named programs; the report's
 phase lines.  (Named late in the alphabet: ROADMAP, the watchdog of
 ``test_anomaly_resume.py``.)
 """
-import dataclasses
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -182,6 +180,22 @@ def test_tick_sample_bounds_the_step_and_tick_spans(tmp_path, tiny, sample):
     assert len(named["retire"]) == len(named["admit"]) == 3
 
 
+def test_every_tick_advances_each_active_slot_by_one_code(tmp_path, tiny):
+    """One decode algorithm: a tick decodes one code a slot it advances, so
+    the tick records' occupied slot-ticks plus the code each admission
+    samples are every code decoded, and the records carry nothing else to
+    count them by."""
+    srv, handles, recs = serve(tiny, tmp_path)
+    ticks = [r for r in recs if r["name"] == "tick" and "ph" not in r]
+    assert not any({"tokens", "spec"} & set(r) for r in ticks)
+    stats = srv.stats()
+    assert stats["decoded_tokens"] == len(handles) * IMAGE_LEN == (
+        sum(r["active_sum"] for r in ticks) + len(handles))
+    assert not [key for key in stats if "accepted" in key]
+    assert build_report(telemetry.read_events(tmp_path))["serve"][
+        "decoded_tokens"] == len(handles) * IMAGE_LEN
+
+
 def test_programs_are_named_for_the_trace_and_nothing_retraces(tiny):
     srv, _, _ = serve(tiny)
     assert srv.trace_counts() == {"prefill": 1, "admit": 1, "tick": 1}
@@ -199,32 +213,6 @@ def test_programs_are_named_for_the_trace_and_nothing_retraces(tiny):
     srv.run_until_idle(max_ticks=100)
     assert handle.result().shape == (IMAGE_LEN,)
     assert srv.trace_counts() == {"prefill": 1, "admit": 1, "tick": 1}
-
-
-def test_spec_decode_names_its_tick_and_carries_accepted_k(tmp_path, tiny):
-    dalle, params, texts = tiny
-    cfg = dataclasses.replace(dalle.cfg, spec_decode=True, spec_k=2,
-                              spec_draft_depth=1)
-    telemetry.init(tmp_path, run_id="spec", beacon_every=0)
-    srv = GenerationServer(DALLE(cfg), params, num_slots=2, filter_thres=1.0)
-    srv.submit(texts[0])
-    srv.run_until_idle(max_ticks=100)
-    telemetry.shutdown()
-    assert sorted(srv.arena.programs()) == [
-        "jit_serve_admit", "jit_serve_prefill", "jit_serve_tick_spec"]
-    recs = [r for r in telemetry.read_events(tmp_path)
-            if r["kind"] == "serve" and r["name"] == "tick"]
-    spans = [r for r in recs if r.get("ph") == "B"]
-    events = [r for r in recs if "ph" not in r]
-    assert len(spans) == len(events) == srv.stats()["ticks"]
-    # what the dropped `graft_serve_spec_accepted_k` gauge exported: tokens
-    # committed over active slot-ticks, from the tick records
-    accepted = (sum(r["tokens"] for r in events)
-                / sum(r["active_sum"] for r in events))
-    assert all(r["spec"] for r in events)
-    assert accepted == pytest.approx(srv.stats()["spec_accepted_k"])
-    assert build_report(telemetry.read_events(tmp_path))["serve"][
-        "accepted_k"] == pytest.approx(accepted)
 
 
 def test_report_prints_the_phases_and_the_steps_self_time(tmp_path, tiny):

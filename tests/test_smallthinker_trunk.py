@@ -36,6 +36,7 @@ from dalle_pytorch_tpu.ops import moe  # noqa: E402
 from dalle_pytorch_tpu.ops.attention import (  # noqa: E402
     AttnPattern, _allowed, apply_rope, dense_pattern_mask, flash_tiles,
     pattern_mask_row, ring_positions)
+from dalle_pytorch_tpu.ops.quant import cache_write_rows  # noqa: E402
 from dalle_pytorch_tpu.ops.transformer import (  # noqa: E402
     TrunkSpec, layer_cache_lens)
 
@@ -462,7 +463,7 @@ def test_trunk_spec_refuses_what_it_cannot_build(bad):
 
 
 @pytest.mark.parametrize("field,value", [
-    ("reversible", True), ("spec_decode", True), ("weights_int8", True),
+    ("reversible", True), ("weights_int8", True),
     ("kv_cache_int8", True), ("ring_axis", "sp"), ("ff_experts", 4)])
 def test_paths_without_a_form_for_this_trunk_refuse(field, value):
     with pytest.raises(AssertionError):
@@ -565,6 +566,31 @@ def test_prefill_writes_the_last_window_of_a_long_prompt_into_the_ring(model):
         np.testing.assert_allclose(ring[1], stepped[1], rtol=1e-5, atol=1e-6)
     tiled_first, tiled = tile_prefill(first, caches, 3)
     assert [e[0].shape[0] for e in tiled] == [3] * 4
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_rings_per_row_write_touches_one_column_of_each_row(dtype):
+    """``cache_write_rows``, the arena's ring write: row r's new keys land in
+    column ``columns[r]`` and nowhere else, every other column and every
+    other row keep what they held, in the ring's storage dtype."""
+    rows, heads, slots, dh = 3, 2, 8, 4
+    ring = jax.random.normal(jax.random.PRNGKey(0),
+                             (rows, heads, slots, dh)).astype(dtype)
+    new = jax.random.normal(jax.random.PRNGKey(1), (rows, heads, 1, dh))
+    columns = jnp.asarray([5, 0, 5], jnp.int32)
+    written = cache_write_rows(ring, new, columns)
+    assert written.dtype == dtype
+    out = np.asarray(written.astype(jnp.float32))
+    want = np.asarray(ring.astype(jnp.float32)).copy()
+    for r, c in enumerate(np.asarray(columns)):
+        want[r, :, c] = np.asarray(new[r, :, 0].astype(dtype).astype(
+            jnp.float32))
+    np.testing.assert_array_equal(out, want)
+    # each row's untouched columns, bit for bit
+    for r, c in enumerate(np.asarray(columns)):
+        keep = np.arange(slots) != c
+        np.testing.assert_array_equal(
+            out[r][:, keep], np.asarray(ring[r].astype(jnp.float32))[:, keep])
 
 
 @pytest.fixture(scope="module")

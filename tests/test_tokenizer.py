@@ -19,7 +19,7 @@ from dalle_pytorch_tpu.data.tokenizer import (
 
 REPO = Path(__file__).resolve().parent.parent
 REF_BPE = Path("/root/reference/dalle_pytorch/data/bpe_simple_vocab_16e6.txt")
-REF_CUB = Path("/root/reference/cub200_bpe_vsize_7800.json")
+CUB_BPE = REPO / "cub200_bpe_vsize_7800.json"
 
 
 def test_bytes_to_unicode_bijective():
@@ -71,9 +71,8 @@ def test_clip_bpe_real_vocab():
     assert tok.encode("  A   Photo ") == tok.encode("a photo")
 
 
-@pytest.mark.skipif(not REF_CUB.exists(), reason="CUB BPE json not present")
 def test_hug_tokenizer_cub():
-    tok = HugTokenizer(REF_CUB)
+    tok = HugTokenizer(CUB_BPE)
     assert tok.vocab_size == 7800 or tok.vocab_size > 7000
     ids = tok.encode("this bird has a yellow crown and black wings")
     out = tok.tokenize("this bird has a yellow crown and black wings",
@@ -91,7 +90,7 @@ def test_bundled_cub_artifacts_resolve_cli_defaults():
     tokenize with the bundled vocab into the geometry the CUB CLIs use."""
     from dalle_pytorch_tpu.data.bundled import load_captions_pickle
 
-    bpe = REPO / "cub200_bpe_vsize_7800.json"
+    bpe = CUB_BPE
     pkl = REPO / "cub_2011_test_captions.pkl"
     assert bpe.exists(), "bundled CUB BPE vocab missing"
     assert pkl.exists(), "bundled CUB test-captions pickle missing"

@@ -337,49 +337,6 @@ def check_serve_tick_no_dequant(cfg: DALLEConfig, num_slots: int = 2) -> None:
                            "serve-tick")
 
 
-def check_spec_verify_no_dequant(cfg: DALLEConfig, num_slots: int = 2) -> None:
-    """C3 over the SPECULATIVE span jaxpr (``DALLE.decode_span`` at
-    K=spec_k, the verify pass of graftspec's tick_spec): the batched
-    K-wide verify is the full weight+cache stream one spec tick pays —
-    a dequant hoist here would scale with K and erase the entire
-    speculation win."""
-    assert cfg.spec_decode, "spec_decode must be on for the spec C3 check"
-    dalle = DALLE(cfg)
-    variables, _ = _init_shapes(dalle, batch=1)
-    S, K = num_slots, cfg.spec_k
-    cache_shape = (S, cfg.heads, cfg.seq_len, cfg.dim_head)
-    if cfg.kv_cache_int8:
-        entry = (_sds(cache_shape, jnp.int8),
-                 _sds((S, cfg.heads, 1, 1), jnp.float32))
-    else:
-        entry = _sds(cache_shape,
-                     jnp.bfloat16 if (cfg.kv_cache_bf16
-                                      or cfg.dtype == jnp.bfloat16)
-                     else cfg.dtype)
-    caches = [(entry, entry) for _ in range(cfg.depth)]
-    codes = _sds((S, K), jnp.int32)
-    qpos = _sds((S, K), jnp.int32)
-    rot = _sds((S,), jnp.int32)
-    valid = _sds((S, K), jnp.bool_)
-    weight_elems = None
-    qw = None
-    if cfg.weights_int8:
-        from dalle_pytorch_tpu.models.dalle import quantize_decode_weights
-
-        qw = jax.eval_shape(lambda v: quantize_decode_weights(v, cfg),
-                            variables)
-        weight_elems = _min_weight_elems(cfg, variables)
-
-    def span(v, codes, caches, qpos, rot, valid, qw):
-        return dalle.apply(v, codes, caches, qpos, rot, valid, None, qw,
-                           method=DALLE.decode_span)
-
-    jaxpr = jax.make_jaxpr(span)(variables, codes, caches, qpos, rot,
-                                 valid, qw)
-    _scan_dequant_converts(jaxpr.jaxpr, _cache_elems(caches), weight_elems,
-                           "spec-verify")
-
-
 # --- C4: parallel strategies --------------------------------------------
 
 # The framework's five parallel strategies (README "Scaling guide"):
@@ -520,13 +477,6 @@ def run_all(quick: bool = False) -> int:
         check_serve_tick_no_dequant, cfg_i8w)
     run("C3 serve-tick no dequant [bf16 cache]",
         check_serve_tick_no_dequant, make_cfg())
-    # graftspec (ISSUE 16): the K-wide verify span is the spec tick's
-    # whole byte stream — walk it under both cache layouts
-    run("C3 spec-verify no dequant [int8 cache+weights]",
-        check_spec_verify_no_dequant,
-        make_cfg(spec_decode=True, kv_cache_int8=True, weights_int8=True))
-    run("C3 spec-verify no dequant [bf16 cache]",
-        check_spec_verify_no_dequant, make_cfg(spec_decode=True))
     for name in STRATEGIES:
         run(f"C4 shardings resolve [{name}]", check_strategy, name)
     if not quick:

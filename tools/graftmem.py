@@ -321,43 +321,6 @@ def _serve_mem_row(make_cfg) -> dict:
                  memrow)
 
 
-def _serve_spec_mem_row(make_cfg) -> dict:
-    """graftspec: the SPECULATIVE arena tick's memory row — the K-1
-    shallow draft passes' transients plus the K-wide verify, walked over
-    the same weights + arena residency as the greedy tick.  Fingerprints
-    identically to graftprof's serve-spec row, so prediction and memory
-    merge onto one ledger row.  The label is "serve-spec" — deliberately
-    NOT a "serve-tick" superstring, so the quick gate's ``--targets
-    serve-tick`` filter still selects exactly one row."""
-    cfg = make_cfg(spec_decode=True, spec_k=4, spec_draft_depth=1)
-    dalle = DALLE(cfg)
-    text = jnp.zeros((1, cfg.text_seq_len), jnp.int32)
-    codes = jnp.zeros((1, cfg.image_seq_len), jnp.int32)
-    variables = jax.eval_shape(dalle.init, jax.random.PRNGKey(0), text,
-                               codes)
-    arena = SlotArena(
-        dalle, jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            variables),
-        num_slots=SERVE_SLOTS)
-    active = jnp.ones((SERVE_SLOTS,), bool)
-    walk = mem.peak_live(
-        jax.make_jaxpr(arena._tick_spec)(arena.variables, arena.state,
-                                         active, arena._qweights),
-        planes=mem.arg_planes(("weights", arena.variables),
-                              ("arena", arena.state),
-                              ("args", (active,)),
-                              ("weights", arena._qweights)))
-    phases = mem.serve_phases(walker_peak_bytes=walk["peak_bytes"])
-    config = graftprof._cfg_payload(cfg, target="serve-spec",
-                                    plan="single", batch=SERVE_SLOTS,
-                                    num_slots=SERVE_SLOTS)
-    memrow = mem.memory_row(phases=phases, planes=walk["planes"],
-                            scopes=walk["scopes"],
-                            walker_peak_bytes=walk["peak_bytes"])
-    return _wrap(prof.row_fingerprint(config), "serve-spec", "single",
-                 memrow)
-
-
 PREFIX_CAPACITY = 32  # the RadixPrefixCache default in serve/scheduler.py
 
 
@@ -416,7 +379,6 @@ def sweep(quick: bool = False, targets_filter=None) -> dict:
     builders.append(("clip", lambda: _clip_mem_row(quick)))
     builders.append(("decode", lambda: _decode_mem_row(make_cfg)))
     builders.append(("serve-tick", lambda: _serve_mem_row(make_cfg)))
-    builders.append(("serve-spec", lambda: _serve_spec_mem_row(make_cfg)))
     builders.append(("serve-prefix",
                      lambda: _serve_prefix_mem_row(make_cfg)))
 

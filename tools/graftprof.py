@@ -340,78 +340,6 @@ def _serve_tick_row(make_cfg) -> dict:
     return row
 
 
-def _decode_spec_row(make_cfg) -> dict:
-    """The SELF-SPECULATIVE sampling loop (models/dalle.py::
-    _decode_codes_spec — shallow drafts + one K-wide verify per
-    iteration), attributed per scope; the loop body is a while_loop so
-    the walker's figures are per-iteration-shaped rather than
-    whole-scan — held stable by construction, which is all the drift
-    gate needs.  The row carries the cost-model speedup
-    (``prof.predicted_spec_speedup``): bytes/token divides by the
-    accepted span length at the price of the draft-fraction overhead."""
-    cfg = make_cfg(spec_decode=True, spec_k=4, spec_draft_depth=1)
-    dalle = DALLE(cfg)
-    from dalle_pytorch_tpu.models.dalle import decode_codes
-
-    text = _sds((DECODE_BATCH, cfg.text_seq_len), jnp.int32)
-    codes = _sds((DECODE_BATCH, cfg.image_seq_len), jnp.int32)
-    variables = jax.eval_shape(dalle.init, jax.random.PRNGKey(0), text,
-                               codes)
-    logits, kvs = jax.eval_shape(
-        lambda v, t: dalle.apply(v, t, method=DALLE.prefill), variables,
-        text)
-    rng = _sds((2,), jnp.uint32)
-    jaxpr = jax.make_jaxpr(
-        lambda v, fl, c, r: decode_codes(dalle, v, fl, c, r))(
-            variables, logits, kvs, rng)
-    attr = prof.attribute(jaxpr)
-    prof.check_coverage(attr, label="decode-spec")
-    roof = prof.roofline(attr, CHIP, devices=1)
-    config = _cfg_payload(cfg, target="decode-spec", plan="single",
-                          batch=DECODE_BATCH)
-    row = prof.predicted_row(target="decode-spec", plan="single", chip=CHIP,
-                             config=config, attr=attr, roof=roof)
-    row["spec"] = prof.predicted_spec_speedup(cfg)
-    return row
-
-
-def _serve_spec_row(make_cfg) -> dict:
-    """One SPECULATIVE arena tick (serve/engine.py tick_spec: K-1 shallow
-    drafts + the K-wide verify), all slots advancing.  Beside the scope
-    attribution the row carries the serving cost model: the greedy
-    bytes/token divided by the expected accepted-K, against the
-    draft-stream overhead (``prof.predicted_spec_speedup``)."""
-    cfg = make_cfg(spec_decode=True, spec_k=4, spec_draft_depth=1)
-    dalle = DALLE(cfg)
-    text = jnp.zeros((1, cfg.text_seq_len), jnp.int32)
-    codes = jnp.zeros((1, cfg.image_seq_len), jnp.int32)
-    variables = jax.eval_shape(dalle.init, jax.random.PRNGKey(0), text,
-                               codes)
-    arena = SlotArena(
-        dalle, jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            variables),
-        num_slots=SERVE_SLOTS)
-    active = jnp.ones((SERVE_SLOTS,), bool)
-    jaxpr = jax.make_jaxpr(arena._tick_spec)(
-        arena.variables, arena.state, active, arena._qweights)
-    attr = prof.attribute(jaxpr)
-    prof.check_coverage(attr, label="serve-spec")
-    roof = prof.roofline(attr, CHIP, devices=1)
-    config = _cfg_payload(cfg, target="serve-spec", plan="single",
-                          batch=SERVE_SLOTS, num_slots=SERVE_SLOTS)
-    row = prof.predicted_row(target="serve-spec", plan="single", chip=CHIP,
-                             config=config, attr=attr, roof=roof)
-    model = prof.predicted_spec_speedup(cfg)
-    bpt = prof.predicted_serve_bytes_per_token(cfg, SERVE_SLOTS)
-    row["spec"] = dict(
-        model,
-        greedy_bytes_per_token=bpt,
-        predicted_bytes_per_token=int(
-            bpt * model["stream_overhead"] / model["assumed_accepted_k"]),
-        num_slots=SERVE_SLOTS)
-    return row
-
-
 # --- sweep -----------------------------------------------------------------
 
 
@@ -432,10 +360,6 @@ def sweep(quick: bool = False, targets_filter=None) -> dict:
     builders.append(("clip", lambda: _clip_row(quick)))
     builders.append(("decode", lambda: _decode_row(make_cfg)))
     builders.append(("serve-tick", lambda: _serve_tick_row(make_cfg)))
-    # graftspec (ISSUE 16): labels deliberately avoid the "serve-tick"
-    # substring so --targets serve-tick keeps selecting exactly one row
-    builders.append(("decode-spec", lambda: _decode_spec_row(make_cfg)))
-    builders.append(("serve-spec", lambda: _serve_spec_row(make_cfg)))
 
     rows = {}
     for label, build in builders:

@@ -358,14 +358,6 @@ def serve_retrace_check(num_slots: int = 3, **cfg_overrides):
         server.step()
     server.submit(prompt(num_slots))        # queued; admits on first retire
     server.run_until_idle(max_ticks=40 * cfg.image_seq_len)
-    # spec-decode ticks commit multiple tokens, so a fixed request count
-    # may finish before the clock wraps — keep the churn going until it
-    # does (greedy runs have already wrapped; the loop is a no-op there)
-    extra = num_slots + 1
-    while server._clock <= cfg.seq_len:
-        server.submit(prompt(extra))
-        extra += 1
-        server.run_until_idle(max_ticks=40 * cfg.image_seq_len)
     assert server._clock > cfg.seq_len, "churn must wrap the arena clock"
     counts = server.trace_counts()
     bad = {k: v for k, v in counts.items() if v != 1}
@@ -709,13 +701,6 @@ def run_all(chip: str = "v4-8", quick: bool = False,
     run("S3-retrace", "serve-tick", serve_retrace_check)
     run("S3-retrace", "serve-tick-int8",
         lambda: serve_retrace_check(kv_cache_int8=True, weights_int8=True))
-    # graftspec (ISSUE 16): the speculative tick replaces the greedy tick
-    # (trace_counts reports `tick_spec`) — same churn, same one-executable
-    # requirement; per-slot accepted lengths are traced values, so
-    # variable progress must not retrace either
-    run("S3-retrace", "serve-tick-spec",
-        lambda: serve_retrace_check(spec_decode=True, spec_k=4,
-                                    spec_draft_depth=1))
 
     # S2 per plan at tiny geometry, FULL-opt compile (donation honoring
     # is structural — layout/sharding mismatches reproduce at any size —
