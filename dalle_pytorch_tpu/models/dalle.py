@@ -40,8 +40,9 @@ from ..obs import metrics, prof, telemetry
 from ..ops.attention import LANES, record_kernel_choices
 from ..ops.ssm import fan_in_normal, normal_init
 from ..ops.transformer import (RMSNorm, Transformer, TrunkSpec,
-                                cache_position_axis, is_latent, is_recurrent,
-                                layer_cache_lens, layer_mixers)
+                                cache_position_axis, caches_positions,
+                                is_latent, is_stateless, layer_cache_lens,
+                                layer_mixers)
 from ..utils.helpers import (TOP_K_PASSES, max_neg_value, top_k_count,
                              top_k_filter, top_p_filter)
 
@@ -202,9 +203,10 @@ class DALLEConfig:
     def mixers(self) -> Tuple[str, ...]:
         """Each layer's mixer, and so the kind of its decode state:
         "attention" carries ``(k, v)`` over every position, "window" over a
-        ring of the window's length, "mamba" ``(window, h)``, "gdn"
-        ``(window, S)`` (ops/transformer.py::is_recurrent), "mla" one
-        latent and one rotated key a position (``is_latent``)."""
+        ring of the window's length, "mamba" and "mamba2" ``(window, h)``,
+        "gdn" ``(window, S)`` (ops/transformer.py::is_recurrent), "mla" one
+        latent and one rotated key a position (``is_latent``), "none"
+        nothing (``is_stateless``: the layer is its feed-forward alone)."""
         return layer_mixers(self.trunk, self.depth)
 
     @property
@@ -647,7 +649,8 @@ class DALLE(nn.Module):
                                     return_kv=True)
         if cfg.trunk is not None:
             # the prompt's positions only: a recurrent layer's state is the
-            # one after the last of them, and an attention layer's keys and
+            # one after the last of them (a layer without a mixer has none),
+            # and an attention layer's keys and
             # values (a latent layer's latent and rotated key) are padded
             # out to the cache's static length along their position axis; a
             # window layer's cache is a ring (position p in slot p mod
@@ -664,7 +667,7 @@ class DALLE(nn.Module):
                     (n_pre - slots) % slots, axis=axis)
 
             with prof.scope("attn-cache"):
-                kvs = [kv if is_recurrent(kind) else
+                kvs = [kv if not caches_positions(kind) else
                        tuple(stored(a, slots, cache_position_axis(kind))
                              for a in kv)
                        for kind, slots, kv in zip(cfg.mixers, cfg.cache_lens,
@@ -924,8 +927,8 @@ def _kv_reach(dalle: DALLE, params, caches, n_pre: int,
         held += nbytes
     bounded = [layer for layer in bounds if layer and len(layer) > 1]
     return {"bounded_layers": len(bounded),
-            "unbounded_layers": sum(not is_recurrent(kind)
-                                    for kind in cfg.mixers) - len(bounded),
+            "unbounded_layers": sum(map(caches_positions, cfg.mixers))
+            - len(bounded),
             "buckets": sum(map(len, bounded)),
             "read_share": read / held if held else 1.0}
 
@@ -942,7 +945,9 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int,
     ``decode.kv_layout`` record and two gauges say how many attention
     layers' caches were folded and how many kept plain, a
     ``decode.state_layout`` record and four gauges how many layers carry
-    keys and values, how many a state-space state, how many a
+    keys and values, how many a state-space state (Mamba-1 or Mamba-2),
+    how many none at all (``stateless_layers``, only where there are any:
+    layers of one sublayer without a mixer), how many a
     linear-attention state (with the shape a row of it is carried in), how
     many a latent pair (``latent_layers``, with the bytes a position holds
     and the bytes its stored form walks, gauge
@@ -963,8 +968,9 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int,
                              method=DALLE.lane_dense_caches)
     latent = [i for i, kind in enumerate(cfg.mixers) if is_latent(kind)]
     attn = [i for i, kind in enumerate(cfg.mixers)
-            if not (is_recurrent(kind) or is_latent(kind))]
+            if caches_positions(kind) and not is_latent(kind)]
     linear = [i for i, kind in enumerate(cfg.mixers) if kind == "gdn"]
+    stateless = sum(map(is_stateless, cfg.mixers))
     dense = sum(cache_values(folded[i][0]).shape
                 != cache_values(caches[i][0]).shape for i in attn)
     rows = int(jax.tree.leaves(caches)[0].shape[0])
@@ -972,8 +978,12 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int,
         "kv_layout": {"kv_lane_dense_layers": dense,
                       "kv_plain_layers": len(attn) - dense},
         "state_layout": {
-            "ssm_layers": len(caches) - len(attn) - len(latent) - len(linear),
+            "ssm_layers": sum(kind in ("mamba", "mamba2")
+                              for kind in cfg.mixers),
             "kv_layers": len(attn), "linear_layers": len(linear),
+            # only where there are any: other models' records stay as they
+            # were
+            **({"stateless_layers": stateless} if stateless else {}),
             "state_bytes_per_row": sum(
                 a.size * a.dtype.itemsize
                 for a in jax.tree.leaves(caches)) // rows}}
@@ -1023,14 +1033,14 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int,
                       "decode_codes' last trace (kv_reach)").set(reach[name])
     if cfg.trunk is not None and cfg.trunk.routed:
         t = cfg.trunk
-        counts = {"moe_layers": cfg.depth - t.dense_layers,
+        counts = {"moe_layers": t.routed_layers(cfg.depth),
                   "window_layers": cfg.mixers.count("window"),
                   "kv_slots_per_row": sum(cfg.cache_lens)}
         telemetry.emit(
             "decode", "moe_layout", rows=rows, layers=counts["moe_layers"],
             experts=t.experts, experts_per_token=t.experts_per_token,
-            expert_bytes_per_layer=3 * t.held_experts * cfg.dim
-            * t.expert_dim * jnp.dtype(t.param_dtype).itemsize,
+            expert_bytes_per_layer=t.expert_matrices * t.held_experts
+            * cfg.dim * t.expert_dim * jnp.dtype(t.param_dtype).itemsize,
             window_layers=counts["window_layers"],
             kv_slots_per_row=counts["kv_slots_per_row"],
             scoring=t.scoring, experts_held=t.held_experts,
@@ -1045,14 +1055,16 @@ def _lane_dense_caches(dalle: DALLE, params, caches, n_pre: int,
 def decode_codes(dalle: DALLE, params, first_logits, caches, rng, *,
                  n_prime: int = 0, prime_codes=None,
                  filter_thres: float = 0.5, temperature: float = 1.0,
-                 top_p: Optional[float] = None, mask=None) -> jax.Array:
+                 top_p: Optional[float] = None, mask=None,
+                 return_caches: bool = False):
     """The sampling half: `lax.scan` KV-cache decode from a prefill state
     (``prefill_codes`` or a ``tile_prefill`` broadcast of one).  Sampling
     semantics match the reference exactly (top_k filter with
     ``k = max(int((1-thres)*vocab), 1)``, temperature softmax, categorical
     draw, image-vocab offset subtraction; ref dalle_pytorch.py:400-415).
     ``top_p`` additionally applies nucleus filtering after top-k (a knob
-    the reference lacks).
+    the reference lacks).  ``return_caches``: return ``(codes, caches)``,
+    the scan's carried decode state after its last step beside the codes.
     """
     cfg = dalle.cfg
     n_pre = cfg.text_seq_len + 1 + n_prime
@@ -1083,14 +1095,15 @@ def decode_codes(dalle: DALLE, params, first_logits, caches, rng, *,
         num_steps = cfg.seq_len - n_pre  # remaining image positions
         keys = (jax.random.split(rng, num_steps) if num_steps > 0
                 else jnp.zeros((0, 2), jnp.uint32))
-        (_, _, _), rest = jax.lax.scan(
+        (_, caches, _), rest = jax.lax.scan(
             step, (first_code, caches, jnp.asarray(n_pre)), keys)
         rest = rest.transpose(1, 0)  # [b, num_steps]
 
         parts = [first_code[:, None], rest]
         if prime_codes is not None and n_prime > 0:
             parts.insert(0, prime_codes)
-        return jnp.concatenate(parts, axis=1)
+        codes = jnp.concatenate(parts, axis=1)
+        return (codes, caches) if return_caches else codes
 
 
 def generate_codes(dalle: DALLE, params, text, rng, *, prime_codes=None,

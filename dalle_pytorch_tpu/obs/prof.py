@@ -43,7 +43,7 @@ SCOPES = ("embed", "attn-qkv", "attn-scores", "attn-cache", "attn-out",
           "serve-tick", "sample",
           "ssm-proj", "ssm-conv", "ssm-scan", "moe-route", "moe-experts",
           "gdn-proj", "gdn-conv", "gdn-state", "mla-proj", "mla-read",
-          "attn-gate")
+          "attn-gate", "ssd-proj", "ssd-conv", "ssd-state")
 
 #: Residual bucket for equations under no scope.
 UNATTRIBUTED = "unattributed"

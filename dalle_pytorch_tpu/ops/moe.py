@@ -38,9 +38,10 @@ Three layers share one routing rule (:func:`route`): :class:`MoEFeedForward`
 above, the 2021 block's GEGLU experts; :class:`ExpertsReGLU`, the dropless
 bias-free ReGLU experts of a ``TrunkSpec`` trunk
 (ops/transformer.py::TrunkMoEBlock), whose router logits come from the
-caller; and :class:`ExpertsSwiGLUShared`, SwiGLU experts under a softmax
-or a sigmoid router (the latter with a selection bias), their weights
-scaled, beside a shared expert, on the experts this device holds
+caller; and :class:`ExpertsSwiGLUShared`, SwiGLU experts (or relu^2
+experts without a gate bank) under a softmax or a sigmoid router (the
+latter with a selection bias), their weights scaled, beside a shared
+expert, on the experts this device holds
 (ops/transformer.py::TrunkSharedMoEBlock).
 """
 from __future__ import annotations
@@ -97,17 +98,27 @@ def route(logits, k: int, scoring: str = "softmax", bias=None,
     return probs, top_idx, combine
 
 
+def relu2(x):
+    """``relu(x)^2``: Nemotron-H's expert nonlinearity, which has no gate."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def _bank_products(x, combine, w_gate, w_up, w_down, act, dtype):
     """Every bank's expert on every token ``x`` ``[t, d]``, the ``combine``
     weights' exact zeros cancelling the unchosen before one contraction over
     experts x width into the model width: float32 ``[t, d]``.  ``combine``
-    ``[t, e]`` has one column a bank; ``act`` is the gate's nonlinearity."""
+    ``[t, e]`` has one column a bank; ``act`` is the gate's nonlinearity,
+    or, where ``w_gate`` is None, the nonlinearity of ``x W_up`` alone."""
     with prof.scope("moe-experts"):
-        # graftlint: disable=DOT001 (uniform: x and the banks are both cast to dtype)
-        gate = jnp.einsum("td,edf->tef", x, w_gate)
-        # graftlint: disable=DOT001 (uniform: x and the banks are both cast to dtype)
-        up = jnp.einsum("td,edf->tef", x, w_up)
-        hidden = act(gate) * up
+        if w_gate is None:
+            # graftlint: disable=DOT001 (uniform: x and the banks are both cast to dtype)
+            hidden = act(jnp.einsum("td,edf->tef", x, w_up))
+        else:
+            # graftlint: disable=DOT001 (uniform: x and the banks are both cast to dtype)
+            gate = jnp.einsum("td,edf->tef", x, w_gate)
+            # graftlint: disable=DOT001 (uniform: x and the banks are both cast to dtype)
+            up = jnp.einsum("td,edf->tef", x, w_up)
+            hidden = act(gate) * up
     with prof.scope("moe-route"):
         hidden = (hidden.astype(jnp.float32)
                   * combine[:, :, None]).astype(dtype)
@@ -198,7 +209,9 @@ class ExpertsSwiGLUShared(nn.Module):
 
     and "softmax" (Laguna's): ``p = softmax(m W_r)``, the k largest of ``p``,
     ``w_e = scale * p_e / sum_chosen p``; no selection bias, and no
-    ``router_bias`` leaf.
+    ``router_bias`` leaf.  ``act`` "relu2" (Nemotron-H's experts) makes every
+    expert, shared or routed, ``W_down relu(W_up m)^2``: no gate bank, no
+    ``w_gate`` or ``shared_gate`` leaf.
 
     ``experts`` stays the router's width; ``held`` banks exist here, experts
     ``first .. first + held - 1`` (the share of a deployment that splits the
@@ -206,9 +219,9 @@ class ExpertsSwiGLUShared(nn.Module):
     all ``experts``, multiplies its own banks, and what the others would have
     added is left out.  On one device there is no exchange; ``held =
     experts`` is the whole layer.  The ``shared`` shared experts are one
-    SwiGLU of width ``shared x expert_dim``.  Scopes: ``moe-route`` (router
-    product, scores, top-k, weights) and ``moe-experts`` (the banks'
-    products and the shared expert's)."""
+    expert of width ``shared_dim``, or (0) ``shared x expert_dim``.
+    Scopes: ``moe-route`` (router product, scores, top-k, weights) and
+    ``moe-experts`` (the banks' products and the shared expert's)."""
 
     dim: int
     experts: int
@@ -217,6 +230,8 @@ class ExpertsSwiGLUShared(nn.Module):
     held: int
     first: int = 0
     shared: int = 1
+    shared_dim: int = 0
+    act: str = "swiglu"
     scoring: str = "sigmoid"
     scale: float = 1.0
     dtype: Any = jnp.float32
@@ -239,18 +254,26 @@ class ExpertsSwiGLUShared(nn.Module):
                 lambda key, shape: jax.random.uniform(key, shape, jnp.float32,
                                                       -0.1, 0.1),
                 (self.experts,))
-        self.w_gate = self.param("w_gate", fan_in_normal(d), (e, d, f),
-                                 **bank)
+        assert self.act in ("swiglu", "relu2"), self.act
+        if self.gated:
+            self.w_gate = self.param("w_gate", fan_in_normal(d), (e, d, f),
+                                     **bank)
         self.w_up = self.param("w_up", fan_in_normal(d), (e, d, f), **bank)
         self.w_down = self.param("w_down", fan_in_normal(f), (e, f, d),
                                  **bank)
-        fs = self.shared * f
-        self.shared_gate = self.param("shared_gate", fan_in_normal(d),
-                                      (d, fs), **bank)
+        fs = self.shared_dim or self.shared * f
+        if self.gated:
+            self.shared_gate = self.param("shared_gate", fan_in_normal(d),
+                                          (d, fs), **bank)
         self.shared_up = self.param("shared_up", fan_in_normal(d), (d, fs),
                                     **bank)
         self.shared_down = self.param("shared_down", fan_in_normal(fs),
                                       (fs, d), **bank)
+
+    @property
+    def gated(self) -> bool:
+        """Every expert has a gate bank (SwiGLU), not relu^2 alone."""
+        return self.act == "swiglu"
 
     def __call__(self, m):
         """``m`` ``[b, n, dim]`` (the normed hidden state) -> ``[b, n, dim]``
@@ -273,17 +296,23 @@ class ExpertsSwiGLUShared(nn.Module):
             self.sow("intermediates", "top_weight", jnp.take_along_axis(
                 combine, top_idx, axis=-1).reshape(b, n, -1))
             combine = combine[:, self.first:self.first + self.held]
-        y = _bank_products(x, combine, self.w_gate.astype(self.dtype),
+        gated = self.gated
+        y = _bank_products(x, combine,
+                           self.w_gate.astype(self.dtype) if gated else None,
                            self.w_up.astype(self.dtype),
-                           self.w_down.astype(self.dtype), jax.nn.silu,
-                           self.dtype)
+                           self.w_down.astype(self.dtype),
+                           jax.nn.silu if gated else relu2, self.dtype)
         with prof.scope("moe-experts"):
-            # graftlint: disable=DOT001 (uniform: x and the kernel are both cast to self.dtype)
-            gate = jnp.dot(x, self.shared_gate.astype(self.dtype))
-            # graftlint: disable=DOT001 (uniform: x and the kernel are both cast to self.dtype)
-            up = jnp.dot(x, self.shared_up.astype(self.dtype))
-            y = y + jnp.dot(jax.nn.silu(gate) * up,
-                            self.shared_down.astype(self.dtype),
+            if gated:
+                # graftlint: disable=DOT001 (uniform: x and the kernel are both cast to self.dtype)
+                gate = jnp.dot(x, self.shared_gate.astype(self.dtype))
+                # graftlint: disable=DOT001 (uniform: x and the kernel are both cast to self.dtype)
+                up = jnp.dot(x, self.shared_up.astype(self.dtype))
+                hidden = jax.nn.silu(gate) * up
+            else:
+                # graftlint: disable=DOT001 (uniform: x and the kernel are both cast to self.dtype)
+                hidden = relu2(jnp.dot(x, self.shared_up.astype(self.dtype)))
+            y = y + jnp.dot(hidden, self.shared_down.astype(self.dtype),
                             preferred_element_type=jnp.float32)
         return y.reshape(b, n, d).astype(m.dtype)
 

@@ -30,15 +30,21 @@ from .attention import YaRN, AttnPattern, MultiHeadAttention
 from .latent_attention import LatentAttention
 from .linear_attention import GatedDeltaMixer
 from .reversible import reversible_sequence, reversible_sequence_naive
-from .ssm import MambaMixer, fan_in_normal, rms_norm
+from .ssm import Mamba2Mixer, MambaMixer, fan_in_normal, rms_norm
 
-MIXERS = ("attention", "gdn", "mamba", "mla", "rotated", "window")
+#: a layer without a mixer: its feed-forward alone (``TrunkSpec.sublayers``)
+NO_MIXER = "none"
+MIXERS = ("attention", "gdn", "mamba", "mamba2", "mla", "rotated", "window",
+          NO_MIXER)
 #: the mixers whose decode state is a recurrent state (two leaves with the
 #: rows on axis 0 and no position axis), not keys and values
-RECURRENT_MIXERS = ("gdn", "mamba")
+RECURRENT_MIXERS = ("gdn", "mamba", "mamba2")
 #: the mixers that rotate their queries and keys by position
 ROTARY_MIXERS = ("mla", "rotated", "window")
 FFS = ("swiglu", "moe_reglu", "moe_swiglu_shared")
+#: a shared-expert layer's experts: gated SiLU, or relu(W_up m)^2 with no
+#: gate bank (ops/moe.py::ExpertsSwiGLUShared)
+EXPERT_ACTS = ("swiglu", "relu2")
 #: the feed-forwards that route tokens to experts
 ROUTED_FFS = ("moe_reglu", "moe_swiglu_shared")
 #: how a routed layer scores its experts (ops/moe.py::route)
@@ -52,7 +58,12 @@ class TrunkSpec:
     layer ``i`` is ``x += Mixer_i(Norm(x)); x += FF(Norm(x))`` (``norm_at``
     "input") or ``x += Norm(Mixer_i(x)); x += Norm(FF(x))`` ("output": the
     sublayers read the un-normed stream) with no LayerScale, bias or
-    dropout, its mixer ``mixers[i % len(mixers)]``.  Absent
+    dropout, its mixer ``mixers[i % len(mixers)]``.  A ``"none"`` mixer
+    leaves the layer its feed-forward alone; ``sublayers`` 1 makes every
+    layer ONE sublayer (Nemotron-H's ``hybrid_override_pattern``): a layer
+    with a mixer has no feed-forward, a ``"none"`` layer is its feed-forward
+    (:meth:`ff_kind`), and such a layer holds no decode state at all
+    (:func:`is_stateless`).  Absent
     (``DALLEConfig.trunk`` None) the stack is LayerScale(PreNorm(
     attention)) + LayerScale(PreNorm(GEGLU x4)) as before.
 
@@ -67,6 +78,11 @@ class TrunkSpec:
     ``global_rope_fraction`` of a head's dimensions, with YaRN's
     frequencies and attention factor where ``yarn_factor`` is set (over
     ``yarn_original_len`` positions: :meth:`yarn`), ``"mamba"`` Mamba-1 with normed ``dt``/``B``/``C``,
+    ``"mamba2"`` Mamba-2 (ops/ssm.py::Mamba2Mixer): ``ssd_heads`` heads of
+    ``ssd_head_dim`` channels, each a ``[ssd_head_dim, ssm_state]`` state
+    with one decay, ``B`` and ``C`` shared by ``ssd_groups`` groups of
+    heads, an ``ssm_conv``-tap convolution, the sequence in chunks of
+    ``ssd_chunk``,
     ``"gdn"`` gated-delta-rule linear attention (ops/linear_attention.py):
     ``DALLEConfig.heads`` heads of ``lin_key_dim`` x ``lin_value_dim`` state
     behind ``lin_conv``-tap convolutions; ``"mla"`` multi-head latent
@@ -89,7 +105,10 @@ class TrunkSpec:
     whose router reads the sublayer's NORMED input, its weights renormalised
     over the chosen and scaled by ``route_scale``, beside ``shared_experts``
     experts that every token takes
-    (ops/moe.py::ExpertsSwiGLUShared); ``experts`` stays the router's width
+    (ops/moe.py::ExpertsSwiGLUShared), each expert ``expert_act``
+    ("swiglu", or "relu2": ``W_down relu(W_up m)^2`` with no gate bank) and
+    the shared ones ``shared_dim`` wide (0: ``shared_experts x
+    expert_dim``); ``experts`` stays the router's width
     and ``experts_held`` (0: all) banks exist here, experts ``experts_first``
     onwards: the share of a deployment that splits each layer's experts over
     devices.  ``scoring`` says how a routed layer scores its experts
@@ -143,6 +162,13 @@ class TrunkSpec:
     yarn_factor: float = 0.0
     yarn_original_len: int = 0
     head_gate: bool = False
+    sublayers: int = 2
+    ssd_heads: int = 0
+    ssd_head_dim: int = 0
+    ssd_groups: int = 0
+    ssd_chunk: int = 128
+    expert_act: str = "swiglu"
+    shared_dim: int = 0
 
     def __post_init__(self):
         object.__setattr__(self, "mixers", tuple(self.mixers))
@@ -179,18 +205,29 @@ class TrunkSpec:
             "YaRN scales a 'rotated' layer's rotation")
         assert self.norm_at in NORM_AT, self.norm_at
         assert self.norm_at == "input" or (
-            not {"mamba", "mla"} & set(self.mixers)
-            and self.ff == "swiglu"), (
+            not {"mamba", "mamba2", "mla", NO_MIXER} & set(self.mixers)
+            and self.ff == "swiglu" and self.sublayers == 2), (
             "norm_at = 'output' closes attention, 'gdn' and swiglu "
             f"sublayers only: {self.mixers}, ff {self.ff!r}")
+        assert self.sublayers in (1, 2), self.sublayers
+        ssd = (self.ssd_heads, self.ssd_head_dim, self.ssd_groups)
+        assert (all(d > 0 for d in ssd) if "mamba2" in self.mixers
+                else not any(ssd)), (
+            f"'mamba2' layers need ssd_heads, ssd_head_dim and ssd_groups, "
+            f"which need them: {self.mixers}, {ssd}")
+        assert not self.ssd_groups or self.ssd_heads % self.ssd_groups == 0, (
+            f"{self.ssd_heads} heads in {self.ssd_groups} groups")
+        assert self.expert_act in EXPERT_ACTS, self.expert_act
         shared = self.ff == "moe_swiglu_shared"
         assert shared or not (self.dense_layers or self.experts_held
                               or self.experts_first or self.shared_experts
                               or self.route_scale != 1.0
-                              or self.scoring == "sigmoid"), (
+                              or self.scoring == "sigmoid"
+                              or self.expert_act != "swiglu"
+                              or self.shared_dim), (
             "dense_layers, experts_held, experts_first, shared_experts, "
-            "route_scale and sigmoid scoring belong to ff = "
-            f"'moe_swiglu_shared', not {self.ff!r}")
+            "route_scale, sigmoid scoring, expert_act and shared_dim belong "
+            f"to ff = 'moe_swiglu_shared', not {self.ff!r}")
         assert self.scoring in SCORINGS, self.scoring
         if not self.routed or self.dense_layers:
             assert self.ff_dim > 0, "a swiglu feed-forward needs ff_dim"
@@ -209,10 +246,22 @@ class TrunkSpec:
     def mixer(self, layer: int) -> str:
         return self.mixers[layer % len(self.mixers)]
 
-    def ff_kind(self, layer: int) -> str:
+    def ff_kind(self, layer: int) -> Optional[str]:
         """Layer ``layer``'s feed-forward: the leading ``dense_layers`` are
-        dense SwiGLUs, the rest ``ff``."""
+        dense SwiGLUs, the rest ``ff``; None where the layer is its mixer
+        alone (``sublayers`` 1)."""
+        if self.sublayers == 1 and self.mixer(layer) != NO_MIXER:
+            return None
         return "swiglu" if layer < self.dense_layers else self.ff
+
+    def routed_layers(self, depth: int) -> int:
+        """How many of ``depth`` layers route their tokens to experts."""
+        return sum(is_routed(self.ff_kind(i)) for i in range(depth))
+
+    @property
+    def expert_matrices(self) -> int:
+        """Weight matrices an expert of a routed layer holds."""
+        return 2 if self.expert_act == "relu2" else 3
 
     @property
     def rotary(self) -> bool:
@@ -250,13 +299,20 @@ def is_routed(ff: str) -> bool:
 def layer_mixers(trunk: Optional[TrunkSpec], depth: int) -> Tuple[str, ...]:
     """Each layer's mixer kind, which is also the kind of its decode state:
     ``(k, v)`` over every position for "attention", ``(k, v)`` over a ring
-    of the window's length for "window", ``(window, h)`` for "mamba",
-    ``(window, S)`` for "gdn" (:func:`is_recurrent` tells the last two from
-    the others), ``(c, k_rope)`` over every position for "mla"
-    (:func:`is_latent`: no head axis, the positions on axis 1)."""
+    of the window's length for "window", ``(window, h)`` for "mamba" and
+    "mamba2", ``(window, S)`` for "gdn" (:func:`is_recurrent` tells the
+    last three from the others), ``(c, k_rope)`` over every position for
+    "mla" (:func:`is_latent`: no head axis, the positions on axis 1), none
+    for "none" (:func:`is_stateless`)."""
     if trunk is None:
         return ("attention",) * depth
     return tuple(trunk.mixer(i) for i in range(depth))
+
+
+def is_stateless(kind: str) -> bool:
+    """A layer of this mixer kind has no mixer, and so no decode state: no
+    leaf at all in the per-layer caches (an entry of None there)."""
+    return kind == NO_MIXER
 
 
 def is_recurrent(kind: str) -> bool:
@@ -264,6 +320,13 @@ def is_recurrent(kind: str) -> bool:
     (``(window, state)``: rows on axis 0, no position axis, replaced whole
     at every step), not a cache of keys and values."""
     return kind in RECURRENT_MIXERS
+
+
+def caches_positions(kind: str) -> bool:
+    """A layer of this mixer kind caches something a position (keys and
+    values, a ring of them, a latent): neither a recurrent state nor
+    nothing."""
+    return not (is_recurrent(kind) or is_stateless(kind))
 
 
 def is_latent(kind: str) -> bool:
@@ -283,7 +346,7 @@ def layer_cache_lens(trunk: Optional[TrunkSpec], depth: int,
     """Slots of each layer's key/value cache: ``seq_len``, or ``min(window,
     seq_len)`` for a "window" layer (a ring: position p in slot ``p mod
     window``); 0 for a layer that keeps no keys."""
-    return tuple(0 if is_recurrent(kind) else
+    return tuple(0 if not caches_positions(kind) else
                  min(trunk.window, seq_len) if kind == "window" else seq_len
                  for kind in layer_mixers(trunk, depth))
 
@@ -461,38 +524,48 @@ class TrunkAttnBlock(nn.Module):
 
 
 class TrunkSSMBlock(nn.Module):
-    """PreNorm(Mamba mixer) of a :class:`TrunkSpec` trunk.  Its decode state
-    ``(window, h)`` (ops/ssm.py) rides where an attention layer's ``(k, v)``
-    does; it has no position axis, so ``index``, ``mask`` and ``write_pos``
-    mean nothing to it."""
+    """PreNorm(state-space mixer) of a :class:`TrunkSpec` trunk: ``kind``
+    ``"mamba"`` a Mamba mixer (``ssm``, scope ``ssm-proj``), ``"mamba2"`` a
+    Mamba-2 mixer (``ssd``, scope ``ssd-proj``; ops/ssm.py).  Its decode
+    state ``(window, h)`` rides where an attention layer's ``(k, v)`` does;
+    it has no position axis, so ``index``, ``mask`` and ``write_pos`` mean
+    nothing to it."""
 
     dim: int
     spec: TrunkSpec
+    kind: str = "mamba"
     dtype: Any = jnp.float32
     param_dtype: Any = jnp.float32
 
     def setup(self):
-        self.norm = RMSNorm(self.spec.norm_eps, name="norm")
-        self.ssm = MambaMixer(
-            dim=self.dim, expand=self.spec.ssm_expand,
-            state=self.spec.ssm_state, conv=self.spec.ssm_conv,
-            dt_rank=self.spec.ssm_dt_rank, eps=self.spec.norm_eps,
-            dtype=self.dtype, param_dtype=self.param_dtype, name="ssm")
+        spec = self.spec
+        kw = dict(dim=self.dim, state=spec.ssm_state, conv=spec.ssm_conv,
+                  eps=spec.norm_eps, dtype=self.dtype,
+                  param_dtype=self.param_dtype)
+        self.norm = RMSNorm(spec.norm_eps, name="norm")
+        if self.kind == "mamba2":
+            self.mixer = Mamba2Mixer(
+                heads=spec.ssd_heads, head_dim=spec.ssd_head_dim,
+                groups=spec.ssd_groups, chunk=spec.ssd_chunk, name="ssd",
+                **kw)
+        else:
+            self.mixer = MambaMixer(expand=spec.ssm_expand,
+                                    dt_rank=spec.ssm_dt_rank, name="ssm", **kw)
 
     def _normed(self, x):
-        with prof.scope("ssm-proj"):
+        with prof.scope("ssd-proj" if self.kind == "mamba2" else "ssm-proj"):
             return self.norm(x).astype(x.dtype)
 
     def __call__(self, x, mask=None, deterministic: bool = True,
                  return_kv: bool = False):
-        return self.ssm(self._normed(x), return_state=return_kv)
+        return self.mixer(self._normed(x), return_state=return_kv)
 
     def decode_step(self, x, window, h, index, mask=None, write_pos=None,
                     qw=None):
-        return self.ssm.decode_step(self._normed(x), window, h)
+        return self.mixer.decode_step(self._normed(x), window, h)
 
     def init_state(self, batch: int):
-        return self.ssm.init_state(batch)
+        return self.mixer.init_state(batch)
 
 
 class TrunkLinearBlock(nn.Module):
@@ -654,6 +727,7 @@ class TrunkSharedMoEBlock(nn.Module):
             dim=self.dim, experts=spec.experts, k=spec.experts_per_token,
             expert_dim=spec.expert_dim, held=spec.held_experts,
             first=spec.experts_first, shared=spec.shared_experts,
+            shared_dim=spec.shared_dim, act=spec.expert_act,
             scoring=spec.scoring, scale=spec.route_scale, dtype=self.dtype,
             param_dtype=self.param_dtype, name="moe")
 
@@ -708,10 +782,12 @@ class Transformer(nn.Module):
     """Depth x (attn, ff) residual stack with cycled attention variants
     (ref transformer.py:71-123); with a ``trunk`` (:class:`TrunkSpec`),
     depth x (mixer, feed-forward) with each layer's mixer global, windowed
-    or latent attention, Mamba or gated-delta-rule linear attention and its
-    feed-forward a SwiGLU or routed experts (of either kind, after the
-    spec's leading dense layers), the norm on each sublayer's input or on
-    its output."""
+    or latent attention, Mamba-1, Mamba-2 or gated-delta-rule linear
+    attention and its feed-forward a SwiGLU or routed experts (of either
+    kind, after the spec's leading dense layers), the norm on each
+    sublayer's input or on its output; a layer may be one of the two alone
+    (``TrunkSpec.sublayers``), and the block it lacks is None in
+    ``attn_blocks`` / ``ff_blocks``."""
 
     dim: int
     depth: int
@@ -776,9 +852,12 @@ class Transformer(nn.Module):
                         spec.norm_eps, name=f"layers_{ind}_mixer_norm"))
                     ff_norms.append(RMSNorm(
                         spec.norm_eps, name=f"layers_{ind}_ff_norm"))
-                if kind == "mamba":
+                if is_stateless(kind):
+                    attn_blocks.append(None)
+                elif kind in ("mamba", "mamba2"):
                     attn_blocks.append(TrunkSSMBlock(
-                        spec=spec, name=f"layers_{ind}_ssm", **kw))
+                        spec=spec, kind=kind, name=f"layers_{ind}_"
+                        + ("ssd" if kind == "mamba2" else "ssm"), **kw))
                 elif kind == "gdn":
                     attn_blocks.append(TrunkLinearBlock(
                         heads=self.heads, spec=spec, prenorm=prenorm,
@@ -810,7 +889,9 @@ class Transformer(nn.Module):
                         head_gate=spec.head_gate,
                         name=f"layers_{ind}_attn", **kw))
                 ff_kind = spec.ff_kind(ind)
-                if ff_kind == "moe_reglu":
+                if ff_kind is None:
+                    ff_blocks.append(None)
+                elif ff_kind == "moe_reglu":
                     ff_blocks.append(TrunkMoEBlock(
                         spec=spec, name=f"layers_{ind}_ff", **kw))
                 elif ff_kind == "moe_swiglu_shared":
@@ -871,8 +952,11 @@ class Transformer(nn.Module):
         aux losses) through it; a raw jax.checkpoint closure would leak
         tracers out of any sown value."""
         routed = self._router_logits(ind, x)
-        x = self._residual(x, ind, self.attn_blocks[ind](
-            x, mask=mask, deterministic=deterministic))
+        if self.attn_blocks[ind] is not None:
+            x = self._residual(x, ind, self.attn_blocks[ind](
+                x, mask=mask, deterministic=deterministic))
+        if self.ff_blocks[ind] is None:
+            return x
         return self._residual(
             x, ind, self._ff(ind, x, routed, deterministic=deterministic),
             ff=True)
@@ -903,7 +987,7 @@ class Transformer(nn.Module):
             telemetry.emit("moe", "route", tokens=x.shape[0] * x.shape[1],
                            experts=self.trunk.experts,
                            k=self.trunk.experts_per_token,
-                           layers=self.depth - self.trunk.dense_layers,
+                           layers=self.trunk.routed_layers(self.depth),
                            scoring=self.trunk.scoring,
                            experts_held=self.trunk.held_experts,
                            shared_experts=self.trunk.shared_experts)
@@ -916,13 +1000,18 @@ class Transformer(nn.Module):
         for ind in range(self.depth):
             if return_kv:
                 routed = self._router_logits(ind, x)
-                h, kv = self.attn_blocks[ind](
-                    x, mask=mask, deterministic=deterministic, return_kv=True)
+                kv = None
+                if self.attn_blocks[ind] is not None:
+                    h, kv = self.attn_blocks[ind](
+                        x, mask=mask, deterministic=deterministic,
+                        return_kv=True)
+                    x = self._residual(x, ind, h)
                 kvs.append(kv)
-                x = self._residual(x, ind, h)
-                x = self._residual(
-                    x, ind, self._ff(ind, x, routed,
-                                     deterministic=deterministic), ff=True)
+                if self.ff_blocks[ind] is not None:
+                    x = self._residual(
+                        x, ind, self._ff(ind, x, routed,
+                                         deterministic=deterministic),
+                        ff=True)
             elif use_remat:
                 x = remat_block(self, x, ind, mask, deterministic)
             else:
@@ -991,7 +1080,8 @@ class Transformer(nn.Module):
         (:attr:`cache_lens`: ``seq_len``, or a ring of the window's length),
         ``(c [b, slots, kv_rank], k_rope [b, slots, rope_dim])`` for a latent
         one, the block's own ``(window, state)`` for a recurrent one
-        (ops/ssm.py, ops/linear_attention.py)."""
+        (ops/ssm.py, ops/linear_attention.py), None for a layer without a
+        mixer."""
         dtype = dtype or self.dtype
         kv_heads = self.heads if self.trunk is None else self.trunk.kv_heads
 
@@ -1000,6 +1090,7 @@ class Transformer(nn.Module):
             return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
         return [
+            None if is_stateless(kind) else
             blk.init_state(batch) if is_recurrent(kind) else
             blk.mla.init_cache(batch, slots, dtype) if is_latent(kind) else
             pair(slots)
@@ -1011,21 +1102,21 @@ class Transformer(nn.Module):
         """Per-layer caches as ``decode_codes``' scan should carry them
         (MultiHeadAttention.lane_dense_cache): :meth:`decode_step` takes
         either layout, told by the shape.  A recurrent state passes as it
-        is; a latent pair has no head to fold, and its layer says whether
-        the two ride as one array (LatentAttention.lane_dense_cache: not
-        where the call is ``masked``, has a key-padding mask)."""
-        return [(ck, cv) if is_recurrent(kind) else
-                blk.mla.lane_dense_cache(ck, cv, masked) if is_latent(kind)
-                else (blk.attn.lane_dense_cache(ck),
-                      blk.attn.lane_dense_cache(cv))
-                for blk, kind, (ck, cv) in zip(self.attn_blocks, self.mixers,
-                                               caches)]
+        is, and a stateless layer's None; a latent pair has no head to
+        fold, and its layer says whether the two ride as one array
+        (LatentAttention.lane_dense_cache: not where the call is ``masked``,
+        has a key-padding mask)."""
+        return [cache if not caches_positions(kind) else
+                blk.mla.lane_dense_cache(*cache, masked) if is_latent(kind)
+                else tuple(map(blk.attn.lane_dense_cache, cache))
+                for blk, kind, cache in zip(self.attn_blocks, self.mixers,
+                                            caches)]
 
     def arena_forms(self, dtype):
         """Per layer, the form the serving arena stores its caches of
         ``dtype`` in (MultiHeadAttention.arena_form); None for a layer that
-        carries a recurrent state."""
-        return [None if is_recurrent(kind) else
+        carries a recurrent state or none."""
+        return [None if not caches_positions(kind) else
                 (blk.mla if is_latent(kind) else blk.attn).arena_form(dtype)
                 for blk, kind in zip(self.attn_blocks, self.mixers)]
 
@@ -1034,9 +1125,9 @@ class Transformer(nn.Module):
         ``dtype`` ends at (MultiHeadAttention.dense_read_bounds, the buckets
         a ``lax.switch`` chooses among; LatentAttention.dense_read_bounds,
         those or, without a key-padding mask, the ends of its one-pass
-        read's blocks); None for a layer that reads slices or carries a
-        recurrent state."""
-        return [None if is_recurrent(kind) else
+        read's blocks); None for a layer that reads slices, carries a
+        recurrent state or carries none."""
+        return [None if not caches_positions(kind) else
                 blk.mla.dense_read_bounds(dtype, masked) if is_latent(kind)
                 else blk.attn.dense_read_bounds()
                 for blk, kind in zip(self.attn_blocks, self.mixers)]
@@ -1071,13 +1162,16 @@ class Transformer(nn.Module):
                 x2 = x2 + (ff(x1, qw=qw) if qw is not None else ff(x1))
                 new_caches.append((ck, cv))
             return (x1 + x2) / 2, new_caches
-        for ind, (attn, (ck, cv), qw) in enumerate(zip(self.attn_blocks,
-                                                       caches, qws)):
+        for ind, (attn, cache, qw) in enumerate(zip(self.attn_blocks, caches,
+                                                    qws)):
             routed = self._router_logits(ind, x)
-            h, ck, cv = attn.decode_step(x, ck, cv, index, mask=mask,
-                                         write_pos=write_pos, qw=qw)
-            x = self._residual(x, ind, h)
-            x = self._residual(x, ind, self._ff(ind, x, routed, qw=qw),
-                               ff=True)
-            new_caches.append((ck, cv))
+            if attn is not None:
+                h, *cache = attn.decode_step(x, *cache, index, mask=mask,
+                                             write_pos=write_pos, qw=qw)
+                cache = tuple(cache)
+                x = self._residual(x, ind, h)
+            if self.ff_blocks[ind] is not None:
+                x = self._residual(x, ind, self._ff(ind, x, routed, qw=qw),
+                                   ff=True)
+            new_caches.append(cache)
         return x, new_caches

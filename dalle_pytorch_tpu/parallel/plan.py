@@ -75,6 +75,13 @@ TRUNK_RULES: Tuple[Tuple[str, P], ...] = (
     (r".*ssm/out_proj/kernel$", P("tp", "fsdp")),
     (r".*ssm/conv_kernel$", P(None, "tp")),
     (r".*ssm/A_log$", P("tp", None)),
+    # Mamba-2 mixer: in_proj's [z | x B C | dt] columns cross the head
+    # and group boundaries, so both projections [dim, width] and [d_in,
+    # dim] split over fsdp on the model width alone; the taps, the bias and
+    # the per-head vectors stay whole
+    (r".*ssd/in_proj/kernel$", P("fsdp", None)),
+    (r".*ssd/out_proj/kernel$", P(None, "fsdp")),
+    (r".*ssd/conv_kernel$", P(None, None)),
     # gated-delta-rule mixer: heads are independent through the
     # convolutions and the rule, so they split over tp — the four wide
     # projections [dim, heads, d] column-parallel, o_proj [heads, d_v, dim]

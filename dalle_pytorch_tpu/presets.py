@@ -89,6 +89,8 @@ PARAM_BANDS = {
     "glm-4.7-flash": (1.1e9, 1.2e9),
     "laguna-tiny": (0.01e6, 1e6),
     "laguna-s-2.1": (1.6e9, 1.7e9),
+    "nemotron-tiny": (0.01e6, 1e6),
+    "nemotron-3-nano-30b-a3b": (1.55e9, 1.65e9),
 }
 
 
@@ -453,6 +455,73 @@ def laguna_s_2_1_config(**overrides):
     return DALLEConfig(**base)
 
 
+#: NVIDIA-Nemotron-3-Nano-30B-A3B's trunk (huggingface.co/nvidia/
+#: NVIDIA-Nemotron-3-Nano-30B-A3B-BF16, config.json, ``nemotron_h``): every
+#: layer is ONE sublayer, as ``hybrid_override_pattern`` says: a Mamba-2
+#: mixer ("M": 64 heads of 64, a 64 x 128 float32 state a head, B and C in 8
+#: groups, a 4-tap convolution, chunks of 128), 128 sigmoid-routed relu^2
+#: experts of 1,856, 6 a token, weights renormalised and scaled by 2.5,
+#: beside a shared relu^2 expert of 3,712 ("E"), or unrotated grouped
+#: attention, 32 queries over 2 keys of 128 ("*"); an untied head.  HERE:
+#: layers 0-8, ``MEMEM*EME`` (one period of the published 52), and one
+#: chip's share of a deployment in which eight chips share each layer, the
+#: 128 experts split 16 a chip (``experts_held``, experts 0-15).
+NEMOTRON_3_NANO_30B_A3B_TRUNK = dict(
+    mixers=("mamba2", "none", "mamba2", "none", "mamba2", "attention",
+            "none", "mamba2", "none"),
+    sublayers=1, kv_heads=2, norm="rms", norm_eps=1e-5,
+    ssm_state=128, ssm_conv=4, ssd_heads=64, ssd_head_dim=64, ssd_groups=8,
+    ssd_chunk=128, ff="moe_swiglu_shared", expert_act="relu2",
+    scoring="sigmoid", experts=128, experts_per_token=6, expert_dim=1856,
+    experts_held=16, experts_first=0, shared_experts=1, shared_dim=3712,
+    route_scale=2.5, tied_table=False, param_dtype="bfloat16")
+
+
+def nemotron_tiny_config(**overrides):
+    """The same trunk at toy width with every mechanism (tests): the
+    published period ``MEMEM*EME``, 4 Mamba-2 heads of 8 channels in 2
+    groups with a state of 8 and chunks of 8 (so that a prompt of 9
+    positions ends inside a chunk), 8 experts of which 4 are held, 3 a
+    token, a shared expert of its own width."""
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=32, depth=9, heads=4, dim_head=8, num_text_tokens=50,
+                text_seq_len=8, num_image_tokens=32, image_size=64,
+                image_fmap_size=4,
+                trunk=dict(NEMOTRON_3_NANO_30B_A3B_TRUNK, ssm_state=8,
+                           ssd_heads=4, ssd_head_dim=8, ssd_groups=2,
+                           ssd_chunk=8, experts=8, experts_per_token=3,
+                           expert_dim=16, experts_held=4, experts_first=2,
+                           shared_dim=24, param_dtype="float32"))
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
+def nemotron_3_nano_30b_a3b_config(**overrides):
+    """DALL-E's client over one chip's share of the Nemotron-3-Nano-30B-A3B
+    trunk, every width as published: layers 0-8 (``MEMEM*EME``: four
+    Mamba-2 layers, four expert layers, one attention layer), each expert
+    layer's router over all 128 experts and the banks of experts 0-15 (16 of
+    128: eight chips share each layer), the shared expert, the whole
+    131,072-row table and head: 1.603B parameters, 3.21 GB in bfloat16.  The
+    rows are 122,624 text ids + 256 per-position pad ids + 8,192 image codes
+    of a 256 px, 32 x 32 code grid (n = 1,280).
+    ``benchmark/configs/nemotron-3-nano-30b-a3b.json`` is the same model as
+    the benchmark runs it; the other 43 layers would lie on further
+    chips."""
+    import jax.numpy as jnp
+
+    from dalle_pytorch_tpu import DALLEConfig
+
+    base = dict(dim=2688, depth=9, heads=32, dim_head=128,
+                num_text_tokens=122624, text_seq_len=256,
+                num_image_tokens=8192, image_size=256, image_fmap_size=32,
+                attn_types=("full",), trunk=NEMOTRON_3_NANO_30B_A3B_TRUNK,
+                dtype=jnp.bfloat16)
+    base.update(overrides)
+    return DALLEConfig(**base)
+
+
 #: Every named config geometry (CLI ``--preset`` surface).
 CONFIG_PRESETS = {
     "tiny": tiny_config,
@@ -469,6 +538,8 @@ CONFIG_PRESETS = {
     "glm-4.7-flash": glm_4_7_flash_config,
     "laguna-tiny": laguna_tiny_config,
     "laguna-s-2.1": laguna_s_2_1_config,
+    "nemotron-tiny": nemotron_tiny_config,
+    "nemotron-3-nano-30b-a3b": nemotron_3_nano_30b_a3b_config,
 }
 
 #: The scale rungs that are ALSO plan-registry entries: registry name ->

@@ -79,6 +79,8 @@ This module is the device half of the fix:
   ...]`` with no position axis: nothing to rotate and nothing a mask could
   hide.  ``admit`` writes the prefilled state whole; ``tick`` advances it
   only where ``active``, so an idle or finished slot's state stands still.
+  A layer without a mixer (``TrunkSpec.sublayers`` 1) holds nothing: its
+  entry is None, and ``admit`` and ``tick`` pass it by.
 
 Sampling reuses ``models.dalle.sample_image_code`` — the serve path and
 ``decode_codes`` share one sampler, so semantics cannot drift; temperature
@@ -103,7 +105,7 @@ from ..models.dalle import (DALLE, prefill_codes, quantize_decode_weights,
                             sample_image_code)
 from ..obs import metrics, prof, telemetry
 from ..ops.quant import cache_values, split_cache
-from ..ops.transformer import is_latent, is_recurrent
+from ..ops.transformer import is_latent, is_recurrent, is_stateless
 
 
 #: ``%name = bf16[128,4,1104,128]{3,2,1,0:T(8,128)(2,1)} copy(`` in a compiled
@@ -183,6 +185,8 @@ class SlotArena:
         # depths cannot share a write column there, so such a layer takes
         # ops/attention.py's per-row ring step and no rotation
         ring = [kind == "window" for kind in cfg.mixers]
+        # a layer without a mixer holds no decode state: None in the arena
+        stateless = [is_stateless(kind) for kind in cfg.mixers]
         # the form each layer's caches are STORED in between the programs
         # (ops/quant.py::CacheForm; None for a recurrent layer), asked of the
         # layers themselves: the tick reads and writes the arrays where
@@ -214,10 +218,12 @@ class SlotArena:
             zero = (dalle.apply(variables, S, method=DALLE.decode_init_state)
                     if any(recurrent) or any(latent) else [None] * cfg.depth)
             return dict(
-                caches=[entry if rec or lat else (fresh_entry(slots, form),
-                                                  fresh_entry(slots, form))
-                        for rec, lat, entry, slots, form in zip(
-                            recurrent, latent, zero, cfg.cache_lens, forms)],
+                caches=[entry if rec or lat or none
+                        else (fresh_entry(slots, form),
+                              fresh_entry(slots, form))
+                        for rec, lat, none, entry, slots, form in zip(
+                            recurrent, latent, stateless, zero,
+                            cfg.cache_lens, forms)],
                 code=jnp.zeros((S,), jnp.int32),
                 index=jnp.zeros((S,), jnp.int32),
                 pos=jnp.zeros((S,), jnp.int32),
@@ -296,12 +302,13 @@ class SlotArena:
                     arena_entry, new_entry.astype(arena_entry.dtype),
                     (slot,) + (0,) * (arena_entry.ndim - 1))
 
-            caches = [tuple(map(install_whole if rec or rng
+            caches = [None if none else
+                      tuple(map(install_whole if rec or rng
                                 else functools.partial(install, form, lat),
                                 old, new))
-                      for rec, rng, lat, form, old, new in zip(
-                          recurrent, ring, latent, forms, state["caches"],
-                          caches1)]
+                      for rec, rng, lat, none, form, old, new in zip(
+                          recurrent, ring, latent, stateless, forms,
+                          state["caches"], caches1)]
             ks = jax.random.split(key, self.geometry.image_seq_len)
             code0 = sample_one(first_logits[0], ks[0], temp)
 
@@ -460,14 +467,18 @@ class SlotArena:
         arena_form) / as ``[slots, kv heads, n, dim_head]``;
         ``ring_layers`` / ``latent_layers`` / ``recurrent_layers``:
         sliding-window rings / latent pairs (one latent and one rotated key
-        a position, no head axis) / recurrent entries; ``install_bytes_per_slot``: what an admission
+        a position, no head axis) / recurrent entries, and
+        ``stateless_layers`` (only where there are any) the layers that hold
+        nothing; ``install_bytes_per_slot``: what an admission
         writes of the caches, one slot's rows; ``tick_relayout_bytes``
         (:func:`relayout_bytes`): cache-sized ``copy`` / ``transpose``
         results in the COMPILED tick, compiled here for the backend the
         arena runs on."""
         forms = [form for form in self._forms if form is not None]
         rings = self.dalle.cfg.mixers.count("window")
-        latent = sum(map(is_latent, self.dalle.cfg.mixers))
+        mixers = self.dalle.cfg.mixers
+        latent = sum(map(is_latent, mixers))
+        stateless = sum(map(is_stateless, mixers))
         folded = sum(form.fold > 1 for form in forms)
         sizes = {cache_values(entry).size
                  for form, pair in zip(self._forms, self.state["caches"])
@@ -479,7 +490,8 @@ class SlotArena:
             "ring_layers": rings,
             # only where there are any: other models' records stay as they were
             **({"latent_layers": latent} if latent else {}),
-            "recurrent_layers": len(self._forms) - len(forms),
+            "recurrent_layers": sum(map(is_recurrent, mixers)),
+            **({"stateless_layers": stateless} if stateless else {}),
             "install_bytes_per_slot": sum(
                 leaf.nbytes for leaf in jax.tree.leaves(self.state["caches"])
             ) // self.geometry.num_slots,

@@ -705,3 +705,39 @@ def test_sharded_step_compiles_for_four_chips(topo, spec):
     mem = compiled.memory_analysis()
     assert (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             < V5E_HBM_BYTES)
+
+
+def test_the_mamba2_decode_step_compiles_at_published_widths(one_chip):
+    """One Mamba-2 layer's decode step at ``nemotron-3-nano-30b-a3b-
+    generate``'s shape (256 rows, 64 heads of 64 channels, the state ``[256,
+    64, 64, 128]`` float32, 2 MiB a row; 8 groups of 128): it compiles for
+    the v5e, the state's buffer is donated to the updated state, and the
+    step holds no copy of the state nor temporaries of its size (the update
+    and the read-out in one pass)."""
+    from dalle_pytorch_tpu.ops.ssm import Mamba2Mixer
+
+    rows, dim = 256, 2688
+    layer = Mamba2Mixer(dim=dim, heads=64, head_dim=64, groups=8, state=128,
+                        conv=4, chunk=128, dtype=jnp.bfloat16,
+                        param_dtype=jnp.bfloat16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                            jnp.zeros((1, 4, dim), jnp.bfloat16))
+    state = (rows, 64, 64, 128)
+    state_bytes = math.prod(state) * 4
+
+    def on(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda p, x, w, h: layer.apply(
+        p, x, w, h, method=Mamba2Mixer.decode_step),
+        donate_argnums=(2, 3)).lower(
+            _on(one_chip, params), on(rows, 1, dim, dtype=jnp.bfloat16),
+            on(rows, 3, 4096 + 2 * 8 * 128, dtype=jnp.bfloat16),
+            on(*state)).compile()
+    text = compiled.as_text()
+    shape = f"f32[{','.join(map(str, state))}]"
+    assert not [line for line in text.splitlines() if f"= {shape}" in line
+                and re.search(r" (copy|copy-start|transpose)\(", line)]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 4
